@@ -82,12 +82,8 @@ KNOWN_ENTRY_POINTS = {
     ("rs", "_reconstruct_jit"),
     ("rs", "_reconstruct_static_jit"),
     ("rs_pallas", "_matmul_words_jit"),
-    ("rs_pallas", "_mxu_matmul_jit"),
     ("rs_pallas", "encode_hash_fused"),
-    ("rs_pallas", "encode_pack_fused"),
     ("rs_pallas", "verify_reconstruct_fused"),
-    ("rs_pallas", "encode_pack_pipelined"),
-    ("rs_pallas", "verify_reconstruct_pipelined"),
     ("codec_step", "encode_and_hash_words"),
     ("codec_step", "encode_and_hash_words_digest"),
     ("codec_step", "encode_words_fused1"),
@@ -97,6 +93,7 @@ KNOWN_ENTRY_POINTS = {
     ("codec_step", "group_flags"),
     ("codec_step", "pack_nonzero_groups"),
     ("codec_step", "verify_hashes_words"),
+    ("codec_step", "digest_words"),
     ("codec_step", "reconstruct_words_batch"),
     ("codec_step", "encode_throughput_probe"),
     ("codec_step", "reconstruct_throughput_probe"),
@@ -143,7 +140,6 @@ DRAIN_SEAMS = {
         "encode_digest_end",
         "drain",
         "_drain_d2h",
-        "_drain_precomputed",
         # GET side: decode IS the sanctioned D2H — reconstructed rows
         # leave the device here and nowhere else
         "reconstruct",
@@ -151,9 +147,8 @@ DRAIN_SEAMS = {
         "verify",
         "digest",
         # sub-chunk overlap pipeline (MINIO_TPU_CODEC_OVERLAP=async):
-        # the chunked parity-plane drain and the GET-side chain that
-        # drains chunk s D2H while chunk s+1 computes
-        "_drain_chunks",
+        # the GET-side chain that drains chunk s D2H while chunk s+1
+        # computes
         "_drain_vr_subchunks",
     ),
     "minio_tpu/s3select/device.py": (
@@ -457,6 +452,20 @@ def run() -> "list[Finding]":
         except Exception as e:
             c.fail(e)
 
+    covers("codec_step", "digest_words")
+    c = ctx(codec_step.digest_words, "minio_tpu/ops/codec_step.py")
+    for k, m, L in CONFIG_GRID:
+        w, n = L // 4, k + m
+        c.config = cfg_str(k, m, L)
+        try:
+            got = codec_step.digest_words.eval_shape(
+                S((_BATCH, n, w), u32), L
+            )
+            c.shape(got, (_BATCH, n, 8), "digests")
+            c.dtype(got, "uint32", "digests")
+        except Exception as e:
+            c.fail(e)
+
     covers("codec_step", "reconstruct_words_batch")
     c = ctx(codec_step.reconstruct_words_batch, "minio_tpu/ops/codec_step.py")
     for k, m, L in CONFIG_GRID:
@@ -526,44 +535,34 @@ def run() -> "list[Finding]":
 
     # ---- codec_step.py: one-kernel codec (fused1) -----------------------
     #
-    # The fused1 entries subsume three legacy passes (encode+digest,
-    # group_flags, pack_nonzero_groups) resp. two (verify, reconstruct).
-    # Portable formulation is checked over CONFIG_GRID; the Pallas path
-    # over FUSED_GRID in interpret mode, both formulations, so contract
-    # coverage matches everything the dispatcher can launch.
+    # The fused1 entries run encode+digest resp. verify+reconstruct as
+    # one pass.  The XLA formulation is checked over CONFIG_GRID; the
+    # Pallas path over FUSED_GRID in interpret mode, both formulations,
+    # so contract coverage matches everything the dispatcher can launch.
 
     covers("codec_step", "encode_words_fused1")
     c = ctx(codec_step.encode_words_fused1, "minio_tpu/ops/codec_step.py")
     for k, m, L in CONFIG_GRID:
         w, n = L // 4, k + m
-        for group in (0, _GROUP):
-            g = w // group if group else 0
-            c.config = cfg_str(k, m, L) + f" [portable, group={group}]"
-            try:
-                parity, digests, flags, packed = (
-                    codec_step.encode_words_fused1.eval_shape(
-                        S((_BATCH, k, w), u32), m, L, group
-                    )
-                )
-                c.shape(parity, (_BATCH, m, w), "fused1 parity")
-                c.dtype(parity, "uint32", "fused1 parity")
-                c.shape(digests, (_BATCH, n, 8), "fused1 digests")
-                c.dtype(digests, "uint32", "fused1 digests")
-                c.shape(flags, (_BATCH, m, g), "fused1 flags")
-                c.dtype(flags, "bool", "fused1 flags")
-                c.shape(packed, (_BATCH, m, w), "fused1 packed")
-                c.dtype(packed, "uint32", "fused1 packed")
-            except Exception as e:
-                c.fail(e)
+        c.config = cfg_str(k, m, L) + " [portable]"
+        try:
+            parity, digests = codec_step.encode_words_fused1.eval_shape(
+                S((_BATCH, k, w), u32), m, L
+            )
+            c.shape(parity, (_BATCH, m, w), "fused1 parity")
+            c.dtype(parity, "uint32", "fused1 parity")
+            c.shape(digests, (_BATCH, n, 8), "fused1 digests")
+            c.dtype(digests, "uint32", "fused1 digests")
+        except Exception as e:
+            c.fail(e)
     for k, m, L in FUSED_GRID:
         w, n = L // 4, k + m
-        group = 256  # compress.PARITY_GROUP_WORDS, the production granule
         for formulation in ("swar", "mxu"):
             c.config = cfg_str(k, m, L) + f" [pallas, {formulation}]"
             try:
-                parity, digests, flags, packed = (
+                parity, digests = (
                     codec_step.encode_words_fused1.eval_shape(
-                        S((_BATCH, k, w), u32), m, L, group,
+                        S((_BATCH, k, w), u32), m, L,
                         formulation, True, True,
                     )
                 )
@@ -571,10 +570,6 @@ def run() -> "list[Finding]":
                 c.dtype(parity, "uint32", "fused1 parity")
                 c.shape(digests, (_BATCH, n, 8), "fused1 digests")
                 c.dtype(digests, "uint32", "fused1 digests")
-                c.shape(flags, (_BATCH, m, w // group), "fused1 flags")
-                c.dtype(flags, "bool", "fused1 flags")
-                c.shape(packed, (_BATCH, m, w), "fused1 packed")
-                c.dtype(packed, "uint32", "fused1 packed")
             except Exception as e:
                 c.fail(e)
 
@@ -597,10 +592,8 @@ def run() -> "list[Finding]":
             c.shape(ok, (_BATCH, n), "fused GET ok mask")
             c.dtype(ok, "bool", "fused GET ok mask")
             # MTPU203: fused1 encode -> fused1 verify+reconstruct closes
-            parity, digests, _, _ = (
-                codec_step.encode_words_fused1.eval_shape(
-                    S((_BATCH, k, w), u32), m, L, 0
-                )
+            parity, digests = codec_step.encode_words_fused1.eval_shape(
+                S((_BATCH, k, w), u32), m, L
             )
             rt, _ = codec_step.verify_and_reconstruct_words.eval_shape(
                 S((_BATCH, k + parity.shape[1], w), parity.dtype),
@@ -649,34 +642,23 @@ def run() -> "list[Finding]":
     for k, m, L in CONFIG_GRID:
         w, n = L // 4, k + m
         for cw in (w, w // 2 if w // 2 % 8 == 0 else w, 8):
-            for group in (0, _GROUP):
-                if group and cw % group:
-                    continue
-                gc = cw // group if group else 0
-                for fin in (False, True):
-                    c.config = (
-                        cfg_str(k, m, L)
-                        + f" [cw={cw}, group={group}, finalize={fin}]"
-                    )
-                    try:
-                        parity, acc, flags, packed = (
-                            codec_step.encode_subchunk_words.eval_shape(
-                                S((_BATCH, k, cw), u32),
-                                S((_BATCH, n, 8), u32),
-                                S((), u32),
-                                m, L, group, fin,
-                            )
+            for fin in (False, True):
+                c.config = cfg_str(k, m, L) + f" [cw={cw}, finalize={fin}]"
+                try:
+                    parity, acc = (
+                        codec_step.encode_subchunk_words.eval_shape(
+                            S((_BATCH, k, cw), u32),
+                            S((_BATCH, n, 8), u32),
+                            S((), u32),
+                            m, L, fin,
                         )
-                        c.shape(parity, (_BATCH, m, cw), "chunk parity")
-                        c.dtype(parity, "uint32", "chunk parity")
-                        c.shape(acc, (_BATCH, n, 8), "chunk partials")
-                        c.dtype(acc, "uint32", "chunk partials")
-                        c.shape(flags, (_BATCH, m, gc), "chunk flags")
-                        c.dtype(flags, "bool", "chunk flags")
-                        c.shape(packed, (_BATCH, m, cw), "chunk packed")
-                        c.dtype(packed, "uint32", "chunk packed")
-                    except Exception as e:
-                        c.fail(e)
+                    )
+                    c.shape(parity, (_BATCH, m, cw), "chunk parity")
+                    c.dtype(parity, "uint32", "chunk parity")
+                    c.shape(acc, (_BATCH, n, 8), "chunk partials")
+                    c.dtype(acc, "uint32", "chunk partials")
+                except Exception as e:
+                    c.fail(e)
 
     covers("codec_step", "verify_reconstruct_subchunk_words")
     c = ctx(
@@ -716,7 +698,7 @@ def run() -> "list[Finding]":
     # of the jit cache key).  The plane grid is tiny — shapes close over
     # N the same way at 64 MiB as at 4 KiB.
 
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     from minio_tpu.ops import select_step
 
@@ -867,59 +849,18 @@ def run() -> "list[Finding]":
     c = ctx(rs_pallas.encode_hash_fused, "minio_tpu/ops/rs_pallas.py")
     for k, m, L in FUSED_GRID:
         w, n = L // 4, k + m
-        c.config = cfg_str(k, m, L)
-        try:
-            parity, hacc = rs_pallas.encode_hash_fused.eval_shape(
-                S((_BATCH, k, w), u32), m, True
-            )
-            c.shape(parity, (_BATCH, m, w), "fused parity")
-            c.dtype(parity, "uint32", "fused parity")
-            c.shape(hacc, (_BATCH, n, 8), "fused hash partials")
-            c.dtype(hacc, "uint32", "fused hash partials")
-        except Exception as e:
-            c.fail(e)
-
-    covers("rs_pallas", "_mxu_matmul_jit")
-    c = ctx(rs_pallas._mxu_matmul_jit, "minio_tpu/ops/rs_pallas.py")
-    for k, m, L in CONFIG_GRID:
-        key = gf.parity_matrix(k, m).tobytes()
-        c.config = cfg_str(k, m, L)
-        try:
-            out = rs_pallas._mxu_matmul_jit.eval_shape(
-                S((k, L), u8), key, m, k, True
-            )
-            c.shape(out, (m, L), "mxu parity bytes")
-            c.dtype(out, "uint8", "mxu parity bytes")
-        except Exception as e:
-            c.fail(e)
-
-    covers("rs_pallas", "encode_pack_fused")
-    c = ctx(rs_pallas.encode_pack_fused, "minio_tpu/ops/rs_pallas.py")
-    for k, m, L in FUSED_GRID:
-        w, n = L // 4, k + m
-        for group in (0, 256):
-            g = w // group if group else 0
-            for formulation in ("swar", "mxu"):
-                c.config = (
-                    cfg_str(k, m, L) + f" [group={group}, {formulation}]"
+        for formulation in ("swar", "mxu"):
+            c.config = cfg_str(k, m, L) + f" [{formulation}]"
+            try:
+                parity, hacc = rs_pallas.encode_hash_fused.eval_shape(
+                    S((_BATCH, k, w), u32), m, formulation, True
                 )
-                try:
-                    parity, hacc, flags, packed = (
-                        rs_pallas.encode_pack_fused.eval_shape(
-                            S((_BATCH, k, w), u32), m, group,
-                            formulation, True,
-                        )
-                    )
-                    c.shape(parity, (_BATCH, m, w), "fused1 parity")
-                    c.dtype(parity, "uint32", "fused1 parity")
-                    c.shape(hacc, (_BATCH, n, 8), "fused1 hash partials")
-                    c.dtype(hacc, "uint32", "fused1 hash partials")
-                    c.shape(flags, (_BATCH, m, g), "fused1 flag words")
-                    c.dtype(flags, "uint32", "fused1 flag words")
-                    c.shape(packed, (_BATCH, m, w), "fused1 packed")
-                    c.dtype(packed, "uint32", "fused1 packed")
-                except Exception as e:
-                    c.fail(e)
+                c.shape(parity, (_BATCH, m, w), "fused parity")
+                c.dtype(parity, "uint32", "fused parity")
+                c.shape(hacc, (_BATCH, n, 8), "fused hash partials")
+                c.dtype(hacc, "uint32", "fused hash partials")
+            except Exception as e:
+                c.fail(e)
 
     covers("rs_pallas", "verify_reconstruct_fused")
     c = ctx(rs_pallas.verify_reconstruct_fused, "minio_tpu/ops/rs_pallas.py")
@@ -943,70 +884,12 @@ def run() -> "list[Finding]":
             except Exception as e:
                 c.fail(e)
 
-    # ---- rs_pallas.py: manual-DMA pipelined twins -----------------------
-    #
-    # MINIO_TPU_CODEC_OVERLAP=pipeline swaps these in for the fused
-    # kernels above — identical output contracts by construction (the
-    # runtime bit-identity tests assert values; here shapes/dtypes),
-    # checked over both formulations like their serialized twins.
-
-    covers("rs_pallas", "encode_pack_pipelined")
-    c = ctx(rs_pallas.encode_pack_pipelined, "minio_tpu/ops/rs_pallas.py")
-    for k, m, L in FUSED_GRID:
-        w, n = L // 4, k + m
-        for group in (0, 256):
-            g = w // group if group else 0
-            for formulation in ("swar", "mxu"):
-                c.config = (
-                    cfg_str(k, m, L) + f" [group={group}, {formulation}]"
-                )
-                try:
-                    parity, hacc, flags, packed = (
-                        rs_pallas.encode_pack_pipelined.eval_shape(
-                            S((_BATCH, k, w), u32), m, group,
-                            formulation, True,
-                        )
-                    )
-                    c.shape(parity, (_BATCH, m, w), "pipelined parity")
-                    c.dtype(parity, "uint32", "pipelined parity")
-                    c.shape(hacc, (_BATCH, n, 8), "pipelined partials")
-                    c.dtype(hacc, "uint32", "pipelined partials")
-                    c.shape(flags, (_BATCH, m, g), "pipelined flag words")
-                    c.dtype(flags, "uint32", "pipelined flag words")
-                    c.shape(packed, (_BATCH, m, w), "pipelined packed")
-                    c.dtype(packed, "uint32", "pipelined packed")
-                except Exception as e:
-                    c.fail(e)
-
-    covers("rs_pallas", "verify_reconstruct_pipelined")
-    c = ctx(
-        rs_pallas.verify_reconstruct_pipelined, "minio_tpu/ops/rs_pallas.py"
-    )
-    for k, m, L in FUSED_GRID:
-        w, n = L // 4, k + m
-        idx = tuple(range(m, n))[:k]
-        for formulation in ("swar", "mxu"):
-            c.config = cfg_str(k, m, L) + f" [{formulation}]"
-            try:
-                data, hacc = (
-                    rs_pallas.verify_reconstruct_pipelined.eval_shape(
-                        S((_BATCH, n, w), u32), idx, k, m,
-                        formulation, True,
-                    )
-                )
-                c.shape(data, (_BATCH, k, w), "pipelined GET data words")
-                c.dtype(data, "uint32", "pipelined GET data words")
-                c.shape(hacc, (_BATCH, n, 8), "pipelined GET partials")
-                c.dtype(hacc, "uint32", "pipelined GET partials")
-            except Exception as e:
-                c.fail(e)
-
     # ---- parallel/mesh.py: compile-seam mesh kernels --------------------
     #
     # Mesh kernels are not module-level jitted attrs: they are built per
     # geometry through the rules.py compile seam.  Contracts abstract-
-    # eval each registered kind through BOTH lowerings (jit+NamedSharding
-    # and shard_map) on a 1-device probe mesh — geometry-independent
+    # eval each registered kind through every lowering it registers
+    # (jit+NamedSharding, shard_map) on a 1-device probe mesh — geometry-independent
     # shape/dtype truth that holds on any host, mirroring how the ops/
     # kernels are checked without an accelerator.
 

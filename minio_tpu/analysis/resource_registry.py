@@ -158,7 +158,6 @@ _DEFAULT_RESOURCES: "tuple[ResourceClass, ...]" = (
         acquire_calls=(
             "_EagerParityRef",
             "_DeviceParityRef",
-            "_SubchunkParityRef",
         ),
         release_methods=("release", "drain"),
         handle=True,
@@ -166,7 +165,6 @@ _DEFAULT_RESOURCES: "tuple[ResourceClass, ...]" = (
             ("minio_tpu/codec/backend.py", "_EagerParityRef.release"),
             ("minio_tpu/codec/backend.py", "_DeviceParityRef.release"),
             ("minio_tpu/codec/backend.py", "_DeviceParityRef.drain"),
-            ("minio_tpu/codec/backend.py", "_SubchunkParityRef.drain"),
         ),
     ),
     # IO-pool futures: a granted slot's future must be waited,

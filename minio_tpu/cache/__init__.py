@@ -72,13 +72,13 @@ def cache_mode() -> str:
     if raw in ("off", "host", "device"):
         return raw
     if raw == "auto":
-        try:
-            import jax
+        import jax
 
-            if any(d.platform != "cpu" for d in jax.devices()):
-                return "device"
-        except Exception as exc:  # noqa: BLE001 - no jax, no device tier
-            _log.debug("auto mode: no device tier: %s", exc)
+        # "no device tier" means JAX reports only host devices; a
+        # jax.devices() that raises (chip missing or held elsewhere) is
+        # not that, and propagates
+        if any(d.platform != "cpu" for d in jax.devices()):
+            return "device"
         return "host"
     return "off"
 

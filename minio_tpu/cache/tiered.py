@@ -33,8 +33,11 @@ from collections import OrderedDict
 
 import numpy as np
 
+from ..utils.log import kv, logger
 from .admission import AdmissionFilter
 from .allocator import DeviceBudget
+
+_log = logger("cache.tiered")
 
 TIER_DEVICE = "device"
 TIER_HOST = "host"
@@ -44,13 +47,19 @@ BUDGET_ACCOUNT = "read_cache"
 
 
 def _to_device(arr: np.ndarray):
-    """Pin an array in device memory; None when no device path exists
-    (jax absent/broken) so the caller can fall back to the host tier."""
-    try:
-        import jax
+    """Pin an array in device memory; None when the device runtime
+    refuses the allocation (out of device memory) - the one case in
+    which this entry belongs in the host tier instead.  Anything else
+    (no device, a bad array) is a bug and propagates."""
+    import jax
 
+    try:
         return jax.device_put(arr)
-    except Exception:  # noqa: BLE001 - host tier is the fallback
+    except jax.errors.JaxRuntimeError as exc:
+        _log.warning(
+            "read cache: device tier refused an entry; host tier takes it",
+            extra=kv(nbytes=int(arr.nbytes), err=str(exc)),
+        )
         return None
 
 

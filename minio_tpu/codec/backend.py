@@ -9,7 +9,7 @@ is codec-agnostic.  This module is that seam for the new framework:
 * TpuBackend: batched fused Pallas/JAX device passes (ops/codec_step).
 * CpuBackend: native C++ AVX2 nibble-shuffle codec (native/csrc/gf_cpu.cc)
   + vectorized numpy phash256 - the klauspost/reedsolomon-equivalent host
-  path, also the fallback when no accelerator is present.
+  path; ``auto`` picks it where JAX reports no accelerator, and says so.
 
 Both produce byte-identical parity and digests; shard files written by one
 backend verify and decode under the other.
@@ -40,14 +40,15 @@ def _record_d2h(plane: str, nbytes: int) -> None:
     KERNEL_STATS.record_d2h(plane, int(nbytes))
 
 
-def _record_pass(kernel: str) -> None:
+def _record_pass(kernel: str, pallas: bool = False) -> None:
     """Account one device-program launch (jitted codec pass) by entry
-    point name.  The fused1 acceptance gate reads these counters: the
-    legacy PUT seam launches three passes per batch (digest encode,
-    group_flags, pack_nonzero_groups) and fused1 exactly one."""
+    point name.  ``pallas`` says the launch ran a Mosaic-compiled (or,
+    under MINIO_TPU_CODEC_INTERPRET, interpreted) Pallas kernel rather
+    than the XLA formulation of the same math - counted apart so a
+    tile-aligned batch that silently left the kernel shows up."""
     from .telemetry import KERNEL_STATS
 
-    KERNEL_STATS.record_pass(kernel)
+    KERNEL_STATS.record_pass(kernel, pallas)
 
 
 def _record_h2d(plane: str, nbytes: int) -> None:
@@ -59,10 +60,10 @@ def _record_h2d(plane: str, nbytes: int) -> None:
 
 
 def _record_overlap(plane: str, windows: int) -> None:
-    """Account completed overlap windows (plane = put|get): iterations
-    where a transfer provably ran concurrently with compute — the
-    snapshot-level evidence the MINIO_TPU_CODEC_OVERLAP pipeline
-    engaged (bench --codec-micro gates on this being > 0)."""
+    """Account completed overlap windows (plane = put|get): sub-chunks
+    whose transfer was dispatched while a neighbor's pass was in
+    flight — the snapshot-level evidence the
+    MINIO_TPU_CODEC_OVERLAP=async pipeline engaged."""
     from .telemetry import KERNEL_STATS
 
     KERNEL_STATS.record_overlap_windows(plane, int(windows))
@@ -204,74 +205,47 @@ class _EagerParityRef:
 
 
 class _DeviceParityRef:
-    """One batch's device-resident parity plane ((B, m, w) u32 words).
+    """One batch's device-resident parity plane: (B, m, w) u32 words,
+    held whole or as the S sub-chunk arrays the async overlap pipeline
+    cut along the stripe-length axis (MINIO_TPU_CODEC_OVERLAP=async).
 
     ``drain()`` is the single D2H seam: thread-safe and memoized, so
     the m per-disk parity writers sharing this ref pay one transfer —
-    and when the transport screen finds the plane sparse, the packed
-    prefix (ops/codec_step.pack_nonzero_groups), not the raw plane,
-    crosses the bus.  Registered with the ParityPlaneCache until
-    drained or released.
-
-    Under the fused1 kernel the occupancy ``flags`` and the prefix
-    ``packed`` plane are produced by the SAME pallas_call as the parity
-    itself (ops/rs_pallas.encode_pack_fused), so the ref carries them
-    and the drain launches ZERO further device passes — it only picks
-    which precomputed plane crosses the bus.  The legacy ref (no
-    precomputed planes) launches group_flags + pack_nonzero_groups at
-    drain time as before.
+    and when MINIO_TPU_DEVICE_COMPRESS screens a plane and finds it
+    sparse, the packed prefix (ops/codec_step.pack_nonzero_groups), not
+    the raw plane, crosses the bus.  Registered with the
+    ParityPlaneCache, which accounts every live chunk, until drained
+    or released.
     """
 
-    __slots__ = (
-        "_lk",
-        "_cache",
-        "_parity_w",
-        "_flags",
-        "_packed",
-        "_group",
-        "_host",
-        "nbytes",
-    )
+    __slots__ = ("_lk", "_cache", "_planes", "_host", "nbytes")
 
-    def __init__(
-        self,
-        cache: ParityPlaneCache,
-        parity_w,
-        flags=None,
-        packed=None,
-        group: int = 0,
-    ):
+    def __init__(self, cache: ParityPlaneCache, planes):
         self._lk = threading.Lock()
         self._cache = cache
-        self._parity_w = parity_w
-        self._flags = flags
-        self._packed = packed
-        self._group = int(group)
+        self._planes = list(planes)
         self._host: "np.ndarray | None" = None
-        plane = int(
-            parity_w.shape[0] * parity_w.shape[1] * parity_w.shape[2] * 4
+        self.nbytes = sum(
+            int(p.shape[0]) * int(p.shape[1]) * int(p.shape[2]) * 4
+            for p in self._planes
         )
-        # the packed twin is a second device-resident plane of the same
-        # size: account it honestly against the write-back budget
-        self.nbytes = plane * (2 if packed is not None else 1)
         cache.add(self)
 
     def drain(self) -> np.ndarray:
         """(B, m, L) uint8 parity bytes, materialized at most once."""
         with self._lk:
-            if self._host is None and self._parity_w is not None:
-                if self._packed is not None:
-                    self._host = self._drain_precomputed(
-                        self._parity_w,
-                        self._flags,
-                        self._packed,
-                        self._group,
-                    )
-                else:
-                    self._host = self._drain_d2h(self._parity_w)
-                self._parity_w = None
-                self._flags = None
-                self._packed = None
+            if self._host is None and self._planes is not None:
+                # per-chunk D2H: reading chunk s overlaps the device
+                # work still in flight behind chunks s+1..; each chunk
+                # is screened on its own, so a sparse chunk of an
+                # otherwise dense plane still crosses the bus packed
+                parts = [self._drain_d2h(p) for p in self._planes]
+                self._host = (
+                    parts[0]
+                    if len(parts) == 1
+                    else np.concatenate(parts, axis=-1)
+                )
+                self._planes = None
                 self._cache.forget(self)
             return self._host
 
@@ -279,10 +253,8 @@ class _DeviceParityRef:
         """Drop an unused plane without the transfer (error-path
         cleanup of handles whose writers were never scheduled)."""
         with self._lk:
-            if self._parity_w is not None:
-                self._parity_w = None
-                self._flags = None
-                self._packed = None
+            if self._planes is not None:
+                self._planes = None
                 self._cache.forget(self)
 
     @staticmethod
@@ -320,129 +292,6 @@ class _DeviceParityRef:
         parity = np.asarray(parity_w)
         _record_d2h("parity", parity.nbytes)
         return codec_step.host_words_to_bytes(parity)
-
-    @staticmethod
-    def _drain_precomputed(parity_w, flags_d, packed_d, group) -> np.ndarray:
-        """fused1 drain: occupancy screen + pack came out of the encode
-        pallas_call itself, so no device pass launches here — only the
-        chosen plane's D2H (flags are a few bytes per row)."""
-        from ..ops import codec_step
-        from . import compress as compmod
-
-        mode = compmod.device_compress_mode()
-        w = int(parity_w.shape[-1])
-        g = w // group
-        flags = np.asarray(flags_d)  # (B, m, g) bool, tiny
-        if mode != "off":
-            kept = int(flags.sum(axis=-1).max()) if flags.size else 0
-            if kept == 0:
-                _record_d2h("parity", flags.nbytes)
-                return np.zeros(
-                    parity_w.shape[:-1] + (w * 4,), dtype=np.uint8
-                )
-            if (
-                mode == "on"
-                or kept / g <= compmod.parity_fill_threshold()
-            ):
-                keep = compmod.prefix_keep(kept, g)
-                prefix = np.asarray(packed_d[..., : keep * group])
-                _record_d2h("parity", flags.nbytes + prefix.nbytes)
-                words = compmod.unpack_nonzero_groups(
-                    flags, prefix, group, w
-                )
-                return codec_step.host_words_to_bytes(words)
-        parity = np.asarray(parity_w)
-        _record_d2h("parity", parity.nbytes)
-        return codec_step.host_words_to_bytes(parity)
-
-
-class _SubchunkParityRef:
-    """One batch's device-resident parity plane held as the S sub-chunk
-    arrays the async overlap pipeline produced (splits along the
-    stripe-length axis, MINIO_TPU_CODEC_OVERLAP=async).
-
-    Same contract as _DeviceParityRef: ``drain()`` is the single
-    memoized D2H seam shared by the m parity writers, ``release()``
-    drops the plane without the transfer, and the ParityPlaneCache
-    accounts every live device plane — parity AND the packed twin when
-    the pack leg ran — so write-back pressure stays honest about the
-    doubled footprint.
-    """
-
-    __slots__ = (
-        "_lk",
-        "_cache",
-        "_parity",
-        "_flags",
-        "_packed",
-        "_group",
-        "_host",
-        "nbytes",
-    )
-
-    def __init__(
-        self,
-        cache: ParityPlaneCache,
-        parity_chunks,
-        flags=None,
-        packed=None,
-        group: int = 0,
-    ):
-        self._lk = threading.Lock()
-        self._cache = cache
-        self._parity = list(parity_chunks)
-        self._flags = list(flags) if flags else None
-        self._packed = list(packed) if packed else None
-        self._group = int(group)
-        self._host: "np.ndarray | None" = None
-        plane = sum(
-            int(p.shape[0]) * int(p.shape[1]) * int(p.shape[2]) * 4
-            for p in self._parity
-        )
-        self.nbytes = plane * (2 if self._packed is not None else 1)
-        cache.add(self)
-
-    def drain(self) -> np.ndarray:
-        """(B, m, L) uint8 parity bytes, materialized at most once."""
-        with self._lk:
-            if self._host is None and self._parity is not None:
-                self._host = self._drain_chunks()
-                self._parity = None
-                self._flags = None
-                self._packed = None
-                self._cache.forget(self)
-            return self._host
-
-    def release(self) -> None:
-        """Drop an undrained plane without the transfer."""
-        with self._lk:
-            if self._parity is not None:
-                self._parity = None
-                self._flags = None
-                self._packed = None
-                self._cache.forget(self)
-
-    def _drain_chunks(self) -> np.ndarray:
-        """Per-chunk D2H, concatenated along the length axis.
-
-        Each chunk reuses the fused1 drain bodies — the occupancy
-        screen picks the packed prefix or the raw plane per chunk, so
-        a sparse chunk of an otherwise dense plane still crosses the
-        bus compressed.  Chunk reads are independent async device
-        values: reading chunk s overlaps the device-side screen of
-        chunk s+1.
-        """
-        parts = [
-            (
-                _DeviceParityRef._drain_precomputed(
-                    p, self._flags[i], self._packed[i], self._group
-                )
-                if self._packed is not None
-                else _DeviceParityRef._drain_d2h(p)
-            )
-            for i, p in enumerate(self._parity)
-        ]
-        return np.concatenate(parts, axis=-1)
 
 
 _PARITY_CACHE: "ParityPlaneCache | None" = None
@@ -647,12 +496,14 @@ class CodecBackend:
 
 
 class TpuBackend(CodecBackend):
-    """Device backend: single-chip fused passes, mesh-parallel when the
-    process sees >1 device (the driver's virtual CPU mesh or a real pod
-    slice).  The mesh path shards stripes over "stripe" and the k data
-    shards over "shard" with an XOR all-reduce (parallel.mesh), mirroring
-    the reference's set- and disk-level fan-out (SURVEY.md section 2.4).
-    Set MINIO_MESH=0 to force the single-device path.
+    """Device backend over the devices JAX reports (device_info() names
+    the platform): fused single-device passes on the device the batcher
+    routed this thread's batch to, mesh-parallel when a batch spans >1
+    device (a real slice, or the tests' virtual CPU mesh).  The mesh
+    path shards stripes over "stripe" and the k data shards over
+    "shard" with an XOR all-reduce (parallel.mesh), mirroring the
+    reference's set- and disk-level fan-out (SURVEY.md section 2.4).
+    Set MINIO_MESH=0 to pin every pass to one device.
     """
 
     name = "tpu"
@@ -697,10 +548,22 @@ class TpuBackend(CodecBackend):
             self._meshes[key] = m
         return m
 
+    def _to_device(self, host: np.ndarray):
+        """Stage a host array on THE device this thread's single-device
+        pass runs on: the submesh the batcher routed the batch to, else
+        the first of the base set.  Never the process default - on a
+        multi-chip host that would land every routed batch on chip 0."""
+        import jax
+
+        from ..parallel import rules as prules
+
+        device = (prules.current_placement() or self._base_devices())[0]
+        return jax.device_put(host, device)
+
     def placement_router(self):
         devices = self._base_devices()
-        if len(devices) <= 1:
-            return None
+        if len(devices) <= 1 or os.environ.get("MINIO_MESH", "1") == "0":
+            return None  # one device, or pinned to one: nothing to route
         with self._router_mu:
             if self._router is None:
                 from ..parallel import rules as prules
@@ -715,12 +578,11 @@ class TpuBackend(CodecBackend):
         """Asynchronous start: JAX dispatch is async, so the returned
         device arrays are futures - the H2D copy and the fused pass
         run while the caller streams the PREVIOUS batch to disk."""
-        import jax.numpy as jnp
-
         from ..ops import codec_step
 
         data = np.ascontiguousarray(data, dtype=np.uint8)
         B, k, L = data.shape
+        compiled = parity_shards > 0 and codec_step.pallas_compiled(L // 4)
         mesh = self._mesh_for(B, k)
         if mesh is not None:
             # shard_map dispatch is as async as plain jit: the mesh
@@ -732,13 +594,19 @@ class TpuBackend(CodecBackend):
                 mesh, codec_step.host_bytes_to_words(data),
                 parity_shards, L,
             )
-            _record_pass("mesh_encode_hash")
+            _record_h2d("data", data.nbytes)
+            # k-sharded meshes run the dynamic XLA bit-walk + all-reduce
+            _record_pass(
+                "mesh_encode_hash",
+                pallas=compiled and mesh.shape["shard"] == 1,
+            )
             return _AsyncHandle("async-mesh", h)
-        words = jnp.asarray(codec_step.host_bytes_to_words(data))
+        words = self._to_device(codec_step.host_bytes_to_words(data))
+        _record_h2d("data", words.nbytes)
         parity_w, digests = codec_step.encode_and_hash_words(
             words, parity_shards, L
         )
-        _record_pass("encode_and_hash_words")
+        _record_pass("encode_and_hash_words", pallas=compiled)
         return _AsyncHandle("async", (parity_w, digests))
 
     def encode_end(self, handle):
@@ -752,22 +620,17 @@ class TpuBackend(CodecBackend):
             from ..parallel import mesh as pm
 
             parity_w, digests = pm.mesh_encode_hash_end(handle.payload)
-            parity_w = np.asarray(parity_w)
-            digests = np.asarray(digests)
-            _record_d2h("parity", parity_w.nbytes)
-            _record_d2h("data", digests.nbytes)
-            result = codec_step.host_words_to_bytes(parity_w), digests
         elif handle.kind == "async":
             parity_w, digests = handle.payload
-            parity_w = np.asarray(parity_w)
-            digests = np.asarray(digests)
-            _record_d2h("parity", parity_w.nbytes)
-            _record_d2h("data", digests.nbytes)
-            result = codec_step.host_words_to_bytes(parity_w), digests
         else:
             raise ValueError(
                 f"encode_end: unknown handle kind {handle.kind!r}"
             )
+        parity_w = np.asarray(parity_w)
+        digests = np.asarray(digests)
+        _record_d2h("parity", parity_w.nbytes)
+        _record_d2h("data", digests.nbytes)
+        result = codec_step.host_words_to_bytes(parity_w), digests
         handle.result = result
         handle.consumed = True
         handle.payload = None  # drop the device refs
@@ -776,14 +639,8 @@ class TpuBackend(CodecBackend):
     def encode_digest_begin(self, data, parity_shards):
         """Digest-only start: the fused donated kernel keeps parity on
         device; only the 32-byte digests are scheduled for readback.
-
-        Under MINIO_TPU_CODEC_KERNEL=fused1 (default) the single pass
-        additionally emits the occupancy flags and the nonzero-group
-        prefix pack, so the eventual drain launches nothing; ``legacy``
-        keeps the three-pass structure as the bisection oracle.
-        """
-        import jax.numpy as jnp
-
+        MINIO_TPU_CODEC_KERNEL picks the jitted entry (``legacy`` is the
+        bisection oracle); both park the plane behind a ParityRef."""
         from ..ops import codec_step
 
         data = np.ascontiguousarray(data, dtype=np.uint8)
@@ -791,8 +648,8 @@ class TpuBackend(CodecBackend):
         if self._mesh_for(B, k) is not None:
             if codec_step.codec_overlap_mode() != "off":
                 # overlap sub-chunking would fight the mesh "seq" axis
-                # for the stripe-length dimension: warn once, fall back
-                # to the serialized (bit-identical) mesh path
+                # for the stripe-length dimension: warn once, take the
+                # serialized (bit-identical) mesh path
                 from ..parallel import mesh as pm
 
                 pm.warn_overlap_fallback()
@@ -803,77 +660,41 @@ class TpuBackend(CodecBackend):
                 "digest-eager", self.encode_begin(data, parity_shards)
             )
         words_h = codec_step.host_bytes_to_words(data)
-        if codec_step.codec_kernel_mode() == "fused1":
-            from . import compress as compmod
-
-            w = L // 4
-            G = compmod.PARITY_GROUP_WORDS
-            group = (
-                G
-                if (
-                    compmod.device_compress_mode() != "off"
-                    and w % G == 0
-                    and w // G >= 2
-                )
-                else 0
-            )
-            use_pallas, interpret = codec_step.pallas_dispatch(w)
-            overlap = codec_step.codec_overlap_mode()
-            if overlap == "async":
-                handle = self._encode_subchunk_begin(
-                    words_h, parity_shards, L, group
-                )
-                if handle is not None:
-                    return handle
-                # batch too small for S >= 3 sub-chunks: serialized path
-            words = jnp.asarray(words_h)
+        if codec_step.codec_kernel_mode() != "fused1":
+            words = self._to_device(words_h)
             _record_h2d("data", words.nbytes)
-            # pipeline mode rides the SAME entry point and pallas_call;
-            # the static only swaps in the manual-DMA kernel body
-            pipeline = overlap == "pipeline" and use_pallas
-            parity_w, digests, flags_d, packed_d = (
-                codec_step.encode_words_fused1(
-                    words,
-                    parity_shards,
-                    L,
-                    group=group,
-                    formulation=codec_step.codec_formulation(),
-                    use_pallas=use_pallas,
-                    interpret=interpret,
-                    pipeline=pipeline,
-                )
+            parity_w, digests = codec_step.encode_and_hash_words_digest(
+                words, parity_shards, L
             )
-            _record_pass("encode_words_fused1")
-            if pipeline:
-                from ..ops import rs_pallas
-
-                nt = w // rs_pallas._TW
-                if nt > 1:
-                    # one window per in-kernel tile step whose prefetch
-                    # DMA overlapped the previous tile's compute
-                    _record_overlap("put", B * (nt - 1))
-            return _AsyncHandle(
-                "digest-fused1",
-                (
-                    parity_w,
-                    digests,
-                    flags_d if group else None,
-                    packed_d if group else None,
-                    group,
-                ),
+            _record_pass(
+                "encode_and_hash_words_digest",
+                pallas=parity_shards > 0
+                and codec_step.pallas_compiled(L // 4),
             )
-        words = jnp.asarray(words_h)
+            return _AsyncHandle("digest", (parity_w, digests))
+        if codec_step.codec_overlap_mode() == "async":
+            handle = self._encode_subchunk_begin(words_h, parity_shards, L)
+            if handle is not None:
+                return handle
+            # batch too small for S >= 3 sub-chunks: serialized path
+        use_pallas, interpret = codec_step.pallas_dispatch(L // 4)
+        words = self._to_device(words_h)
         _record_h2d("data", words.nbytes)
-        parity_w, digests = codec_step.encode_and_hash_words_digest(
-            words, parity_shards, L
+        parity_w, digests = codec_step.encode_words_fused1(
+            words,
+            parity_shards,
+            L,
+            formulation=codec_step.codec_formulation(),
+            use_pallas=use_pallas,
+            interpret=interpret,
         )
-        _record_pass("encode_and_hash_words_digest")
+        _record_pass("encode_words_fused1", pallas=use_pallas)
         return _AsyncHandle("digest", (parity_w, digests))
 
-    def _encode_subchunk_begin(self, words_h, parity_shards, shard_len, group):
+    def _encode_subchunk_begin(self, words_h, parity_shards, shard_len):
         """MINIO_TPU_CODEC_OVERLAP=async PUT: split the stripe batch
         along w into S sub-chunks and double-buffer them through the
-        device — chunk s+1's H2D staging (async jnp.asarray dispatch)
+        device — chunk s+1's H2D staging (async device_put dispatch)
         overlaps chunk s's encode pass, whose donated ping-pong
         accumulator carries the phash256 partials; the LAST chunk
         finalizes the digests in its own program, so the chain launches
@@ -882,62 +703,53 @@ class TpuBackend(CodecBackend):
         Returns the in-flight handle, or None when the batch is too
         small to cut S >= 3 chunks (caller takes the serialized path).
         """
-        import jax.numpy as jnp
-
-        from ..ops import codec_step
+        from ..ops import codec_step, rs
+        from . import compress as compmod
         from .erasure import subchunk_words
 
         B, k, w = words_h.shape
         m = parity_shards
-        cw = subchunk_words(w, group if group else 8)
+        # cut on parity-group boundaries while the drain screens per
+        # chunk, on the hash partition stride otherwise
+        screened = compmod.device_compress_mode() != "off"
+        cw = subchunk_words(w, compmod.PARITY_GROUP_WORDS if screened else 8)
         if not cw:
             return None
         offs = list(range(0, w, cw))
         # ping-pong staging: two sub-chunk input buffers live at once
         reserved = _stage_reserve(2 * B * k * cw * 4)
         try:
-            acc = jnp.zeros((B, k + m, 8), jnp.uint32)
-            parity_c, flags_c, packed_c = [], [], []
+            acc = self._to_device(np.zeros((B, k + m, 8), np.uint32))
+            parity_c = []
             for i, off in enumerate(offs):
                 end = min(off + cw, w)
-                chunk = jnp.asarray(
+                chunk = self._to_device(
                     np.ascontiguousarray(words_h[:, :, off:end])
                 )
                 _record_h2d("data", (end - off) * B * k * 4)
-                p_c, acc, f_c, pk_c = codec_step.encode_subchunk_words(
+                p_c, acc = codec_step.encode_subchunk_words(
                     chunk,
                     acc,
                     np.uint32(off),
                     m,
                     shard_len,
-                    group=group,
                     finalize=i == len(offs) - 1,
                 )
-                _record_pass("encode_subchunk_words")
+                # the chunk's parity product is rs._matmul_static
+                _record_pass(
+                    "encode_subchunk_words",
+                    pallas=m > 0 and rs.lowering_for_tpu(),
+                )
                 parity_c.append(p_c)
-                if group:
-                    flags_c.append(f_c)
-                    packed_c.append(pk_c)
             _record_overlap("put", len(offs) - 1)
         except BaseException:
             _stage_release(reserved)
             raise
-        return _AsyncHandle(
-            "digest-subchunk",
-            (
-                parity_c,
-                acc,
-                flags_c or None,
-                packed_c or None,
-                group,
-                reserved,
-            ),
-        )
+        return _AsyncHandle("digest-subchunk", (parity_c, acc, reserved))
 
     def encode_digest_end(self, handle):
         if not isinstance(handle, _AsyncHandle) or handle.kind not in (
             "digest",
-            "digest-fused1",
             "digest-subchunk",
             "digest-eager",
         ):
@@ -952,34 +764,11 @@ class TpuBackend(CodecBackend):
                     np.ascontiguousarray(parity, dtype=np.uint8)
                 ),
             )
-        elif handle.kind == "digest-fused1":
-            # digests are the ONLY eager readback (MTPU107); parity,
-            # flags and packed stay device-resident behind the ref
-            parity_w, digests_d, flags_d, packed_d, group = handle.payload
-            digests = np.asarray(digests_d)
-            _record_d2h("data", digests.nbytes)
-            result = (
-                digests,
-                _DeviceParityRef(
-                    parity_plane_cache(),
-                    parity_w,
-                    flags=flags_d,
-                    packed=packed_d,
-                    group=group,
-                ),
-            )
         elif handle.kind == "digest-subchunk":
             # async-overlap twin: same digest-only eager readback; the
             # staging ping-pong reservation drops here — the last
             # chunk's pass has produced everything the ref holds
-            (
-                parity_c,
-                digests_d,
-                flags_c,
-                packed_c,
-                group,
-                reserved,
-            ) = handle.payload
+            parity_c, digests_d, reserved = handle.payload
             # the reservation must drop even when the digest D2H
             # throws (device reset mid-drain): an exception here must
             # not strand staging-ledger bytes for the process lifetime
@@ -990,21 +779,17 @@ class TpuBackend(CodecBackend):
                 _stage_release(reserved)
             result = (
                 digests,
-                _SubchunkParityRef(
-                    parity_plane_cache(),
-                    parity_c,
-                    flags=flags_c,
-                    packed=packed_c,
-                    group=group,
-                ),
+                _DeviceParityRef(parity_plane_cache(), parity_c),
             )
         else:
+            # digests are the ONLY eager readback (MTPU107); parity
+            # stays device-resident behind the ref
             parity_w, digests_d = handle.payload
             digests = np.asarray(digests_d)
             _record_d2h("data", digests.nbytes)
             result = (
                 digests,
-                _DeviceParityRef(parity_plane_cache(), parity_w),
+                _DeviceParityRef(parity_plane_cache(), [parity_w]),
             )
         handle.result = result
         handle.consumed = True
@@ -1021,9 +806,7 @@ class TpuBackend(CodecBackend):
         return parity_cache_pressure()
 
     def reconstruct(self, shards, present, data_shards, parity_shards):
-        import jax.numpy as jnp
-
-        from ..ops import codec_step
+        from ..ops import codec_step, rs
 
         shards = np.ascontiguousarray(shards, dtype=np.uint8)
         B = shards.shape[0]
@@ -1038,28 +821,36 @@ class TpuBackend(CodecBackend):
                 data_shards,
                 parity_shards,
             )
-            _record_pass("mesh_reconstruct")
+            _record_pass(
+                "mesh_reconstruct",
+                pallas=rs.lowering_for_tpu() and mesh.shape["shard"] == 1,
+            )
+            # k compacted survivor rows go up, k data rows come back
+            _record_h2d("data", dw.nbytes)
+            _record_d2h("data", dw.nbytes)
             return codec_step.host_words_to_bytes(dw)
-        words = jnp.asarray(codec_step.host_bytes_to_words(shards))
+        words = self._to_device(codec_step.host_bytes_to_words(shards))
+        _record_h2d("data", words.nbytes)
         dw = codec_step.reconstruct_words_batch(
             words, tuple(bool(b) for b in present), data_shards, parity_shards
         )
-        _record_pass("reconstruct_words_batch")
-        return codec_step.host_words_to_bytes(np.asarray(dw))
+        # rs._matmul_static pads any width up to the Pallas tile
+        _record_pass("reconstruct_words_batch", pallas=rs.lowering_for_tpu())
+        dw = np.asarray(dw)
+        _record_d2h("data", dw.nbytes)
+        return codec_step.host_words_to_bytes(dw)
 
     def reconstruct_and_verify(
         self, shards, digests, present, data_shards, parity_shards
     ):
         """Fused GET-side pass (fused1): digest checks + survivor decode
         in ONE device pass (codec_step.verify_and_reconstruct_words),
-        replacing the verify -> reconstruct pair on the quorum-read/heal
-        path.  Optimistic like CpuBackend: decode from the first k
-        present rows while hashing all of them; on the rare digest
-        mismatch among the chosen survivors, re-pick survivors from the
-        verified mask and re-solve just the hit stripes.  The legacy
-        mode composes the separate passes (bisection oracle)."""
-        import jax.numpy as jnp
-
+        replacing the verify -> reconstruct pair on the heal path.
+        Optimistic like CpuBackend: decode from the first k present
+        rows while hashing all of them; on the rare digest mismatch
+        among the chosen survivors, re-pick survivors from the verified
+        mask and re-solve just the hit stripes.  The legacy mode
+        composes the separate passes (bisection oracle)."""
         from ..ops import codec_step
 
         if codec_step.codec_kernel_mode() != "fused1":
@@ -1071,13 +862,16 @@ class TpuBackend(CodecBackend):
         B, n, L = shards.shape
         present_t = tuple(bool(b) for b in pres)
         words = codec_step.host_bytes_to_words(shards)
+        use_pallas, interpret = codec_step.pallas_dispatch(L // 4)
+        overlap = codec_step.codec_overlap_mode()
         mesh = self._mesh_for(B, data_shards)
+        got = None
         if mesh is not None:
             from ..parallel import mesh as pm
 
-            if codec_step.codec_overlap_mode() != "off":
+            if overlap != "off":
                 pm.warn_overlap_fallback()
-            dw, ok = pm.mesh_verify_reconstruct(
+            got = pm.mesh_verify_reconstruct(
                 mesh,
                 words,
                 np.asarray(digests),
@@ -1085,44 +879,37 @@ class TpuBackend(CodecBackend):
                 data_shards,
                 parity_shards,
                 L,
+                formulation=codec_step.codec_formulation(),
+                use_pallas=use_pallas,
+                interpret=interpret,
             )
-            _record_pass("mesh_verify_reconstruct")
+            _record_pass("mesh_verify_reconstruct", pallas=use_pallas)
+            _record_h2d("data", words.nbytes)
+            _record_d2h("data", got[0].nbytes)
+        elif overlap == "async":
+            got = self._drain_vr_subchunks(
+                words, digests, present_t, data_shards, parity_shards, L
+            )
+        if got is not None:
+            dw, ok = got
         else:
-            overlap = codec_step.codec_overlap_mode()
-            got = None
-            if overlap == "async":
-                got = self._drain_vr_subchunks(
-                    words, digests, present_t, data_shards, parity_shards, L
-                )
-            if got is not None:
-                dw, ok = got
-            else:
-                w = L // 4
-                use_pallas, interpret = codec_step.pallas_dispatch(w)
-                pipeline = overlap == "pipeline" and use_pallas
-                words_d = jnp.asarray(words)
-                _record_h2d("data", words_d.nbytes)
-                dw_d, ok_d = codec_step.verify_and_reconstruct_words(
-                    words_d,
-                    jnp.asarray(digests),
-                    present_t,
-                    data_shards,
-                    parity_shards,
-                    L,
-                    formulation=codec_step.codec_formulation(),
-                    use_pallas=use_pallas,
-                    interpret=interpret,
-                    pipeline=pipeline,
-                )
-                _record_pass("verify_and_reconstruct_words")
-                if pipeline:
-                    from ..ops import rs_pallas
-
-                    nt = w // rs_pallas._TW
-                    if nt > 1:
-                        _record_overlap("get", B * (nt - 1))
-                dw = np.asarray(dw_d)
-                ok = np.asarray(ok_d)
+            words_d = self._to_device(words)
+            _record_h2d("data", words_d.nbytes)
+            dw_d, ok_d = codec_step.verify_and_reconstruct_words(
+                words_d,
+                self._to_device(np.asarray(digests)),
+                present_t,
+                data_shards,
+                parity_shards,
+                L,
+                formulation=codec_step.codec_formulation(),
+                use_pallas=use_pallas,
+                interpret=interpret,
+            )
+            _record_pass("verify_and_reconstruct_words", pallas=use_pallas)
+            dw = np.asarray(dw_d)
+            ok = np.asarray(ok_d)
+            _record_d2h("data", dw.nbytes)
         data = codec_step.host_words_to_bytes(dw)
         surv = np.nonzero(pres)[0][:data_shards]
         bad = ~ok[:, surv].all(axis=1)
@@ -1149,9 +936,7 @@ class TpuBackend(CodecBackend):
         Returns (data words (B, k, w), ok (B, n) bool), or None when
         the batch is too small to cut S >= 3 chunks.
         """
-        import jax.numpy as jnp
-
-        from ..ops import codec_step
+        from ..ops import codec_step, rs
         from .erasure import subchunk_words
 
         B, n, w = words_h.shape
@@ -1161,14 +946,14 @@ class TpuBackend(CodecBackend):
         offs = list(range(0, w, cw))
         reserved = _stage_reserve(2 * B * n * cw * 4)
         try:
-            digests_d = jnp.asarray(np.asarray(digests))
-            acc = jnp.zeros((B, n, 8), jnp.uint32)
+            digests_d = self._to_device(np.asarray(digests))
+            acc = self._to_device(np.zeros((B, n, 8), np.uint32))
             parts: "list[np.ndarray]" = []
             prev = None
             ok_d = None
             for i, off in enumerate(offs):
                 end = min(off + cw, w)
-                chunk = jnp.asarray(
+                chunk = self._to_device(
                     np.ascontiguousarray(words_h[:, :, off:end])
                 )
                 _record_h2d("data", (end - off) * B * n * 4)
@@ -1185,7 +970,10 @@ class TpuBackend(CodecBackend):
                         finalize=i == len(offs) - 1,
                     )
                 )
-                _record_pass("verify_reconstruct_subchunk_words")
+                _record_pass(
+                    "verify_reconstruct_subchunk_words",
+                    pallas=rs.lowering_for_tpu(),
+                )
                 if prev is not None:
                     # drain chunk i-1 while chunk i computes: this is
                     # the D2H leg of the three-deep overlap
@@ -1203,8 +991,6 @@ class TpuBackend(CodecBackend):
         return np.concatenate(parts, axis=-1), ok
 
     def digest(self, shards):
-        import jax.numpy as jnp
-
         from ..ops import codec_step
 
         shards = np.ascontiguousarray(shards, dtype=np.uint8)
@@ -1216,11 +1002,18 @@ class TpuBackend(CodecBackend):
             words = codec_step.host_bytes_to_words(shards)
             flat = words.reshape(B * n, -1)
             _record_pass("mesh_digest")
-            return pm.mesh_digest(mesh, flat, L).reshape(B, n, 8)
-        words = jnp.asarray(codec_step.host_bytes_to_words(shards))
-        got = phash.phash256_words_batched(words, L)
-        _record_pass("phash256_words_batched")
-        return np.asarray(got)
+            got = pm.mesh_digest(mesh, flat, L).reshape(B, n, 8)
+            _record_h2d("data", words.nbytes)
+            _record_d2h("data", got.nbytes)
+            return got
+        words = self._to_device(codec_step.host_bytes_to_words(shards))
+        _record_h2d("data", words.nbytes)
+        # the healthy-read digest has no Pallas kernel: one XLA pass
+        got = codec_step.digest_words(words, L)
+        _record_pass("digest_words")
+        got = np.asarray(got)
+        _record_d2h("data", got.nbytes)
+        return got
 
 
 class CpuBackend(CodecBackend):
@@ -1437,6 +1230,15 @@ def get_backend(name: "str | None" = None) -> CodecBackend:
     return _make(name)
 
 
+def _pinned_to(platform: str) -> bool:
+    """Did the operator hold JAX to this platform (JAX_PLATFORMS=cpu, the
+    tests' configuration)?  Then asking for the device backend by name
+    gets it there; a platform JAX merely fell back to is not a TPU."""
+    import jax
+
+    return platform in (jax.config.jax_platforms or "").split(",")
+
+
 def _make(name: str) -> CodecBackend:
     # kernel telemetry wraps the CONCRETE backend, under the batcher:
     # a coalesced flush is one recorded call with real device seconds,
@@ -1444,21 +1246,66 @@ def _make(name: str) -> CodecBackend:
     from .batcher import maybe_wrap
     from .telemetry import instrument
 
-    if name == "cpu":
-        return maybe_wrap(instrument(CpuBackend()))
-    if name == "tpu":
-        return maybe_wrap(instrument(TpuBackend()))
-    if name == "auto":
-        try:
-            import jax
+    if name not in ("cpu", "tpu", "auto"):
+        raise ValueError(f"unknown erasure backend {name!r}")
+    if name != "cpu":
+        import jax
 
-            # any jax backend (tpu or the CPU test platform) works; the
-            # device path dispatches pallas-vs-portable internally
-            jax.devices()
+        # no try: a chip that is missing or held by another process
+        # makes jax.devices() raise, and that must stop the server, not
+        # quietly serve from the host codec
+        platform = jax.devices()[0].platform
+        if platform == "tpu" or (name == "tpu" and _pinned_to(platform)):
+            _log.info(
+                "codec backend resolved",
+                extra=kv(requested=name, backend="tpu", platform=platform),
+            )
             return maybe_wrap(instrument(TpuBackend()))
-        except Exception:
-            return maybe_wrap(instrument(CpuBackend()))
-    raise ValueError(f"unknown erasure backend {name!r}")
+        if name == "tpu":
+            raise RuntimeError(
+                f"MINIO_ERASURE_BACKEND=tpu but JAX found platform "
+                f"{platform!r}; set MINIO_ERASURE_BACKEND=cpu (or auto) "
+                "to serve from the host codec"
+            )
+        _log.info(
+            "codec backend resolved: no accelerator, host codec",
+            extra=kv(requested=name, backend="cpu", platform=platform),
+        )
+    return maybe_wrap(instrument(CpuBackend()))
+
+
+def backend_info() -> dict:
+    """The resolved backend and the devices under it, for the boot log,
+    ``healthinfo`` and ``kernel-stats``: backend name, the knob values
+    that pick its kernels, and utils.jaxenv.device_info().  Resolves the
+    backend if nothing has yet, and raises if that fails."""
+    from ..ops import codec_step
+    from ..parallel import rules as prules
+    from ..utils import jaxenv
+    from . import compress as compmod
+
+    be = get_backend()
+    inner = be
+    while hasattr(inner, "inner"):
+        inner = inner.inner
+    doc = {"backend": inner.name, "batched": be is not inner}
+    if isinstance(inner, CpuBackend):
+        native = CpuBackend._native_fused() is not None
+        doc["codec"] = "native" if native else "numpy"
+        return doc
+    doc.update(jaxenv.device_info())
+    doc["kernel"] = codec_step.codec_kernel_mode()
+    doc["formulation"] = codec_step.codec_formulation()
+    doc["overlap"] = codec_step.codec_overlap_mode()
+    doc["device_compress"] = compmod.device_compress_mode()
+    n = doc["device_count"]
+    if n > 1:
+        doc["placement"] = (
+            "pinned to device 0 (MINIO_MESH=0)"
+            if os.environ.get("MINIO_MESH", "1") == "0"
+            else f"{prules.placement_policy()} over {n} devices"
+        )
+    return doc
 
 
 def reset_backend() -> None:

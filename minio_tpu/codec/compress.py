@@ -95,7 +95,7 @@ def is_compressible(key: str, content_type: str, size: int) -> bool:
 # drain (codec/backend.py).  Parity of compressible/zero-padded objects
 # is mostly zero groups, so ops/codec_step.pack_nonzero_groups compacts
 # the nonzero groups to the front on device and only flags + the packed
-# prefix cross PCIe; unpack_nonzero_groups below restores the full
+# prefix cross the bus; unpack_nonzero_groups below restores the full
 # plane host-side, bit-identically.
 
 # words per transport group (1 KiB of parity per flag bit)
@@ -103,14 +103,17 @@ PARITY_GROUP_WORDS = 256
 
 
 def device_compress_mode() -> str:
-    """MINIO_TPU_DEVICE_COMPRESS = auto|on|off (default auto).
+    """MINIO_TPU_DEVICE_COMPRESS = off|auto|on (default off).
 
-    auto: screen with ops/codec_step.group_flags and pack only when the
-    nonzero fill is below parity_fill_threshold(); on: always pack;
-    off: every drain moves the full plane.
+    off: every drain moves the full plane, and launches nothing.
+    auto: screen at drain with ops/codec_step.group_flags and pack only
+    when the nonzero fill is below parity_fill_threshold(); on: always
+    pack.  The screen costs one device pass and one small synchronous
+    readback before any parity byte moves - worth it only where the bus
+    is far slower than the device, so it is not the default.
     """
-    v = os.environ.get("MINIO_TPU_DEVICE_COMPRESS", "auto").lower()
-    return v if v in ("auto", "on", "off") else "auto"
+    v = os.environ.get("MINIO_TPU_DEVICE_COMPRESS", "off").lower()
+    return v if v in ("auto", "on", "off") else "off"
 
 
 def parity_fill_threshold() -> float:

@@ -30,30 +30,12 @@ import secrets
 import threading
 import urllib.parse
 
+from cryptography.exceptions import InvalidTag
+from cryptography.hazmat.primitives.ciphers.aead import AESGCM
+
+
 class KMSError(Exception):
     pass
-
-
-# gate the hard dependency the same way codec/sse.py does: the module
-# stays importable without `cryptography`, KMS operations fail with a
-# clear KMSError at use time
-try:
-    from cryptography.exceptions import InvalidTag
-    from cryptography.hazmat.primitives.ciphers.aead import AESGCM
-
-    _CRYPTO_IMPORT_ERROR: "Exception | None" = None
-except ImportError as _e:  # pragma: no cover - depends on environment
-    _CRYPTO_IMPORT_ERROR = _e
-
-    class InvalidTag(Exception):  # type: ignore[no-redef]
-        pass
-
-    class AESGCM:  # type: ignore[no-redef]
-        def __init__(self, key):
-            raise KMSError(
-                "KMS sealing requires the 'cryptography' package: "
-                f"{_CRYPTO_IMPORT_ERROR}"
-            )
 
 
 def context_aad(context: "dict[str, str]") -> bytes:

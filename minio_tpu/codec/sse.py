@@ -35,26 +35,8 @@ import os
 import secrets
 import struct
 
-# gate the hard dependency: environments without `cryptography` can
-# still import the object layer (SSE requests fail with a clear
-# SSEError at use time instead of the whole package failing to import)
-try:
-    from cryptography.exceptions import InvalidTag
-    from cryptography.hazmat.primitives.ciphers.aead import AESGCM
-
-    _CRYPTO_IMPORT_ERROR: "Exception | None" = None
-except ImportError as _e:  # pragma: no cover - depends on environment
-    _CRYPTO_IMPORT_ERROR = _e
-
-    class InvalidTag(Exception):  # type: ignore[no-redef]
-        pass
-
-    class AESGCM:  # type: ignore[no-redef]
-        def __init__(self, key):
-            raise SSEError(
-                "server-side encryption requires the 'cryptography' "
-                f"package: {_CRYPTO_IMPORT_ERROR}"
-            )
+from cryptography.exceptions import InvalidTag
+from cryptography.hazmat.primitives.ciphers.aead import AESGCM
 
 from .compress import RangeSatisfied
 

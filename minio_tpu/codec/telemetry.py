@@ -70,9 +70,11 @@ class KernelStats:
         # (PR 18), not an inference from wall clock
         self._h2d: "dict[str, list]" = {}
         self._overlap: "dict[str, int]" = {}
-        # device-program launches by jitted entry point: the fused1
-        # acceptance gate (legacy PUT seam = 3 passes/batch, fused1 = 1)
+        # device-program launches by jitted entry point, and the subset
+        # of them that ran a Pallas kernel (the rest ran the XLA
+        # formulation: ragged widths, non-TPU platforms, XLA-only passes)
         self._passes: "dict[str, int]" = {}
+        self._pallas_passes: "dict[str, int]" = {}
         # submesh placement: outcome ("span"|"route") -> batches, and
         # per-submesh in-flight depth (current + high-water mark)
         self._placement: "dict[str, int]" = {}
@@ -124,17 +126,22 @@ class KernelStats:
             row[1] += nbytes
 
     def record_overlap_windows(self, plane: str, windows: int) -> None:
-        """``windows`` sub-chunks (or in-kernel tile steps) whose
-        transfer overlapped a neighbor's compute, keyed by direction:
+        """``windows`` sub-chunks whose transfer overlapped a
+        neighbor's compute, keyed by direction:
         plane = put (encode side) | get (verify/reconstruct side)."""
         with self._mu:
             self._overlap[plane] = self._overlap.get(plane, 0) + windows
 
-    def record_pass(self, kernel: str) -> None:
+    def record_pass(self, kernel: str, pallas: bool = False) -> None:
         """One device-program launch (jitted codec pass) by entry-point
-        name — backend.py records these at every launch site."""
+        name — backend.py records these at every launch site, and says
+        whether the launch ran a Pallas kernel."""
         with self._mu:
             self._passes[kernel] = self._passes.get(kernel, 0) + 1
+            if pallas:
+                self._pallas_passes[kernel] = (
+                    self._pallas_passes.get(kernel, 0) + 1
+                )
 
     def record_stages(self, op: str, stages: "dict[str, float]") -> None:
         """One stream's stage breakdown (assemble / codec / disk)."""
@@ -230,6 +237,12 @@ class KernelStats:
                     for plane in ("put", "get")
                 },
                 "device_passes": dict(sorted(self._passes.items())),
+                "pallas_passes": dict(sorted(self._pallas_passes.items())),
+                "portable_passes": {
+                    kernel: n - self._pallas_passes.get(kernel, 0)
+                    for kernel, n in sorted(self._passes.items())
+                    if n > self._pallas_passes.get(kernel, 0)
+                },
                 "parity_cache": _parity_cache_stats(),
                 "hedge": {
                     kind: self._hedge.get(kind, 0)
@@ -294,6 +307,7 @@ class KernelStats:
             self._h2d.clear()
             self._overlap.clear()
             self._passes.clear()
+            self._pallas_passes.clear()
             self._placement.clear()
             self._submesh_depth.clear()
             self._submesh_depth_hwm.clear()

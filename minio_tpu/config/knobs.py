@@ -64,13 +64,13 @@ KNOBS: "dict[str, Knob]" = {
     "MINIO_TPU_CA_FILE": Knob("", "TLS client-verification CA path"),
     # -- codec / device plane ----------------------------------------------
     "MINIO_TPU_CODEC_KERNEL": Knob(
-        "fused1", "erasure kernel variant selector"
+        "fused1", "codec entry points: fused1 | legacy"
     ),
     "MINIO_TPU_CODEC_FORMULATION": Knob(
         "swar", "GF(2^8) product formulation: swar | mxu"
     ),
     "MINIO_TPU_CODEC_OVERLAP": Knob(
-        "auto", "overlapped sub-chunk DMA pipeline: on | off | auto"
+        "off", "host-driven sub-chunk transfer overlap: async | off"
     ),
     "MINIO_TPU_CODEC_SUBCHUNK_KB": Knob(
         "256", "sub-chunk size for the overlap pipeline (KiB)"
@@ -92,7 +92,7 @@ KNOBS: "dict[str, Knob]" = {
     ),
     "MINIO_TPU_COMPRESS": Knob("off", "transparent object compression"),
     "MINIO_TPU_DEVICE_COMPRESS": Knob(
-        "auto", "device-side compression codec pass: on | off | auto"
+        "off", "drain-time parity transport compression: off | auto | on"
     ),
     "MINIO_TPU_DCOMP_MAX_FILL": Knob(
         "0.75", "device-compression max output fill ratio"

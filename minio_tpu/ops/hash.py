@@ -159,56 +159,54 @@ def phash256_words(words, nbytes: int):
     return phash256_words_batched(words[None], nbytes)[0]
 
 
+def _xor_fold(x, axis: int):
+    import jax
+
+    return jax.lax.reduce(
+        x, np.uint32(0), jax.lax.bitwise_xor, (axis % x.ndim,)
+    )
+
+
+# lane width of the partition fold below: one full vreg row
+_LANES = 128
+
+
+def _fold_parts(mix):
+    """(..., n) u32 mixes -> (..., 4) word-index-mod-4 partition XORs.
+
+    Folds 128-word rows first and the 128 lanes down to the 4
+    partitions last, so the large intermediate keeps a full lane dim:
+    reshaping the stream to (..., n/4, 4) instead pads every 4-lane row
+    to a vreg on TPU (the compiler reported 1.5 GiB of temporaries for
+    an 80 MiB batch and compile times of 30-50 s at 4-row shapes).
+    128 % 4 == 0, so lane l of every row holds partition l % 4; a
+    ragged tail of < 128 words takes the direct 4-lane fold.
+    """
+    n = mix.shape[-1]
+    lead = mix.shape[:-1]
+    main = n - n % _LANES
+    acc = None
+    if main:
+        rows = _xor_fold(
+            mix[..., :main].reshape(*lead, main // _LANES, _LANES), -2
+        )
+        acc = _xor_fold(rows.reshape(*lead, _LANES // _PARTS, _PARTS), -2)
+    if n - main:
+        tail = _xor_fold(
+            mix[..., main:].reshape(*lead, (n - main) // _PARTS, _PARTS),
+            -2,
+        )
+        acc = tail if acc is None else acc ^ tail
+    return acc
+
+
 def phash256_words_batched(words, nbytes: int):
     """Device digest over the LAST axis: (..., w) uint32 -> (..., 8).
 
     Vectorized over leading axes with no vmap - every op is a full-size
     array op, so hashing (n_shards, batch, w) is one VPU pass.
     """
-    import jax
-    import jax.numpy as jnp
-
-    n = words.shape[-1]
-    if n % _PARTS:
-        raise ValueError(f"word count {n} must be a multiple of {_PARTS}")
-    lead = words.shape[:-1]
-    idx = jax.lax.iota(jnp.uint32, n)
-    key = _mix_jnp(idx * _C1 + jnp.uint32(1))
-    m1 = _mix_jnp((words ^ key) * _M1)
-    m2 = _mix_jnp((words + key) * _M2)
-    red = lambda m: jax.lax.reduce(
-        m.reshape(*lead, n // _PARTS, _PARTS),
-        np.uint32(0),
-        jax.lax.bitwise_xor,
-        (len(lead),),
-    )
-    out = jnp.concatenate([red(m1), red(m2)], axis=-1)  # (..., 8)
-    return _mix_jnp(
-        out ^ jnp.uint32(nbytes) * _C1 + jax.lax.iota(jnp.uint32, 8)
-    )
-
-
-def tile_partials(words, key):
-    """XOR partials of one contiguous tile for the fused Pallas kernel.
-
-    words, key: (w,) uint32 (key = _mix(global_index * C1 + 1) for the
-    tile's global word positions).  Returns (8,) uint32: 4 partials of the
-    m1 mix then 4 of m2.  XOR-fold partials of all tiles, then apply
-    finalize_partials to obtain phash256_words output.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    n = words.shape[-1]
-    m1 = _mix_jnp((words ^ key) * _M1)
-    m2 = _mix_jnp((words + key) * _M2)
-    red = lambda m: jax.lax.reduce(
-        m.reshape(n // _PARTS, _PARTS),
-        np.uint32(0),
-        jax.lax.bitwise_xor,
-        (0,),
-    )
-    return jnp.concatenate([red(m1), red(m2)])
+    return finalize_partials(tile_partials_batched(words, 0), nbytes)
 
 
 def tile_partials_batched(words, offset):
@@ -219,7 +217,7 @@ def tile_partials_batched(words, offset):
     sub-chunk of a stream reuses one compiled program.  offset must be
     a multiple of _PARTS (the strided word-index-mod-4 partitions must
     stay aligned across chunks); the codec sub-chunk sizing guarantees
-    this by cutting on parity-group boundaries.  Returns (..., 8)
+    this by cutting on hash-partition boundaries.  Returns (..., 8)
     partials — XOR-fold the chunks in any order, then apply
     finalize_partials to obtain phash256_words_batched output.
     """
@@ -229,18 +227,11 @@ def tile_partials_batched(words, offset):
     n = words.shape[-1]
     if n % _PARTS:
         raise ValueError(f"word count {n} must be a multiple of {_PARTS}")
-    lead = words.shape[:-1]
     idx = jnp.uint32(offset) + jax.lax.iota(jnp.uint32, n)
     key = _mix_jnp(idx * _C1 + jnp.uint32(1))
     m1 = _mix_jnp((words ^ key) * _M1)
     m2 = _mix_jnp((words + key) * _M2)
-    red = lambda m: jax.lax.reduce(
-        m.reshape(*lead, n // _PARTS, _PARTS),
-        np.uint32(0),
-        jax.lax.bitwise_xor,
-        (len(lead),),
-    )
-    return jnp.concatenate([red(m1), red(m2)], axis=-1)
+    return jnp.concatenate([_fold_parts(m1), _fold_parts(m2)], axis=-1)
 
 
 def finalize_partials(partials, nbytes: int):

@@ -99,18 +99,38 @@ def _encode_words(data_words: jax.Array, matrix: np.ndarray) -> jax.Array:
     return jnp.stack(rows)
 
 
+def lowering_for_tpu() -> bool:
+    """The one trace-time platform read in ops/: True when the program
+    being traced will be compiled for a TPU.  Every static Pallas-vs-XLA
+    dispatch (here, codec_step.pallas_dispatch) asks this, so the AOT
+    compile test can stand in for the chip by replacing one function."""
+    return jax.default_backend() == "tpu"
+
+
 def _matmul_static(words: jax.Array, matrix: np.ndarray) -> jax.Array:
     """Static-matrix GF matmul: Pallas kernel on TPU, fused XLA elsewhere.
 
     Trace-time dispatch: on the TPU backend the tiled VMEM kernel
-    (rs_pallas.matmul_words) is ~15x the fused-XLA path; CPU tests and
-    the virtual multi-chip mesh take the portable jnp path.
+    (rs_pallas.matmul_words, compiled - never interpreted); CPU tests
+    and the virtual multi-chip mesh take the portable jnp path.  The
+    ratio between the two: not measured on this code.
     """
-    if jax.default_backend() == "tpu":
+    if lowering_for_tpu():
         from . import rs_pallas
 
         return rs_pallas.matmul_words(matrix, words, interpret=False)
     return _encode_words(words, matrix)
+
+
+def _matmul_static_batch(rows: jax.Array, matrix: np.ndarray) -> jax.Array:
+    """(B, s, w) shard rows x static (o, s) matrix -> (B, o, w).
+
+    RS is column-local, so a batch is ONE flat (s, B*w) product - no
+    vmap of small ops."""
+    B, s, w = rows.shape
+    flat = rows.transpose(1, 0, 2).reshape(s, B * w)
+    out = _matmul_static(flat, matrix)
+    return out.reshape(matrix.shape[0], B, w).transpose(1, 0, 2)
 
 
 def _matmul_words_dynamic(shards_words: jax.Array, matrix: jax.Array) -> jax.Array:

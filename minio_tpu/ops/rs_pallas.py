@@ -1,25 +1,28 @@
 """Pallas TPU kernels for the GF(2^8) shard codec hot path.
 
 Two device formulations of "GF matrix @ shards" (the klauspost/reedsolomon
-role behind cmd/erasure-coding.go:54-64):
+role behind cmd/erasure-coding.go:54-64), selectable inside the fused
+kernels below (MINIO_TPU_CODEC_FORMULATION):
 
-1. SWAR/VPU kernel (`matmul_words`, the default): shards live as uint32
-   words (4 field elements per lane).  Multiply-by-constant uses the
+1. SWAR/VPU (`_swar_rows`, the default): shards live as uint32 words
+   (4 field elements per lane).  Multiply-by-constant uses the
    xtime-powers decomposition with the generator matrix baked into the
    kernel at trace time, so each tile is a straight-line XOR chain over
    VMEM-resident vectors - no tables, no gathers, no dtype conversions.
-   Measured ~450 GiB/s data throughput at EC 8+4 on v5e-1 (HBM-bound:
-   the kernel reads each data byte and writes each parity byte once).
 
-2. MXU bit-matrix kernel (`gf_matmul_mxu`): GF(2^8) mul-by-constant is an
-   8x8 linear map over GF(2), so the whole codec lifts to one
+2. MXU bit-matrix (`_mxu_rows`): GF(2^8) mul-by-constant is an 8x8
+   linear map over GF(2), so the whole codec lifts to one
    (8o x 8s) @ (8s x T) bf16 matmul per tile, mod 2.  Higher arithmetic
-   intensity but pays ~30 VPU ops/byte in bit unpack/repack, which caps it
-   below the SWAR kernel at storage geometries (k <= 16).  Kept as the
-   backend for very wide/dense matrices and as MXU reference.
+   intensity but pays ~30 VPU ops/byte in bit unpack/repack.
 
-Both run under interpret mode for CPU tests; production dispatch lives in
-rs.encode / rs.reconstruct.
+Throughput of either: not measured on this code (PERF.md).
+
+Every entry point takes ``interpret`` explicitly: False compiles the
+kernel with Mosaic (TPU only), True runs the Pallas interpreter (the
+CPU test mode).  Nothing here guesses from the platform; the dispatch
+that does lives in codec_step.pallas_dispatch / rs._matmul_static.
+All kernels pipeline HBM<->VMEM through their BlockSpecs (Pallas
+double-buffers the blocked grid), so each is exactly one pallas_call.
 """
 
 from __future__ import annotations
@@ -30,14 +33,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from . import gf, rs
 
 # uint32 words per shard per tile (16 KiB of shard bytes per grid step)
 _TW = 4096
-# lane-dim tile for the MXU kernel: bytes per shard per grid step
-_T_BLK = 8192
 
 
 def _swar_kernel(matrix: np.ndarray):
@@ -81,27 +81,15 @@ def _matmul_words_jit(
     return out[:, :w] if pad else out
 
 
-def matmul_words(
-    matrix: np.ndarray, words, interpret: "bool | None" = None
-):
+def matmul_words(matrix: np.ndarray, words, interpret: bool):
     """(o, s) static GF matrix @ (s, w) uint32 shard words -> (o, w)."""
     o, s = matrix.shape
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     key = np.ascontiguousarray(matrix, dtype=np.uint8).tobytes()
     return _matmul_words_jit(words, key, o, s, interpret)
 
 
-def encode_words(data_words, parity_shards: int, interpret=None):
-    """Pallas RS encode on packed words: (k, w) -> (m, w)."""
-    k = data_words.shape[0]
-    return matmul_words(
-        gf.parity_matrix(k, parity_shards), data_words, interpret
-    )
-
-
 # ---------------------------------------------------------------------------
-# Fused encode + bitrot hash (the PutObject device pass)
+# Tile bodies shared by the fused kernels: bitrot partials, SWAR rows
 # ---------------------------------------------------------------------------
 
 
@@ -134,29 +122,6 @@ def _tile_hash_partials(all_rows, i, tw: int):
     return jnp.concatenate([red(m1), red(m2)], axis=1)  # (rows, 8)
 
 
-def _fused_kernel_factory(matrix: np.ndarray, tw: int):
-    m, k = matrix.shape
-
-    def kernel(data_ref, parity_ref, hacc_ref):
-        i = pl.program_id(1)
-
-        @pl.when(i == 0)
-        def _zero():
-            hacc_ref[...] = jnp.zeros_like(hacc_ref)
-
-        data = data_ref[0]  # (k, tw)
-        # ---- encode (same XOR chain as _swar_kernel, inlined) ----
-        parity_rows = _swar_rows(matrix, data)
-        all_rows = jnp.concatenate(
-            [data, jnp.stack(parity_rows)], axis=0
-        )  # (n, tw)
-        parity_ref[0] = all_rows[k:]
-        # ---- hash partials for this tile, all shards at once ----
-        hacc_ref[0] = hacc_ref[0] ^ _tile_hash_partials(all_rows, i, tw)
-
-    return kernel
-
-
 def _swar_rows(matrix: np.ndarray, data) -> list:
     """Shared XOR-chain: parity rows of a (k, t) uint32 tile (traced)."""
     o, s = matrix.shape
@@ -187,45 +152,8 @@ def _swar_rows(matrix: np.ndarray, data) -> list:
     return rows
 
 
-@functools.partial(
-    jax.jit, static_argnames=("parity_shards", "interpret")
-)
-def encode_hash_fused(words, parity_shards: int, interpret: bool = False):
-    """One kernel pass: (B, k, w) data words -> ((B, m, w) parity words,
-    (B, n, 8) un-finalized phash partials covering data AND parity rows).
-
-    Grid is (batch, w-tiles); the hash-partial output block for a stripe is
-    revisited across its w-tiles and XOR-accumulated in VMEM, so HBM
-    traffic is exactly data-in + parity-out (data shards never round-trip:
-    the host already holds their bytes).  Finalize partials with
-    hash.finalize_partials(partials, shard_len_bytes).
-    """
-    B, k, w = words.shape
-    m = parity_shards
-    n = k + m
-    matrix = gf.parity_matrix(k, m)
-    if w % _TW:
-        raise ValueError(f"words per shard ({w}) must be a multiple of {_TW}")
-    kernel = _fused_kernel_factory(matrix, _TW)
-    parity, hacc = pl.pallas_call(
-        kernel,
-        out_shape=(
-            jax.ShapeDtypeStruct((B, m, w), jnp.uint32),
-            jax.ShapeDtypeStruct((B, n, 8), jnp.uint32),
-        ),
-        grid=(B, w // _TW),
-        in_specs=[pl.BlockSpec((1, k, _TW), lambda b, i: (b, 0, i))],
-        out_specs=(
-            pl.BlockSpec((1, m, _TW), lambda b, i: (b, 0, i)),
-            pl.BlockSpec((1, n, 8), lambda b, i: (b, 0, 0)),
-        ),
-        interpret=interpret,
-    )(words)
-    return parity, hacc
-
-
 # ---------------------------------------------------------------------------
-# MXU bit-matrix variant
+# MXU bit-matrix formulation of the same rows
 # ---------------------------------------------------------------------------
 
 
@@ -246,68 +174,6 @@ def _bit_matrix(matrix_bytes: bytes, o: int, s: int) -> np.ndarray:
                 for t in range(8):
                     out[8 * r + t, 8 * c + b] = (prod >> t) & 1
     return out
-
-
-def _mxu_kernel(mat_ref, data_ref, out_ref):
-    o8 = mat_ref.shape[0]
-    s, t = data_ref.shape
-    x = data_ref[:].astype(jnp.int32)  # (s, T)
-    bits = jnp.stack(
-        [(x >> b) & 1 for b in range(8)], axis=1
-    )  # (s, 8, T), row order 8c+b after reshape
-    bits = bits.reshape(8 * s, t).astype(jnp.bfloat16)
-    counts = jnp.dot(
-        mat_ref[:].astype(jnp.bfloat16),
-        bits,
-        preferred_element_type=jnp.float32,
-    )  # (8o, T); exact small integers
-    pbits = counts.astype(jnp.int32) & 1
-    pbits = pbits.reshape(o8 // 8, 8, t)
-    acc = pbits[:, 0, :]
-    for tbit in range(1, 8):
-        acc = acc | (pbits[:, tbit, :] << tbit)
-    out_ref[:] = acc.astype(jnp.uint8)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("matrix_key", "o", "s", "interpret")
-)
-def _mxu_matmul_jit(shards, matrix_key: bytes, o: int, s: int, interpret):
-    length = shards.shape[1]
-    pad = (-length) % _T_BLK
-    if pad:
-        shards = jnp.pad(shards, ((0, 0), (0, pad)))
-    plen = length + pad
-    mat = jnp.asarray(_bit_matrix(matrix_key, o, s))
-    out = pl.pallas_call(
-        _mxu_kernel,
-        out_shape=jax.ShapeDtypeStruct((o, plen), jnp.uint8),
-        grid=(plen // _T_BLK,),
-        in_specs=[
-            pl.BlockSpec((8 * o, 8 * s), lambda i: (0, 0)),
-            pl.BlockSpec((s, _T_BLK), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((o, _T_BLK), lambda i: (0, i)),
-        interpret=interpret,
-    )(mat, shards)
-    return out[:, :length] if pad else out
-
-
-def gf_matmul_mxu(
-    matrix: np.ndarray, shards, interpret: "bool | None" = None
-) -> jax.Array:
-    """(o, s) GF matrix @ (s, length) u8 shards on the MXU (see module doc)."""
-    o, s = matrix.shape
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    shards = jnp.asarray(shards, dtype=jnp.uint8)
-    key = np.ascontiguousarray(matrix, dtype=np.uint8).tobytes()
-    return _mxu_matmul_jit(shards, key, o, s, interpret)
-
-
-# ---------------------------------------------------------------------------
-# One-kernel codec (fused1): single pass per direction
-# ---------------------------------------------------------------------------
 
 
 def _mxu_rows(matrix: np.ndarray, data, mat=None) -> list:
@@ -359,24 +225,22 @@ def _rows_fn(formulation: str):
     raise ValueError(f"unknown codec formulation: {formulation!r}")
 
 
-def _fused1_kernel_factory(
-    matrix: np.ndarray, tw: int, group: int, formulation: str
-):
+# ---------------------------------------------------------------------------
+# One-kernel codec: a single pass per direction (PUT encode+hash, GET
+# verify+reconstruct)
+# ---------------------------------------------------------------------------
+
+
+def _encode_kernel_factory(matrix: np.ndarray, tw: int, formulation: str):
     m, k = matrix.shape
     mxu = _rows_fn(formulation) is _mxu_rows
-    gpt = tw // group if group else 0
 
-    def impl(data_ref, parity_ref, hacc_ref, flags_ref, packed_ref,
-             kept_ref, mat):
+    def impl(data_ref, parity_ref, hacc_ref, mat):
         i = pl.program_id(1)
 
         @pl.when(i == 0)
         def _zero():
             hacc_ref[...] = jnp.zeros_like(hacc_ref)
-            if group:
-                packed_ref[...] = jnp.zeros_like(packed_ref)
-                for r in range(m):
-                    kept_ref[r] = 0
 
         data = data_ref[0]  # (k, tw)
         parity_rows = (
@@ -387,166 +251,77 @@ def _fused1_kernel_factory(
         )  # (n, tw)
         parity_ref[0] = all_rows[k:]
         hacc_ref[0] = hacc_ref[0] ^ _tile_hash_partials(all_rows, i, tw)
-        if not group:
-            return
-        # ---- occupancy flags + prefix pack of this tile's groups ----
-        # The packed row block is resident in VMEM for the whole w-tile
-        # loop of a stripe; an SMEM counter per parity row carries the
-        # next free group slot across the (sequential) grid steps.  Zero
-        # groups are never stored: the row starts zeroed, which makes
-        # the result bit-identical to the legacy argsort pack
-        # (codec_step.pack_nonzero_groups).
-        flags = []
-        for r in range(m):
-            flags.append(
-                [
-                    jnp.any(
-                        parity_rows[r][j * group : (j + 1) * group] != 0
-                    )
-                    for j in range(gpt)
-                ]
-            )
-        flags_ref[0] = jnp.stack(
-            [jnp.stack(fr).astype(jnp.uint32) for fr in flags]
-        )
-        for r in range(m):
-            off = kept_ref[r]
-            for j in range(gpt):
 
-                @pl.when(flags[r][j])
-                def _store(off=off, r=r, j=j):
-                    packed_ref[0, r, pl.ds(off * group, group)] = (
-                        parity_rows[r][j * group : (j + 1) * group]
-                    )
-
-                off = off + flags[r][j].astype(jnp.int32)
-            kept_ref[r] = off
-
-    if mxu and group:
-
-        def kernel(mat_ref, data_ref, parity_ref, hacc_ref, flags_ref,
-                   packed_ref, kept_ref):
-            impl(data_ref, parity_ref, hacc_ref, flags_ref, packed_ref,
-                 kept_ref, mat_ref[...])
-
-    elif mxu:
+    if mxu:
 
         def kernel(mat_ref, data_ref, parity_ref, hacc_ref):
-            impl(data_ref, parity_ref, hacc_ref, None, None, None,
-                 mat_ref[...])
-
-    elif group:
-
-        def kernel(data_ref, parity_ref, hacc_ref, flags_ref, packed_ref,
-                   kept_ref):
-            impl(data_ref, parity_ref, hacc_ref, flags_ref, packed_ref,
-                 kept_ref, None)
+            impl(data_ref, parity_ref, hacc_ref, mat_ref[...])
 
     else:
 
         def kernel(data_ref, parity_ref, hacc_ref):
-            impl(data_ref, parity_ref, hacc_ref, None, None, None, None)
+            impl(data_ref, parity_ref, hacc_ref, None)
 
     return kernel
 
 
-def _mxu_operand(matrix: np.ndarray, grid_dims: int = 2):
-    """(bit-matrix input list, matching in_spec list) for an MXU kernel.
-
-    ``grid_dims`` picks the index-map arity: 2 for the (batch, w-tile)
-    fused grids, 1 for the pipelined (batch,) grids whose w loop runs
-    inside the kernel."""
+def _mxu_operand(matrix: np.ndarray):
+    """(bit-matrix input list, matching in_spec list) for an MXU kernel
+    on the (batch, w-tile) fused grids."""
     o, s = matrix.shape
     key = np.ascontiguousarray(matrix, dtype=np.uint8).tobytes()
     mat = jnp.asarray(_bit_matrix(key, o, s))
-    index_map = (
-        (lambda b: (0, 0)) if grid_dims == 1 else (lambda b, i: (0, 0))
-    )
-    return [mat], [pl.BlockSpec((8 * o, 8 * s), index_map)]
+    return [mat], [pl.BlockSpec((8 * o, 8 * s), lambda b, i: (0, 0))]
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("parity_shards", "group", "formulation", "interpret"),
+    static_argnames=("parity_shards", "formulation", "interpret"),
 )
-def encode_pack_fused(
+def encode_hash_fused(
     words,
     parity_shards: int,
-    group: int = 0,
     formulation: str = "swar",
     interpret: bool = False,
 ):
-    """One-kernel PUT codec pass (fused1): parity + bitrot partials +
-    group-occupancy flags + nonzero-group prefix pack, ONE pallas_call.
+    """One-kernel PUT codec pass: (B, k, w) data words -> ((B, m, w)
+    parity words, (B, n, 8) un-finalized phash partials covering data
+    AND parity rows), ONE pallas_call.
 
-    words: (B, k, w) u32.  Returns (parity (B, m, w) u32, partials
-    (B, n, 8) u32 un-finalized, flags (B, m, g) u32 0/1, packed
-    (B, m, w) u32) with g = w // group.  group == 0 disables the pack
-    leg: flags has g == 0 and packed aliases parity.
-
-    Same grid as encode_hash_fused; the parity tile is additionally
-    screened per 256-word group and nonzero groups are appended to the
-    VMEM-resident packed row at the slot a per-row SMEM counter tracks
-    (TPU grids run sequentially, so the counter survives the w-tile
-    loop).  The raw parity plane is still emitted - the drain picks raw
-    vs packed by fill AFTER the fact - and each data byte is read from
-    HBM exactly once.
+    Grid is (batch, w-tiles); the hash-partial output block for a stripe
+    is revisited across its w-tiles and XOR-accumulated in VMEM, so HBM
+    traffic is exactly data-in + parity-out (data shards never
+    round-trip: the host already holds their bytes).  Finalize partials
+    with hash.finalize_partials(partials, shard_len_bytes).
     """
     B, k, w = words.shape
     m = parity_shards
     n = k + m
     if m <= 0:
-        raise ValueError("encode_pack_fused needs parity_shards >= 1")
+        raise ValueError("encode_hash_fused needs parity_shards >= 1")
     if w % _TW:
         raise ValueError(f"words per shard ({w}) must be a multiple of {_TW}")
-    if group and _TW % group:
-        raise ValueError(f"group must divide the {_TW}-word tile")
     matrix = gf.parity_matrix(k, m)
-    kernel = _fused1_kernel_factory(matrix, _TW, group, formulation)
+    kernel = _encode_kernel_factory(matrix, _TW, formulation)
     extra_in, extra_specs = (
         _mxu_operand(matrix) if formulation == "mxu" else ([], [])
     )
-    in_specs = extra_specs + [
-        pl.BlockSpec((1, k, _TW), lambda b, i: (b, 0, i))
-    ]
-    if not group:
-        parity, hacc = pl.pallas_call(
-            kernel,
-            out_shape=(
-                jax.ShapeDtypeStruct((B, m, w), jnp.uint32),
-                jax.ShapeDtypeStruct((B, n, 8), jnp.uint32),
-            ),
-            grid=(B, w // _TW),
-            in_specs=in_specs,
-            out_specs=(
-                pl.BlockSpec((1, m, _TW), lambda b, i: (b, 0, i)),
-                pl.BlockSpec((1, n, 8), lambda b, i: (b, 0, 0)),
-            ),
-            interpret=interpret,
-        )(*extra_in, words)
-        return parity, hacc, jnp.zeros((B, m, 0), jnp.uint32), parity
-    g = w // group
-    gpt = _TW // group
-    parity, hacc, flags, packed = pl.pallas_call(
+    parity, hacc = pl.pallas_call(
         kernel,
         out_shape=(
             jax.ShapeDtypeStruct((B, m, w), jnp.uint32),
             jax.ShapeDtypeStruct((B, n, 8), jnp.uint32),
-            jax.ShapeDtypeStruct((B, m, g), jnp.uint32),
-            jax.ShapeDtypeStruct((B, m, w), jnp.uint32),
         ),
         grid=(B, w // _TW),
-        in_specs=in_specs,
+        in_specs=extra_specs
+        + [pl.BlockSpec((1, k, _TW), lambda b, i: (b, 0, i))],
         out_specs=(
             pl.BlockSpec((1, m, _TW), lambda b, i: (b, 0, i)),
             pl.BlockSpec((1, n, 8), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, m, gpt), lambda b, i: (b, 0, i)),
-            pl.BlockSpec((1, m, w), lambda b, i: (b, 0, 0)),
         ),
-        scratch_shapes=[pltpu.SMEM((m,), jnp.int32)],
         interpret=interpret,
     )(*extra_in, words)
-    return parity, hacc, flags, packed
+    return parity, hacc
 
 
 def _vr_kernel_factory(
@@ -634,376 +409,6 @@ def verify_reconstruct_fused(
         out_specs=(
             pl.BlockSpec((1, k, _TW), lambda b, i: (b, 0, i)),
             pl.BlockSpec((1, n, 8), lambda b, i: (b, 0, 0)),
-        ),
-        interpret=interpret,
-    )(*extra_in, shards)
-    return data, hacc
-
-
-# ---------------------------------------------------------------------------
-# DMA-pipelined codec (MINIO_TPU_CODEC_OVERLAP=pipeline): manual
-# double-buffered HBM<->VMEM staging inside ONE pallas_call per direction
-# ---------------------------------------------------------------------------
-#
-# The fused1 kernels above lean on the blocked-grid pipeline Pallas
-# derives from their BlockSpecs; these variants restructure the same
-# math around explicit make_async_copy stages so the overlap is under
-# our control and visible: the shard plane stays in ANY/HBM memory
-# space, a 2-slot VMEM double buffer prefetches w-tile t+1 while tile t
-# computes, and the parity (or reconstructed-data) tile of t-1 drains
-# VMEM->HBM behind the compute - the three-deep sub-chunk pipeline of
-# ROADMAP item 1, one level below the host's batch double buffering.
-# Outputs are bit-identical to the fused kernels: the hash accumulator,
-# occupancy flags and the packed row stay VMEM-resident across the
-# in-kernel w loop exactly as the fused kernels carry them across grid
-# steps.
-
-
-def _pipe_encode_kernel_factory(
-    matrix: np.ndarray, tw: int, group: int, formulation: str, nt: int
-):
-    m, k = matrix.shape
-    mxu = _rows_fn(formulation) is _mxu_rows
-    gpt = tw // group if group else 0
-
-    def impl(data_hbm, parity_hbm, hacc_ref, flags_ref, packed_ref, mat):
-        # hoisted: program_id inside lax.cond/fori closures does not
-        # lower under interpret mode
-        b = pl.program_id(0)
-        hacc_ref[...] = jnp.zeros_like(hacc_ref)
-        if group:
-            packed_ref[...] = jnp.zeros_like(packed_ref)
-
-        def scoped(in_vmem, par_vmem, in_sem, par_sem, kept_ref):
-            def in_copy(t, slot):
-                return pltpu.make_async_copy(
-                    data_hbm.at[b, :, pl.ds(t * tw, tw)],
-                    in_vmem.at[slot],
-                    in_sem.at[slot],
-                )
-
-            def par_copy(t, slot):
-                return pltpu.make_async_copy(
-                    par_vmem.at[slot],
-                    parity_hbm.at[b, :, pl.ds(t * tw, tw)],
-                    par_sem.at[slot],
-                )
-
-            if group:
-                for r in range(m):
-                    kept_ref[r] = 0
-            in_copy(0, 0).start()  # warm-up: stage tile 0
-
-            def body(t, carry):
-                slot = jax.lax.rem(t, 2)
-                nslot = jax.lax.rem(t + 1, 2)
-
-                @pl.when(t + 1 < nt)
-                def _prefetch():
-                    in_copy(t + 1, nslot).start()
-
-                in_copy(t, slot).wait()
-                data = in_vmem[slot]
-                parity_rows = (
-                    _mxu_rows(matrix, data, mat)
-                    if mxu
-                    else _swar_rows(matrix, data)
-                )
-                all_rows = jnp.concatenate(
-                    [data, jnp.stack(parity_rows)], axis=0
-                )
-                par_vmem[slot] = all_rows[k:]
-                hacc_ref[0] = hacc_ref[0] ^ _tile_hash_partials(
-                    all_rows, t, tw
-                )
-                par_copy(t, slot).start()
-                if group:
-                    flags = [
-                        [
-                            jnp.any(
-                                parity_rows[r][
-                                    j * group : (j + 1) * group
-                                ]
-                                != 0
-                            )
-                            for j in range(gpt)
-                        ]
-                        for r in range(m)
-                    ]
-                    flags_ref[0, :, pl.ds(t * gpt, gpt)] = jnp.stack(
-                        [
-                            jnp.stack(fr).astype(jnp.uint32)
-                            for fr in flags
-                        ]
-                    )
-                    for r in range(m):
-                        off = kept_ref[r]
-                        for j in range(gpt):
-
-                            @pl.when(flags[r][j])
-                            def _store(off=off, r=r, j=j):
-                                packed_ref[
-                                    0, r, pl.ds(off * group, group)
-                                ] = parity_rows[r][
-                                    j * group : (j + 1) * group
-                                ]
-
-                            off = off + flags[r][j].astype(jnp.int32)
-                        kept_ref[r] = off
-
-                @pl.when(t >= 1)
-                def _drain_prev():
-                    par_copy(t - 1, nslot).wait()
-
-                return carry
-
-            jax.lax.fori_loop(0, nt, body, 0)
-            par_copy(nt - 1, (nt - 1) % 2).wait()
-
-        pl.run_scoped(
-            scoped,
-            in_vmem=pltpu.VMEM((2, k, tw), jnp.uint32),
-            par_vmem=pltpu.VMEM((2, m, tw), jnp.uint32),
-            in_sem=pltpu.SemaphoreType.DMA((2,)),
-            par_sem=pltpu.SemaphoreType.DMA((2,)),
-            kept_ref=pltpu.SMEM((max(m, 1),), jnp.int32),
-        )
-
-    if mxu and group:
-
-        def kernel(mat_ref, data_hbm, parity_hbm, hacc_ref, flags_ref,
-                   packed_ref):
-            impl(data_hbm, parity_hbm, hacc_ref, flags_ref, packed_ref,
-                 mat_ref[...])
-
-    elif mxu:
-
-        def kernel(mat_ref, data_hbm, parity_hbm, hacc_ref):
-            impl(data_hbm, parity_hbm, hacc_ref, None, None, mat_ref[...])
-
-    elif group:
-
-        def kernel(data_hbm, parity_hbm, hacc_ref, flags_ref, packed_ref):
-            impl(data_hbm, parity_hbm, hacc_ref, flags_ref, packed_ref,
-                 None)
-
-    else:
-
-        def kernel(data_hbm, parity_hbm, hacc_ref):
-            impl(data_hbm, parity_hbm, hacc_ref, None, None, None)
-
-    return kernel
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("parity_shards", "group", "formulation", "interpret"),
-)
-def encode_pack_pipelined(
-    words,
-    parity_shards: int,
-    group: int = 0,
-    formulation: str = "swar",
-    interpret: bool = False,
-):
-    """DMA-pipelined twin of encode_pack_fused: same outputs, same ONE
-    pallas_call, but the w loop runs inside the kernel with manual
-    double-buffered async copies so tile t+1's HBM->VMEM staging and
-    tile t-1's parity VMEM->HBM drain overlap tile t's compute.
-
-    Bit-identity contract (non-negotiable, tests/test_overlap.py):
-    parity, un-finalized hash partials and flags are element-identical
-    to encode_pack_fused; ``packed`` agrees on the compacted prefix
-    [0, kept_r*group) of every row — all the drain ever reads
-    (compress.unpack_nonzero_groups) — with zeros behind it.
-    """
-    B, k, w = words.shape
-    m = parity_shards
-    n = k + m
-    if m <= 0:
-        raise ValueError("encode_pack_pipelined needs parity_shards >= 1")
-    if w % _TW:
-        raise ValueError(f"words per shard ({w}) must be a multiple of {_TW}")
-    if group and _TW % group:
-        raise ValueError(f"group must divide the {_TW}-word tile")
-    nt = w // _TW
-    matrix = gf.parity_matrix(k, m)
-    kernel = _pipe_encode_kernel_factory(
-        matrix, _TW, group, formulation, nt
-    )
-    extra_in, extra_specs = (
-        _mxu_operand(matrix, grid_dims=1)
-        if formulation == "mxu"
-        else ([], [])
-    )
-    in_specs = extra_specs + [pl.BlockSpec(memory_space=pltpu.ANY)]
-    if not group:
-        parity, hacc = pl.pallas_call(
-            kernel,
-            out_shape=(
-                jax.ShapeDtypeStruct((B, m, w), jnp.uint32),
-                jax.ShapeDtypeStruct((B, n, 8), jnp.uint32),
-            ),
-            grid=(B,),
-            in_specs=in_specs,
-            out_specs=(
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec((1, n, 8), lambda b: (b, 0, 0)),
-            ),
-            interpret=interpret,
-        )(*extra_in, words)
-        return parity, hacc, jnp.zeros((B, m, 0), jnp.uint32), parity
-    g = w // group
-    parity, hacc, flags, packed = pl.pallas_call(
-        kernel,
-        out_shape=(
-            jax.ShapeDtypeStruct((B, m, w), jnp.uint32),
-            jax.ShapeDtypeStruct((B, n, 8), jnp.uint32),
-            jax.ShapeDtypeStruct((B, m, g), jnp.uint32),
-            jax.ShapeDtypeStruct((B, m, w), jnp.uint32),
-        ),
-        grid=(B,),
-        in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec((1, n, 8), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, m, g), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, m, w), lambda b: (b, 0, 0)),
-        ),
-        interpret=interpret,
-    )(*extra_in, words)
-    return parity, hacc, flags, packed
-
-
-def _pipe_vr_kernel_factory(
-    rmatrix: np.ndarray,
-    idx: tuple,
-    n: int,
-    tw: int,
-    formulation: str,
-    nt: int,
-):
-    mxu = _rows_fn(formulation) is _mxu_rows
-    k = rmatrix.shape[0]
-
-    def impl(sh_hbm, data_hbm, hacc_ref, mat):
-        b = pl.program_id(0)  # hoisted (see _pipe_encode_kernel_factory)
-        hacc_ref[...] = jnp.zeros_like(hacc_ref)
-
-        def scoped(in_vmem, out_vmem, in_sem, out_sem):
-            def in_copy(t, slot):
-                return pltpu.make_async_copy(
-                    sh_hbm.at[b, :, pl.ds(t * tw, tw)],
-                    in_vmem.at[slot],
-                    in_sem.at[slot],
-                )
-
-            def out_copy(t, slot):
-                return pltpu.make_async_copy(
-                    out_vmem.at[slot],
-                    data_hbm.at[b, :, pl.ds(t * tw, tw)],
-                    out_sem.at[slot],
-                )
-
-            in_copy(0, 0).start()
-
-            def body(t, carry):
-                slot = jax.lax.rem(t, 2)
-                nslot = jax.lax.rem(t + 1, 2)
-
-                @pl.when(t + 1 < nt)
-                def _prefetch():
-                    in_copy(t + 1, nslot).start()
-
-                in_copy(t, slot).wait()
-                sh = in_vmem[slot]  # (n, tw), rows AS READ
-                surv = jnp.stack([sh[j, :] for j in idx])
-                rows = (
-                    _mxu_rows(rmatrix, surv, mat)
-                    if mxu
-                    else _swar_rows(rmatrix, surv)
-                )
-                out_vmem[slot] = jnp.stack(rows)
-                hacc_ref[0] = hacc_ref[0] ^ _tile_hash_partials(sh, t, tw)
-                out_copy(t, slot).start()
-
-                @pl.when(t >= 1)
-                def _drain_prev():
-                    out_copy(t - 1, nslot).wait()
-
-                return carry
-
-            jax.lax.fori_loop(0, nt, body, 0)
-            out_copy(nt - 1, (nt - 1) % 2).wait()
-
-        pl.run_scoped(
-            scoped,
-            in_vmem=pltpu.VMEM((2, n, tw), jnp.uint32),
-            out_vmem=pltpu.VMEM((2, k, tw), jnp.uint32),
-            in_sem=pltpu.SemaphoreType.DMA((2,)),
-            out_sem=pltpu.SemaphoreType.DMA((2,)),
-        )
-
-    if mxu:
-
-        def kernel(mat_ref, sh_hbm, data_hbm, hacc_ref):
-            impl(sh_hbm, data_hbm, hacc_ref, mat_ref[...])
-
-    else:
-
-        def kernel(sh_hbm, data_hbm, hacc_ref):
-            impl(sh_hbm, data_hbm, hacc_ref, None)
-
-    return kernel
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "present_idx",
-        "data_shards",
-        "parity_shards",
-        "formulation",
-        "interpret",
-    ),
-)
-def verify_reconstruct_pipelined(
-    shards,
-    present_idx: tuple,
-    data_shards: int,
-    parity_shards: int,
-    formulation: str = "swar",
-    interpret: bool = False,
-):
-    """DMA-pipelined twin of verify_reconstruct_fused (same outputs,
-    one pallas_call): shard-tile staging, the verify+reconstruct
-    compute, and the reconstructed-data drain overlap per w-tile."""
-    B, n, w = shards.shape
-    k, m = data_shards, parity_shards
-    if n != k + m:
-        raise ValueError("shard rows must equal k + m")
-    idx = tuple(int(i) for i in present_idx)
-    if len(idx) != k:
-        raise ValueError(f"need exactly {k} survivor indices, got {len(idx)}")
-    if w % _TW:
-        raise ValueError(f"words per shard ({w}) must be a multiple of {_TW}")
-    nt = w // _TW
-    rm = gf.reconstruction_matrix(k, m, idx)
-    kernel = _pipe_vr_kernel_factory(rm, idx, n, _TW, formulation, nt)
-    extra_in, extra_specs = (
-        _mxu_operand(rm, grid_dims=1) if formulation == "mxu" else ([], [])
-    )
-    data, hacc = pl.pallas_call(
-        kernel,
-        out_shape=(
-            jax.ShapeDtypeStruct((B, k, w), jnp.uint32),
-            jax.ShapeDtypeStruct((B, n, 8), jnp.uint32),
-        ),
-        grid=(B,),
-        in_specs=extra_specs + [pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=(
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec((1, n, 8), lambda b: (b, 0, 0)),
         ),
         interpret=interpret,
     )(*extra_in, shards)

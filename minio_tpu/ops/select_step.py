@@ -11,7 +11,7 @@ Layout contract
   matches; the word view is a little-endian bitcast of 8 consecutive
   bytes, so lane ``i`` of word ``w`` is byte ``8*w + i``.  uint64
   requires x64 — every caller wraps these entry points in
-  ``jax.experimental.enable_x64()`` (the flag is part of the jit
+  ``jax.enable_x64()`` (the flag is part of the jit
   cache key, so the contract checker does the same).
 * Shifted lane flags come from static slices of a zero-padded word
   buffer (``W(k)`` = lanes of bytes at p+k), memoized and shared

@@ -18,9 +18,12 @@ TPU-native analogue maps those strategies onto a jax.sharding.Mesh:
 
 Shardings are not written here: every entry point declares its operand
 planes by name and `parallel.rules` resolves them (PARTITION_RULES) and
-picks the lowering (shard_map when the shard axis needs the XOR
-all-reduce, jit+NamedSharding for collective-free geometries) behind one
-compile cache keyed on device ids rather than Mesh identity.
+picks the lowering behind one compile cache keyed on device ids rather
+than Mesh identity.  Kernels whose per-device body can lower to a Pallas
+call (encode+hash, reconstruct, verify+reconstruct) register a
+shard_map body only: Mosaic kernels cannot be partitioned by XLA, so
+jit+NamedSharding is left to the pure-XLA kernels (digest, the demo
+encodes).
 
 All entry points work under jit/shard_map with static shapes.
 """
@@ -34,9 +37,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops import gf, rs
 from . import rules
-
-# compat alias: tests and older callers import the shim from here
-_shard_map = rules._shard_map
 
 
 def make_mesh(
@@ -69,7 +69,7 @@ _overlap_fallback_warned = False
 
 
 def warn_overlap_fallback() -> None:
-    """Warn once that MINIO_TPU_CODEC_OVERLAP degrades to "off" on mesh.
+    """Warn once that MINIO_TPU_CODEC_OVERLAP=async is ignored on mesh.
 
     The sub-chunk overlap pipeline double-buffers per-device staging
     arrays; the mesh entry points shard one whole stripe batch across
@@ -102,10 +102,7 @@ def xor_allreduce(x: jax.Array, axis_name: str) -> jax.Array:
     log2(n) ppermute steps (falls back to all-gather+fold for non powers
     of two).
     """
-    # lax.axis_size is missing on older releases; psum of a unit is the
-    # portable spelling and stays a static int under shard_map
-    _axis_size = getattr(jax.lax, "axis_size", None)
-    n = _axis_size(axis_name) if _axis_size else jax.lax.psum(1, axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     if n & (n - 1) == 0:
@@ -164,9 +161,10 @@ def _pad_batch(arr: np.ndarray, rows: int) -> np.ndarray:
 # ---------------------------------------------------------------------------
 #
 # Each kernel kind has up to two builders: `build_local` (per-device body
-# for shard_map; may use the XOR all-reduce over "shard") and
-# `build_global` (whole-array program for jit+NamedSharding; XLA
-# partitions it, valid because it needs no hand-rolled collective).
+# for shard_map; may use the XOR all-reduce over "shard", may call a
+# Pallas kernel) and `build_global` (whole-array program for
+# jit+NamedSharding; XLA partitions it, valid only for pure-XLA bodies
+# that need no hand-rolled collective).
 
 
 def _encode_local(mesh: Mesh, k: int, m: int):
@@ -211,9 +209,22 @@ def _encode_seq_global(mesh: Mesh, k: int, m: int):
 
 
 def _encode_hash_local(mesh: Mesh, k: int, m: int, shard_len: int):
-    from ..ops import hash as phash
+    from ..ops import codec_step, hash as phash
 
     shard_n = mesh.shape["shard"]
+    if shard_n == 1:
+
+        def whole(local: jax.Array):
+            # whole stripes are device-local on a stripe-only mesh: run
+            # the fused single-device step (static matrix -> the Pallas
+            # kernel on TPU) instead of the dynamic bit-walk
+            parity, digests = codec_step.encode_and_hash_words(
+                local, m, shard_len
+            )
+            return parity, digests[:, :k], digests[:, k:]
+
+        return whole
+
     col_blocks = _col_blocks(gf.parity_matrix(k, m), shard_n)
 
     def step(local: jax.Array):
@@ -231,24 +242,19 @@ def _encode_hash_local(mesh: Mesh, k: int, m: int, shard_len: int):
     return step
 
 
-def _encode_hash_global(mesh: Mesh, k: int, m: int, shard_len: int):
-    from ..ops import codec_step
-
-    def step(words: jax.Array):
-        # whole stripes are device-local on a stripe-only mesh: run the
-        # fused single-device kernel (static matrix -> Pallas on TPU)
-        # instead of the dynamic bit-walk
-        parity, digests = codec_step.encode_and_hash_words(
-            words, m, shard_len
-        )
-        return parity, digests[:, :k], digests[:, k:]
-
-    return step
-
-
 def _reconstruct_local(mesh: Mesh, k: int, m: int, idx: tuple[int, ...]):
     shard_n = mesh.shape["shard"]
     rm = gf.reconstruction_matrix(k, m, idx)  # (k, k) survivors -> data
+    if shard_n == 1:
+
+        def whole(local: jax.Array):
+            # local: (B_local, k, w) compacted survivor rows, whole
+            # stripes per device: the static product (the Pallas kernel
+            # on TPU) instead of the dynamic bit-walk
+            return rs._matmul_static_batch(local, rm)
+
+        return whole
+
     col_blocks = _col_blocks(rm, shard_n)
 
     def step(local: jax.Array):
@@ -263,16 +269,6 @@ def _reconstruct_local(mesh: Mesh, k: int, m: int, idx: tuple[int, ...]):
     return step
 
 
-def _reconstruct_global(mesh: Mesh, k: int, m: int, idx: tuple[int, ...]):
-    rm = gf.reconstruction_matrix(k, m, idx)
-
-    def step(surv: jax.Array):
-        # surv: (B, k, w) compacted survivor rows
-        return jax.vmap(lambda wds: rs._matmul_static(wds, rm))(surv)
-
-    return step
-
-
 def _digest_global(mesh: Mesh, shard_len: int):
     from ..ops import hash as phash
 
@@ -283,23 +279,32 @@ def _digest_global(mesh: Mesh, shard_len: int):
     return step
 
 
-def _verify_reconstruct_global(
+def _verify_reconstruct_local(
     mesh: Mesh,
     k: int,
     m: int,
     present: tuple[bool, ...],
     shard_len: int,
+    formulation: str = "swar",
+    use_pallas: bool = False,
+    interpret: bool = False,
 ):
     from ..ops import codec_step
 
     def step(words: jax.Array, digests: jax.Array):
-        # words: (B, n, w) quorum rows; stripes are device-local on the
-        # stripe axis, so the fused GET step (verify + reconstruct in
-        # one program) partitions with no collective.  The portable
-        # formulation keeps the program XLA-partitionable; the Pallas
-        # kernel stays on the single-device path.
+        # words: (B_local, n, w) quorum rows; whole stripes are
+        # device-local on the stripe axis (and replicated over "shard"),
+        # so the fused GET step runs per device with no collective
         return codec_step.verify_and_reconstruct_words(
-            words, digests, present, k, m, shard_len
+            words,
+            digests,
+            present,
+            k,
+            m,
+            shard_len,
+            formulation=formulation,
+            use_pallas=use_pallas,
+            interpret=interpret,
         )
 
     return step
@@ -323,7 +328,6 @@ rules.register_kernel(
     in_names=("stripe_words",),
     out_names=("parity_words", "data_digests", "parity_digests"),
     build_local=_encode_hash_local,
-    build_global=_encode_hash_global,
     # the data-words buffer is a fresh device_put per batch; donating it
     # lets XLA alias it into the parity output instead of copying
     donate_argnums=(0,),
@@ -333,7 +337,6 @@ rules.register_kernel(
     in_names=("survivor_words",),
     out_names=("recon_words",),
     build_local=_reconstruct_local,
-    build_global=_reconstruct_global,
 )
 rules.register_kernel(
     "mesh_digest",
@@ -345,7 +348,7 @@ rules.register_kernel(
     "mesh_verify_reconstruct",
     in_names=("quorum_words", "quorum_digests"),
     out_names=("recon_words", "ok_mask"),
-    build_global=_verify_reconstruct_global,
+    build_local=_verify_reconstruct_local,
 )
 
 
@@ -516,13 +519,18 @@ def mesh_verify_reconstruct(
     data_shards: int,
     parity_shards: int,
     shard_len: int,
+    formulation: str = "swar",
+    use_pallas: bool = False,
+    interpret: bool = False,
 ) -> tuple[np.ndarray, np.ndarray]:
     """Mesh-parallel fused GET step: verify digests + reconstruct, one program.
 
     words: (B, n, w) quorum rows, digests: (B, n, 8) expected phash256 -
     both sharded over "stripe".  Returns ((B, k, w) data, (B, n) ok mask).
     Padded stripes hash to garbage and come back ok=False; the [:B] slice
-    drops them before anyone looks.
+    drops them before anyone looks.  ``use_pallas``/``interpret``/
+    ``formulation`` are codec_step.pallas_dispatch's statics, threaded
+    to the per-device body.
     """
     k, m = data_shards, parity_shards
     B = words.shape[0]
@@ -537,6 +545,9 @@ def mesh_verify_reconstruct(
         m=m,
         present=tuple(bool(p) for p in present),
         shard_len=shard_len,
+        formulation=formulation,
+        use_pallas=use_pallas,
+        interpret=interpret,
     )
     dw = put_sharded(mesh, words, rules.spec_for("quorum_words"))
     dg = put_sharded(mesh, digests, rules.spec_for("quorum_digests"))

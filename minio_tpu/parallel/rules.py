@@ -11,12 +11,12 @@ planes map onto mesh axes.  Three layers live here:
   (enforced by lint rule MTPU109).
 
 * **Compile seam** (``register_kernel``/``compile_kernel``): a
-  Titanax-style memoized factory that picks the cheaper lowering per
-  geometry.  Kernels that need the XOR all-reduce register a
-  ``build_local`` (per-device body for shard_map); collective-free
-  geometries (shard axis == 1, or kernels that are embarrassingly
-  parallel) lower through plain ``jax.jit`` with ``NamedSharding``
-  in/out constraints instead.  The memo is keyed on the rules
+  Titanax-style memoized factory that picks the lowering per geometry.
+  Kernels that need the XOR all-reduce, or whose per-device body may be
+  a Pallas call (which XLA cannot partition), register a ``build_local``
+  (per-device body for shard_map); pure-XLA collective-free kernels
+  register a ``build_global`` and lower through plain ``jax.jit`` with
+  ``NamedSharding`` in/out constraints.  The memo is keyed on the rules
   fingerprint, the mesh's *device ids* and axis shape, and the static
   geometry - so a rebuilt ``Mesh`` over the same devices hits the cache
   instead of silently recompiling (``Mesh`` equality is
@@ -56,18 +56,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable"
 )
-
-# jax.shard_map only exists as a top-level alias in newer releases;
-# older ones (e.g. 0.4.x) ship it under jax.experimental.shard_map with
-# the replication check spelled `check_rep` instead of `check_vma`
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _exp_shard_map(
-            f, mesh, in_specs, out_specs, check_rep=check_vma
-        )
 
 
 # ---------------------------------------------------------------------------
@@ -295,7 +283,7 @@ def compile_kernel(
     elif mode == "shard_map":
         step = kd.build_local(mesh, **statics)
         fn = jax.jit(
-            _shard_map(
+            jax.shard_map(
                 step,
                 mesh=mesh,
                 in_specs=_single(in_specs),

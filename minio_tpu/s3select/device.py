@@ -398,7 +398,7 @@ class DeviceScan(vector.FastScan):
         """Screen host bytes on device -> candidate-row bytes, or None
         for a whole-chunk host fallback."""
         import jax
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         t0 = time.perf_counter()
         router = _scan_router()
@@ -508,7 +508,7 @@ class DeviceScan(vector.FastScan):
         PAD_BYTE to a BLOCK_BYTES multiple, newline-terminated at
         ``nbytes - 1``); only candidate rows are gathered D2H."""
         import jax
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         scr = self._screen
         if scr is None and not self._screen_failed:
@@ -563,7 +563,7 @@ def as_device_plane(chunks, total: int):
     with nbytes covering ``total`` plus a terminating newline."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     with enable_x64():
         flat = []

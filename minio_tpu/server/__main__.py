@@ -291,7 +291,44 @@ def run_gateway(args) -> int:
     return 0
 
 
+def resolve_codec_backend() -> None:
+    """Resolve the codec backend NOW and say once what it runs on.
+
+    Done at boot rather than on the first PUT so that a chip that is
+    missing, or held by another process (``jax.devices()`` raises),
+    stops the server here instead of surfacing as 500s - or worse, as
+    a quiet switch to the host codec.
+    """
+    from ..codec import backend as backend_mod
+    from ..utils import log
+
+    try:
+        info = backend_mod.backend_info()
+    except RuntimeError as e:
+        # what jax.devices() and get_backend raise; one line, exit 1
+        raise SystemExit(
+            f"minio-tpu: codec backend unavailable, not starting: {e}"
+        ) from e
+    fields = {
+        k: v
+        for k, v in info.items()
+        if k not in ("devices", "compile_cache")
+    }
+    if "compile_cache" in info:
+        fields["compile_cache_dir"] = info["compile_cache"]["dir"]
+    print(
+        "minio-tpu codec "
+        + " ".join(f"{k}={v!r}" for k, v in fields.items())
+    )
+    sys.stdout.flush()
+    log.logger("server").info("codec backend", extra=log.kv(**fields))
+
+
 def main(argv=None) -> int:
+    from ..utils import jaxenv
+
+    jaxenv.setup_compile_cache()  # before the first JAX use
+
     p = argparse.ArgumentParser(prog="minio-tpu server")
     p.add_argument(
         "zones",
@@ -337,6 +374,8 @@ def main(argv=None) -> int:
     # `server gateway s3 http://upstream:9000`
     if args.zones and args.zones[0] == "gateway":
         return run_gateway(args)
+
+    resolve_codec_backend()
 
     from ..cluster.endpoints import resolve_endpoints
     from ..storage.rest_server import StorageRESTServer

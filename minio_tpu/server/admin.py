@@ -125,9 +125,15 @@ class AdminAPI:
         # codec kernel telemetry dump (codec/telemetry.py): per-op
         # calls/bytes/device-seconds, batcher occupancy, stream totals
         if route == ("GET", "kernel-stats"):
+            from ..codec import backend as codec_backend
             from ..codec.telemetry import KERNEL_STATS
 
-            return 200, _json(KERNEL_STATS.snapshot())
+            return 200, _json(
+                dict(
+                    KERNEL_STATS.snapshot(),
+                    device=codec_backend.backend_info(),
+                )
+            )
         # profiling (admin-router.go:82): start on every node, download
         # collects per-node artifacts in one JSON document
         if route == ("POST", "profiling/start"):
@@ -606,6 +612,11 @@ class AdminAPI:
         from ..codec.telemetry import KERNEL_STATS
         from ..ops import codec_step
 
+        # what the codec runs on, as JAX reports it: backend, platform,
+        # device kind/count, versions, compile cache, per-device memory
+        from ..codec import backend as codec_backend
+
+        doc["device"] = codec_backend.backend_info()
         ksnap = KERNEL_STATS.snapshot()
         doc["codec_overlap"] = {
             "mode": codec_step.codec_overlap_mode(),

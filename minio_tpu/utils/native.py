@@ -5,12 +5,14 @@ native/build/.  This is the CPU fallback erasure backend - the counterpart
 of klauspost/reedsolomon's role in the reference - selected when no TPU is
 present or via MINIO_ERASURE_BACKEND=cpu (BASELINE.json north-star seam).
 
-The built artifact is fingerprinted by a hash of the source file plus the
-compiler flags (``libgf_cpu-<hash>.so``): editing csrc or changing flags
-yields a different path and therefore a rebuild, so a stale library body
-can never be silently loaded (an mtime check misses checkouts and clock
-skew, and the old ``AttributeError`` guard only caught *missing* symbols,
-not stale ones).
+The built artifact is fingerprinted by a hash of the source file, the
+compiler flags and the host CPU's feature flags (``libgf_cpu-<hash>.so``):
+editing csrc, changing flags or moving the tree to a different CPU yields
+a different path and therefore a rebuild, so a stale library body - or one
+whose ``-march=native`` resolved to instructions this host lacks - can
+never be silently loaded (an mtime check misses checkouts and clock skew,
+and the old ``AttributeError`` guard only caught *missing* symbols, not
+stale ones).
 
 The hot entry points are batch-native: ``encode_and_hash_cpu`` runs the
 fused single-pass encode+digest kernel over a whole (B, k, L) batch in ONE
@@ -65,12 +67,27 @@ def _flags(variant: str = "") -> "list[str]":
     return list(_CFLAGS)
 
 
+def _host_isa() -> str:
+    """What ``-march=native`` resolves against on this host: the CPU's
+    feature flags as the kernel reports them (x86 ``flags``, arm
+    ``Features``).  Part of the .so identity, because a library built
+    on an AVX-512 host and copied with the tree dies of an illegal
+    instruction on a host without it."""
+    with open("/proc/cpuinfo") as f:
+        for line in f:
+            if line.startswith(("flags", "Features")):
+                return " ".join(sorted(line.split(":", 1)[1].split()))
+    return ""
+
+
 def _fingerprint(variant: str = "") -> str:
-    """Hash of the source body + compiler flags: the .so identity."""
+    """Hash of the source body + compiler flags + host ISA: the .so
+    identity."""
     h = hashlib.sha256()
     with open(_SRC, "rb") as f:
         h.update(f.read())
     h.update(b"\x00" + " ".join(_flags(variant)).encode())
+    h.update(b"\x00" + _host_isa().encode())
     return h.hexdigest()[:16]
 
 

@@ -55,7 +55,9 @@ class S3Client:
         access_key: str = "minioadmin",
         secret_key: str = "minioadmin",
         region: str = "us-east-1",
+        timeout: float = 30,
     ):
+        self.timeout = timeout
         parsed = urllib.parse.urlsplit(endpoint)
         self.host = parsed.hostname
         self.tls = parsed.scheme == "https"
@@ -72,10 +74,10 @@ class S3Client:
             ctx.check_hostname = False
             ctx.verify_mode = ssl.CERT_NONE
             return http.client.HTTPSConnection(
-                self.host, self.port, timeout=30, context=ctx
+                self.host, self.port, timeout=self.timeout, context=ctx
             )
         return http.client.HTTPConnection(
-            self.host, self.port, timeout=30
+            self.host, self.port, timeout=self.timeout
         )
 
     def request(

@@ -213,6 +213,18 @@ def test_admin_healthinfo(server, root_client):
     assert node["state"] == "online"
     assert node["cpus"] >= 1
     assert node["mem_total_bytes"] > 0
+    # what the codec runs on, as JAX reports it - never guessed
+    import jax
+
+    dev = node["device"]
+    assert dev["backend"] == "tpu"
+    assert dev["platform"] == jax.devices()[0].platform == "cpu"
+    assert dev["device_kind"] == jax.devices()[0].device_kind
+    assert dev["device_count"] == len(jax.devices())
+    assert [d["id"] for d in dev["devices"]] == [
+        d.id for d in jax.devices()
+    ]
+    assert {"jax", "jaxlib", "libtpu", "compile_cache"} <= set(dev)
     drives = node["drives"]
     assert len(drives) == 4
     for d in drives:

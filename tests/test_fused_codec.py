@@ -1,19 +1,20 @@
-"""One-kernel codec (fused1) tests — ISSUE 14 tentpole.
+"""One-kernel codec (fused1) tests.
 
 Covers the single-pass PUT/GET codec kernels end to end:
 
 * bit-identity of ``encode_words_fused1`` (portable and Pallas
-  interpret, SWAR and MXU formulations) against the legacy three-pass
-  structure AND the CPU-native reference, across k/m geometries
-  including k=1, m=0, ragged tails, and all-zero groups;
+  interpret, SWAR and MXU formulations) against the legacy entry AND
+  the CPU-native reference, across k/m geometries including k=1, m=0,
+  ragged tails, and all-zero stripes;
 * bit-identity of ``verify_and_reconstruct_words`` against the
   verify_hashes_words -> reconstruct_words_batch pair, with bitrot;
-* pass accounting through the backend seam: fused1 PUT is exactly ONE
-  device pass where legacy takes three, fused1 GET is one pass where
-  legacy takes two (KERNEL_STATS ``device_passes``);
-* the digest-only contract: fused1 ``encode_digest_end`` materializes
-  digest bytes only, the parity plane (and its packed twin) crosses
-  D2H at drain — which launches zero kernels;
+* pass accounting through the backend seam: PUT is exactly ONE device
+  pass before the drain in both kernel modes, the drain launches
+  nothing with the transport screen off and the two screen passes with
+  it on; fused1 heal is one pass where legacy takes two (KERNEL_STATS
+  ``device_passes``, with the Pallas/portable split);
+* the digest-only contract: ``encode_digest_end`` materializes digest
+  bytes only, the parity plane crosses D2H at drain;
 * donation safety: ``donate_argnums`` on the data words never corrupts
   a retained reference or the host source array.
 """
@@ -50,52 +51,41 @@ def _stripes(batch, k, length, seed=0):
     return rng.integers(0, 256, (batch, k, length)).astype(np.uint8)
 
 
-def _legacy_encode(words, m, L, group):
-    """The legacy three-pass structure fused1 must match bit for bit."""
+def _legacy_encode(words, m, L):
+    """The legacy entry fused1 must match bit for bit."""
     parity, digests = codec_step.encode_and_hash_words(words, m, L)
-    if group:
-        flags, packed = codec_step.pack_nonzero_groups(parity, group)
-    else:
-        B, mm, w = np.asarray(parity).shape
-        flags = np.zeros((B, mm, 0), bool)
-        packed = parity
-    return (
-        np.asarray(parity),
-        np.asarray(digests),
-        np.asarray(flags),
-        np.asarray(packed),
-    )
+    return np.asarray(parity), np.asarray(digests)
 
 
 # -- bit-identity: fused1 vs legacy vs CPU native ------------------------
 
-# (k, m, L, group): k=1 degenerate, m=0 digest-only, ragged tail
-# (w=24 not a multiple of the Pallas tile), all covered.
+# (k, m, L): k=1 degenerate, m=0 digest-only, ragged tail (w=24 words,
+# under one 128-lane hash row) and a width with a ragged hash tail
+# behind full rows (w=136), all covered.
 _GEOMETRIES = [
-    (1, 1, 128, 8),
-    (2, 1, 128, 8),
-    (4, 2, 256, 8),
-    (8, 4, 256, 16),
-    (4, 0, 128, 8),
-    (4, 2, 96, 8),  # ragged: w=24 words
-    (4, 2, 128, 0),  # pack leg disabled
+    (1, 1, 128),
+    (2, 1, 128),
+    (4, 2, 256),
+    (8, 4, 256),
+    (4, 0, 128),
+    (4, 2, 96),
+    (4, 2, 544),
+    (4, 2, 2048),
 ]
 
 
-@pytest.mark.parametrize("k,m,L,group", _GEOMETRIES)
-def test_fused1_portable_matches_legacy_and_native(k, m, L, group):
+@pytest.mark.parametrize("k,m,L", _GEOMETRIES)
+def test_fused1_portable_matches_legacy_and_native(k, m, L):
     B = 3
     data = _stripes(B, k, L, seed=k * 31 + m)
-    data[1] = 0  # one all-zero stripe: every group flag must drop
+    data[1] = 0  # one all-zero stripe
     words = codec_step.host_bytes_to_words(data)
-    parity, digests, flags, packed = codec_step.encode_words_fused1(
-        jnp.asarray(words), m, L, group
+    parity, digests = codec_step.encode_words_fused1(
+        jnp.asarray(words), m, L
     )
-    lp, ld, lf, lpk = _legacy_encode(jnp.asarray(words), m, L, group)
+    lp, ld = _legacy_encode(jnp.asarray(words), m, L)
     np.testing.assert_array_equal(np.asarray(parity), lp)
     np.testing.assert_array_equal(np.asarray(digests), ld)
-    np.testing.assert_array_equal(np.asarray(flags), lf)
-    np.testing.assert_array_equal(np.asarray(packed), lpk)
     # CPU-native reference: gf.encode_ref parity + phash256_host digests
     pbytes = codec_step.host_words_to_bytes(np.asarray(parity))
     for b in range(B):
@@ -112,14 +102,14 @@ def test_fused1_portable_matches_legacy_and_native(k, m, L, group):
 @pytest.mark.parametrize("formulation", ["swar", "mxu"])
 def test_fused1_pallas_interpret_smoke(formulation):
     """Fast tier-1 smoke: one Pallas tile through the interpreter."""
-    k, m, L, group = 2, 1, 4 * rs_pallas._TW, 256
+    k, m, L = 2, 1, 4 * rs_pallas._TW
     data = _stripes(2, k, L, seed=9)
-    data[0, :, : L // 2] = 0  # sparse half: pack leg must engage
+    data[0, :, : L // 2] = 0
     words = jnp.asarray(codec_step.host_bytes_to_words(data))
     got = codec_step.encode_words_fused1(
-        words, m, L, group, formulation, True, True
+        words, m, L, formulation, True, True
     )
-    want = _legacy_encode(words, m, L, group)
+    want = _legacy_encode(words, m, L)
     for g, w_ in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), w_)
 
@@ -129,14 +119,14 @@ def test_fused1_pallas_interpret_smoke(formulation):
 @pytest.mark.parametrize("k,m", [(2, 1), (4, 2), (8, 4)])
 def test_fused1_pallas_interpret_full_grid(k, m, formulation):
     """The full FUSED_GRID geometry through the Pallas interpreter."""
-    L, group = 4 * rs_pallas._TW, 256
+    L = 4 * rs_pallas._TW
     data = _stripes(2, k, L, seed=k + m)
     data[1] = 0
     words = jnp.asarray(codec_step.host_bytes_to_words(data))
     got = codec_step.encode_words_fused1(
-        words, m, L, group, formulation, True, True
+        words, m, L, formulation, True, True
     )
-    want = _legacy_encode(words, m, L, group)
+    want = _legacy_encode(words, m, L)
     for g, w_ in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), w_)
 
@@ -202,9 +192,9 @@ def test_fused_get_below_quorum_raises():
 # -- the backend seam: pass accounting + digest-only contract ------------
 
 
-def _encode_passes(mode, monkeypatch, drain=True):
+def _encode_passes(mode, compress, monkeypatch):
     monkeypatch.setenv("MINIO_TPU_CODEC_KERNEL", mode)
-    monkeypatch.setenv("MINIO_TPU_DEVICE_COMPRESS", "on")
+    monkeypatch.setenv("MINIO_TPU_DEVICE_COMPRESS", compress)
     be = TpuBackend()
     data = _stripes(2, 4, 4096, seed=2)
     data[:, :, : 4096 // 2] = 0  # sparse: the pack pass must run
@@ -220,24 +210,58 @@ def _encode_passes(mode, monkeypatch, drain=True):
     return pre, post
 
 
-def test_fused1_put_is_one_device_pass(single_device, monkeypatch):
-    """The headline claim: 3 passes -> 1, bit-identical output."""
-    pre, post = _encode_passes("fused1", monkeypatch)
-    assert pre == {"encode_words_fused1": 1}
+_PUT_ENTRY = {
+    "fused1": "encode_words_fused1",
+    "legacy": "encode_and_hash_words_digest",
+}
+
+
+@pytest.mark.parametrize("mode", ["fused1", "legacy"])
+def test_put_is_one_device_pass_unscreened(single_device, monkeypatch, mode):
+    """Default transport (MINIO_TPU_DEVICE_COMPRESS=off): one launch per
+    batch, and the drain launches nothing."""
+    pre, post = _encode_passes(mode, "off", monkeypatch)
+    assert pre == {_PUT_ENTRY[mode]: 1}
     assert post == pre, f"drain launched kernels: {post}"
 
 
-def test_legacy_put_is_three_device_passes(single_device, monkeypatch):
-    pre, post = _encode_passes("legacy", monkeypatch)
-    assert pre == {"encode_and_hash_words_digest": 1}
+@pytest.mark.parametrize("mode", ["fused1", "legacy"])
+def test_put_screened_drain_is_two_more_passes(
+    single_device, monkeypatch, mode
+):
+    pre, post = _encode_passes(mode, "on", monkeypatch)
+    assert pre == {_PUT_ENTRY[mode]: 1}
     assert sum(post.values()) == 3, post
     assert post["group_flags"] == 1
     assert post["pack_nonzero_groups"] == 1
 
 
+def test_pallas_and_portable_passes_counted_apart(
+    single_device, monkeypatch
+):
+    """A tile-aligned batch under the interpreter counts as a Pallas
+    pass; a ragged one (and the XLA-only digest) as portable."""
+    monkeypatch.setenv("MINIO_TPU_CODEC_INTERPRET", "1")
+    be = TpuBackend()
+    KERNEL_STATS.reset()
+    aligned = _stripes(1, 2, 4 * rs_pallas._TW, seed=3)
+    ragged = _stripes(1, 2, 4096, seed=4)
+    for data in (aligned, ragged):
+        _, ref = be.encode_digest_end(be.encode_digest_begin(data, 1))
+        ref.release()
+    be.digest(aligned)
+    snap = KERNEL_STATS.snapshot()
+    assert snap["device_passes"]["encode_words_fused1"] == 2
+    assert snap["pallas_passes"] == {"encode_words_fused1": 1}
+    assert snap["portable_passes"] == {
+        "digest_words": 1,
+        "encode_words_fused1": 1,
+    }
+
+
 def test_fused1_digest_only_before_drain(single_device, monkeypatch):
     """MTPU107 contract at runtime: only digest bytes cross D2H at the
-    end seam; the parity plane (and packed twin) waits for drain."""
+    end seam; the parity plane waits for drain."""
     monkeypatch.setenv("MINIO_TPU_CODEC_KERNEL", "fused1")
     be = TpuBackend()
     data = _stripes(2, 4, 4096, seed=6)
@@ -281,7 +305,7 @@ def test_backend_reconstruct_and_verify_modes_agree(
     if mode == "fused1":
         assert passes.get("verify_and_reconstruct_words") == 1
     else:
-        assert passes.get("phash256_words_batched") == 1
+        assert passes.get("digest_words") == 1
         assert passes.get("reconstruct_words_batch", 0) >= 1
 
 
@@ -296,11 +320,11 @@ def test_donated_words_never_corrupt_retained_reference():
     words_np = codec_step.host_bytes_to_words(host)
     words = jnp.asarray(words_np)
     retained = words ^ 0  # independent buffer derived pre-donation
-    out1 = codec_step.encode_words_fused1(words, m, L, 8)
+    out1 = codec_step.encode_words_fused1(words, m, L)
     np.testing.assert_array_equal(np.asarray(retained), words_np)
     assert np.array_equal(words_np, codec_step.host_bytes_to_words(host))
     # repeat-call determinism: a fresh transfer reproduces everything
-    out2 = codec_step.encode_words_fused1(jnp.asarray(words_np), m, L, 8)
+    out2 = codec_step.encode_words_fused1(jnp.asarray(words_np), m, L)
     for a, b in zip(out1, out2):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 

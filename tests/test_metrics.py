@@ -413,6 +413,10 @@ def test_admin_kernel_stats_route(server, client):
     )
     # the parity-plane counters ride the same snapshot
     assert "d2h" in doc and "parity_cache" in doc
+    # so do the Pallas/portable split and the device the passes ran on
+    assert {"device_passes", "pallas_passes", "portable_passes"} <= set(doc)
+    assert doc["device"]["platform"] == "cpu"
+    assert doc["device"]["backend"] == "tpu"
 
 
 def test_admin_healthinfo_includes_api_stats(server, client):

@@ -368,6 +368,17 @@ def test_so_fingerprint_tracks_source_and_flags(tmp_path, monkeypatch):
     assert native._so_path() != p2
 
 
+def test_so_fingerprint_tracks_host_isa(monkeypatch):
+    """-march=native bakes the build host's ISA into the library; a tree
+    copied to a host with other CPU features must rebuild, not load."""
+    here = native._so_path()
+    assert native._host_isa(), "no CPU feature flags found on this host"
+    monkeypatch.setattr(
+        native, "_host_isa", lambda: "fpu sse2 ssse3"  # no avx anything
+    )
+    assert native._so_path() != here
+
+
 # ---------------------------------------------------------------------
 # ASan/UBSan-instrumented builds: the san variant compiles under its
 # own fingerprint, and a slow sweep replays the bit-identity and

@@ -1,15 +1,16 @@
-"""Device-side transfer/compute overlap (ISSUE 18): the sub-chunk DMA
-pipeline behind MINIO_TPU_CODEC_OVERLAP.
+"""Host-driven transfer/compute overlap: the sub-chunk pipeline behind
+MINIO_TPU_CODEC_OVERLAP=async.
 
-Bit-identity is the whole contract — ``pipeline`` (manual-DMA Pallas
-kernels, interpret mode here) and ``async`` (portable sub-chunked
-ping-pong twin) must produce byte-identical digests, parity and GET
-reconstructions vs ``off`` (the serialized PR 14 path, the bisection
-oracle) across the geometry grid: k=1, m=0, ragged tails, sub-chunk
-sizes that do not divide the stripe, and the S=1 degenerate fallback.
-Also covered: encode_digest_end idempotency for the sub-chunked handle,
+Bit-identity is the whole contract — ``async`` (sub-chunked ping-pong
+chain) must produce byte-identical digests, parity and GET
+reconstructions vs ``off`` (one pass per batch) across the geometry
+grid: k=1, m=0, ragged tails, sub-chunk sizes that do not divide the
+stripe, and the S<3 degenerate fallback.  Also covered:
+encode_digest_end idempotency for the sub-chunked handle,
 donation-aliasing of the ping-pong buffers, the staging-bytes ledger
-lifecycle, overlap-window telemetry, and the warn-once mesh fallback.
+lifecycle, overlap-window telemetry, and the warn-once mesh notice.
+(The manual-DMA ``pipeline`` kernels were removed: Mosaic refused
+them, tests/test_tpu_compile.py.)
 """
 
 import warnings
@@ -18,11 +19,7 @@ import numpy as np
 import pytest
 
 from minio_tpu.codec import backend as backend_mod
-from minio_tpu.codec.backend import (
-    TpuBackend,
-    _SubchunkParityRef,
-    reset_backend,
-)
+from minio_tpu.codec.backend import TpuBackend, reset_backend
 from minio_tpu.codec.erasure import subchunk_words
 from minio_tpu.codec.telemetry import KERNEL_STATS
 from minio_tpu.ops import codec_step, hash as phash
@@ -62,11 +59,8 @@ def _roundtrip(data, m, drop=()):
     return np.asarray(digests), np.asarray(parity), out, ok
 
 
-def _modes_equal(monkeypatch, mode, data, m, drop=(), sub_kb=None,
-                 interpret=False):
+def _modes_equal(monkeypatch, mode, data, m, drop=(), sub_kb=None):
     """Run ``off`` then ``mode``; assert every output bit-identical."""
-    if interpret:
-        monkeypatch.setenv("MINIO_TPU_CODEC_INTERPRET", "1")
     if sub_kb is not None:
         monkeypatch.setenv("MINIO_TPU_CODEC_SUBCHUNK_KB", str(sub_kb))
     monkeypatch.setenv("MINIO_TPU_CODEC_OVERLAP", "off")
@@ -126,6 +120,7 @@ def test_async_bit_identical_to_off(monkeypatch, B, k, m, L, sub_kb, drop):
 def test_async_sparse_parity_packs_per_chunk(monkeypatch):
     """A sparse tail keeps the packed-prefix drain leg bit-identical
     per chunk (the occupancy screen runs chunk-locally)."""
+    monkeypatch.setenv("MINIO_TPU_DEVICE_COMPRESS", "auto")
     data = _data(2, 4, 11264, seed=9)
     data[:, :, 2048:] = 0  # zero tail -> zero parity groups there
     _modes_equal(monkeypatch, "async", data, 2, drop=(1,), sub_kb=3)
@@ -142,38 +137,13 @@ def test_async_degenerate_small_batch_falls_back(monkeypatch):
     assert snap["device_passes"].get("encode_words_fused1") == 1
 
 
-# -- pipeline mode (manual-DMA Pallas kernels, interpret) ----------------
-
-
-def test_pipeline_bit_identical_smoke(monkeypatch):
-    """Tier-1 smoke: one 2-tile geometry through the manual-DMA kernels
-    under interpret; 1 launch per direction and overlap windows > 0."""
-    L = 4096 * 4 * 2  # 2 pipeline tiles per row
-    snap = _modes_equal(
-        monkeypatch, "pipeline", _data(1, 2, L), 1, drop=(0,),
-        interpret=True,
-    )
-    assert snap["device_passes"].get("encode_words_fused1") == 1
-    assert snap["device_passes"].get("verify_and_reconstruct_words") == 1
-    assert snap["overlap_windows"]["put"] == 1  # B * (nt - 1)
-    assert snap["overlap_windows"]["get"] == 1
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("B,k,m,nt,drop", [
-    (2, 4, 2, 3, (1, 4)),
-    (1, 1, 1, 2, (0,)),
-    (2, 2, 2, 2, (0, 1)),   # all-data loss, parity-only decode
-    (1, 8, 4, 2, (2,)),
-])
-def test_pipeline_bit_identical_grid(monkeypatch, B, k, m, nt, drop):
-    L = 4096 * 4 * nt
-    snap = _modes_equal(
-        monkeypatch, "pipeline", _data(B, k, L, seed=nt), m, drop=drop,
-        interpret=True,
-    )
-    assert snap["overlap_windows"]["put"] == B * (nt - 1)
-    assert snap["overlap_windows"]["get"] == B * (nt - 1)
+def test_removed_pipeline_value_resolves_to_off(monkeypatch):
+    """``pipeline`` is no longer a value of the knob: like any unknown
+    value it resolves to the default, never to another kernel."""
+    monkeypatch.setenv("MINIO_TPU_CODEC_OVERLAP", "pipeline")
+    assert codec_step.codec_overlap_mode() == "off"
+    monkeypatch.delenv("MINIO_TPU_CODEC_OVERLAP")
+    assert codec_step.codec_overlap_mode() == "off"
 
 
 # -- handle lifecycle ----------------------------------------------------
@@ -185,7 +155,7 @@ def test_subchunk_encode_end_idempotent(monkeypatch):
     be = TpuBackend()
     h = be.encode_digest_begin(_data(2, 4, 4096), 2)
     digests, ref = be.encode_digest_end(h)
-    assert isinstance(ref, _SubchunkParityRef)
+    assert len(ref._planes) >= 3  # held as the sub-chunk arrays
     digests2, ref2 = be.encode_digest_end(h)
     assert digests2 is digests and ref2 is ref
     parity = ref.drain()
@@ -206,17 +176,15 @@ def test_subchunk_release_without_drain(monkeypatch):
     assert cache.stats()["occupancy_bytes"] == 0
 
 
-def test_subchunk_ref_accounts_packed_twin(monkeypatch):
-    """The cache must see BOTH device planes (parity + packed) of every
-    chunk — the honest doubled footprint of the fused pack leg."""
+def test_subchunk_ref_accounts_every_chunk(monkeypatch):
+    """The cache must see the whole parity plane, summed over chunks."""
     monkeypatch.setenv("MINIO_TPU_CODEC_OVERLAP", "async")
     monkeypatch.setenv("MINIO_TPU_CODEC_SUBCHUNK_KB", "1")
     B, k, m, L = 2, 4, 2, 4096
     be = TpuBackend()
     h = be.encode_digest_begin(_data(B, k, L), m)
     _, ref = be.encode_digest_end(h)
-    plane = B * m * L  # parity words * 4 bytes, summed over chunks
-    assert ref.nbytes == plane * 2  # pack leg on: parity + packed
+    assert ref.nbytes == B * m * L
     ref.release()
 
 
@@ -231,7 +199,7 @@ def test_staging_ledger_lifecycle(monkeypatch):
     B, k, L = 2, 4, 4096
     be = TpuBackend()
     h = be.encode_digest_begin(_data(B, k, L), 2)
-    cw = subchunk_words(L // 4, 256)
+    cw = subchunk_words(L // 4, 8)
     assert backend_mod._staging_bytes == 2 * B * k * cw * 4
     assert device_budget().usage("codec_staging") == (
         backend_mod._staging_bytes
@@ -259,8 +227,8 @@ def test_subchunk_ping_pong_donation_aliasing():
     parity_c = []
     for i, off in enumerate(range(0, w, cw)):
         chunk = jnp.asarray(words[:, :, off:off + cw])
-        p_c, acc, _, _ = codec_step.encode_subchunk_words(
-            chunk, acc, np.uint32(off), m, L, group=0,
+        p_c, acc = codec_step.encode_subchunk_words(
+            chunk, acc, np.uint32(off), m, L,
             finalize=i == (w // cw) - 1,
         )
         parity_c.append(p_c)

@@ -30,7 +30,7 @@ def test_xor_allreduce_pow2(axis_n):
     vals = np.random.default_rng(axis_n).integers(
         0, 2**32, (axis_n, 16), dtype=np.uint32
     )
-    fn = pm._shard_map(
+    fn = jax.shard_map(
         lambda v: pm.xor_allreduce(v, "x"),
         mesh=mesh,
         in_specs=P("x", None),

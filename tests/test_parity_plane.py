@@ -202,8 +202,9 @@ def test_tpu_digest_path_with_transport_compression(
     np.testing.assert_array_equal(ref.drain(), parity)
 
 
-def test_tpu_digest_path_all_zero_plane(single_device):
+def test_tpu_digest_path_all_zero_plane(single_device, monkeypatch):
     """Degenerate screen result: zero parity never crosses the bus."""
+    monkeypatch.setenv("MINIO_TPU_DEVICE_COMPRESS", "auto")
     be = TpuBackend()
     data = np.zeros((1, 4, 2048), dtype=np.uint8)
     KERNEL_STATS.reset()
