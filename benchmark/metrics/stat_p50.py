@@ -1,0 +1,6 @@
+"""Median STAT latency in the window, ms."""
+import readers
+
+
+def read(run):
+    return readers.median_ms(run, "STAT")
