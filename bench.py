@@ -2334,14 +2334,6 @@ def main() -> None:
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument(
-        "--no-instrument",
-        action="store_true",
-        help="skip the codec telemetry wrapper (codec/telemetry.py) so "
-        "the benchmark measures the bare backend; detail.kernel_stats "
-        "then reflects only what ran before the flag took effect "
-        "(i.e. nothing)",
-    )
-    ap.add_argument(
         "--codec-micro",
         action="store_true",
         help="run ONLY the fused-vs-split CPU encode+digest microbench "
@@ -2416,12 +2408,6 @@ def main() -> None:
     if args.put_readback:
         print(json.dumps(bench_put_readback(), indent=1))
         return
-    if args.no_instrument:
-        os.environ["MINIO_TPU_NO_INSTRUMENT"] = "1"
-        from minio_tpu.codec import backend as backend_mod
-
-        backend_mod.reset_backend()  # drop any already-wrapped singleton
-
     cpu = bench_cpu_baseline()
     # e2e config #2 (BASELINE.md): through the object layer.  Two codec
     # variants: the native CPU codec isolates the control-plane + disk

@@ -140,6 +140,9 @@ DRAIN_SEAMS = {
         "encode_digest_end",
         "drain",
         "_drain_d2h",
+        # the one read-back helper every seam above goes through:
+        # block_until_ready (seam_kernel_wait) then np.asarray (seam_d2h)
+        "_host_readback",
         # GET side: decode IS the sanctioned D2H — reconstructed rows
         # leave the device here and nowhere else
         "reconstruct",

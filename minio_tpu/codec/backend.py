@@ -24,6 +24,7 @@ import threading
 import numpy as np
 
 from ..ops import gf, hash as phash
+from ..utils import spans
 from ..utils.log import kv, logger
 
 _log = logger("codec.backend")
@@ -38,6 +39,33 @@ def _record_d2h(plane: str, nbytes: int) -> None:
     from .telemetry import KERNEL_STATS
 
     KERNEL_STATS.record_d2h(plane, int(nbytes))
+
+
+def _host_readback(array, plane: "str | None"):
+    """The seam's one device->host read-back, split where the thread
+    waits for two different things: ``seam_kernel_wait`` is
+    ``block_until_ready`` on the result (the kernel, and the H2D before
+    it), ``seam_d2h`` the copy to the host alone.  The same thread waits
+    the same time as the bare ``np.asarray`` did.  ``plane`` (data |
+    parity) accounts the bytes; None where the caller accounts several
+    read-backs as one transfer."""
+    ready = getattr(array, "block_until_ready", None)
+    if ready is not None:  # a mesh path hands host arrays through
+        with spans.span(spans.SEAM_KERNEL_WAIT):
+            ready()
+    with spans.span(spans.SEAM_D2H):
+        host = np.asarray(array)
+    if plane is not None:
+        _record_d2h(plane, host.nbytes)
+    return host
+
+
+def _launch() -> "spans.span":
+    """``with _launch():`` round a jitted call (it returns at enqueue):
+    the ``seam_launch`` span.  The stamp the batcher left at its flush is
+    closed here, so ``flush_to_launch`` ends where the launch begins."""
+    spans.picked_up()
+    return spans.span(spans.SEAM_LAUNCH)
 
 
 def _record_pass(kernel: str, pallas: bool = False) -> None:
@@ -269,7 +297,7 @@ class _DeviceParityRef:
         g = w // G if w % G == 0 else 0
         if mode != "off" and g >= 2:
             _record_pass("group_flags")
-            flags = np.asarray(codec_step.group_flags(parity_w, G))
+            flags = _host_readback(codec_step.group_flags(parity_w, G), None)
             kept = int(flags.sum(axis=-1).max()) if flags.size else 0
             if kept == 0:
                 _record_d2h("parity", flags.nbytes)
@@ -283,14 +311,13 @@ class _DeviceParityRef:
                 _record_pass("pack_nonzero_groups")
                 _f, packed = codec_step.pack_nonzero_groups(parity_w, G)
                 keep = compmod.prefix_keep(kept, g)
-                prefix = np.asarray(packed[..., : keep * G])
+                prefix = _host_readback(packed[..., : keep * G], None)
                 _record_d2h("parity", flags.nbytes + prefix.nbytes)
                 words = compmod.unpack_nonzero_groups(
                     flags, prefix, G, w
                 )
                 return codec_step.host_words_to_bytes(words)
-        parity = np.asarray(parity_w)
-        _record_d2h("parity", parity.nbytes)
+        parity = _host_readback(parity_w, "parity")
         return codec_step.host_words_to_bytes(parity)
 
 
@@ -560,6 +587,18 @@ class TpuBackend(CodecBackend):
         device = (prules.current_placement() or self._base_devices())[0]
         return jax.device_put(host, device)
 
+    def _stage(self, host_bytes: np.ndarray):
+        """``seam_stage``: bytes -> words and the device_put, up to the
+        jitted call."""
+        from ..ops import codec_step
+
+        with spans.span(spans.SEAM_STAGE):
+            words = self._to_device(
+                codec_step.host_bytes_to_words(host_bytes)
+            )
+        _record_h2d("data", words.nbytes)
+        return words
+
     def placement_router(self):
         devices = self._base_devices()
         if len(devices) <= 1 or os.environ.get("MINIO_MESH", "1") == "0":
@@ -590,10 +629,12 @@ class TpuBackend(CodecBackend):
             # encode/write overlap survives on the mesh path too
             from ..parallel import mesh as pm
 
-            h = pm.mesh_encode_hash_begin(
-                mesh, codec_step.host_bytes_to_words(data),
-                parity_shards, L,
-            )
+            # on a mesh the staging happens inside the mesh call
+            with _launch():
+                h = pm.mesh_encode_hash_begin(
+                    mesh, codec_step.host_bytes_to_words(data),
+                    parity_shards, L,
+                )
             _record_h2d("data", data.nbytes)
             # k-sharded meshes run the dynamic XLA bit-walk + all-reduce
             _record_pass(
@@ -601,11 +642,11 @@ class TpuBackend(CodecBackend):
                 pallas=compiled and mesh.shape["shard"] == 1,
             )
             return _AsyncHandle("async-mesh", h)
-        words = self._to_device(codec_step.host_bytes_to_words(data))
-        _record_h2d("data", words.nbytes)
-        parity_w, digests = codec_step.encode_and_hash_words(
-            words, parity_shards, L
-        )
+        words = self._stage(data)
+        with _launch():
+            parity_w, digests = codec_step.encode_and_hash_words(
+                words, parity_shards, L
+            )
         _record_pass("encode_and_hash_words", pallas=compiled)
         return _AsyncHandle("async", (parity_w, digests))
 
@@ -626,10 +667,8 @@ class TpuBackend(CodecBackend):
             raise ValueError(
                 f"encode_end: unknown handle kind {handle.kind!r}"
             )
-        parity_w = np.asarray(parity_w)
-        digests = np.asarray(digests)
-        _record_d2h("parity", parity_w.nbytes)
-        _record_d2h("data", digests.nbytes)
+        parity_w = _host_readback(parity_w, "parity")
+        digests = _host_readback(digests, "data")
         result = codec_step.host_words_to_bytes(parity_w), digests
         handle.result = result
         handle.consumed = True
@@ -659,13 +698,14 @@ class TpuBackend(CodecBackend):
             return _AsyncHandle(
                 "digest-eager", self.encode_begin(data, parity_shards)
             )
-        words_h = codec_step.host_bytes_to_words(data)
         if codec_step.codec_kernel_mode() != "fused1":
-            words = self._to_device(words_h)
-            _record_h2d("data", words.nbytes)
-            parity_w, digests = codec_step.encode_and_hash_words_digest(
-                words, parity_shards, L
-            )
+            words = self._stage(data)
+            with _launch():
+                parity_w, digests = (
+                    codec_step.encode_and_hash_words_digest(
+                        words, parity_shards, L
+                    )
+                )
             _record_pass(
                 "encode_and_hash_words_digest",
                 pallas=parity_shards > 0
@@ -673,21 +713,23 @@ class TpuBackend(CodecBackend):
             )
             return _AsyncHandle("digest", (parity_w, digests))
         if codec_step.codec_overlap_mode() == "async":
-            handle = self._encode_subchunk_begin(words_h, parity_shards, L)
+            handle = self._encode_subchunk_begin(
+                codec_step.host_bytes_to_words(data), parity_shards, L
+            )
             if handle is not None:
                 return handle
             # batch too small for S >= 3 sub-chunks: serialized path
         use_pallas, interpret = codec_step.pallas_dispatch(L // 4)
-        words = self._to_device(words_h)
-        _record_h2d("data", words.nbytes)
-        parity_w, digests = codec_step.encode_words_fused1(
-            words,
-            parity_shards,
-            L,
-            formulation=codec_step.codec_formulation(),
-            use_pallas=use_pallas,
-            interpret=interpret,
-        )
+        words = self._stage(data)
+        with _launch():
+            parity_w, digests = codec_step.encode_words_fused1(
+                words,
+                parity_shards,
+                L,
+                formulation=codec_step.codec_formulation(),
+                use_pallas=use_pallas,
+                interpret=interpret,
+            )
         _record_pass("encode_words_fused1", pallas=use_pallas)
         return _AsyncHandle("digest", (parity_w, digests))
 
@@ -723,18 +765,20 @@ class TpuBackend(CodecBackend):
             parity_c = []
             for i, off in enumerate(offs):
                 end = min(off + cw, w)
-                chunk = self._to_device(
-                    np.ascontiguousarray(words_h[:, :, off:end])
-                )
+                with spans.span(spans.SEAM_STAGE):
+                    chunk = self._to_device(
+                        np.ascontiguousarray(words_h[:, :, off:end])
+                    )
                 _record_h2d("data", (end - off) * B * k * 4)
-                p_c, acc = codec_step.encode_subchunk_words(
-                    chunk,
-                    acc,
-                    np.uint32(off),
-                    m,
-                    shard_len,
-                    finalize=i == len(offs) - 1,
-                )
+                with _launch():
+                    p_c, acc = codec_step.encode_subchunk_words(
+                        chunk,
+                        acc,
+                        np.uint32(off),
+                        m,
+                        shard_len,
+                        finalize=i == len(offs) - 1,
+                    )
                 # the chunk's parity product is rs._matmul_static
                 _record_pass(
                     "encode_subchunk_words",
@@ -773,8 +817,7 @@ class TpuBackend(CodecBackend):
             # throws (device reset mid-drain): an exception here must
             # not strand staging-ledger bytes for the process lifetime
             try:
-                digests = np.asarray(digests_d)
-                _record_d2h("data", digests.nbytes)
+                digests = _host_readback(digests_d, "data")
             finally:
                 _stage_release(reserved)
             result = (
@@ -785,8 +828,7 @@ class TpuBackend(CodecBackend):
             # digests are the ONLY eager readback (MTPU107); parity
             # stays device-resident behind the ref
             parity_w, digests_d = handle.payload
-            digests = np.asarray(digests_d)
-            _record_d2h("data", digests.nbytes)
+            digests = _host_readback(digests_d, "data")
             result = (
                 digests,
                 _DeviceParityRef(parity_plane_cache(), [parity_w]),
@@ -814,13 +856,14 @@ class TpuBackend(CodecBackend):
         if mesh is not None:
             from ..parallel import mesh as pm
 
-            dw = pm.mesh_reconstruct(
-                mesh,
-                codec_step.host_bytes_to_words(shards),
-                tuple(bool(b) for b in present),
-                data_shards,
-                parity_shards,
-            )
+            with _launch():  # staging, kernel and read-back are inside
+                dw = pm.mesh_reconstruct(
+                    mesh,
+                    codec_step.host_bytes_to_words(shards),
+                    tuple(bool(b) for b in present),
+                    data_shards,
+                    parity_shards,
+                )
             _record_pass(
                 "mesh_reconstruct",
                 pallas=rs.lowering_for_tpu() and mesh.shape["shard"] == 1,
@@ -829,16 +872,17 @@ class TpuBackend(CodecBackend):
             _record_h2d("data", dw.nbytes)
             _record_d2h("data", dw.nbytes)
             return codec_step.host_words_to_bytes(dw)
-        words = self._to_device(codec_step.host_bytes_to_words(shards))
-        _record_h2d("data", words.nbytes)
-        dw = codec_step.reconstruct_words_batch(
-            words, tuple(bool(b) for b in present), data_shards, parity_shards
-        )
+        words = self._stage(shards)
+        with _launch():
+            dw = codec_step.reconstruct_words_batch(
+                words,
+                tuple(bool(b) for b in present),
+                data_shards,
+                parity_shards,
+            )
         # rs._matmul_static pads any width up to the Pallas tile
         _record_pass("reconstruct_words_batch", pallas=rs.lowering_for_tpu())
-        dw = np.asarray(dw)
-        _record_d2h("data", dw.nbytes)
-        return codec_step.host_words_to_bytes(dw)
+        return codec_step.host_words_to_bytes(_host_readback(dw, "data"))
 
     def reconstruct_and_verify(
         self, shards, digests, present, data_shards, parity_shards
@@ -871,18 +915,19 @@ class TpuBackend(CodecBackend):
 
             if overlap != "off":
                 pm.warn_overlap_fallback()
-            got = pm.mesh_verify_reconstruct(
-                mesh,
-                words,
-                np.asarray(digests),
-                present_t,
-                data_shards,
-                parity_shards,
-                L,
-                formulation=codec_step.codec_formulation(),
-                use_pallas=use_pallas,
-                interpret=interpret,
-            )
+            with _launch():  # staging, kernel and read-back are inside
+                got = pm.mesh_verify_reconstruct(
+                    mesh,
+                    words,
+                    np.asarray(digests),
+                    present_t,
+                    data_shards,
+                    parity_shards,
+                    L,
+                    formulation=codec_step.codec_formulation(),
+                    use_pallas=use_pallas,
+                    interpret=interpret,
+                )
             _record_pass("mesh_verify_reconstruct", pallas=use_pallas)
             _record_h2d("data", words.nbytes)
             _record_d2h("data", got[0].nbytes)
@@ -893,23 +938,25 @@ class TpuBackend(CodecBackend):
         if got is not None:
             dw, ok = got
         else:
-            words_d = self._to_device(words)
+            with spans.span(spans.SEAM_STAGE):
+                words_d = self._to_device(words)
+                digests_d = self._to_device(np.asarray(digests))
             _record_h2d("data", words_d.nbytes)
-            dw_d, ok_d = codec_step.verify_and_reconstruct_words(
-                words_d,
-                self._to_device(np.asarray(digests)),
-                present_t,
-                data_shards,
-                parity_shards,
-                L,
-                formulation=codec_step.codec_formulation(),
-                use_pallas=use_pallas,
-                interpret=interpret,
-            )
+            with _launch():
+                dw_d, ok_d = codec_step.verify_and_reconstruct_words(
+                    words_d,
+                    digests_d,
+                    present_t,
+                    data_shards,
+                    parity_shards,
+                    L,
+                    formulation=codec_step.codec_formulation(),
+                    use_pallas=use_pallas,
+                    interpret=interpret,
+                )
             _record_pass("verify_and_reconstruct_words", pallas=use_pallas)
-            dw = np.asarray(dw_d)
-            ok = np.asarray(ok_d)
-            _record_d2h("data", dw.nbytes)
+            dw = _host_readback(dw_d, "data")
+            ok = _host_readback(ok_d, None)
         data = codec_step.host_words_to_bytes(dw)
         surv = np.nonzero(pres)[0][:data_shards]
         bad = ~ok[:, surv].all(axis=1)
@@ -953,23 +1000,25 @@ class TpuBackend(CodecBackend):
             ok_d = None
             for i, off in enumerate(offs):
                 end = min(off + cw, w)
-                chunk = self._to_device(
-                    np.ascontiguousarray(words_h[:, :, off:end])
-                )
-                _record_h2d("data", (end - off) * B * n * 4)
-                d_c, acc, ok_d = (
-                    codec_step.verify_reconstruct_subchunk_words(
-                        chunk,
-                        acc,
-                        digests_d,
-                        np.uint32(off),
-                        present,
-                        data_shards,
-                        parity_shards,
-                        shard_len,
-                        finalize=i == len(offs) - 1,
+                with spans.span(spans.SEAM_STAGE):
+                    chunk = self._to_device(
+                        np.ascontiguousarray(words_h[:, :, off:end])
                     )
-                )
+                _record_h2d("data", (end - off) * B * n * 4)
+                with _launch():
+                    d_c, acc, ok_d = (
+                        codec_step.verify_reconstruct_subchunk_words(
+                            chunk,
+                            acc,
+                            digests_d,
+                            np.uint32(off),
+                            present,
+                            data_shards,
+                            parity_shards,
+                            shard_len,
+                            finalize=i == len(offs) - 1,
+                        )
+                    )
                 _record_pass(
                     "verify_reconstruct_subchunk_words",
                     pallas=rs.lowering_for_tpu(),
@@ -977,14 +1026,10 @@ class TpuBackend(CodecBackend):
                 if prev is not None:
                     # drain chunk i-1 while chunk i computes: this is
                     # the D2H leg of the three-deep overlap
-                    part = np.asarray(prev)
-                    _record_d2h("data", part.nbytes)
-                    parts.append(part)
+                    parts.append(_host_readback(prev, "data"))
                 prev = d_c
-            part = np.asarray(prev)
-            _record_d2h("data", part.nbytes)
-            parts.append(part)
-            ok = np.asarray(ok_d)
+            parts.append(_host_readback(prev, "data"))
+            ok = _host_readback(ok_d, None)
             _record_overlap("get", len(offs) - 1)
         finally:
             _stage_release(reserved)
@@ -1002,18 +1047,17 @@ class TpuBackend(CodecBackend):
             words = codec_step.host_bytes_to_words(shards)
             flat = words.reshape(B * n, -1)
             _record_pass("mesh_digest")
-            got = pm.mesh_digest(mesh, flat, L).reshape(B, n, 8)
+            with _launch():  # staging, kernel and read-back are inside
+                got = pm.mesh_digest(mesh, flat, L).reshape(B, n, 8)
             _record_h2d("data", words.nbytes)
             _record_d2h("data", got.nbytes)
             return got
-        words = self._to_device(codec_step.host_bytes_to_words(shards))
-        _record_h2d("data", words.nbytes)
+        words = self._stage(shards)
         # the healthy-read digest has no Pallas kernel: one XLA pass
-        got = codec_step.digest_words(words, L)
+        with _launch():
+            got = codec_step.digest_words(words, L)
         _record_pass("digest_words")
-        got = np.asarray(got)
-        _record_d2h("data", got.nbytes)
-        return got
+        return _host_readback(got, "data")
 
 
 class CpuBackend(CodecBackend):

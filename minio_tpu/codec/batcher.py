@@ -28,14 +28,15 @@ import time
 
 import numpy as np
 
+from ..utils import spans
 from .backend import CodecBackend
 from .telemetry import KERNEL_STATS
 
 
 class _Job:
     __slots__ = (
-        "op", "key", "arrays", "result", "error", "done", "created",
-        "client", "ended",
+        "op", "key", "arrays", "result", "error", "done", "created_ns",
+        "client", "ended", "ctx",
     )
 
     def __init__(self, op: str, key: tuple, arrays: tuple):
@@ -45,7 +46,9 @@ class _Job:
         self.result = None
         self.error: "BaseException | None" = None
         self.done = threading.Event()
-        self.created = time.monotonic()
+        self.created_ns = spans.now()
+        # the submitter's request, restored on the thread that flushes
+        self.ctx = spans.capture()
         self.client = threading.get_ident()
         # set by the first encode_end: a second end of the same handle
         # (error-path cleanup racing the normal consume) must not
@@ -105,10 +108,14 @@ class _SubmeshWorker(threading.Thread):
             item = self.q.get()
             if item is None:
                 return
-            op, key, group = item
+            op, key, group, flushed_ns = item
             try:
-                with prules.placed(self.sub.devices):
-                    self.backend._run_group_safe(op, key, group)
+                with prules.placed(self.sub.devices), spans.adopt(
+                    [j.ctx for j in group]
+                ):
+                    self.backend._run_group_traced(
+                        op, key, group, flushed_ns
+                    )
             finally:
                 self.router.release(self.sub)
                 KERNEL_STATS.record_submesh_depths(self.router.depths())
@@ -173,7 +180,8 @@ class BatchingBackend(CodecBackend):
         with self._cv:
             self._jobs.append(job)
             self._cv.notify_all()
-        job.done.wait()
+        with spans.span(spans.BATCH_RESULT_WAIT):
+            job.done.wait()
         if job.error is not None:
             raise job.error
         return job.result
@@ -202,7 +210,9 @@ class BatchingBackend(CodecBackend):
 
     def encode_end(self, handle):
         job = handle
-        job.done.wait()
+        if not job.done.is_set():
+            with spans.span(spans.BATCH_RESULT_WAIT):
+                job.done.wait()
         with self._cv:
             # pair with the SUBMITTING thread's entry exactly once: a
             # pipelined caller may end a handle from a different
@@ -354,17 +364,29 @@ class BatchingBackend(CodecBackend):
                 if not self._running:
                     return
                 continue
-            now = time.monotonic()
-            KERNEL_STATS.record_batch_flush(
-                len(jobs),
-                sum(j.arrays[0].shape[0] for j in jobs),
-                sum(now - j.created for j in jobs),
-            )
+            # one clock reading closes every job's queue wait, feeds the
+            # old wait sum and opens the flush
+            flushed_ns = spans.now()
+            rows = sum(j.arrays[0].shape[0] for j in jobs)
+            waited_ns = 0
+            for j in jobs:
+                with spans.adopt(j.ctx):
+                    spans.wait(
+                        spans.BATCH_QUEUE_WAIT, j.created_ns, flushed_ns
+                    )
+                waited_ns += flushed_ns - j.created_ns
+            KERNEL_STATS.record_batch_flush(len(jobs), rows, waited_ns / 1e9)
             groups: dict[tuple, list[_Job]] = {}
             for j in jobs:
                 groups.setdefault((j.op, j.key), []).append(j)
-            for (op, key), group in groups.items():
-                self._dispatch_group(op, key, group)
+            ctxs = [j.ctx for j in jobs]
+            with spans.adopt(ctxs), spans.span(
+                spans.BATCH_FLUSH,
+                requests=len({c[0] for c in ctxs if c is not None}),
+                jobs=len(jobs), rows=rows,
+            ):
+                for (op, key), group in groups.items():
+                    self._dispatch_group(op, key, group, flushed_ns)
 
     def _router(self):
         """The inner backend's submesh router, feature-detected once."""
@@ -375,7 +397,7 @@ class BatchingBackend(CodecBackend):
         return self._router_obj
 
     def _dispatch_group(
-        self, op: str, key: tuple, group: "list[_Job]"
+        self, op: str, key: tuple, group: "list[_Job]", flushed_ns: int
     ) -> None:
         """Place one merged group: on the least-loaded submesh (its
         worker thread, overlapping with other submeshes) or inline on
@@ -396,11 +418,11 @@ class BatchingBackend(CodecBackend):
                 sub = router.route(blocks)
         if sub is None:
             KERNEL_STATS.record_placement("span")
-            self._run_group_safe(op, key, group)
+            self._run_group_traced(op, key, group, flushed_ns)
             return
         KERNEL_STATS.record_placement("route")
         KERNEL_STATS.record_submesh_depths(router.depths())
-        self._worker(router, sub).submit((op, key, group))
+        self._worker(router, sub).submit((op, key, group, flushed_ns))
 
     def _worker(self, router, sub) -> _SubmeshWorker:
         with self._workers_mu:
@@ -409,6 +431,19 @@ class BatchingBackend(CodecBackend):
                 w = _SubmeshWorker(self, router, sub)
                 self._workers[sub.name] = w
             return w
+
+    def _run_group_traced(
+        self, op: str, key: tuple, group: "list[_Job]", flushed_ns: int
+    ) -> None:
+        """Run one group on whichever thread placement chose.  The
+        flush's stamp goes down with it: the seam closes
+        ``flush_to_launch`` at its first jitted call, so grouping, concat,
+        pad, bytes -> words and device_put all lie inside."""
+        spans.hand_over(spans.FLUSH_TO_LAUNCH, flushed_ns)
+        try:
+            self._run_group_safe(op, key, group)
+        finally:
+            spans.drop_handoff()  # a host backend never launches
 
     def _run_group_safe(
         self, op: str, key: tuple, group: "list[_Job]"

@@ -34,6 +34,7 @@ from .telemetry import KERNEL_STATS
 
 from ..parallel import iopool
 from ..storage import health as disk_health
+from ..utils import spans
 from ..utils.log import kv, logger
 
 _log = logger("codec")
@@ -48,6 +49,18 @@ _RA_SEQ = itertools.count()
 # stage accounting from iopool workers (frame assembly runs on the
 # writer's queue, not the submitting thread)
 _STAGE_LK = threading.Lock()
+
+
+def _staged(stage: str) -> "spans.span":
+    """The span of one stage of a stream.  ``kernel-stats.stages`` is fed
+    from the same two clock readings (``stages[stage] += sp.seconds``):
+    assemble and disk under their own names, every codec stage (codec,
+    codec_fused, codec_drain) as the stream's wait for the codec."""
+    if stage == "assemble":
+        return spans.span(spans.STREAM_ASSEMBLE)
+    if stage == "disk":
+        return spans.span(spans.STREAM_DISK)
+    return spans.span(spans.STREAM_CODEC_WAIT)
 
 
 def _codec_stage(be) -> str:
@@ -362,16 +375,16 @@ class Erasure:
             # quorum-early mode settles only the DATA slots here — the
             # parity stragglers are adopted by the band, and the
             # liveness picture for them is optimistic until settle
-            t0 = time.monotonic()
-            dead = (
-                flusher.drain_slots(range(k))
-                if parity_band is not None
-                else flusher.drain()
-            )
-            for s in dead:
-                if s < len(writers):
-                    writers[s] = None
-            stages["disk"] += time.monotonic() - t0
+            with _staged("disk") as sp:
+                dead = (
+                    flusher.drain_slots(range(k))
+                    if parity_band is not None
+                    else flusher.drain()
+                )
+                for s in dead:
+                    if s < len(writers):
+                        writers[s] = None
+            stages["disk"] += sp.seconds
             if flusher.submitted:
                 alive = sum(1 for w in writers if w is not None)
                 if alive < write_quorum:
@@ -420,32 +433,32 @@ class Erasure:
             )
         started = []
         for shard_len, group_block, group in groups:
-            t0 = time.monotonic()
-            batch = np.zeros((len(group), k, shard_len), dtype=np.uint8)
-            for bi, block in enumerate(group):
-                # one reshape scatters the whole block across its k
-                # shard rows (the per-shard slice loop was O(k) tiny
-                # copies per block)
-                ss = self.shard_size(len(block))
-                a = np.frombuffer(block, dtype=np.uint8)
-                rows, rem = divmod(len(a), ss)
-                if rows:
-                    batch[bi, :rows, :ss] = a[: rows * ss].reshape(
-                        rows, ss
-                    )
-                if rem:
-                    batch[bi, rows, :rem] = a[rows * ss :]
-            stages["assemble"] += time.monotonic() - t0
-            t0 = time.monotonic()
-            handle = (
-                be.encode_digest_begin(batch, m)
-                if digest_mode
-                else be.encode_begin(batch, m)
-            )
-            started.append(
-                _Begun(handle, batch, digest_mode, group_block)
-            )
-            stages[_codec_stage(be)] += time.monotonic() - t0
+            with _staged("assemble") as sp:
+                batch = np.zeros((len(group), k, shard_len), dtype=np.uint8)
+                for bi, block in enumerate(group):
+                    # one reshape scatters the whole block across its k
+                    # shard rows (the per-shard slice loop was O(k) tiny
+                    # copies per block)
+                    ss = self.shard_size(len(block))
+                    a = np.frombuffer(block, dtype=np.uint8)
+                    rows, rem = divmod(len(a), ss)
+                    if rows:
+                        batch[bi, :rows, :ss] = a[: rows * ss].reshape(
+                            rows, ss
+                        )
+                    if rem:
+                        batch[bi, rows, :rem] = a[rows * ss :]
+            stages["assemble"] += sp.seconds
+            with _staged(_codec_stage(be)) as sp:
+                handle = (
+                    be.encode_digest_begin(batch, m)
+                    if digest_mode
+                    else be.encode_begin(batch, m)
+                )
+                started.append(
+                    _Begun(handle, batch, digest_mode, group_block)
+                )
+            stages[_codec_stage(be)] += sp.seconds
         return started
 
     def _flush_batch(
@@ -476,15 +489,14 @@ class Erasure:
         the data batch OR the parity array — so a straggler generation
         costs one shared array, never per-disk copies."""
         def _job():
-            t0 = time.monotonic()
-            shard = src[:, col, :]
-            B = shard.shape[0]
-            run = np.empty((B, ds + shard.shape[1]), dtype=np.uint8)
-            run[:, :ds] = dig_s
-            run[:, ds:] = shard
-            dt = time.monotonic() - t0
+            with _staged("assemble") as sp:
+                shard = src[:, col, :]
+                B = shard.shape[0]
+                run = np.empty((B, ds + shard.shape[1]), dtype=np.uint8)
+                run[:, :ds] = dig_s
+                run[:, ds:] = shard
             with _STAGE_LK:
-                stages["assemble"] += dt
+                stages["assemble"] += sp.seconds
             # hand the writer a view, not a bytes copy: every write
             # path (file, REST pipe, test shards) copies on its own
             # terms, so the run is never duplicated wholesale
@@ -499,20 +511,18 @@ class Erasure:
         drain on its own iopool worker — behind the data-quorum ack —
         and the sibling parity disks reuse the materialized plane."""
         def _job():
-            t0 = time.monotonic()
-            par = pref.drain()
-            dt = time.monotonic() - t0
+            with _staged("codec_drain") as sp:
+                par = pref.drain()
             with _STAGE_LK:
-                stages["codec_drain"] += dt
-            t0 = time.monotonic()
-            shard = par[:, col, :]
-            B = shard.shape[0]
-            run = np.empty((B, ds + shard.shape[1]), dtype=np.uint8)
-            run[:, :ds] = dig_s
-            run[:, ds:] = shard
-            dt = time.monotonic() - t0
+                stages["codec_drain"] += sp.seconds
+            with _staged("assemble") as sp:
+                shard = par[:, col, :]
+                B = shard.shape[0]
+                run = np.empty((B, ds + shard.shape[1]), dtype=np.uint8)
+                run[:, :ds] = dig_s
+                run[:, ds:] = shard
             with _STAGE_LK:
-                stages["assemble"] += dt
+                stages["assemble"] += sp.seconds
             w.write(run.reshape(-1).data)
         return _job
 
@@ -531,23 +541,23 @@ class Erasure:
         jobs = []
         for rec in started:
             batch = rec.batch
-            t0 = time.monotonic()
-            if rec.digest_mode:
-                digests, pref = rec.end_digest(be)
-                par = None
-            else:
-                parity, digests = rec.end(be)
-                par = np.asarray(parity, dtype=np.uint8)
-                pref = None
-            stages[_codec_stage(be)] += time.monotonic() - t0
-            t0 = time.monotonic()
-            B, shard_len = batch.shape[0], batch.shape[2]
-            ds = bitrot.DIGEST_SIZE
-            # digest words -> 32B frames, all (block, shard) cells at
-            # once; byte layout matches bitrot.digest_to_bytes
-            dig_u32 = np.ascontiguousarray(digests, dtype=np.uint32)
-            dig = dig_u32.view(np.uint8).reshape(B, n, ds)
-            stages["assemble"] += time.monotonic() - t0
+            with _staged(_codec_stage(be)) as sp:
+                if rec.digest_mode:
+                    digests, pref = rec.end_digest(be)
+                    par = None
+                else:
+                    parity, digests = rec.end(be)
+                    par = np.asarray(parity, dtype=np.uint8)
+                    pref = None
+            stages[_codec_stage(be)] += sp.seconds
+            with _staged("assemble") as sp:
+                B, shard_len = batch.shape[0], batch.shape[2]
+                ds = bitrot.DIGEST_SIZE
+                # digest words -> 32B frames, all (block, shard) cells at
+                # once; byte layout matches bitrot.digest_to_bytes
+                dig_u32 = np.ascontiguousarray(digests, dtype=np.uint32)
+                dig = dig_u32.view(np.uint8).reshape(B, n, ds)
+            stages["assemble"] += sp.seconds
             if cache_ctx is not None:
                 # PUT population: the batch's data rows + their digest
                 # words, before any disk write settles — the next GET
@@ -579,9 +589,9 @@ class Erasure:
             raise QuorumError(
                 f"write quorum lost: {len(alive)} < {write_quorum}"
             )
-        t0 = time.monotonic()
-        dead = flusher.flush(jobs, write_quorum)
-        stages["disk"] += time.monotonic() - t0
+        with _staged("disk") as sp:
+            dead = flusher.flush(jobs, write_quorum)
+        stages["disk"] += sp.seconds
         for s in dead:
             if s < len(writers):
                 writers[s] = None
@@ -788,23 +798,23 @@ class Erasure:
             group = block_indices[i:j]
             shard_len = sizes[i]
             if cache_ctx is not None:
-                t0 = time.monotonic()
-                cached = cache_ctx.lookup(
-                    be, group[0], len(group), shard_len
-                )
-                stages["codec"] += time.monotonic() - t0
+                with _staged("codec") as sp:
+                    cached = cache_ctx.lookup(
+                        be, group[0], len(group), shard_len
+                    )
+                stages["codec"] += sp.seconds
                 if cached is not None:
-                    t0 = time.monotonic()
-                    for gi, b in enumerate(group):
-                        block_len = self._block_len(b, total_length)
-                        ss = self.shard_size(block_len)
-                        # one strided copy; the [:block_len] trim is a
-                        # view and _write_blocks streams views as-is
-                        flat = np.ascontiguousarray(
-                            cached[gi, :, :ss]
-                        ).reshape(-1)
-                        out.append(flat[:block_len])
-                    stages["assemble"] += time.monotonic() - t0
+                    with _staged("assemble") as sp:
+                        for gi, b in enumerate(group):
+                            block_len = self._block_len(b, total_length)
+                            ss = self.shard_size(block_len)
+                            # one strided copy; the [:block_len] trim is a
+                            # view and _write_blocks streams views as-is
+                            flat = np.ascontiguousarray(
+                                cached[gi, :, :ss]
+                            ).reshape(-1)
+                            out.append(flat[:block_len])
+                    stages["assemble"] += sp.seconds
                     i = j
                     continue
             if readers is None:
@@ -828,28 +838,28 @@ class Erasure:
             # at all - fusing would decode k rows per group that the
             # fast path below streams out as views
             # reconstruct per distinct pattern (usually one)
-            t0 = time.monotonic()
-            patterns: dict[tuple, list[int]] = {}
-            for gi in range(len(group)):
-                pat = tuple(bool(x) for x in ok[gi])
-                patterns.setdefault(pat, []).append(gi)
-            if len(patterns) == 1 and all(next(iter(patterns))[:k]):
-                # healthy fast path: every block has its data rows
-                # intact, so stream straight out of the frame buffer -
-                # no (g, k, shard_len) copy, no fancy-index temporaries
-                datas = shards[:, :k, :]
-            else:
-                datas = np.zeros(
-                    (len(group), k, shard_len), dtype=np.uint8
-                )
-                for pat, gis in patterns.items():
-                    if all(pat[:k]):
-                        datas[gis] = shards[gis][:, :k]
-                    else:
-                        datas[np.asarray(gis)] = be.reconstruct(
-                            shards[np.asarray(gis)], pat, k, m
-                        )
-            stages["codec"] += time.monotonic() - t0
+            with _staged("codec") as sp:
+                patterns: dict[tuple, list[int]] = {}
+                for gi in range(len(group)):
+                    pat = tuple(bool(x) for x in ok[gi])
+                    patterns.setdefault(pat, []).append(gi)
+                if len(patterns) == 1 and all(next(iter(patterns))[:k]):
+                    # healthy fast path: every block has its data rows
+                    # intact, so stream straight out of the frame buffer -
+                    # no (g, k, shard_len) copy, no fancy-index temporaries
+                    datas = shards[:, :k, :]
+                else:
+                    datas = np.zeros(
+                        (len(group), k, shard_len), dtype=np.uint8
+                    )
+                    for pat, gis in patterns.items():
+                        if all(pat[:k]):
+                            datas[gis] = shards[gis][:, :k]
+                        else:
+                            datas[np.asarray(gis)] = be.reconstruct(
+                                shards[np.asarray(gis)], pat, k, m
+                            )
+            stages["codec"] += sp.seconds
             if cache_ctx is not None and not g_heal:
                 # admit the decoded data rows.  When every data slot
                 # read intact, reuse the digest words that just
@@ -872,14 +882,14 @@ class Erasure:
                     )
             # raw frames die before blocks copy out
             shards = digests = ok = None
-            t0 = time.monotonic()
-            for gi, b in enumerate(group):
-                block_len = self._block_len(b, total_length)
-                ss = self.shard_size(block_len)
-                block = datas[gi, :, :ss].reshape(-1)[:block_len]
-                out.append(block.tobytes())
-            datas = None  # only the extracted blocks survive the group
-            stages["assemble"] += time.monotonic() - t0
+            with _staged("assemble") as sp:
+                for gi, b in enumerate(group):
+                    block_len = self._block_len(b, total_length)
+                    ss = self.shard_size(block_len)
+                    block = datas[gi, :, :ss].reshape(-1)[:block_len]
+                    out.append(block.tobytes())
+                datas = None  # only the extracted blocks survive the group
+            stages["assemble"] += sp.seconds
             i = j
         return out, heal
 
@@ -1018,26 +1028,26 @@ class Erasure:
                         f"read quorum lost: {intact}/{n} shards intact,"
                         f" need {k}"
                     )
-                t0 = time.monotonic()
-                # wait for any completion, racing the hedge deadline
-                # (clocked from the oldest outstanding read or the last
-                # hedge, whichever is later — each hedge gets a full
-                # deadline before the next one may fire)
-                timeout = None
-                if (
-                    deadline is not None
-                    and remaining
-                    and hedges < m
-                ):
-                    base = max(
-                        min(v[1] for v in outstanding.values()),
-                        last_hedge,
+                with _staged("disk") as sp:
+                    # wait for any completion, racing the hedge deadline
+                    # (clocked from the oldest outstanding read or the last
+                    # hedge, whichever is later — each hedge gets a full
+                    # deadline before the next one may fire)
+                    timeout = None
+                    if (
+                        deadline is not None
+                        and remaining
+                        and hedges < m
+                    ):
+                        base = max(
+                            min(v[1] for v in outstanding.values()),
+                            last_hedge,
+                        )
+                        timeout = max(0.0, base + deadline - sp.t0 / 1e9)
+                    done = iopool.wait_any(
+                        [v[0] for v in outstanding.values()], timeout
                     )
-                    timeout = max(0.0, base + deadline - t0)
-                done = iopool.wait_any(
-                    [v[0] for v in outstanding.values()], timeout
-                )
-                stages["disk"] += time.monotonic() - t0
+                stages["disk"] += sp.seconds
                 if not done:
                     # deadline expired, quorum still short: duplicate
                     # read on the next preferred (parity) shard
@@ -1049,51 +1059,51 @@ class Erasure:
                 batch = sorted(
                     s for s, v in outstanding.items() if v[0].done()
                 )
-                t0 = time.monotonic()
-                for s in batch:
-                    fut, t_launch, is_hedge = outstanding.pop(s)
-                    frames = fut.result if fut.error is None else None
-                    if frames is None:
-                        frames = [None] * g
-                    got_any = False
-                    for gi, c in enumerate(frames):
-                        if c is None:
-                            heal = True  # chosen shard missing/short
-                            continue
-                        digests[gi, s] = bitrot.digest_from_bytes(
-                            c[: bitrot.DIGEST_SIZE]
-                        )
-                        shards[gi, s] = np.frombuffer(
-                            c[bitrot.DIGEST_SIZE :], dtype=np.uint8
-                        )
-                        present[gi, s] = True
-                        got_any = True
-                    if is_hedge and got_any:
-                        KERNEL_STATS.record_hedge("won")
-                frames = None  # ranged-read buffers die before verify
-                stages["assemble"] += time.monotonic() - t0
+                with _staged("assemble") as sp:
+                    for s in batch:
+                        fut, t_launch, is_hedge = outstanding.pop(s)
+                        frames = fut.result if fut.error is None else None
+                        if frames is None:
+                            frames = [None] * g
+                        got_any = False
+                        for gi, c in enumerate(frames):
+                            if c is None:
+                                heal = True  # chosen shard missing/short
+                                continue
+                            digests[gi, s] = bitrot.digest_from_bytes(
+                                c[: bitrot.DIGEST_SIZE]
+                            )
+                            shards[gi, s] = np.frombuffer(
+                                c[bitrot.DIGEST_SIZE :], dtype=np.uint8
+                            )
+                            present[gi, s] = True
+                            got_any = True
+                        if is_hedge and got_any:
+                            KERNEL_STATS.record_hedge("won")
+                    frames = None  # ranged-read buffers die before verify
+                stages["assemble"] += sp.seconds
                 # verify only the shards just read: a healthy GET
                 # hashes exactly k columns, and escalation rounds never
                 # re-hash already-verified shards
-                t0 = time.monotonic()
-                bcols = np.asarray(batch)
-                if batch == list(
-                    range(batch[0], batch[0] + len(batch))
-                ):
-                    # contiguous columns (the healthy k-data-shard
-                    # case): basic slices give verify views, not 4 MiB
-                    # temporaries
-                    sh_cols = shards[:, batch[0] : batch[0] + len(batch)]
-                    dg_cols = digests[:, batch[0] : batch[0] + len(batch)]
-                else:
-                    sh_cols = shards[:, bcols]
-                    dg_cols = digests[:, bcols]
-                okb = be.verify(sh_cols, dg_cols) & present[:, bcols]
-                sh_cols = dg_cols = None
-                if (okb != present[:, bcols]).any():
-                    heal = True  # bitrot detected somewhere
-                ok[:, bcols] = okb
-                stages["codec"] += time.monotonic() - t0
+                with _staged("codec") as sp:
+                    bcols = np.asarray(batch)
+                    if batch == list(
+                        range(batch[0], batch[0] + len(batch))
+                    ):
+                        # contiguous columns (the healthy k-data-shard
+                        # case): basic slices give verify views, not 4 MiB
+                        # temporaries
+                        sh_cols = shards[:, batch[0] : batch[0] + len(batch)]
+                        dg_cols = digests[:, batch[0] : batch[0] + len(batch)]
+                    else:
+                        sh_cols = shards[:, bcols]
+                        dg_cols = digests[:, bcols]
+                    okb = be.verify(sh_cols, dg_cols) & present[:, bcols]
+                    sh_cols = dg_cols = None
+                    if (okb != present[:, bcols]).any():
+                        heal = True  # bitrot detected somewhere
+                    ok[:, bcols] = okb
+                stages["codec"] += sp.seconds
         finally:
             # disavow stragglers: quorum is met (or lost) without them.
             # Queued losers resolve IopoolAbandoned without running;

@@ -6,21 +6,28 @@ bitrot hashing) running as batched device passes behind the
 those passes in production:
 
 * ``KernelStats`` - process-wide registry of per-op counters: calls,
-  bytes processed, and host-observed device seconds, labeled by the
-  resolved backend (``tpu``/``cpu``); plus batcher occupancy (jobs
-  coalesced per flush, queue wait) and erasure-stream totals.
+  bytes processed, and the seconds the calling thread spent inside the
+  seam, labeled by the resolved backend (``tpu``/``cpu``); plus batcher
+  occupancy (jobs coalesced per flush, queue wait) and erasure-stream
+  totals.  Its snapshot also carries ``spans`` and ``probe``: the
+  always-on counters of utils/spans.py, merged over the threads.
 * ``InstrumentedBackend`` - a CodecBackend decorator recording every
   encode / encode_begin-end / digest / reconstruct /
   reconstruct_and_verify through the seam.
   It wraps the CONCRETE backend (below the batching layer), so a
-  coalesced flush counts as one call and its seconds are real device
-  launch time, not queue wait - queue wait is the batcher's own series.
+  coalesced flush counts as one call and queue wait stays out of it -
+  queue wait is the batcher's own series.
 
-"Device seconds" are host-observed: the time the calling thread spends
-inside the codec call (for the async begin/end pair, dispatch time plus
-materialization time).  On a host-only backend that IS compute time; on
-a device backend it includes H2D/D2H transfers - which is exactly the
-cost an operator provisioning the serving path cares about.
+``ops.seconds`` is what the calling thread's clock saw between entering
+the codec call and leaving it (for the async begin/end pair, dispatch
+time plus materialization time).  On a host-only backend that is compute
+time.  On a device backend it is NOT device time: it holds staging,
+dispatch, the kernel, the read-back and every wait for the GIL in
+between, in one sum (5.8 ms a call against 0.3-0.8 ms of kernel on a
+v5e).  The four seam spans of utils/spans.py split it: ``seam_stage``
+(bytes -> words, pad, device_put), ``seam_launch`` (the jitted call,
+returns at enqueue), ``seam_kernel_wait`` (block_until_ready on the
+results) and ``seam_d2h`` (the copy to the host alone).
 
 Everything is exported as ``miniotpu_codec_*`` Prometheus families
 (server/metrics.py) and snapshot-dumpable via ``admin kernel-stats``
@@ -32,6 +39,7 @@ from __future__ import annotations
 import threading
 import time
 
+from ..utils import spans
 from .backend import CodecBackend
 
 
@@ -174,12 +182,6 @@ class KernelStats:
         with self._mu:
             self._hedge[kind] = self._hedge.get(kind, 0) + 1
 
-    def record_io_depth(self, queue: str, depth: int) -> None:
-        """Queue depth observed at enqueue (high-water mark only)."""
-        with self._mu:
-            if depth > self._iopool_depth_hwm:
-                self._iopool_depth_hwm = depth
-
     def record_placement(self, outcome: str) -> None:
         """One batch placement decision (outcome = span|route)."""
         with self._mu:
@@ -197,8 +199,17 @@ class KernelStats:
 
     def snapshot(self) -> dict:
         """JSON-friendly dump (admin kernel-stats, bench.py trajectory)."""
+        # the enqueue-side mark lives on the pool's queues (their own
+        # lock is held there anyway); the dequeue-side one is ours
+        from ..parallel import iopool
+
+        enqueue_hwm = iopool.depth_hwm()
+        # utils/spans.py: [{role, name, count, wall_seconds, cpu_seconds}]
+        # and the interpreter probe; merged outside our mutex
+        span_tables = spans.snapshot()
         with self._mu:
             return {
+                **span_tables,
                 "ops": [
                     {
                         "op": op,
@@ -285,7 +296,7 @@ class KernelStats:
                             self._iopool.items()
                         )
                     ],
-                    "depth_hwm": self._iopool_depth_hwm,
+                    "depth_hwm": max(self._iopool_depth_hwm, enqueue_hwm),
                     "slowest_job_seconds": round(
                         self._iopool_slowest_s, 6
                     ),
@@ -311,6 +322,8 @@ class KernelStats:
             self._placement.clear()
             self._submesh_depth.clear()
             self._submesh_depth_hwm.clear()
+        if self is KERNEL_STATS:
+            spans.reset()
 
 
 def _parity_cache_stats() -> dict:
@@ -330,6 +343,10 @@ KERNEL_STATS = KernelStats()
 class InstrumentedBackend(CodecBackend):
     """CodecBackend decorator feeding a KernelStats registry.
 
+    ``ops.seconds`` is the caller's clock round the whole call - staging,
+    dispatch, kernel, read-back and GIL waits in one sum, not device
+    time; the spans ``seam_stage`` / ``seam_launch`` /
+    ``seam_kernel_wait`` / ``seam_d2h`` inside TpuBackend split it.
     ``name`` mirrors the inner backend so layers keying behavior off it
     (the batcher's power-of-two padding for ``tpu``) are unaffected.
     ``verify`` is inherited from CodecBackend on purpose: the default
@@ -456,16 +473,7 @@ class InstrumentedBackend(CodecBackend):
 def instrument(
     backend: CodecBackend, stats: "KernelStats | None" = None
 ) -> CodecBackend:
-    """Wrap a concrete backend with kernel telemetry (idempotent).
-
-    MINIO_TPU_NO_INSTRUMENT=1 returns the backend bare — used by
-    `bench.py --no-instrument` to measure the codec without the
-    per-op timing/accounting wrapper in the loop.
-    """
-    import os
-
-    if os.environ.get("MINIO_TPU_NO_INSTRUMENT") == "1":
-        return backend
+    """Wrap a concrete backend with kernel telemetry (idempotent)."""
     if isinstance(backend, InstrumentedBackend):
         return backend
     return InstrumentedBackend(backend, stats)

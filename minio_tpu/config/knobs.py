@@ -97,9 +97,6 @@ KNOBS: "dict[str, Knob]" = {
     "MINIO_TPU_DCOMP_MAX_FILL": Knob(
         "0.75", "device-compression max output fill ratio"
     ),
-    "MINIO_TPU_NO_INSTRUMENT": Knob(
-        "0", "disable codec telemetry instrumentation"
-    ),
     "MINIO_TPU_PLACEMENT": Knob(
         "auto", "device placement policy for sharded ops"
     ),

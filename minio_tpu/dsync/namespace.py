@@ -13,6 +13,8 @@ import contextlib
 import threading
 import time
 
+from ..utils import spans
+
 
 class _RWLock:
     """Writer-preference RW lock with timeout support."""
@@ -103,7 +105,9 @@ class NamespaceLock:
         key = f"{volume}/{path}"
         lk = self._get(key)
         try:
-            if not lk.acquire_read(timeout):
+            with spans.span(spans.NSLOCK_WAIT):
+                got = lk.acquire_read(timeout)
+            if not got:
                 raise LockTimeout(key)
             try:
                 yield
@@ -117,7 +121,9 @@ class NamespaceLock:
         key = f"{volume}/{path}"
         lk = self._get(key)
         try:
-            if not lk.acquire_write(timeout):
+            with spans.span(spans.NSLOCK_WAIT):
+                got = lk.acquire_write(timeout)
+            if not got:
                 raise LockTimeout(key)
             try:
                 yield
@@ -167,18 +173,17 @@ class DistNamespaceLock:
 
     @contextlib.contextmanager
     def read(self, volume: str, path: str, timeout: "float | None" = None):
-        import time as _t
-
         from .drwmutex import DRWMutex
 
         if timeout is None:
             timeout = self._rtimeout.timeout
         m = DRWMutex(self._ds, f"{volume}/{path}")
-        t0 = _t.monotonic()
-        if not m.get_rlock(self._source, timeout):
+        with spans.span(spans.NSLOCK_WAIT) as sp:
+            got = m.get_rlock(self._source, timeout)
+        if not got:
             self._rtimeout.log_failure()
             raise LockTimeout(f"{volume}/{path}")
-        self._rtimeout.log_success(_t.monotonic() - t0)
+        self._rtimeout.log_success(sp.seconds)
         try:
             yield
         finally:
@@ -186,18 +191,17 @@ class DistNamespaceLock:
 
     @contextlib.contextmanager
     def write(self, volume: str, path: str, timeout: "float | None" = None):
-        import time as _t
-
         from .drwmutex import DRWMutex
 
         if timeout is None:
             timeout = self._wtimeout.timeout
         m = DRWMutex(self._ds, f"{volume}/{path}")
-        t0 = _t.monotonic()
-        if not m.get_lock(self._source, timeout):
+        with spans.span(spans.NSLOCK_WAIT) as sp:
+            got = m.get_lock(self._source, timeout)
+        if not got:
             self._wtimeout.log_failure()
             raise LockTimeout(f"{volume}/{path}")
-        self._wtimeout.log_success(_t.monotonic() - t0)
+        self._wtimeout.log_success(sp.seconds)
         try:
             yield
         finally:

@@ -26,6 +26,7 @@ from ..storage.meta import (
     new_version_id,
     now_ns,
 )
+from ..utils import spans
 from ..utils.hashreader import HashReader
 from . import api
 from .api import (
@@ -240,6 +241,7 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
     # put (erasure-object.go:570-765)
     # ------------------------------------------------------------------
 
+    @spans.spanned(spans.OL_PUT_OBJECT)
     def put_object(
         self, bucket, object_name, reader, size=-1, metadata=None,
         versioned=False, compress=None, sse=None,
@@ -533,6 +535,7 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
         fi = find_fileinfo_in_quorum(fis, self.read_quorum)
         return fi, fis
 
+    @spans.spanned(spans.OL_GET_OBJECT_INFO)
     def get_object_info(
         self, bucket, object_name, version_id=""
     ) -> ObjectInfo:
@@ -731,6 +734,7 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
             parts=list(fi.parts),
         )
 
+    @spans.spanned(spans.OL_GET_OBJECT)
     def get_object(
         self, bucket, object_name, writer, offset=0, length=-1,
         version_id="", sse=None,
@@ -983,6 +987,7 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
     # delete (erasure-object.go:793+)
     # ------------------------------------------------------------------
 
+    @spans.spanned(spans.OL_DELETE_OBJECT)
     def delete_object(
         self, bucket, object_name, version_id="", versioned=False,
         version_suspended=False,

@@ -13,6 +13,7 @@ import binascii
 
 from ..storage import errors as serrors
 from ..storage.meta import FileInfo
+from ..utils import spans
 from . import api
 
 
@@ -38,6 +39,7 @@ def shuffle_disks(disks: list, distribution: list[int]) -> list:
     return out
 
 
+@spans.spanned(spans.META_READ_ALL)
 def read_all_fileinfo(
     disks: list, volume: str, path: str, version_id: str = ""
 ) -> tuple[list, list]:

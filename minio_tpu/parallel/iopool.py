@@ -33,8 +33,8 @@ from __future__ import annotations
 import collections
 import os
 import threading
-import time
 
+from ..utils import spans
 from ..utils.log import kv, logger
 
 _log = logger("iopool")
@@ -113,10 +113,13 @@ class IOFuture:
         return self._event.is_set()
 
     def wait(self, timeout: "float | None" = None) -> bool:
-        return self._event.wait(timeout)
+        if self._event.is_set():
+            return True
+        with spans.span(spans.IOPOOL_RESULT_WAIT):
+            return self._event.wait(timeout)
 
     def result_or_raise(self, timeout: "float | None" = None):
-        if not self._event.wait(timeout):
+        if not self.wait(timeout):
             raise IopoolTimeout(
                 f"iopool job did not complete within {timeout}s"
             )
@@ -126,14 +129,16 @@ class IOFuture:
 
 
 class _IOQueue:
-    __slots__ = ("idx", "label", "cv", "items", "thread")
+    __slots__ = ("idx", "label", "cv", "items", "thread", "depth_hwm")
 
     def __init__(self, idx: int):
         self.idx = idx
         self.label = f"q{idx}"
         self.cv = threading.Condition()
+        # (future, fn, nbytes, enqueued at ns, the submitter's span context)
         self.items: "collections.deque" = collections.deque()
         self.thread: "threading.Thread | None" = None
+        self.depth_hwm = 0  # deepest backlog seen at enqueue; cv held
 
 
 class IOPool:
@@ -204,13 +209,18 @@ class IOPool:
         if q.thread is threading.current_thread():
             self._run_job(q, fut, fn, nbytes, len(q.items))
             return fut
+        ctx = spans.capture()
         with q.cv:
             while len(q.items) >= self.depth and self._running:
                 q.cv.wait(0.5)
             if not self._running:
                 raise RuntimeError("iopool is shut down")
-            q.items.append((fut, fn, nbytes))
-            depth = len(q.items)
+            # stamped once through the backpressure: the wait measured
+            # at dequeue is the queue's, the submitter's own stall shows
+            # in whatever span it submits from
+            q.items.append((fut, fn, nbytes, spans.now(), ctx))
+            if len(q.items) > q.depth_hwm:
+                q.depth_hwm = len(q.items)
             if q.thread is None:
                 q.thread = threading.Thread(
                     target=self._worker,
@@ -220,7 +230,6 @@ class IOPool:
                 )
                 q.thread.start()
             q.cv.notify_all()
-        _stats_record_depth(q.label, depth)
         return fut
 
     # -- worker -----------------------------------------------------------
@@ -232,14 +241,16 @@ class IOPool:
                     q.cv.wait(0.5)
                 if not q.items:
                     return  # shut down and drained
-                fut, fn, nbytes = q.items.popleft()
+                fut, fn, nbytes, since_ns, ctx = q.items.popleft()
                 depth = len(q.items)
                 q.cv.notify_all()  # wake backpressured submitters
-            self._run_job(q, fut, fn, nbytes, depth)
+            with spans.adopt(ctx):
+                spans.wait(spans.IOPOOL_QUEUE_WAIT, since_ns)
+                self._run_job(q, fut, fn, nbytes, depth)
             # an idle worker must not pin its last job's closure or
             # result (a decoded read-ahead batch is many MiB) until
             # the next job happens to arrive
-            del fut, fn
+            del fut, fn, ctx
 
     def submit_hedged(self, key, fn, nbytes: int = 0) -> IOFuture:
         """Launch a duplicate/alternate read racing a straggler
@@ -262,17 +273,15 @@ class IOPool:
                 None, IopoolAbandoned("job abandoned before dequeue")
             )
             return
-        t0 = time.monotonic()
         result = None
         error: "BaseException | None" = None
+        with spans.span(spans.IOPOOL_JOB) as sp:
+            try:
+                result = fn()
+            except BaseException as e:  # noqa: BLE001 - surfaced via future
+                error = e
         try:
-            result = fn()
-        except BaseException as e:  # noqa: BLE001 - surfaced via future
-            error = e
-        try:
-            _stats_record_job(
-                q.label, nbytes, time.monotonic() - t0, depth
-            )
+            _stats_record_job(q.label, nbytes, sp.seconds, depth)
         except Exception as exc:  # stats must never wedge a future
             _log.warning("iopool stats failed", extra=kv(err=str(exc)))
         fut._resolve(result, error)
@@ -567,10 +576,6 @@ def _stats_record_job(queue: str, nbytes: int, seconds: float, depth: int):
     _kernel_stats().record_io_job(queue, nbytes, seconds, depth)
 
 
-def _stats_record_depth(queue: str, depth: int):
-    _kernel_stats().record_io_depth(queue, depth)
-
-
 # -- process-wide singleton (one I/O plane per process) -------------------
 
 _POOL: "IOPool | None" = None
@@ -594,6 +599,14 @@ def queued_depth() -> int:
     plane)."""
     p = _POOL
     return p.queued_jobs() if p is not None else 0
+
+
+def depth_hwm() -> int:
+    """Deepest per-queue backlog seen at enqueue (kernel-stats
+    ``iopool.depth_hwm``): kept on the queues, under the lock
+    ``submit`` holds anyway, and read here at snapshot time."""
+    p = _POOL
+    return max((q.depth_hwm for q in p._queues), default=0) if p else 0
 
 
 def reset_pool() -> None:
@@ -620,11 +633,10 @@ def fanout(ops, pool: "IOPool | None" = None) -> list:
     overlap is real even on a single-core host."""
     p = pool or get_pool()
     futs = [p.submit(k, f) for k, f in ops]
-    errs = []
-    for fut in futs:
-        fut.wait()
-        errs.append(fut.error)
-    return errs
+    with spans.span(spans.IOPOOL_RESULT_WAIT):
+        for fut in futs:
+            fut._event.wait()
+    return [fut.error for fut in futs]
 
 
 def wait_any(futs, timeout: "float | None" = None) -> list:
@@ -646,7 +658,8 @@ def wait_any(futs, timeout: "float | None" = None) -> list:
 
     for f in futs:
         f.add_done_callback(_wake)
-    ev.wait(timeout)
+    with spans.span(spans.IOPOOL_RESULT_WAIT):
+        ev.wait(timeout)
     return [f for f in futs if f.done()]
 
 

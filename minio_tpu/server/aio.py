@@ -75,6 +75,7 @@ from http import client as _hclient
 
 from . import s3errors
 from . import response as xmlr
+from ..utils import spans
 from ..utils.log import kv, logger
 
 _log = logger("aio")
@@ -139,8 +140,12 @@ class _LoopReader:
 
     def _call(self, coro):
         try:
-            fut = asyncio.run_coroutine_threadsafe(coro, self._owner.loop)
-            return fut.result()
+            # the handler blocked on the loop (and, behind it, the client)
+            with spans.span(spans.BODY_READ_WAIT):
+                fut = asyncio.run_coroutine_threadsafe(
+                    coro, self._owner.loop
+                )
+                return fut.result()
         except asyncio.TimeoutError:
             raise socket.timeout("body read timed out") from None
         except (RuntimeError, ConnectionError, asyncio.CancelledError) as e:
@@ -196,9 +201,10 @@ class _LoopWriter:
             await writer.drain()
 
         try:
-            asyncio.run_coroutine_threadsafe(
-                _wr(), self._owner.loop
-            ).result()
+            with spans.span(spans.RESP_WRITE_WAIT):
+                asyncio.run_coroutine_threadsafe(
+                    _wr(), self._owner.loop
+                ).result()
         except (RuntimeError, ConnectionError, asyncio.CancelledError) as e:
             raise OSError(f"connection lost: {e}") from None
         return n
@@ -305,6 +311,9 @@ class _ServerLoop:
         self._thread: "threading.Thread | None" = None
         self.lstats.register_stage("parse", lambda: len(self._conns))
         self.lstats.register_stage("handler", self.pool.depth)
+        # the loop's lag (kernel-stats.probe.loops): queue time seen from
+        # the loop's side
+        self._probe = spans.PROBE.add_loop(index)
 
     # -- lifecycle --------------------------------------------------------
 
@@ -325,9 +334,11 @@ class _ServerLoop:
 
     def _run_loop(self) -> None:
         asyncio.set_event_loop(self.loop)
+        self._probe.start(self.loop)
         try:
             self.loop.run_forever()
         finally:
+            self._probe.stop()
             try:
                 self.loop.close()
             except Exception as exc:  # noqa: BLE001
@@ -472,11 +483,26 @@ class _ServerLoop:
     async def _read_head(self, reader, writer, first: bool):
         """One request head (bytes through the blank line), or None on
         EOF/timeout/oversize.  The timeout caps the WHOLE head — a
-        slow-loris trickling header bytes gets 408, not a held slot."""
-        timeout = self.header_timeout if first else self.idle_timeout
+        slow-loris trickling header bytes gets 408, not a held slot.
+
+        A kept-alive connection that stays idle is closed WITHOUT a
+        reply, as the threaded plane and the reference's net/http do: a
+        408 written to an idle connection is what the client reads as
+        the answer to its next request (the benchmark's admin client met
+        it whenever a set-up outlasted the idle timeout)."""
+        lead = b""
         try:
-            return await asyncio.wait_for(
-                reader.readuntil(b"\r\n\r\n"), timeout
+            if not first:
+                lead = await asyncio.wait_for(
+                    reader.readexactly(1), self.idle_timeout
+                )
+        except asyncio.TimeoutError:
+            return None  # idle: the close is the whole answer
+        except (asyncio.IncompleteReadError, ConnectionError, OSError):
+            return None  # client went away
+        try:
+            return lead + await asyncio.wait_for(
+                reader.readuntil(b"\r\n\r\n"), self.header_timeout
             )
         except asyncio.TimeoutError:
             await self._reject(writer, 408, "RequestTimeout",
@@ -556,7 +582,14 @@ class _ServerLoop:
             if not done.done():
                 done.set_result(None)
 
+        queued_ns = spans.now()
+
         def _work():
+            # loop -> handler pool: the time the request sat in the queue
+            # before a worker ran a line of it
+            h._queue_wait_ns = (
+                spans.wait(spans.AIO_QUEUE_WAIT, queued_ns) - queued_ns
+            )
             try:
                 h.route()
             except Exception as exc:  # noqa: BLE001 - connection-fatal only

@@ -24,6 +24,8 @@ import hmac
 import json
 import urllib.parse
 
+from ..utils import spans
+
 SIGN_V4_ALGORITHM = "AWS4-HMAC-SHA256"
 SIGN_V2_ALGORITHM = "AWS"
 UNSIGNED_PAYLOAD = "UNSIGNED-PAYLOAD"
@@ -174,6 +176,7 @@ class SigV4Verifier:
 
     # -- entry points ----------------------------------------------------
 
+    @spans.spanned(spans.SIGV4_VERIFY)
     def verify_stream(
         self,
         method: str,

@@ -32,6 +32,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from ..iam.sys import IAMSys
 from ..objectlayer.api import CompletePart, ObjectInfo
 from ..objectlayer.bucket_meta import BucketMetadataSys
+from ..utils import spans
 from ..utils.hashreader import HashReader
 from . import auth as authmod, authz, response as xmlr, s3errors
 from .auth import (
@@ -230,6 +231,7 @@ class S3Server:
 
         self.plane_stats.register_stage("codec", _codec_depth)
         self._plane = None  # AsyncPlane when server_mode == "async"
+        self._probe_started = False
         self.server_mode = "threaded"
 
     def _requests_max(self) -> int:
@@ -353,6 +355,10 @@ class S3Server:
             os.environ.get("MINIO_TPU_SERVER") or DEFAULT_SERVER_MODE
         ).lower()
         self.server_mode = "async" if mode == "async" else "threaded"
+        # the interpreter probe (kernel-stats.probe): one daemon thread a
+        # process, counted per server, stopped in shutdown()
+        spans.PROBE.start()
+        self._probe_started = True
         if self.server_mode == "async":
             from . import aio
 
@@ -397,6 +403,8 @@ class S3Server:
         if getattr(self, "_shutdown_done", False):
             return
         self._shutdown_done = True
+        if self._probe_started:
+            spans.PROBE.stop()
         if self._plane is not None:
             self._plane.stop(drain_s)
         if self._httpd:
@@ -584,7 +592,7 @@ class _Handler(BaseHTTPRequestHandler):
         self.send_response(status)
         self.send_header("Server", "MinIO-TPU")
         self.send_header(
-            "x-amz-request-id", uuid.uuid4().hex[:16].upper()
+            "x-amz-request-id", self._request_id()
         )
         for k, v in (headers or {}).items():
             self.send_header(k, v)
@@ -615,7 +623,7 @@ class _Handler(BaseHTTPRequestHandler):
             self._respond(304)
             return
         body = xmlr.error_xml(
-            err.code, err.message, resource, uuid.uuid4().hex[:16]
+            err.code, err.message, resource, self._request_id()
         )
         self._respond(err.status, body)
 
@@ -699,7 +707,27 @@ class _Handler(BaseHTTPRequestHandler):
             self.wfile.write(b"HTTP/1.1 100 Continue\r\n\r\n")
             self.wfile.flush()
 
+    def _request_id(self) -> str:
+        """The identifier minted at the top of route(): the one every
+        span of the request carries.  (A response written outside
+        route() - the stdlib's own error path - gets a fresh one.)"""
+        return spans.request_id() or uuid.uuid4().hex[:16].upper()
+
     def route(self):
+        """One request under its root span.  The identifier is minted
+        here, once; while a trace subscriber listens the spans keep
+        records and ride the request's trace entry."""
+        spans.begin_request(self.s3.tracer.active)
+        self._trace_tail = None
+        try:
+            with spans.span(spans.S3_REQUEST):
+                self._route()
+        finally:
+            records = spans.end_request()
+            if self._trace_tail is not None:
+                self._emit_trace_audit(*self._trace_tail, records)
+
+    def _route(self):
         path, query = self._parse()
         self._headers_sent = False
         self._raw_body = None
@@ -839,9 +867,12 @@ class _Handler(BaseHTTPRequestHandler):
                 bytes_out=self._resp_bytes,
                 ttfb=self._ttfb,
             )
-            self._emit_trace_audit(path, query, dur, cl)
+            # published by route() once the root span has closed
+            self._trace_tail = (path, query, dur, cl, spans.request_id())
 
-    def _emit_trace_audit(self, path, query, dur, bytes_in) -> None:
+    def _emit_trace_audit(
+        self, path, query, dur, bytes_in, request_id, records
+    ) -> None:
         """httpTrace + logger.AuditLog tail of every request."""
         from . import trace as tracemod
 
@@ -859,6 +890,9 @@ class _Handler(BaseHTTPRequestHandler):
                     self._resp_bytes,
                     client,
                     self._action or "Unknown",
+                    request_id=request_id,
+                    spans=records,
+                    queue_wait_ns=getattr(self, "_queue_wait_ns", None),
                 )
             )
         if self.s3.audit.enabled:
@@ -2452,7 +2486,7 @@ class _Handler(BaseHTTPRequestHandler):
         self.send_response(status)
         self.send_header("Server", "MinIO-TPU")
         self.send_header(
-            "x-amz-request-id", uuid.uuid4().hex[:16].upper()
+            "x-amz-request-id", self._request_id()
         )
         for k, v in headers.items():
             self.send_header(k, v)

@@ -13,6 +13,8 @@ import threading
 import time
 from bisect import bisect_left
 
+from ..utils import spans
+
 START_TIME = time.time()
 
 # Serving-path latency distributions (cmd/metrics.go httpRequestsDuration).
@@ -659,6 +661,28 @@ class Metrics:
             "miniotpu_iopool_slowest_job_seconds", "gauge",
             "Longest single I/O job observed (the slowest-disk signal)",
             [({}, f'{io["slowest_job_seconds"]:.6f}')],
+        )
+        # interpreter contention (utils/spans.py): how late a 20 ms
+        # sleep wakes up, on a thread of its own and on each server loop
+        probe = snap[spans.PROBE_NAME]
+        emit(
+            "miniotpu_interpreter_probe_late_seconds_total", "counter",
+            "Lateness of the probe thread's 20 ms wake-ups, summed"
+            " (divide by the samples: mean wait for the interpreter)",
+            [({}, f'{probe["late_seconds"]:.6f}')],
+        )
+        emit(
+            "miniotpu_interpreter_probe_samples_total", "counter",
+            "Wake-ups of the interpreter probe thread",
+            [({}, probe["samples"])],
+        )
+        emit(
+            "miniotpu_server_loop_lag_seconds_total", "counter",
+            "Lateness of each server loop's 20 ms timer, summed",
+            [
+                ({"loop": str(c["loop"])}, f'{c["late_seconds"]:.6f}')
+                for c in probe["loops"]
+            ],
         )
 
     @staticmethod

@@ -106,9 +106,21 @@ def trace_info(
     bytes_out: int,
     client: str,
     api: str,
+    request_id: str = "",
+    spans: "list[dict] | None" = None,
+    queue_wait_ns: "int | None" = None,
 ) -> dict:
-    """The pkg/trace.Info DTO shape, trimmed to JSON-friendly fields."""
-    return {
+    """The pkg/trace.Info DTO shape, trimmed to JSON-friendly fields.
+
+    ``spans`` (utils/spans.py, recorded only while a subscriber
+    listens): the request's spans, root first - name, ``start_us`` from
+    the root's start, ``dur_us``, ``parent`` (index into this list, -1
+    for the root), the ``role`` of the thread they ran on and, on the
+    spans that bound a layer, ``cpu_us``.  ``request_id`` is the
+    ``x-amz-request-id`` the client got; ``queue_wait_us`` the time the
+    request sat in its loop's handler queue before the root span began
+    (async plane)."""
+    info = {
         "node": node,
         "time": time.time(),
         "api": api,
@@ -121,6 +133,13 @@ def trace_info(
         "tx": bytes_out,
         "client": client,
     }
+    if request_id:
+        info["request_id"] = request_id
+    if spans:
+        info["spans"] = spans
+        if queue_wait_ns is not None:
+            info["queue_wait_us"] = queue_wait_ns // 1000
+    return info
 
 
 class AuditLog:

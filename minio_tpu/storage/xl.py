@@ -23,6 +23,7 @@ import uuid
 
 from . import errors
 from .api import DiskInfo, ShardReader, ShardWriter, StatInfo, StorageAPI, VolInfo
+from ..utils import spans
 from .meta import FileInfo, XLMeta
 
 SYS_DIR = ".sys"
@@ -40,9 +41,11 @@ class _FileShardWriter(ShardWriter):
         os.makedirs(os.path.dirname(path), exist_ok=True)
         self._f = open(path, "wb")
 
+    @spans.spanned(spans.XL_SHARD_WRITE)
     def write(self, data: bytes) -> None:
         self._f.write(data)
 
+    @spans.spanned(spans.XL_SHARD_FSYNC)
     def close(self) -> None:
         self._f.flush()
         os.fsync(self._f.fileno())
@@ -58,6 +61,7 @@ class _FileShardReader(ShardReader):
         except IsADirectoryError:
             raise errors.IsNotRegular(path) from None
 
+    @spans.spanned(spans.XL_SHARD_READ)
     def read_at(self, offset: int, length: int) -> bytes:
         self._f.seek(offset)
         return self._f.read(length)
@@ -206,6 +210,7 @@ class XLStorage(StorageAPI):
                 break
         return out
 
+    @spans.spanned(spans.XL_READ_ALL)
     def read_all(self, volume: str, path: str) -> bytes:
         self._require_vol(volume)
         try:
@@ -216,6 +221,7 @@ class XLStorage(StorageAPI):
         except IsADirectoryError:
             raise errors.IsNotRegular(path) from None
 
+    @spans.spanned(spans.XL_WRITE_ALL)
     def write_all(self, volume: str, path: str, data: bytes) -> None:
         self._require_vol(volume)
         full = self._file_path(volume, path)
@@ -231,6 +237,7 @@ class XLStorage(StorageAPI):
             os.fsync(f.fileno())
         os.replace(tmp, full)
 
+    @spans.spanned(spans.XL_DELETE_FILE)
     def delete_file(self, volume: str, path: str, recursive: bool = False) -> None:
         self._require_vol(volume)
         full = self._file_path(volume, path)
@@ -297,6 +304,7 @@ class XLStorage(StorageAPI):
         raw = self.read_all(volume, f"{path}/{XL_META}")
         return XLMeta.from_bytes(raw, volume, path)
 
+    @spans.spanned(spans.XL_READ_VERSION)
     def read_version(
         self, volume: str, path: str, version_id: str = ""
     ) -> FileInfo:
@@ -318,6 +326,7 @@ class XLStorage(StorageAPI):
         xl.add_version(fi)
         self.write_all(volume, f"{path}/{XL_META}", xl.to_bytes())
 
+    @spans.spanned(spans.XL_DELETE_VERSION)
     def delete_version(self, volume: str, path: str, fi: FileInfo) -> None:
         xl = self.read_xl(volume, path)
         victim = xl.delete_version(fi.version_id)
@@ -333,6 +342,7 @@ class XLStorage(StorageAPI):
         else:
             self.delete_file(volume, f"{path}/{XL_META}")
 
+    @spans.spanned(spans.XL_RENAME_DATA)
     def rename_data(
         self,
         src_volume: str,

@@ -9,6 +9,8 @@ from __future__ import annotations
 
 import hashlib
 
+from . import spans
+
 
 class BadDigest(Exception):
     def __init__(self, want: str, got: str):
@@ -41,6 +43,7 @@ class HashReader:
         self._want_sha = sha256_hex.lower()
         self._eof = False
 
+    @spans.spanned(spans.HASHREADER_READ)
     def read(self, n: int = -1) -> bytes:
         if self._eof:
             return b""

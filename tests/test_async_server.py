@@ -290,6 +290,29 @@ def test_slow_loris_timeout_threaded(leakcheck, tmp_path):
         _teardown(booted)
 
 
+@pytest.mark.parametrize("mode", ["async", "threaded"])
+def test_idle_keepalive_closes_without_a_reply(leakcheck, tmp_path, mode):
+    """A kept-alive connection that sits idle past MINIO_TPU_IDLE_TIMEOUT_S
+    is closed with no bytes on the wire: a 408 written there would be read
+    by the client as the answer to its NEXT request (the benchmark's admin
+    client met exactly that after a set-up of over a minute)."""
+    booted = _boot(tmp_path, mode, MINIO_TPU_IDLE_TIMEOUT_S="0.5")
+    try:
+        c = S3Client(booted.srv.endpoint)
+        s = _connect(booted.srv)
+        try:
+            s.sendall(_signed_head(c, "GET", "/"))
+            f = s.makefile("rb")
+            status, _, _ = _read_response(f)
+            assert status == 200
+            s.settimeout(8.0)
+            assert f.read(1) == b""  # EOF, and nothing before it
+        finally:
+            s.close()
+    finally:
+        _teardown(booted)
+
+
 # -- backpressure + admission ---------------------------------------------
 
 
