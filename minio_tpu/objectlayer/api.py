@@ -204,6 +204,52 @@ def prepare_copy_meta(src_info, metadata: "dict | None") -> dict:
     return meta
 
 
+class ObjectReader:
+    """What ``get_object_n_info`` hands back (GetObjectNInfo's
+    GetObjectReader, cmd/erasure-object.go:141-190): the ObjectInfo the
+    one metadata read found, and the means to stream bytes of exactly
+    that version.  Whatever the read holds (a namespace read lock) is
+    held until ``close()``, so the caller closes on every exit; ``with``
+    does."""
+
+    def __init__(self, info: ObjectInfo, stream, close=None):
+        self.info = info
+        self._stream = stream
+        self._close = close
+
+    def stream(self, writer, offset: int = 0, length: int = -1,
+               sse=None) -> ObjectInfo:
+        """Write [offset, offset + length) of the object to ``writer``."""
+        return self._stream(writer, offset, length, sse)
+
+    def close(self) -> None:
+        close, self._close = self._close, None
+        if close is not None:
+            close()
+
+    def __enter__(self) -> "ObjectReader":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()
+
+
+def reader_from_info_and_get(ol, bucket, object_name, version_id="",
+                             **info_kw) -> ObjectReader:
+    """``get_object_n_info`` for a layer that has no single locked read:
+    its own ``get_object_info`` now, its own ``get_object`` when the
+    caller streams; nothing is held in between.  ``info_kw`` is what the
+    layer's ``get_object_info`` takes beyond the interface (the S3
+    gateway's ``sse`` pass-through)."""
+    info = ol.get_object_info(bucket, object_name, version_id, **info_kw)
+    return ObjectReader(
+        info,
+        lambda writer, offset, length, sse: ol.get_object(
+            bucket, object_name, writer, offset, length, version_id, sse
+        ),
+    )
+
+
 class ObjectLayer:
     """Abstract object store (subset grows as surfaces land)."""
 
@@ -238,6 +284,17 @@ class ObjectLayer:
         offset: int = 0, length: int = -1, version_id: str = "",
     ) -> ObjectInfo:
         raise NotImplementedError
+
+    def get_object_n_info(
+        self, bucket: str, object_name: str, version_id: str = "",
+        **info_kw,
+    ) -> ObjectReader:
+        """The served GET's entry: one metadata read answers the headers
+        and the body.  A layer with a namespace lock overrides this to
+        read under the lock it streams under."""
+        return reader_from_info_and_get(
+            self, bucket, object_name, version_id, **info_kw
+        )
 
     def delete_object(
         self, bucket: str, object_name: str, version_id: str = ""

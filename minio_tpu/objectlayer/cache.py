@@ -19,7 +19,7 @@ import os
 import shutil
 import threading
 
-from .api import ObjectInfo, ObjectNotFound
+from .api import ObjectInfo, ObjectNotFound, reader_from_info_and_get
 
 # GC watermarks (disk-cache.go cacheGCHighWater/LowWater defaults)
 HIGH_WATERMARK = 0.80
@@ -248,6 +248,14 @@ class CacheObjectLayer:
                 raise
         return self._ol.get_object(
             bucket, object_name, writer, offset, length
+        )
+
+    def get_object_n_info(self, bucket, object_name, version_id="",
+                          **info_kw):
+        # defined here, not left to __getattr__: the backend's own
+        # entry would stream past this layer's get_object and its cache
+        return reader_from_info_and_get(
+            self, bucket, object_name, version_id, **info_kw
         )
 
     # -- writes invalidate ------------------------------------------------

@@ -9,6 +9,7 @@ the codec work itself is the batched device pass in codec/erasure.py.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import time
 import uuid
@@ -734,29 +735,73 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
             parts=list(fi.parts),
         )
 
+    def _read_fileinfo_locked(
+        self, bucket, object_name, version_id=""
+    ) -> FileInfo:
+        """The one quorum metadata read of a GET; the caller holds the
+        namespace read lock."""
+        # latest-version GETs consult the read cache's FileInfo
+        # side-car before fanning xl.meta reads across the set; the
+        # namespace lock orders the store against any mutation's
+        # post-commit invalidate, so a cached FileInfo is never
+        # staler than what an uncached quorum read would return
+        rc = rcache.read_cache() if not version_id else None
+        fi = rc.meta_lookup(bucket, object_name) if rc else None
+        if fi is None:
+            fi, _ = self._read_quorum_fileinfo(
+                bucket, object_name, version_id
+            )
+            if rc is not None and not fi.deleted:
+                rc.meta_store(bucket, object_name, fi)
+        if fi.deleted:
+            raise ObjectNotFound(f"{bucket}/{object_name}")
+        return fi
+
+    def get_object_n_info(
+        self, bucket, object_name, version_id=""
+    ) -> api.ObjectReader:
+        """GetObjectNInfo (cmd/erasure-object.go:141-190): take the
+        namespace read lock, read xl.meta from the set once, and hand
+        back the ObjectInfo with a reader that streams that same
+        FileInfo and holds the lock until it is closed."""
+        check_object_name(object_name)
+        self._require_bucket(bucket)
+        with contextlib.ExitStack() as held:
+            held.enter_context(self.nslock.read(bucket, object_name))
+            fi = self._read_fileinfo_locked(
+                bucket, object_name, version_id
+            )
+            unlock = held.pop_all().close
+        return api.ObjectReader(
+            self._to_object_info(bucket, object_name, fi),
+            lambda writer, offset, length, sse: self.get_object(
+                bucket, object_name, writer, offset, length, version_id,
+                sse, locked_fi=fi,
+            ),
+            unlock,
+        )
+
     @spans.spanned(spans.OL_GET_OBJECT)
     def get_object(
         self, bucket, object_name, writer, offset=0, length=-1,
-        version_id="", sse=None,
+        version_id="", sse=None, *, locked_fi: "FileInfo | None" = None,
     ) -> ObjectInfo:
-        check_object_name(object_name)
-        self._require_bucket(bucket)
-        with self.nslock.read(bucket, object_name):
-            # latest-version GETs consult the read cache's FileInfo
-            # side-car before fanning xl.meta reads across the set; the
-            # namespace lock orders the store against any mutation's
-            # post-commit invalidate, so a cached FileInfo is never
-            # staler than what an uncached quorum read would return
-            rc = rcache.read_cache() if not version_id else None
-            fi = rc.meta_lookup(bucket, object_name) if rc else None
+        """``locked_fi`` is ``get_object_n_info``'s: its reader already
+        holds the lock and the FileInfo, so neither is taken again (a
+        second read lock behind a waiting writer would never be
+        granted)."""
+        if locked_fi is None:
+            check_object_name(object_name)
+            self._require_bucket(bucket)
+            lock = self.nslock.read(bucket, object_name)
+        else:
+            lock = contextlib.nullcontext()
+        with lock:
+            fi = locked_fi
             if fi is None:
-                fi, _ = self._read_quorum_fileinfo(
+                fi = self._read_fileinfo_locked(
                     bucket, object_name, version_id
                 )
-                if rc is not None and not fi.deleted:
-                    rc.meta_store(bucket, object_name, fi)
-            if fi.deleted:
-                raise ObjectNotFound(f"{bucket}/{object_name}")
             compressed = bool(fi.metadata.get(compmod.META_COMPRESSION))
             encrypted = bool(fi.metadata.get(ssemod.META_SSE))
             transformed = compressed or encrypted

@@ -125,6 +125,11 @@ class ErasureSets(ObjectLayer):
             bucket, object_name, version_id
         )
 
+    def get_object_n_info(self, bucket, object_name, version_id=""):
+        return self.set_for(object_name).get_object_n_info(
+            bucket, object_name, version_id
+        )
+
     def device_scan_source(self, bucket, object_name):
         return self.set_for(object_name).device_scan_source(
             bucket, object_name

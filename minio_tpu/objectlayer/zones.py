@@ -4,6 +4,16 @@ The top-level ObjectLayer in server mode (newObjectLayer,
 server-main.go:559): writes go to the zone with the most free space
 (getAvailableZoneIdx, erasure-zones.go:113), reads/deletes query zones in
 order, listings merge across zones.  Each zone is an ErasureSets.
+
+A single zone skips every probe.  Finding the owning zone costs one
+quorum read of xl.meta per zone asked, and with one zone the answer
+decides nothing: placement (`_put_zone_index`) and every read, delete
+and metadata update go straight to ``zones[0]``, whose own quorum read
+is then the request's only one.  The reference short-circuits the same
+calls on ``SingleZone()`` (cmd/erasure-zones.go).  With several zones
+the probe's answer is kept, not thrown away: ``get_object_info`` returns
+what `_find_zone` read, and ``get_object`` / ``get_object_n_info`` ask
+each zone for the object itself, so the owning zone is read once.
 """
 
 from __future__ import annotations
@@ -148,17 +158,32 @@ class ErasureZones(ObjectLayer):
                 return i
         return len(self.zones) - 1
 
-    def _find_zone(self, bucket: str, object_name: str, version_id=""):
-        last_err: Exception = api.ObjectNotFound(
-            f"{bucket}/{object_name}"
-        )
+    def _first_hit(self, call):
+        """``call(zone)`` of the first zone, in order, that does not
+        answer not-found; the last zone's not-found otherwise.  A zone
+        that misses has read its xl.meta once and done nothing else."""
+        last_err: Exception = api.ObjectNotFound("no zone has the object")
         for z in self.zones:
             try:
-                z.get_object_info(bucket, object_name, version_id)
-                return z
+                return z, call(z)
             except (api.ObjectNotFound, api.VersionNotFound) as e:
                 last_err = e
         raise last_err
+
+    def _find_zone(self, bucket: str, object_name: str, version_id=""):
+        """(owning zone, the ObjectInfo its probe read)."""
+        return self._first_hit(
+            lambda z: z.get_object_info(bucket, object_name, version_id)
+        )
+
+    def _zone_of(self, bucket: str, object_name: str, version_id=""):
+        """The zone an existing object's mutation goes to.  With one
+        zone there is nothing to choose (SingleZone(),
+        cmd/erasure-zones.go): no probe, the zone's own read under its
+        lock answers not-found."""
+        if len(self.zones) == 1:
+            return self.zones[0]
+        return self._find_zone(bucket, object_name, version_id)[0]
 
     # -- buckets ----------------------------------------------------------
 
@@ -216,26 +241,33 @@ class ErasureZones(ObjectLayer):
     def get_object(self, bucket, object_name, writer, offset=0, length=-1,
                    version_id="", sse=None):
         self.zones[0].get_bucket_info(bucket)
-        z = self._find_zone(bucket, object_name, version_id)
-        return z.get_object(
-            bucket, object_name, writer, offset, length, version_id,
-            sse,
-        )
+        # a zone without the object says so before it writes a byte
+        return self._first_hit(
+            lambda z: z.get_object(
+                bucket, object_name, writer, offset, length, version_id,
+                sse,
+            )
+        )[1]
+
+    def get_object_n_info(self, bucket, object_name, version_id=""):
+        self.zones[0].get_bucket_info(bucket)
+        return self._first_hit(
+            lambda z: z.get_object_n_info(bucket, object_name, version_id)
+        )[1]
 
     def get_object_info(self, bucket, object_name, version_id=""):
         self.zones[0].get_bucket_info(bucket)
-        z = self._find_zone(bucket, object_name, version_id)
-        return z.get_object_info(bucket, object_name, version_id)
+        return self._find_zone(bucket, object_name, version_id)[1]
 
     def device_scan_source(self, bucket, object_name):
         self.zones[0].get_bucket_info(bucket)
-        z = self._find_zone(bucket, object_name, "")
+        z = self._zone_of(bucket, object_name)
         return z.device_scan_source(bucket, object_name)
 
     def update_object_meta(self, bucket, object_name, updates,
                            version_id=""):
         self.zones[0].get_bucket_info(bucket)
-        z = self._find_zone(bucket, object_name, version_id)
+        z = self._zone_of(bucket, object_name, version_id)
         out = z.update_object_meta(
             bucket, object_name, updates, version_id
         )
@@ -269,7 +301,7 @@ class ErasureZones(ObjectLayer):
             object_path_updated(f"{bucket}/{object_name}")
             return dinfo
         try:
-            z = self._find_zone(bucket, object_name, version_id)
+            z = self._zone_of(bucket, object_name, version_id)
         except (api.ObjectNotFound, api.VersionNotFound):
             # the named version may be a delete marker, invisible to
             # get_object_info - fall back to the journal probe
@@ -285,7 +317,7 @@ class ErasureZones(ObjectLayer):
                     sse=None):
         from ..utils.pipe import streaming_copy
 
-        src_zone = self._find_zone(src_bucket, src_object)
+        src_zone, src_info = self._find_zone(src_bucket, src_object)
         if src_bucket == dst_bucket and src_object == dst_object:
             # self-copy: delegate down to the set, whose sequential
             # path avoids the namespace-lock deadlock
@@ -295,20 +327,19 @@ class ErasureZones(ObjectLayer):
             )
             object_path_updated(f"{dst_bucket}/{dst_object}")
             return info
-        info = src_zone.get_object_info(src_bucket, src_object)
-        meta = api.prepare_copy_meta(info, metadata)
+        meta = api.prepare_copy_meta(src_info, metadata)
         return streaming_copy(
             lambda sink: src_zone.get_object(
                 src_bucket, src_object, sink, sse=sse_src
             ),
             lambda source: self.put_object(
-                dst_bucket, dst_object, source, info.size, meta,
+                dst_bucket, dst_object, source, src_info.size, meta,
                 versioned=versioned, sse=sse,
             ),
         )
 
     def heal_object(self, bucket, object_name, version_id="", dry_run=False):
-        z = self._find_zone(bucket, object_name, version_id)
+        z = self._find_zone(bucket, object_name, version_id)[0]
         return z.heal_object(bucket, object_name, version_id, dry_run)
 
     def probe_object_health(self, bucket, object_name, version_id=""):

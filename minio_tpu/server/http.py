@@ -2462,51 +2462,56 @@ class _Handler(BaseHTTPRequestHandler):
     def _get_object(self, bucket, key, query):
         """Stream the object body straight to the socket: headers go out
         first (size known from metadata), then the erasure decode writes
-        block-by-block into wfile - constant memory per request."""
+        block-by-block into wfile - constant memory per request.  One
+        metadata read serves both: the reader holds the namespace lock
+        from that read until it is closed, on every way out."""
         ol = self.s3.object_layer
         version_id = query.get("versionId", [""])[0]
-        info, sse = self._read_info_and_sse(ol, bucket, key, version_id)
-        self._check_conditions(info)
-        rng = self._parse_range(info.size)
-        headers = self._object_headers(info)
-        headers.update(self._sse_response_headers(info.user_defined))
-        headers.pop("Content-Type-Override", None)
-        # tag count rides GET responses only (GetObject API contract)
-        tag_enc = info.user_defined.get("x-amz-tagging", "")
-        if tag_enc:
-            headers["x-amz-tagging-count"] = str(len(tag_enc.split("&")))
-        ct = info.content_type or "application/octet-stream"
-        if rng:
-            lo, hi = rng
-            status, length = 206, hi - lo + 1
-            headers["Content-Range"] = f"bytes {lo}-{hi}/{info.size}"
-        else:
-            status, length = 200, info.size
-            lo = 0
-        self.send_response(status)
-        self.send_header("Server", "MinIO-TPU")
-        self.send_header(
-            "x-amz-request-id", self._request_id()
+        reader, sse = self._open_object_and_sse(
+            ol, bucket, key, version_id
         )
-        for k, v in headers.items():
-            self.send_header(k, v)
-        self.send_header("Content-Type", ct)
-        self.send_header("Content-Length", str(length))
-        self.end_headers()
-        if length:
-            try:
-                ol.get_object(
-                    bucket, key, self.wfile, lo, length, version_id,
-                    sse,
+        with reader:
+            info = reader.info
+            self._check_conditions(info)
+            rng = self._parse_range(info.size)
+            headers = self._object_headers(info)
+            headers.update(self._sse_response_headers(info.user_defined))
+            headers.pop("Content-Type-Override", None)
+            # tag count rides GET responses only (GetObject API contract)
+            tag_enc = info.user_defined.get("x-amz-tagging", "")
+            if tag_enc:
+                headers["x-amz-tagging-count"] = str(
+                    len(tag_enc.split("&"))
                 )
-                self._resp_bytes += length
-            except Exception:  # noqa: BLE001
-                # headers already sent; the only honest signal is a
-                # broken connection (the reference behaves the same)
-                self.close_connection = True
-                raise ConnectionError(
-                    "mid-stream decode failure"
-                ) from None
+            ct = info.content_type or "application/octet-stream"
+            if rng:
+                lo, hi = rng
+                status, length = 206, hi - lo + 1
+                headers["Content-Range"] = f"bytes {lo}-{hi}/{info.size}"
+            else:
+                status, length = 200, info.size
+                lo = 0
+            self.send_response(status)
+            self.send_header("Server", "MinIO-TPU")
+            self.send_header(
+                "x-amz-request-id", self._request_id()
+            )
+            for k, v in headers.items():
+                self.send_header(k, v)
+            self.send_header("Content-Type", ct)
+            self.send_header("Content-Length", str(length))
+            self.end_headers()
+            if length:
+                try:
+                    reader.stream(self.wfile, lo, length, sse)
+                    self._resp_bytes += length
+                except Exception:  # noqa: BLE001
+                    # headers already sent; the only honest signal is a
+                    # broken connection (the reference behaves the same)
+                    self.close_connection = True
+                    raise ConnectionError(
+                        "mid-stream decode failure"
+                    ) from None
         from ..event.event import EventName
 
         self._notify(
@@ -2801,7 +2806,7 @@ class _Handler(BaseHTTPRequestHandler):
         return info, self._read_sse(info, copy_source=True)
 
     def _read_info_and_sse(self, ol, bucket, key, version_id):
-        """(info, read-spec) for a GET/HEAD.  Gateway layers do SSE
+        """(info, read-spec) for a HEAD.  Gateway layers do SSE
         pass-through: the UPSTREAM owns encryption, so the request's
         customer key rides the gateway HEAD/GET verbatim and the
         local _read_sse guards do not apply (gateway-s3-sse.go)."""
@@ -2815,6 +2820,24 @@ class _Handler(BaseHTTPRequestHandler):
             return info, spec
         info = ol.get_object_info(bucket, key, version_id)
         return info, self._read_sse(info)
+
+    def _open_object_and_sse(self, ol, bucket, key, version_id):
+        """(reader, read-spec) for a GET: _read_info_and_sse with an
+        ObjectReader, which the caller closes, in the info's place."""
+        if getattr(ol, "sse_passthrough", False):
+            spec = self._parse_ssec_headers(
+                "x-amz-server-side-encryption-customer"
+            )
+            reader = ol.get_object_n_info(
+                bucket, key, version_id, sse=spec
+            )
+            return reader, spec
+        reader = ol.get_object_n_info(bucket, key, version_id)
+        try:
+            return reader, self._read_sse(reader.info)
+        except BaseException:
+            reader.close()
+            raise
 
     def _read_sse(self, info, copy_source: bool = False):
         """Spec needed to READ ``info``; enforces that SSE-C objects

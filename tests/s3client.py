@@ -89,6 +89,31 @@ class S3Client:
         headers: "dict[str, str] | None" = None,
         sign: bool = True,
     ) -> S3Response:
+        url, headers = self.signed(
+            method, path, query, body, headers, sign
+        )
+        conn = self._connect()
+        try:
+            conn.request(method, url, body=body or None, headers=headers)
+            resp = conn.getresponse()
+            data = resp.read()
+            return S3Response(
+                resp.status, {k.lower(): v for k, v in resp.getheaders()}, data
+            )
+        finally:
+            conn.close()
+
+    def signed(
+        self,
+        method: str,
+        path: str,
+        query: "dict[str, str] | None" = None,
+        body: bytes = b"",
+        headers: "dict[str, str] | None" = None,
+        sign: bool = True,
+    ) -> "tuple[str, dict[str, str]]":
+        """(url, headers) of the request, for a test that drives the
+        connection itself (``_connect()``)."""
         query = dict(query or {})
         headers = {k.lower(): v for k, v in (headers or {}).items()}
         amz_date = datetime.datetime.now(
@@ -113,16 +138,7 @@ class S3Client:
             )
         qs = urllib.parse.urlencode(query)
         url = urllib.parse.quote(path) + (f"?{qs}" if qs else "")
-        conn = self._connect()
-        try:
-            conn.request(method, url, body=body or None, headers=headers)
-            resp = conn.getresponse()
-            data = resp.read()
-            return S3Response(
-                resp.status, {k.lower(): v for k, v in resp.getheaders()}, data
-            )
-        finally:
-            conn.close()
+        return url, headers
 
     # -- conveniences -----------------------------------------------------
 

@@ -30,26 +30,37 @@ def _get(layer, key, **kw):
     return buf.getvalue()
 
 
-def test_read_through_and_hit(layers):
+def _get_n_info(layer, key, **kw):
+    """The served GET's way in: it must not slip past the cache to the
+    backend's own reader."""
+    buf = io.BytesIO()
+    with layer.get_object_n_info("bkt", key) as reader:
+        reader.stream(buf, **kw)
+    return buf.getvalue()
+
+
+@pytest.mark.parametrize("get", [_get, _get_n_info])
+def test_read_through_and_hit(layers, get):
     backend, cache = layers
     data = os.urandom(9000)
     cache.put_object("bkt", "obj", io.BytesIO(data), len(data))
-    assert _get(cache, "obj") == data  # miss: populates
+    assert get(cache, "obj") == data  # miss: populates
     assert cache.misses == 1 and cache.hits == 0
-    assert _get(cache, "obj") == data  # hit
+    assert get(cache, "obj") == data  # hit
     assert cache.hits == 1
     # range served from the cached whole object
-    assert _get(cache, "obj", offset=100, length=50) == data[100:150]
+    assert get(cache, "obj", offset=100, length=50) == data[100:150]
     assert cache.hits == 2
 
 
-def test_overwrite_invalidates(layers):
+@pytest.mark.parametrize("get", [_get, _get_n_info])
+def test_overwrite_invalidates(layers, get):
     backend, cache = layers
     cache.put_object("bkt", "obj", io.BytesIO(b"v1-data!"), 8)
-    assert _get(cache, "obj") == b"v1-data!"
-    assert _get(cache, "obj") == b"v1-data!"
+    assert get(cache, "obj") == b"v1-data!"
+    assert get(cache, "obj") == b"v1-data!"
     cache.put_object("bkt", "obj", io.BytesIO(b"v2-data!"), 8)
-    assert _get(cache, "obj") == b"v2-data!"  # not the stale v1
+    assert get(cache, "obj") == b"v2-data!"  # not the stale v1
 
 
 def test_stale_etag_detected_even_without_invalidate(layers):

@@ -232,3 +232,70 @@ def test_storage_info(setup):
     si = ol.storage_info()
     assert si["disks"] == 6 and si["online"] == 6
     assert si["data"] == 3 and si["parity"] == 3
+
+
+# ---------------------------------------------------------------------------
+# get_object_n_info: one read for the headers and the body
+# ---------------------------------------------------------------------------
+
+
+def _erasure_layer(tmp_path):
+    disks = [XLStorage(str(tmp_path / f"n{i}")) for i in range(4)]
+    return ErasureObjects(disks, block_size=BLOCK)
+
+
+def _fs_layer(tmp_path):
+    from minio_tpu.objectlayer.fs import FSObjects
+
+    # overrides nothing: ObjectLayer's default, info then get_object
+    return FSObjects(str(tmp_path / "drive"))
+
+
+@pytest.mark.parametrize("make", [_erasure_layer, _fs_layer])
+def test_get_object_n_info_is_info_plus_get(tmp_path, make):
+    ol = make(tmp_path)
+    ol.make_bucket("bucket")
+    payload = _payload(3 * BLOCK + 17, seed=5)
+    ol.put_object("bucket", "obj", io.BytesIO(payload), len(payload))
+    info = ol.get_object_info("bucket", "obj")
+    with ol.get_object_n_info("bucket", "obj") as reader:
+        assert reader.info == info
+        whole, part = io.BytesIO(), io.BytesIO()
+        assert reader.stream(whole).etag == info.etag
+        reader.stream(part, BLOCK - 3, BLOCK + 9)
+        with pytest.raises(api.InvalidRange):
+            reader.stream(io.BytesIO(), len(payload), 1)
+    assert whole.getvalue() == payload
+    assert part.getvalue() == payload[BLOCK - 3 : 2 * BLOCK + 6]
+    with pytest.raises(api.ObjectNotFound):
+        ol.get_object_n_info("bucket", "absent")
+    with pytest.raises(api.BucketNotFound):
+        ol.get_object_n_info("nobucket", "obj")
+
+
+def test_n_info_holds_the_read_lock_until_closed(setup):
+    """An overwrite waits for an open reader and goes through once it is
+    closed; a failed open leaves nothing held."""
+    import threading
+
+    ol, _ = setup
+    ol.put_object("bucket", "obj", io.BytesIO(b"old"), 3)
+    reader = ol.get_object_n_info("bucket", "obj")
+    done = threading.Event()
+
+    def overwrite():
+        ol.put_object("bucket", "obj", io.BytesIO(b"newer"), 5)
+        done.set()
+
+    t = threading.Thread(target=overwrite, daemon=True)
+    t.start()
+    assert not done.wait(0.3)  # the writer is behind the reader's lock
+    buf = io.BytesIO()
+    reader.stream(buf)  # no second lock behind the waiting writer
+    assert buf.getvalue() == b"old" and reader.info.size == 3
+    reader.close()
+    reader.close()  # idempotent
+    assert done.wait(10)
+    with pytest.raises(api.ObjectNotFound):
+        ol.get_object_n_info("bucket", "absent")
+    assert not ol.nslock._locks  # every lock given back

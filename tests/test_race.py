@@ -17,6 +17,7 @@ import hashlib
 import io
 import os
 import threading
+import time
 
 import pytest
 
@@ -412,3 +413,121 @@ def test_concurrent_server_requests(tmp_path):
         assert r.status == 200
     finally:
         srv.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# a GET reads xl.meta once, under the lock it streams under
+# ---------------------------------------------------------------------------
+
+BIG_BLOCK = 1 << 20
+
+
+@pytest.fixture()
+def zoned_server(tmp_path):
+    """The served stack as `python -m minio_tpu.server` builds it: one
+    zone of one set behind ErasureZones."""
+    from minio_tpu.objectlayer.sets import ErasureSets
+    from minio_tpu.objectlayer.zones import ErasureZones
+
+    disks = [XLStorage(str(tmp_path / f"zd{i}")) for i in range(4)]
+    ol = ErasureZones([ErasureSets(disks, 1, 4, block_size=BIG_BLOCK)])
+    srv = S3Server(ol, address="127.0.0.1:0").start()
+    try:
+        assert S3Client(srv.endpoint).make_bucket("lockb").status == 200
+        yield srv
+    finally:
+        srv.shutdown()
+
+
+def test_get_headers_and_body_are_one_version(zoned_server):
+    """Overwrites with another size race GETs of the key: every answer's
+    body has the length and the MD5 its own headers state."""
+    payloads = [os.urandom(n) for n in (700, 300_005, 65_536, 1_500_000)]
+    boot = S3Client(zoned_server.endpoint, timeout=60)
+    assert boot.put_object("lockb", "hot", payloads[0]).status == 200
+    stop = threading.Event()
+    seen = [0]
+
+    def writer():
+        c = S3Client(zoned_server.endpoint, timeout=60)
+        try:
+            for r in range(30):
+                # readers get in between two writes: a writer that never
+                # lets go starves them, and the race with them
+                time.sleep(0.03)
+                p = payloads[r % len(payloads)]
+                assert c.put_object("lockb", "hot", p).status == 200
+        finally:
+            stop.set()
+
+    def reader():
+        c = S3Client(zoned_server.endpoint, timeout=60)
+        while not stop.is_set():
+            # headers of one version before a shorter one's bytes end in
+            # a cut connection: http.client raises, and that fails too
+            got = c.get_object("lockb", "hot")
+            assert got.status == 200
+            assert len(got.body) == int(got.headers["content-length"])
+            etag = got.headers["etag"].strip('"')
+            assert hashlib.md5(got.body).hexdigest() == etag
+            seen[0] += 1
+
+    _run_all([writer, reader, reader, reader])
+    assert seen[0] > 0
+
+
+CKEY = bytes(range(32))
+
+
+def _hang_up_mid_body(client):
+    """GET the big object, read the first bytes, and go away."""
+    url, headers = client.signed("GET", "/lockb/big")
+    conn = client._connect()
+    try:
+        conn.request("GET", url, headers=headers)
+        resp = conn.getresponse()
+        assert resp.status == 200
+        assert len(resp.read(4096)) == 4096
+        resp.close()
+    finally:
+        conn.close()
+
+
+# (object, request headers, status) of a GET that ends before or inside
+# its body; each must leave the key's read lock released
+_EARLY_EXITS = {
+    "not_modified_304": ("plain", {"If-None-Match": "*"}, 304),
+    "precondition_412": ("plain", {"If-Match": '"nope"'}, 412),
+    "invalid_range_416": ("plain", {"Range": "bytes=999999-"}, 416),
+    "ssec_key_missing": ("sealed", {}, 400),
+    "client_closes_mid_body": ("big", None, 200),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_EARLY_EXITS))
+def test_get_early_exit_releases_lock(zoned_server, case):
+    from minio_tpu.codec import sse as ssemod
+
+    key, headers, status = _EARLY_EXITS[case]
+    # a leaked read lock holds a PUT for the lock's 30 s and then fails
+    # it: the client's own timeout turns that into this test's failure
+    client = S3Client(zoned_server.endpoint, timeout=15)
+    ol = zoned_server.object_layer
+    if key == "sealed":
+        ol.put_object(
+            "lockb", key, io.BytesIO(b"s" * 5000), 5000,
+            sse=ssemod.SSESpec("C", CKEY),
+        )
+    elif key == "big":
+        big = os.urandom(32 * BIG_BLOCK)
+        ol.put_object("lockb", key, io.BytesIO(big), len(big))
+    else:
+        assert client.put_object("lockb", key, b"p" * 5000).status == 200
+    if headers is None:
+        _hang_up_mid_body(client)
+    else:
+        got = client.get_object("lockb", key, headers=headers)
+        assert got.status == status, got.body
+    assert client.put_object("lockb", key, b"after").status == 200
+    got = client.get_object("lockb", key)
+    assert (got.status, got.body) == (200, b"after")

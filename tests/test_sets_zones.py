@@ -264,14 +264,135 @@ def test_zones_placement_skips_full_zone(zones, monkeypatch):
     assert zones._put_zone_index("bucket", "huge", 10**12) == 1
 
 
-def test_zones_single_zone_no_probe(tmp_path):
+def _stream_n_info(z, bucket, key):
+    with z.get_object_n_info(bucket, key) as reader:
+        buf = io.BytesIO()
+        reader.stream(buf)
+        return reader.info, buf.getvalue()
+
+
+# what a single zone must do without asking who owns the key: each call,
+# and how many times it may reach the zone's get_object_info
+_SINGLE_ZONE_CALLS = {
+    "place": (lambda z: z._put_zone_index("bucket", "obj", 5), 0),
+    "get_object_info": (lambda z: z.get_object_info("bucket", "obj"), 1),
+    "get_object": (
+        lambda z: z.get_object("bucket", "obj", io.BytesIO()), 0,
+    ),
+    "get_object_n_info": (lambda z: _stream_n_info(z, "bucket", "obj"), 0),
+    "update_object_meta": (
+        lambda z: z.update_object_meta("bucket", "obj", {"k": "v"}), 0,
+    ),
+    "device_scan_source": (
+        lambda z: z.device_scan_source("bucket", "obj"), 0,
+    ),
+    "delete_object": (lambda z: z.delete_object("bucket", "obj"), 0),
+}
+
+
+@pytest.mark.parametrize("call", sorted(_SINGLE_ZONE_CALLS))
+def test_zones_single_zone_no_probe(tmp_path, call):
     z1 = ErasureSets(_disks(tmp_path, 4, "sz"), 1, 4, block_size=BLOCK)
     z = ErasureZones([z1])
+    z.make_bucket("bucket")
+    z.put_object("bucket", "obj", io.BytesIO(b"12345"), 5)
     calls = []
     orig = z1.get_object_info
     z1.get_object_info = lambda *a, **k: (calls.append(a), orig(*a, **k))[1]
-    assert z._put_zone_index("bucket", "obj", 5) == 0
-    assert calls == []  # single-zone placement never stats
+    fn, probes = _SINGLE_ZONE_CALLS[call]
+    fn(z)
+    # single-zone placement never stats, and no read or delete probes
+    # for an owner there is no choice of
+    assert len(calls) == probes
+
+
+class _CountingDisk(XLStorage):
+    """A drive that counts the xl.meta reads it serves."""
+
+    reads = 0
+
+    def read_version(self, *a, **kw):
+        self.reads += 1
+        return super().read_version(*a, **kw)
+
+
+def _counting_zone(tmp_path, prefix):
+    disks = [
+        _CountingDisk(str(tmp_path / f"{prefix}{i}")) for i in range(4)
+    ]
+    return ErasureSets(disks, 1, 4, block_size=BLOCK), disks
+
+
+def _rounds(disks) -> "list[int]":
+    return [d.reads for d in disks]
+
+
+_BODY = b"x" * (3 * BLOCK + 17)
+
+# one served operation each; every one must read xl.meta from each drive
+# of the set exactly once
+_ONE_ROUND_CALLS = {
+    "get_object_info": lambda z: z.get_object_info("bucket", "obj"),
+    "get_object_n_info": lambda z: _stream_n_info(z, "bucket", "obj"),
+    "get_object": lambda z: z.get_object("bucket", "obj", io.BytesIO()),
+    "get_object_range": lambda z: z.get_object(
+        "bucket", "obj", io.BytesIO(), 5, BLOCK
+    ),
+    "delete_object": lambda z: z.delete_object("bucket", "obj"),
+    "put_object_over": lambda z: z.put_object(
+        "bucket", "obj", io.BytesIO(b"new"), 3
+    ),
+    "update_object_meta": lambda z: z.update_object_meta(
+        "bucket", "obj", {"x-amz-tagging": "a=b"}
+    ),
+}
+
+
+@pytest.mark.parametrize("call", sorted(_ONE_ROUND_CALLS))
+def test_single_zone_one_metadata_round_per_call(tmp_path, call):
+    z1, disks = _counting_zone(tmp_path, "cz")
+    z = ErasureZones([z1])
+    z.make_bucket("bucket")
+    z.put_object("bucket", "obj", io.BytesIO(_BODY), len(_BODY))
+    before = _rounds(disks)
+    _ONE_ROUND_CALLS[call](z)
+    assert [a - b for a, b in zip(_rounds(disks), before)] == [1] * 4
+
+
+def test_n_info_streams_what_it_read(tmp_path):
+    z1, _disks_ = _counting_zone(tmp_path, "nz")
+    z = ErasureZones([z1])
+    z.make_bucket("bucket")
+    put = z.put_object("bucket", "obj", io.BytesIO(_BODY), len(_BODY))
+    info, body = _stream_n_info(z, "bucket", "obj")
+    assert (info.etag, info.size) == (put.etag, len(_BODY))
+    assert body == _BODY
+    with z.get_object_n_info("bucket", "obj") as reader:
+        buf = io.BytesIO()
+        reader.stream(buf, 7, BLOCK + 1)
+        assert buf.getvalue() == _BODY[7 : 7 + BLOCK + 1]
+    with pytest.raises(api.ObjectNotFound):
+        z.get_object_n_info("bucket", "absent")
+
+
+# with two zones and the object in the second, the first zone misses once
+# and the owner is read once: never a third round
+_TWO_ZONE_CALLS = {
+    k: _ONE_ROUND_CALLS[k]
+    for k in ("get_object_info", "get_object_n_info", "get_object")
+}
+
+
+@pytest.mark.parametrize("call", sorted(_TWO_ZONE_CALLS))
+def test_two_zones_one_round_in_each(tmp_path, call):
+    z1, d1 = _counting_zone(tmp_path, "ta")
+    z2, d2 = _counting_zone(tmp_path, "tb")
+    z = ErasureZones([z1, z2])
+    z.make_bucket("bucket")
+    z2.put_object("bucket", "obj", io.BytesIO(_BODY), len(_BODY))
+    before = _rounds(d1 + d2)
+    _TWO_ZONE_CALLS[call](z)
+    assert [a - b for a, b in zip(_rounds(d1 + d2), before)] == [1] * 8
 
 
 def test_zones_usage_snapshot_cached(zones):
