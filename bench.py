@@ -124,11 +124,11 @@ def _bench_config(k: int, m: int, trials=5) -> dict:
     assert m >= 2, "grid configs need >=2 parity shards"
     present = np.ones(k + m, dtype=bool)
     present[list(range(m - 1)) + [k + 1]] = False
-    present_t = tuple(bool(b) for b in present)
+    survivors, matrix = codec_step.host_pattern(present, k, m)
 
     def run_rec(r):
         out = codec_step.reconstruct_throughput_probe(
-            shards, present_t, k, m, r
+            shards, survivors, matrix, k, m, r
         )
         np.asarray(out[1])
 
@@ -341,19 +341,23 @@ def bench_codec_micro() -> dict:
     kshards = np.concatenate(
         [kwords, np.asarray(lp)], axis=1
     )
-    present = (False,) * km + (True,) * (n - km)
+    present = np.asarray((False,) * km + (True,) * (n - km))
+    survivors, matrix = codec_step.host_pattern(present, kk, km)
     digs = jnp.asarray(ld)
     dsh = jnp.asarray(kshards)
 
     def rec_legacy():
         ok = codec_step.verify_hashes_words(dsh, digs, kL)
-        dwords = codec_step.reconstruct_words_batch(dsh, present, kk, km)
+        dwords = codec_step.reconstruct_words_batch(
+            dsh, survivors, matrix, kk, km
+        )
         return _block((ok, dwords))
 
     def rec_fused(formulation="swar", pallas=False):
         return _block(
             codec_step.verify_and_reconstruct_words(
-                dsh, digs, present, kk, km, kL, formulation, pallas, pallas
+                dsh, digs, present, survivors, matrix, kk, km, kL,
+                formulation, pallas, pallas
             )
         )
 

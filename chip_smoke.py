@@ -19,7 +19,9 @@ it runs three children one after another and checks what they report:
    objects by 8 concurrent clients, a multi-block object with a short
    tail, a 4 KiB object and an odd-length one; every GET compared byte
    for byte with its PUT payload; STAT and DELETE; the shard files of two
-   drives removed and everything read again (device reconstruct); one
+   drives removed - one pair of drives under half the objects, another
+   pair under the rest - and everything read again (device reconstruct,
+   several loss patterns, one program: the codec child counts it); one
    object healed and read healthy; SIGTERM and a clean exit.
 3. the same server a second time on the same drives: objects
    acknowledged before the restart read back identical, and the compile
@@ -62,8 +64,11 @@ MIB = 1 << 20
 BLOCK = 10 * MIB  # blockSizeV1
 ADMIN = "/minio-tpu/admin/v1"
 BUCKET = "smoke"
-# drives whose shard files the degraded phase removes
+# drives whose shard files the degraded phase removes: the first pair
+# under every other object (and the one that is healed), the second
+# under the rest, so that the reads decode from different loss patterns
 LOST_DRIVES = (2, 7)
+LOST_DRIVES_TOO = (4, 11)
 
 
 class SmokeFailure(Exception):
@@ -91,7 +96,7 @@ def codec_child(seed: int, rehearse: bool) -> int:
     from minio_tpu.codec.backend import CpuBackend, TpuBackend
     from minio_tpu.codec.erasure import Erasure
     from minio_tpu.codec.telemetry import KERNEL_STATS
-    from minio_tpu.ops import rs_pallas
+    from minio_tpu.ops import codec_step, rs_pallas
     from minio_tpu.parallel import rules as prules
 
     info = jaxenv.device_info()  # raises if the chip is missing or held
@@ -148,8 +153,17 @@ def codec_child(seed: int, rehearse: bool) -> int:
         check(np.array_equal(dig2, want_dig), f"{label}: rerun differs")
 
         n = k + m
-        shards = np.concatenate([data, par], axis=1)
-        present = [True] * n
+        whole = np.concatenate([data, par], axis=1)
+        # a second lost pair first: a loss pattern is an operand, so the
+        # pair after it must find its program compiled
+        shards, present = whole.copy(), [True] * n
+        for lost in (1, k)[: min(m, 2)]:
+            present[lost] = False
+            shards[:, lost] = 0x5A
+        rec = be.reconstruct(shards, tuple(present), k, m)
+        check(np.array_equal(rec, data), f"{label}: reconstruct (2) differs")
+        programs = codec_step.reconstruct_words_batch._cache_size()
+        shards, present = whole.copy(), [True] * n
         for lost in (0, n - 1)[: min(m, 2)]:
             present[lost] = False
             shards[:, lost] = 0xA5  # garbage where the shard is gone
@@ -168,6 +182,10 @@ def codec_child(seed: int, rehearse: bool) -> int:
         rec = be.reconstruct(shards, tuple(present), k, m)
         rec_cold = time.monotonic() - t0
         check(np.array_equal(rec, data), f"{label}: reconstruct differs")
+        check(
+            codec_step.reconstruct_words_batch._cache_size() == programs,
+            f"{label}: a second lost pair compiled a second reconstruct",
+        )
         t0 = time.monotonic()
         dg = be.digest(shards[:, :k])
         dg_cold = time.monotonic() - t0
@@ -187,6 +205,8 @@ def codec_child(seed: int, rehearse: bool) -> int:
                 "digest": round(dg_cold, 3),
             },
             warm_encode_digest_drain_seconds=round(warm, 4),
+            lost_pairs=2,
+            reconstruct_programs=1,
             bit_identical=True,
         )
 
@@ -490,18 +510,25 @@ def served_phases(seed: int, rehearse: bool, env: dict, workdir: str,
               f"STAT {gone} after DELETE is not 404")
         del sizes[gone]
 
-        # degrade: the shard files of two drives go away
+        # degrade: the shard files of two drives go away, one pair of
+        # drives under every other object, another under the rest
         removed = 0
-        for i in LOST_DRIVES:
-            bdir = os.path.join(drives[i - 1], BUCKET)
-            for key in os.listdir(bdir):
-                shutil.rmtree(os.path.join(bdir, key))
+        for j, key in enumerate(sorted(sizes)):
+            first = j % 2 == 0 or key == "multi-tail"  # the healed one
+            for i in LOST_DRIVES if first else LOST_DRIVES_TOO:
+                shutil.rmtree(os.path.join(drives[i - 1], BUCKET, key))
                 removed += 1
         check(removed == 2 * len(sizes),
               f"removed {removed} shard dirs, expected {2 * len(sizes)}")
+        before = c.admin("GET", "kernel-stats").get("reconstruct", {})
         get_all(c, list(sizes), "degraded")
-        say(event="degraded", lost_drives=list(LOST_DRIVES),
-            shard_dirs_removed=removed, degraded_reads="identical")
+        recon = c.admin("GET", "kernel-stats")["reconstruct"]
+        seen = recon["patterns_seen"] - before.get("patterns_seen", 0)
+        check(seen >= 2, f"two lost pairs gave {seen} loss pattern(s)")
+        say(event="degraded", lost_drives=[list(LOST_DRIVES),
+                                           list(LOST_DRIVES_TOO)],
+            shard_dirs_removed=removed, degraded_reads="identical",
+            reconstruct=recon)
 
         healed = c.admin("POST", "heal", bucket=BUCKET, object="multi-tail")
         for i in LOST_DRIVES:

@@ -80,10 +80,10 @@ ENTRY_POINT_PATHS = {
 KNOWN_ENTRY_POINTS = {
     ("rs", "_encode_jit"),
     ("rs", "_reconstruct_jit"),
-    ("rs", "_reconstruct_static_jit"),
     ("rs_pallas", "_matmul_words_jit"),
     ("rs_pallas", "encode_hash_fused"),
-    ("rs_pallas", "verify_reconstruct_fused"),
+    ("rs_pallas", "matmul_rows_runtime"),
+    ("rs_pallas", "verify_reconstruct_runtime"),
     ("codec_step", "encode_and_hash_words"),
     ("codec_step", "encode_and_hash_words_digest"),
     ("codec_step", "encode_words_fused1"),
@@ -303,6 +303,16 @@ def run() -> "list[Finding]":
     def covers(mod, name):
         checked.add((mod, name))
 
+    def pattern(k, n=None):
+        """A loss pattern's operands, abstract: (present bool[n],
+        survivors int32[k], matrix uint8[k, k]).  No value, so one
+        evaluation stands for every pattern of the geometry."""
+        return (
+            S((n if n is not None else k,), jnp.bool_),
+            S((k,), jnp.int32),
+            S((k, k), u8),
+        )
+
     # ---- rs.py ----------------------------------------------------------
 
     covers("rs", "_encode_jit")
@@ -334,27 +344,17 @@ def run() -> "list[Finding]":
         except Exception as e:
             c.fail(e)
 
-    covers("rs", "_reconstruct_static_jit")
-    c = ctx(rs._reconstruct_static_jit, "minio_tpu/ops/rs.py")
+    # MTPU203: encode -> reconstruct round-trip in the byte domain; the
+    # pattern is an operand (mask + matrix), so ONE abstract evaluation
+    # stands for every loss pattern of the geometry
     for k, m, L in CONFIG_GRID:
         n = k + m
-        # worst admissible erasure: all m losses fall on data shards
-        present = (False,) * m + (True,) * (n - m)
         c.config = cfg_str(k, m, L)
         try:
-            out = rs._reconstruct_static_jit.eval_shape(
-                S((n, L), u8), present, k, m, True
-            )
-            c.shape(out, (n, L), "rebuilt (want_parity)")
-            c.dtype(out, "uint8", "rebuilt")
-            # MTPU203: encode -> reconstruct round-trip in the byte domain
             parity = rs._encode_jit.eval_shape(S((k, L), u8), k, m)
-            data_only = rs._reconstruct_static_jit.eval_shape(
+            data_only = rs._reconstruct_jit.eval_shape(
                 S((k + parity.shape[0], L), parity.dtype),
-                present,
-                k,
-                m,
-                False,
+                S((n,), u8), S((k, k), u8), k, m, False,
             )
             c.expect(
                 "MTPU203",
@@ -473,11 +473,10 @@ def run() -> "list[Finding]":
     c = ctx(codec_step.reconstruct_words_batch, "minio_tpu/ops/codec_step.py")
     for k, m, L in CONFIG_GRID:
         w, n = L // 4, k + m
-        present = (False,) * m + (True,) * (n - m)
         c.config = cfg_str(k, m, L)
         try:
             dw = codec_step.reconstruct_words_batch.eval_shape(
-                S((_BATCH, n, w), u32), present, k, m
+                S((_BATCH, n, w), u32), *pattern(k)[1:], k, m
             )
             c.shape(dw, (_BATCH, k, w), "data words")
             c.dtype(dw, "uint32", "data words")
@@ -488,7 +487,7 @@ def run() -> "list[Finding]":
             )
             rt = codec_step.reconstruct_words_batch.eval_shape(
                 S((_BATCH, k + parity.shape[1], w), parity.dtype),
-                present,
+                *pattern(k)[1:],
                 k,
                 m,
             )
@@ -509,7 +508,6 @@ def run() -> "list[Finding]":
         covers("codec_step", name)
     for k, m, L in CONFIG_GRID:
         w, n = L // 4, k + m
-        present = (False,) * m + (True,) * (n - m)
         probes = [
             (
                 codec_step.encode_throughput_probe,
@@ -517,7 +515,7 @@ def run() -> "list[Finding]":
             ),
             (
                 codec_step.reconstruct_throughput_probe,
-                (S((_BATCH, n, w), u32), present, k, m, reps),
+                (S((_BATCH, n, w), u32), *pattern(k)[1:], k, m, reps),
             ),
             (
                 codec_step.verify_throughput_probe,
@@ -583,12 +581,11 @@ def run() -> "list[Finding]":
     )
     for k, m, L in CONFIG_GRID:
         w, n = L // 4, k + m
-        present = (False,) * m + (True,) * (n - m)
         c.config = cfg_str(k, m, L) + " [portable]"
         try:
             data, ok = codec_step.verify_and_reconstruct_words.eval_shape(
                 S((_BATCH, n, w), u32), S((_BATCH, n, 8), u32),
-                present, k, m, L,
+                *pattern(k, n), k, m, L,
             )
             c.shape(data, (_BATCH, k, w), "fused GET data words")
             c.dtype(data, "uint32", "fused GET data words")
@@ -601,7 +598,7 @@ def run() -> "list[Finding]":
             rt, _ = codec_step.verify_and_reconstruct_words.eval_shape(
                 S((_BATCH, k + parity.shape[1], w), parity.dtype),
                 S(tuple(digests.shape), digests.dtype),
-                present, k, m, L,
+                *pattern(k, n), k, m, L,
             )
             c.expect(
                 "MTPU203",
@@ -613,14 +610,13 @@ def run() -> "list[Finding]":
             c.fail(e)
     for k, m, L in FUSED_GRID:
         w, n = L // 4, k + m
-        present = (False,) * m + (True,) * (n - m)
         for formulation in ("swar", "mxu"):
             c.config = cfg_str(k, m, L) + f" [pallas, {formulation}]"
             try:
                 data, ok = (
                     codec_step.verify_and_reconstruct_words.eval_shape(
                         S((_BATCH, n, w), u32), S((_BATCH, n, 8), u32),
-                        present, k, m, L, formulation, True, True,
+                        *pattern(k, n), k, m, L, formulation, True, True,
                     )
                 )
                 c.shape(data, (_BATCH, k, w), "fused GET data words")
@@ -670,7 +666,6 @@ def run() -> "list[Finding]":
     )
     for k, m, L in CONFIG_GRID:
         w, n = L // 4, k + m
-        present = (False,) * m + (True,) * (n - m)
         for cw in (w, 8):
             for fin in (False, True):
                 c.config = cfg_str(k, m, L) + f" [cw={cw}, finalize={fin}]"
@@ -682,7 +677,7 @@ def run() -> "list[Finding]":
                             S((_BATCH, n, 8), u32),
                             S((_BATCH, n, 8), u32),
                             S((), u32),
-                            present, k, m, L, fin,
+                            *pattern(k, n), k, m, L, fin,
                         )
                     )
                     c.shape(data, (_BATCH, k, cw), "chunk data words")
@@ -865,18 +860,35 @@ def run() -> "list[Finding]":
             except Exception as e:
                 c.fail(e)
 
-    covers("rs_pallas", "verify_reconstruct_fused")
-    c = ctx(rs_pallas.verify_reconstruct_fused, "minio_tpu/ops/rs_pallas.py")
+    covers("rs_pallas", "matmul_rows_runtime")
+    c = ctx(rs_pallas.matmul_rows_runtime, "minio_tpu/ops/rs_pallas.py")
     for k, m, L in FUSED_GRID:
         w, n = L // 4, k + m
-        # worst admissible erasure: all m losses fall on data shards
-        idx = tuple(range(m, n))[:k]
+        # all n rows as read against the (k, n) scattered inverse, and k
+        # compacted survivor rows against the (k, k) inverse (the mesh)
+        for rows in (n, k):
+            c.config = cfg_str(k, m, L) + f" [{rows} rows]"
+            try:
+                out = rs_pallas.matmul_rows_runtime.eval_shape(
+                    S((_BATCH, rows, w), u32), S((k, rows), u8), True
+                )
+                c.shape(out, (_BATCH, k, w), "runtime-matrix data words")
+                c.dtype(out, "uint32", "runtime-matrix data words")
+            except Exception as e:
+                c.fail(e)
+
+    covers("rs_pallas", "verify_reconstruct_runtime")
+    c = ctx(
+        rs_pallas.verify_reconstruct_runtime, "minio_tpu/ops/rs_pallas.py"
+    )
+    for k, m, L in FUSED_GRID:
+        w, n = L // 4, k + m
         for formulation in ("swar", "mxu"):
             c.config = cfg_str(k, m, L) + f" [{formulation}]"
             try:
                 data, hacc = (
-                    rs_pallas.verify_reconstruct_fused.eval_shape(
-                        S((_BATCH, n, w), u32), idx, k, m,
+                    rs_pallas.verify_reconstruct_runtime.eval_shape(
+                        S((_BATCH, n, w), u32), S((k, n), u8),
                         formulation, True,
                     )
                 )
@@ -976,15 +988,13 @@ def run() -> "list[Finding]":
     c = mesh_ctx("mesh_reconstruct")
     for k, m, L in CONFIG_GRID:
         w, n = L // 4, k + m
-        # worst admissible erasure: all m losses fall on data shards
-        idx = tuple(range(m, n))[:k]
         for mode in mesh_modes("mesh_reconstruct"):
             c.config = cfg_str(k, m, L) + f" [{mode}]"
             try:
                 out = mesh_eval(
                     "mesh_reconstruct", mode,
-                    (S((_BATCH, k, w), u32),),
-                    dict(k=k, m=m, idx=idx),
+                    (S((_BATCH, k, w), u32), S((k, k), u8)),
+                    dict(k=k, m=m),
                 )
                 c.shape(out, (_BATCH, k, w), "mesh recon words")
                 c.dtype(out, "uint32", "mesh recon words")
@@ -997,7 +1007,7 @@ def run() -> "list[Finding]":
                 surv = S((_BATCH, parity.shape[1] + (k - m), w), parity.dtype)
                 rt = mesh_eval(
                     "mesh_reconstruct", mode,
-                    (surv,), dict(k=k, m=m, idx=idx),
+                    (surv, S((k, k), u8)), dict(k=k, m=m),
                 )
                 c.expect(
                     "MTPU203",
@@ -1028,14 +1038,14 @@ def run() -> "list[Finding]":
     c = mesh_ctx("mesh_verify_reconstruct")
     for k, m, L in CONFIG_GRID:
         w, n = L // 4, k + m
-        present = (False,) * m + (True,) * (n - m)
         for mode in mesh_modes("mesh_verify_reconstruct"):
             c.config = cfg_str(k, m, L) + f" [{mode}]"
             try:
                 data, ok = mesh_eval(
                     "mesh_verify_reconstruct", mode,
-                    (S((_BATCH, n, w), u32), S((_BATCH, n, 8), u32)),
-                    dict(k=k, m=m, present=present, shard_len=L),
+                    (S((_BATCH, n, w), u32), S((_BATCH, n, 8), u32))
+                    + pattern(k, n),
+                    dict(k=k, m=m, shard_len=L),
                 )
                 c.shape(data, (_BATCH, k, w), "mesh fused GET data words")
                 c.dtype(data, "uint32", "mesh fused GET data words")

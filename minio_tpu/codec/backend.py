@@ -97,6 +97,130 @@ def _record_overlap(plane: str, windows: int) -> None:
     KERNEL_STATS.record_overlap_windows(plane, int(windows))
 
 
+# ---------------------------------------------------------------------------
+# The loss pattern as operands: survivors + inverse, picked on the host
+# ---------------------------------------------------------------------------
+#
+# Which k of n rows a read decodes from is known when the read ends (a
+# lost drive, a hedge that won, a block that failed its digest).  The
+# device programs take it as two small arrays, so the host's part is to
+# pick the survivors and look up - or, the first time, invert - their
+# matrix.  One table for the process: a pattern costs one Gauss-Jordan
+# elimination over GF(2^8) once (a millisecond alone, ten on a busy
+# interpreter: PERF.md, PR 27), a dict hit after.
+
+_plans: "dict[tuple, tuple[np.ndarray, np.ndarray]]" = {}  # by mask
+_patterns: "dict[tuple, tuple[np.ndarray, np.ndarray]]" = {}  # by survivors
+
+
+def decode_plan(present, data_shards: int, parity_shards: int):
+    """bool[n] availability -> (survivors int32[k], matrix uint8[k, k])
+    for the decode programs: the first k present rows and the inverse of
+    their generator rows.  ``seam_matrix`` spans it; kernel-stats counts
+    ``reconstruct.matrix_cache`` hit/miss and ``patterns_seen``.  Raises
+    ValueError below k present rows."""
+    from ..ops import codec_step
+    from .telemetry import KERNEL_STATS
+
+    with spans.span(spans.SEAM_MATRIX):
+        pres = np.asarray(present, dtype=bool)
+        key = (data_shards, parity_shards, pres.tobytes())
+        plan = _plans.get(key)
+        hit = plan is not None
+        if not hit:
+            survivors, matrix = codec_step.host_pattern(
+                pres, data_shards, parity_shards
+            )
+            # masks that differ only in rows past the first k decode
+            # alike: one pattern, one pair of arrays
+            plan = _patterns.setdefault(
+                (data_shards, parity_shards, survivors.tobytes()),
+                (survivors, matrix),
+            )
+            _plans[key] = plan
+        KERNEL_STATS.record_decode_plan(hit)
+    return plan
+
+
+def patterns_seen() -> int:
+    """Distinct survivor sets decoded from since boot (every geometry)."""
+    return len(_patterns)
+
+
+# ---------------------------------------------------------------------------
+# The ladder: the leading dimension of a read-side program is one of a few
+# ---------------------------------------------------------------------------
+#
+# A jitted program is compiled per shape, about a second each on the one
+# dispatcher thread.  Coalesced flushes and settle batches come in any
+# size, so the seam rounds the leading dimension (stripes of a
+# reconstruct, shard rows of a digest) up a ladder with padding rows,
+# and above ``LAUNCH_BYTES`` of input launches more than once.  The
+# ladder: every size up to LADDER_UNIT (a healthy read settles its k
+# shards in batches of 1 to k rows, so any traffic meets those sizes
+# within seconds, and padding them would copy every batch on the one
+# dispatcher thread: 2-5 % of `mixed-10m`'s rate, PERF.md PR 27), then
+# powers of two (sizes only a coalesced flush reaches, met late or
+# never, which is where a compile lands inside somebody's request).  At
+# 10 MiB blocks of EC 8+4: digest 1-8 and 16 rows, reconstruct 1-2
+# stripes.  Padding rows are the seam's own cost: they cross the bus,
+# count in h2d, and never reach a caller.
+
+LAUNCH_BYTES = 32 << 20
+LADDER_CAP = 256  # the batcher's max_batch_blocks: no launch is longer
+LADDER_UNIT = 8  # sizes up to here are their own rung
+
+
+def launch_rows(row_bytes: int) -> int:
+    """Most rows of ``row_bytes`` one launch takes: the power of two
+    that fits LAUNCH_BYTES, at least 1, at most LADDER_CAP."""
+    fit = max(1, min(LADDER_CAP, LAUNCH_BYTES // max(1, row_bytes)))
+    return 1 << (fit.bit_length() - 1)
+
+
+def ladder(rows: int) -> int:
+    """The rung that holds ``rows`` (>= 1): itself up to LADDER_UNIT, the
+    next power of two above."""
+    return rows if rows <= LADDER_UNIT else 1 << (rows - 1).bit_length()
+
+
+_pad_buffers = threading.local()
+
+
+def _pad_buffer(shape: tuple, dtype) -> np.ndarray:
+    """This thread's staging array of a padded launch's shape, kept from
+    one seam call to the next.  A fresh 10 MiB array per call is an
+    mmap, a page fault a page and a munmap that stops every thread of
+    the process for its TLB flush; the ladder makes the shapes few, so
+    the arrays are too.  Safe to refill once the call that staged from
+    it has read its result back (a seam call does before it returns);
+    what the padding rows hold does not matter, their results are
+    dropped."""
+    held = _pad_buffers.__dict__.setdefault("held", {})
+    buf = held.get((shape, dtype))
+    if buf is None:
+        buf = held[(shape, dtype)] = np.empty(shape, dtype=dtype)
+    return buf
+
+
+def _ladder_chunks(arr: np.ndarray, row_bytes: int):
+    """Cut the leading axis into launches and pad each to the ladder:
+    yields (lo, hi, host array of ladder(hi - lo) rows).  Only the last
+    launch of a call can be short of its rung, so a call fills at most
+    one padding buffer."""
+    cap = launch_rows(row_bytes)
+    total = arr.shape[0]
+    for lo in range(0, total, cap):
+        hi = min(lo + cap, total)
+        rows = ladder(hi - lo)
+        part = arr[lo:hi]
+        if rows != hi - lo:
+            padded = _pad_buffer((rows,) + arr.shape[1:], arr.dtype)
+            padded[: hi - lo] = part
+            part = padded
+        yield lo, hi, part
+
+
 # Ping-pong staging ledger for the async sub-chunk pipeline: while a
 # batch is between encode_digest_begin and _end, TWO sub-chunk staging
 # buffers are live on device (the one computing and the one prefetching)
@@ -848,10 +972,12 @@ class TpuBackend(CodecBackend):
         return parity_cache_pressure()
 
     def reconstruct(self, shards, present, data_shards, parity_shards):
-        from ..ops import codec_step, rs
+        from ..ops import codec_step
 
         shards = np.ascontiguousarray(shards, dtype=np.uint8)
-        B = shards.shape[0]
+        B, n, L = shards.shape
+        survivors, matrix = decode_plan(present, data_shards, parity_shards)
+        use_pallas, interpret = codec_step.pallas_dispatch(L // 4)
         mesh = self._mesh_for(B, data_shards)
         if mesh is not None:
             from ..parallel import mesh as pm
@@ -860,29 +986,54 @@ class TpuBackend(CodecBackend):
                 dw = pm.mesh_reconstruct(
                     mesh,
                     codec_step.host_bytes_to_words(shards),
-                    tuple(bool(b) for b in present),
+                    survivors,
+                    matrix,
                     data_shards,
                     parity_shards,
+                    use_pallas=use_pallas and mesh.shape["shard"] == 1,
+                    interpret=interpret,
                 )
             _record_pass(
                 "mesh_reconstruct",
-                pallas=rs.lowering_for_tpu() and mesh.shape["shard"] == 1,
+                pallas=use_pallas and mesh.shape["shard"] == 1,
             )
             # k compacted survivor rows go up, k data rows come back
             _record_h2d("data", dw.nbytes)
             _record_d2h("data", dw.nbytes)
             return codec_step.host_words_to_bytes(dw)
-        words = self._stage(shards)
-        with _launch():
-            dw = codec_step.reconstruct_words_batch(
-                words,
-                tuple(bool(b) for b in present),
-                data_shards,
-                parity_shards,
-            )
-        # rs._matmul_static pads any width up to the Pallas tile
-        _record_pass("reconstruct_words_batch", pallas=rs.lowering_for_tpu())
-        return codec_step.host_words_to_bytes(_host_readback(dw, "data"))
+        launched = []
+        for lo, hi, part in _ladder_chunks(shards, n * L):
+            words = self._stage(part)
+            with _launch():
+                dw = codec_step.reconstruct_words_batch(
+                    words,
+                    survivors,
+                    matrix,
+                    data_shards,
+                    parity_shards,
+                    use_pallas=use_pallas,
+                    interpret=interpret,
+                )
+            _record_pass("reconstruct_words_batch", pallas=use_pallas)
+            launched.append((lo, hi, dw))
+        return self._gather(launched, (B, data_shards, L))
+
+    @staticmethod
+    def _gather(launched, shape):
+        """Read back the launches of one seam call: (lo, hi, device
+        words) each, padding rows dropped.  One launch (the common
+        case) hands its buffer through as a view."""
+        from ..ops import codec_step
+
+        if len(launched) == 1:
+            lo, hi, dw = launched[0]
+            got = codec_step.host_words_to_bytes(_host_readback(dw, "data"))
+            return got[: hi - lo]
+        out = np.empty(shape, dtype=np.uint8)
+        for lo, hi, dw in launched:
+            got = codec_step.host_words_to_bytes(_host_readback(dw, "data"))
+            out[lo:hi] = got[: hi - lo]
+        return out
 
     def reconstruct_and_verify(
         self, shards, digests, present, data_shards, parity_shards
@@ -904,7 +1055,7 @@ class TpuBackend(CodecBackend):
         shards = np.ascontiguousarray(shards, dtype=np.uint8)
         pres = np.asarray(present, dtype=bool)
         B, n, L = shards.shape
-        present_t = tuple(bool(b) for b in pres)
+        survivors, matrix = decode_plan(pres, data_shards, parity_shards)
         words = codec_step.host_bytes_to_words(shards)
         use_pallas, interpret = codec_step.pallas_dispatch(L // 4)
         overlap = codec_step.codec_overlap_mode()
@@ -920,7 +1071,9 @@ class TpuBackend(CodecBackend):
                     mesh,
                     words,
                     np.asarray(digests),
-                    present_t,
+                    pres,
+                    survivors,
+                    matrix,
                     data_shards,
                     parity_shards,
                     L,
@@ -933,7 +1086,8 @@ class TpuBackend(CodecBackend):
             _record_d2h("data", got[0].nbytes)
         elif overlap == "async":
             got = self._drain_vr_subchunks(
-                words, digests, present_t, data_shards, parity_shards, L
+                words, digests, (pres, survivors, matrix),
+                data_shards, parity_shards, L,
             )
         if got is not None:
             dw, ok = got
@@ -946,7 +1100,9 @@ class TpuBackend(CodecBackend):
                 dw_d, ok_d = codec_step.verify_and_reconstruct_words(
                     words_d,
                     digests_d,
-                    present_t,
+                    pres,
+                    survivors,
+                    matrix,
                     data_shards,
                     parity_shards,
                     L,
@@ -958,8 +1114,7 @@ class TpuBackend(CodecBackend):
             dw = _host_readback(dw_d, "data")
             ok = _host_readback(ok_d, None)
         data = codec_step.host_words_to_bytes(dw)
-        surv = np.nonzero(pres)[0][:data_shards]
-        bad = ~ok[:, surv].all(axis=1)
+        bad = ~ok[:, survivors].all(axis=1)
         if bad.any():
             idxs = np.nonzero(bad)[0]
             if not data.flags.writeable:  # zero-copy view of a jax buffer
@@ -970,7 +1125,7 @@ class TpuBackend(CodecBackend):
         return data, ok
 
     def _drain_vr_subchunks(
-        self, words_h, digests, present, data_shards, parity_shards, shard_len
+        self, words_h, digests, pattern, data_shards, parity_shards, shard_len
     ):
         """MINIO_TPU_CODEC_OVERLAP=async GET: the sub-chunked
         verify+reconstruct chain, a registered drain seam — each
@@ -980,10 +1135,12 @@ class TpuBackend(CodecBackend):
         through the donated ping-pong accumulator and the LAST chunk's
         program producing the verify mask.
 
+        ``pattern`` is decode_plan's pair behind the present mask:
+        (present, survivors, matrix), the chunk program's operands.
         Returns (data words (B, k, w), ok (B, n) bool), or None when
         the batch is too small to cut S >= 3 chunks.
         """
-        from ..ops import codec_step, rs
+        from ..ops import codec_step
         from .erasure import subchunk_words
 
         B, n, w = words_h.shape
@@ -1012,17 +1169,15 @@ class TpuBackend(CodecBackend):
                             acc,
                             digests_d,
                             np.uint32(off),
-                            present,
+                            *pattern,
                             data_shards,
                             parity_shards,
                             shard_len,
                             finalize=i == len(offs) - 1,
                         )
                     )
-                _record_pass(
-                    "verify_reconstruct_subchunk_words",
-                    pallas=rs.lowering_for_tpu(),
-                )
+                # cut on hash strides, not kernel tiles: the XLA form
+                _record_pass("verify_reconstruct_subchunk_words")
                 if prev is not None:
                     # drain chunk i-1 while chunk i computes: this is
                     # the D2H leg of the three-deep overlap
@@ -1052,12 +1207,22 @@ class TpuBackend(CodecBackend):
             _record_h2d("data", words.nbytes)
             _record_d2h("data", got.nbytes)
             return got
-        words = self._stage(shards)
-        # the healthy-read digest has no Pallas kernel: one XLA pass
-        with _launch():
-            got = codec_step.digest_words(words, L)
-        _record_pass("digest_words")
-        return _host_readback(got, "data")
+        # digests are row-local: the rows of the whole batch lie flat,
+        # (1, rows, w), and the row count walks the ladder - one
+        # program per ladder size whatever (B, n) a flush came in
+        rows = shards.reshape(B * n, L)
+        out = np.empty((B * n, 8), dtype=np.uint32)
+        launched = []
+        for lo, hi, part in _ladder_chunks(rows, L):
+            words = self._stage(part[None])
+            # the healthy-read digest has no Pallas kernel: one XLA pass
+            with _launch():
+                got = codec_step.digest_words(words, L)
+            _record_pass("digest_words")
+            launched.append((lo, hi, got))
+        for lo, hi, got in launched:
+            out[lo:hi] = _host_readback(got, "data")[0, : hi - lo]
+        return out.reshape(B, n, 8)
 
 
 class CpuBackend(CodecBackend):
@@ -1360,6 +1525,8 @@ def reset_backend() -> None:
         _backend = None
         _PARITY_CACHE = None
         _staging_bytes = 0
+    _plans.clear()
+    _patterns.clear()
     try:
         from ..cache.allocator import device_budget
 

@@ -278,8 +278,12 @@ class BatchingBackend(CodecBackend):
     def reconstruct(self, shards, present, data_shards, parity_shards):
         shards = np.ascontiguousarray(shards, dtype=np.uint8)
         B, n, L = shards.shape
-        key = (n, L, tuple(bool(b) for b in present), data_shards,
-               parity_shards)
+        # jobs coalesce by what they decode FROM: the first k present
+        # rows (a hedged read may hold a ninth, which no decode uses)
+        first_k = np.flatnonzero(np.asarray(present, dtype=bool))
+        pres = np.zeros(n, dtype=bool)
+        pres[first_k[:data_shards]] = True
+        key = (n, L, tuple(pres.tolist()), data_shards, parity_shards)
         client = threading.get_ident()
         with self._cv:
             self._enter(client)
@@ -466,10 +470,14 @@ class BatchingBackend(CodecBackend):
         total = merged.shape[0]
         # device backends jit-compile per batch shape: arbitrary merged
         # sizes would each pay a fresh XLA compile (seconds).  Pad the
-        # merged batch up to a power of two so the compile cache stays
-        # O(log max_batch) regardless of traffic mix.
+        # merged encode batch up to a power of two so the compile cache
+        # stays O(log max_batch) regardless of traffic mix.  (The read
+        # side - digest, reconstruct - walks its own ladder inside the
+        # seam, codec.backend.ladder: its rows flatten first.)
         padded = total
-        if getattr(self.inner, "name", "") == "tpu":
+        if getattr(self.inner, "name", "") == "tpu" and op in (
+            "encode", "encode_digest"
+        ):
             padded = 1 << (total - 1).bit_length()
             if padded != total:
                 pad = np.zeros(

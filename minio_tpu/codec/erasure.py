@@ -788,6 +788,7 @@ class Erasure:
         ]
         readers = None
         heal = False
+        all_online = False  # every drive of the set, when the read began
         out: list[bytes] = []
         # group contiguous runs with equal shard size into one device pass
         i = 0
@@ -823,9 +824,8 @@ class Erasure:
                 # shard: flag heal even though the k-read path may
                 # never need it (a fully-cached GET skips this check by
                 # design — it observes no disks at all)
-                heal = heal or any(
-                    readers[s] is None for s in range(n)
-                )
+                all_online = all(readers[s] is not None for s in range(n))
+                heal = heal or not all_online
             shards, digests, ok, g_heal = self._read_group_quorum(
                 be, readers, group, shard_len, stages
             )
@@ -858,6 +858,11 @@ class Erasure:
                         else:
                             datas[np.asarray(gis)] = be.reconstruct(
                                 shards[np.asarray(gis)], pat, k, m
+                            )
+                            KERNEL_STATS.record_reconstruct(
+                                healthy=all_online,
+                                rows_rebuilt=len(gis) * (k - sum(pat[:k])),
+                                row_bytes=shard_len,
                             )
             stages["codec"] += sp.seconds
             if cache_ctx is not None and not g_heal:
@@ -1004,8 +1009,11 @@ class Erasure:
         outstanding: "dict[int, tuple]" = {}  # s -> (fut, t0, is_hedge)
         last_hedge = 0.0
         hedges = 0
+        launched = 0  # shard reads of this group, hedges among them
 
         def launch(hedge: bool) -> None:
+            nonlocal launched
+            launched += 1
             s = remaining.pop(0)
             submit = pool.submit_hedged if hedge else pool.submit
             fut = submit(
@@ -1121,6 +1129,7 @@ class Erasure:
                     )
                 if is_hedge:
                     KERNEL_STATS.record_hedge("wasted")
+            KERNEL_STATS.record_hedge("shard_reads", launched)
         return shards, digests, ok, heal
 
     # ---- heal (cmd/erasure-lowlevel-heal.go:28-48) ----------------------

@@ -64,8 +64,20 @@ class KernelStats:
         self._iopool: "dict[str, list]" = {}
         self._iopool_depth_hwm = 0
         self._iopool_slowest_s = 0.0
-        # hedged shard reads: kind in {launched, won, wasted}
+        # hedged shard reads: kind in {launched, won, wasted}; and
+        # shard_reads, every GET shard read launched (hedges included),
+        # so that launched / shard_reads is the hedged share
         self._hedge: "dict[str, int]" = {}
+        # served reads decoded by reconstruct (erasure stream): calls,
+        # healthy_calls (every drive of the set online when the read
+        # began: a hedge or a demotion chose parity, no drive was lost),
+        # rows_rebuilt / bytes_rebuilt (data rows not among the
+        # survivors); and the seam's decode-plan table: hits, misses
+        self._recon = {
+            "calls": 0, "healthy_calls": 0, "rows_rebuilt": 0,
+            "bytes_rebuilt": 0,
+        }
+        self._plan = {"hit": 0, "miss": 0}
         # device->host readback by plane: plane -> [transfers, bytes];
         # plane in {"data", "parity"} (digests ride the data plane).
         # The parity-plane PUT restructure exists to drive the parity
@@ -175,12 +187,30 @@ class KernelStats:
             if seconds > self._iopool_slowest_s:
                 self._iopool_slowest_s = seconds
 
-    def record_hedge(self, kind: str) -> None:
+    def record_hedge(self, kind: str, n: int = 1) -> None:
         """One hedged-read event: ``launched`` (duplicate read fired),
         ``won`` (the hedge produced intact shard cells), ``wasted``
-        (abandoned without contributing)."""
+        (abandoned without contributing); and ``shard_reads``, the
+        shard reads a GET's block group launched, hedges among them
+        (counted once a group, ``n`` at a time)."""
         with self._mu:
-            self._hedge[kind] = self._hedge.get(kind, 0) + 1
+            self._hedge[kind] = self._hedge.get(kind, 0) + n
+
+    def record_reconstruct(
+        self, healthy: bool, rows_rebuilt: int, row_bytes: int
+    ) -> None:
+        """One served-read reconstruct call of the erasure stream."""
+        with self._mu:
+            r = self._recon
+            r["calls"] += 1
+            r["healthy_calls"] += bool(healthy)
+            r["rows_rebuilt"] += rows_rebuilt
+            r["bytes_rebuilt"] += rows_rebuilt * row_bytes
+
+    def record_decode_plan(self, hit: bool) -> None:
+        """One look-up of a loss pattern's survivors + matrix (seam)."""
+        with self._mu:
+            self._plan["hit" if hit else "miss"] += 1
 
     def record_placement(self, outcome: str) -> None:
         """One batch placement decision (outcome = span|route)."""
@@ -257,8 +287,14 @@ class KernelStats:
                 "parity_cache": _parity_cache_stats(),
                 "hedge": {
                     kind: self._hedge.get(kind, 0)
-                    for kind in ("launched", "won", "wasted")
+                    for kind in ("launched", "won", "wasted", "shard_reads")
                 },
+                "reconstruct": {
+                    **self._recon,
+                    "patterns_seen": _patterns_seen(),
+                    "matrix_cache": dict(self._plan),
+                },
+                "breaker": _breaker_demotions(),
                 "stages": [
                     {
                         "op": op,
@@ -314,6 +350,8 @@ class KernelStats:
             self._iopool_depth_hwm = 0
             self._iopool_slowest_s = 0.0
             self._hedge.clear()
+            self._recon = dict.fromkeys(self._recon, 0)
+            self._plan = {"hit": 0, "miss": 0}
             self._d2h.clear()
             self._h2d.clear()
             self._overlap.clear()
@@ -333,6 +371,20 @@ def _parity_cache_stats() -> dict:
     from . import backend as backend_mod
 
     return backend_mod.parity_cache_stats()
+
+
+def _patterns_seen() -> int:
+    from . import backend as backend_mod
+
+    return backend_mod.patterns_seen()
+
+
+def _breaker_demotions() -> dict:
+    """Demotions of a drive's breaker by cause, summed over the drives
+    (storage/health.py is the source of truth, read at snapshot time)."""
+    from ..storage import health
+
+    return health.registry().demotions()
 
 
 # Process-wide singleton: one codec seam per process (backend.py caches

@@ -284,10 +284,68 @@ def encode_words_fused1(
     return parity, jnp.concatenate([ddig, pdig], axis=1)
 
 
+def expand_matrix(survivors: jax.Array, matrix: jax.Array, n: int):
+    """The (k, k) inverse scattered to its survivors' columns of a
+    (k, n) matrix, zero elsewhere: ``full GF@ all n rows`` equals
+    ``matrix GF@ the k survivor rows``, so a kernel reads the rows as
+    they lie and needs no gather.  Traced: a few bytes of XLA work."""
+    k = matrix.shape[0]
+    return jnp.zeros((k, n), jnp.uint8).at[:, survivors].set(
+        matrix.astype(jnp.uint8)
+    )
+
+
+def _check_pattern(shards, survivors, matrix, k: int, m: int) -> None:
+    if shards.shape[1] != k + m:
+        raise ValueError("shard rows must equal k + m")
+    if survivors.shape != (k,) or matrix.shape != (k, k):
+        raise ValueError(
+            f"need {k} survivor indices and a ({k}, {k}) matrix, got "
+            f"{survivors.shape} and {matrix.shape}"
+        )
+
+
+def matmul_rows(rows: jax.Array, matrix: jax.Array, use_pallas: bool,
+                interpret: bool):
+    """(B, s, w) shard rows x TRACED (o, s) GF matrix -> (B, o, w): the
+    runtime-matrix Pallas kernel on a tile-aligned width, else the XLA
+    bit-walk (column-locality makes the batch one flat (s, B*w)
+    product).  For use inside a caller's jit or shard_map body."""
+    B, s, w = rows.shape
+    if use_pallas and w % rs_pallas._TW == 0:
+        return rs_pallas.matmul_rows_runtime(rows, matrix, interpret=interpret)
+    flat = rows.transpose(1, 0, 2).reshape(s, B * w)
+    dw = rs._matmul_words_dynamic(flat, matrix)
+    return dw.reshape(matrix.shape[0], B, w).transpose(1, 0, 2)
+
+
+def reconstruct_rows(
+    shards: jax.Array,
+    survivors: jax.Array,
+    matrix: jax.Array,
+    use_pallas: bool,
+    interpret: bool,
+):
+    """(B, n, w) rows as read x the pattern's operands -> (B, k, w).
+
+    The one decode product every read-side entry point shares (inside
+    its own jit).  The kernel reads all n rows where they lie, against
+    the inverse scattered to its survivors' columns; the XLA form
+    gathers the k survivor rows first.  Nothing here depends on WHICH
+    rows survived, so neither does the compiled program.
+    """
+    n, w = shards.shape[1:]
+    if use_pallas and w % rs_pallas._TW == 0:
+        full = expand_matrix(survivors, matrix, n)
+        return matmul_rows(shards, full, True, interpret)
+    return matmul_rows(
+        jnp.take(shards, survivors, axis=1), matrix, False, False
+    )
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "present",
         "data_shards",
         "parity_shards",
         "shard_len",
@@ -299,7 +357,9 @@ def encode_words_fused1(
 def verify_and_reconstruct_words(
     shards: jax.Array,
     digests: jax.Array,
-    present: tuple[bool, ...],
+    present: jax.Array,
+    survivors: jax.Array,
+    matrix: jax.Array,
     data_shards: int,
     parity_shards: int,
     shard_len: int,
@@ -315,39 +375,32 @@ def verify_and_reconstruct_words(
     the RS product.
 
     shards: (B, n, w) u32 as read (absent rows hold garbage); digests:
-    (B, n, 8) u32 stored; present: static per-row availability.
-    Returns (data (B, k, w) u32 reconstructed from the first k present
-    rows, ok (B, n) bool = digest match AND present).  The caller
-    rechecks ok over its chosen survivors and re-solves per-stripe when
-    one was corrupt (backend reconstruct_and_verify escalation).
+    (B, n, 8) u32 stored.  The loss pattern is three TRACED operands -
+    present: bool[n] availability, survivors: int32[k] the rows to
+    decode from, matrix: uint8[k, k] their inverse
+    (gf.reconstruction_matrix) - so one program serves every pattern.
+    Returns (data (B, k, w) u32 reconstructed from the survivor rows,
+    ok (B, n) bool = digest match AND present).  The caller rechecks ok
+    over its chosen survivors and re-solves per-stripe when one was
+    corrupt (backend reconstruct_and_verify escalation).
     """
     k, m = data_shards, parity_shards
     B, n, w = shards.shape
     if shard_len != 4 * w:
         raise ValueError("shard_len must equal 4 * words-per-shard")
-    idx = [i for i, p in enumerate(present) if p][:k]
-    if len(idx) < k:
-        raise ValueError(f"need {k} shards, have {len(idx)}")
-    pres = jnp.asarray(np.asarray(present, dtype=bool))
+    _check_pattern(shards, survivors, matrix, k, m)
     if use_pallas and w % rs_pallas._TW == 0:
-        data, partials = rs_pallas.verify_reconstruct_fused(
+        data, partials = rs_pallas.verify_reconstruct_runtime(
             shards,
-            tuple(idx),
-            k,
-            m,
+            expand_matrix(survivors, matrix, n),
             formulation=formulation,
             interpret=interpret,
         )
         got = phash.finalize_partials(partials, shard_len)
     else:
         got = phash.phash256_words_batched(shards, shard_len)
-        rm = gf.reconstruction_matrix(k, m, tuple(idx))
-        flat = shards.transpose(1, 0, 2).reshape(n, B * w)
-        surv = jnp.stack([flat[i] for i in idx])
-        data = (
-            rs._matmul_static(surv, rm).reshape(k, B, w).transpose(1, 0, 2)
-        )
-    ok = jnp.all(got == digests, axis=-1) & pres
+        data = reconstruct_rows(shards, survivors, matrix, False, False)
+    ok = jnp.all(got == digests, axis=-1) & present
     return data, ok
 
 
@@ -414,7 +467,6 @@ def encode_subchunk_words(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "present",
         "data_shards",
         "parity_shards",
         "shard_len",
@@ -427,7 +479,9 @@ def verify_reconstruct_subchunk_words(
     acc: jax.Array,
     digests: jax.Array,
     word_offset,
-    present: tuple[bool, ...],
+    present: jax.Array,
+    survivors: jax.Array,
+    matrix: jax.Array,
     data_shards: int,
     parity_shards: int,
     shard_len: int,
@@ -435,7 +489,10 @@ def verify_reconstruct_subchunk_words(
 ):
     """One GET sub-chunk: reconstruct a (B, n, cw) slice of the shard
     rows AND accumulate verify partials (donated ping-pong ``acc`` and
-    staging ``chunk``, like encode_subchunk_words).
+    staging ``chunk``, like encode_subchunk_words).  The loss pattern is
+    traced (present bool[n], survivors int32[k], matrix uint8[k, k]) as
+    in verify_and_reconstruct_words; a sub-chunk is cut on hash strides,
+    not kernel tiles, so the product is the XLA bit-walk.
 
     Returns (data (B, k, cw) u32, acc' (B, n, 8), ok (B, n) bool).
     ``ok`` is meaningful only on the ``finalize`` call (digest match of
@@ -444,21 +501,14 @@ def verify_reconstruct_subchunk_words(
     reads ``ok`` once from the last.
     """
     B, n, cw = chunk.shape
-    k, m = data_shards, parity_shards
-    idx = [i for i, p in enumerate(present) if p][:k]
-    if len(idx) < k:
-        raise ValueError(f"need {k} shards, have {len(idx)}")
+    _check_pattern(chunk, survivors, matrix, data_shards, parity_shards)
     acc = acc ^ phash.tile_partials_batched(
         chunk.transpose(1, 0, 2), word_offset
     ).transpose(1, 0, 2)
-    rm = gf.reconstruction_matrix(k, m, tuple(idx))
-    flat = chunk.transpose(1, 0, 2).reshape(n, B * cw)
-    surv = jnp.stack([flat[i] for i in idx])
-    data = rs._matmul_static(surv, rm).reshape(k, B, cw).transpose(1, 0, 2)
+    data = reconstruct_rows(chunk, survivors, matrix, False, False)
     if finalize:
-        pres = jnp.asarray(np.asarray(present, dtype=bool))
         got = phash.finalize_partials(acc, shard_len)
-        ok = jnp.all(got == digests, axis=-1) & pres
+        ok = jnp.all(got == digests, axis=-1) & present
         return data, acc, ok
     return data, acc, jnp.zeros((B, n), bool)
 
@@ -488,30 +538,44 @@ def digest_words(shards: jax.Array, shard_len: int):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("present", "data_shards", "parity_shards")
+    jax.jit,
+    static_argnames=(
+        "data_shards", "parity_shards", "use_pallas", "interpret"
+    ),
 )
 def reconstruct_words_batch(
     shards: jax.Array,
-    present: tuple[bool, ...],
+    survivors: jax.Array,
+    matrix: jax.Array,
     data_shards: int,
     parity_shards: int,
+    use_pallas: bool = False,
+    interpret: bool = False,
 ):
-    """Static-pattern batched reconstruct: (B, n, w) -> (B, k, w) words.
+    """Batched reconstruct: (B, n, w) -> (B, k, w) words, ONE program
+    per (k, m, w, B) whatever the loss pattern.
 
-    Column-locality makes the whole batch one flat (k, B*w) matmul with
-    the pattern's inverted sub-matrix (rows where present is False hold
-    garbage and are ignored).
+    survivors: int32[k] row indices to decode from, matrix: uint8[k, k]
+    their inverse (gf.reconstruction_matrix) - both TRACED, picked on
+    the host when the read ends (codec.backend.decode_plan).  Rows not
+    among the survivors hold garbage and are ignored.  ``use_pallas`` /
+    ``interpret`` are codec_step.pallas_dispatch's statics.
     """
-    k, m = data_shards, parity_shards
-    idx = [i for i, p in enumerate(present) if p][:k]
-    if len(idx) < k:
-        raise ValueError(f"need {k} shards, have {len(idx)}")
-    rm = gf.reconstruction_matrix(k, m, tuple(idx))
-    B, n, w = shards.shape
-    flat = shards.transpose(1, 0, 2).reshape(n, B * w)
-    surv = jnp.stack([flat[i] for i in idx])
-    dw = rs._matmul_static(surv, rm)  # (k, B*w)
-    return dw.reshape(k, B, w).transpose(1, 0, 2)
+    _check_pattern(shards, survivors, matrix, data_shards, parity_shards)
+    return reconstruct_rows(shards, survivors, matrix, use_pallas, interpret)
+
+
+def host_pattern(present, data_shards: int, parity_shards: int):
+    """Host side of the pattern operands: a bool[n] mask -> (survivors
+    int32[k] = the first k present rows, matrix uint8[k, k]).  Raises
+    ValueError below k survivors (errXLReadQuorum analogue)."""
+    idx = np.flatnonzero(np.asarray(present, dtype=bool))[:data_shards]
+    if len(idx) < data_shards:
+        raise ValueError(f"need {data_shards} shards, have {len(idx)}")
+    rm = gf.reconstruction_matrix(
+        data_shards, parity_shards, tuple(int(i) for i in idx)
+    )
+    return idx.astype(np.int32), rm
 
 
 # ---------------------------------------------------------------------------
@@ -576,11 +640,9 @@ def decode_and_verify(
             f"bitrot: only {int(ok.sum())}/{n} shards intact, "
             f"need {data_shards}"
         )
+    survivors, matrix = host_pattern(ok, data_shards, parity_shards)
     dw = reconstruct_words_batch(
-        words[None],
-        tuple(bool(b) for b in ok),
-        data_shards,
-        parity_shards,
+        words[None], survivors, matrix, data_shards, parity_shards
     )[0]
     data = host_words_to_bytes(np.asarray(dw))
     return data, ok
@@ -620,22 +682,25 @@ def encode_throughput_probe(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("present", "data_shards", "parity_shards"),
+    static_argnames=("data_shards", "parity_shards"),
 )
 def reconstruct_throughput_probe(
     shards: jax.Array,
-    present: tuple[bool, ...],
+    survivors: jax.Array,
+    matrix: jax.Array,
     data_shards: int,
     parity_shards: int,
     reps,
 ):
-    """Chained batched static-pattern reconstructs (see encode probe)."""
+    """Chained batched reconstructs (see encode probe)."""
     k = data_shards
+    use_pallas = pallas_compiled(shards.shape[-1])
 
     def body(_, carry):
         shards_c, acc = carry
         data = reconstruct_words_batch(
-            shards_c, present, data_shards, parity_shards
+            shards_c, survivors, matrix, data_shards, parity_shards,
+            use_pallas=use_pallas,
         )
         nxt = shards_c.at[:, :k].set(shards_c[:, :k] ^ data)
         return nxt, acc ^ data[0, 0, 0]

@@ -143,19 +143,32 @@ def _matmul_words_dynamic(shards_words: jax.Array, matrix: jax.Array) -> jax.Arr
     s, w = shards_words.shape
     o = matrix.shape[0]
     m32 = matrix.astype(jnp.uint32)  # (o, s)
-    # Accumulate without materializing an (o, s, 8, w) intermediate: walk
-    # the xtime chain of each survivor lazily and fold masked terms into
-    # the (o, w) accumulator; stays HBM-friendly.
-    acc = jnp.zeros((o, w), dtype=jnp.uint32)
+    # No (o, s, 8, w) intermediate: walk the xtime chain of each survivor
+    # lazily and fold its eight masked terms into one (o, w) partial,
+    # then XOR the partials pairwise - a tree, so the expression is
+    # 8 + log2(s) deep, not 8 * s (the AOT compile gate's child died
+    # once inside libtpu with one frame repeated down its stack while
+    # the chain was 8 * s long; it has not since).
+    parts = []
     for i in range(s):
         p = shards_words[i]
+        acc = None
         for b in range(8):
             bit = (m32[:, i] >> np.uint32(b)) & np.uint32(1)  # (o,)
             mask = (bit * jnp.uint32(0xFFFFFFFF))[:, None]
-            acc = acc ^ (mask & p[None, :])
+            term = mask & p[None, :]
+            acc = term if acc is None else acc ^ term
             if b != 7:
                 p = _xtime(p)
-    return acc
+        parts.append(acc)
+    if not parts:
+        return jnp.zeros((o, w), dtype=jnp.uint32)
+    while len(parts) > 1:
+        parts = [
+            parts[j] ^ parts[j + 1] if j + 1 < len(parts) else parts[j]
+            for j in range(0, len(parts), 2)
+        ]
+    return parts[0]
 
 
 def _xor_reduce(x: jax.Array, axis: int) -> jax.Array:
@@ -220,50 +233,12 @@ def _reconstruct_jit(
     return jnp.where(keep, shards[: rebuilt.shape[0]], rebuilt)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("present", "data_shards", "parity_shards", "want_parity"),
-)
-def _reconstruct_static_jit(
-    shards: jax.Array,
-    present: tuple[bool, ...],
-    data_shards: int,
-    parity_shards: int,
-    want_parity: bool,
-) -> jax.Array:
-    """Static-pattern reconstruct: the erasure pattern is baked into the
-    compiled program, so the matrix XOR-select is pruned at trace time
-    (same cost profile as encode).
-
-    Production reads hit few distinct patterns - a dead drive yields the
-    same pattern for every object in the set, and heal sweeps
-    (cmd/erasure-lowlevel-heal.go) fix one pattern across the whole set -
-    so the per-pattern jit cache amortizes; `reconstruct` keeps the
-    dynamic-matrix fallback for pattern churn.
-    """
-    k, m = data_shards, parity_shards
-    idx = tuple(i for i, p in enumerate(present) if p)[:k]
-    rm = gf.reconstruction_matrix(k, m, idx)
-    words = bytes_to_words(shards)
-    survivors = jnp.stack([words[i] for i in idx])
-    data_words = _matmul_static(survivors, rm)
-    if want_parity:
-        parity = _matmul_static(data_words, gf.parity_matrix(k, m))
-        all_words = jnp.concatenate([data_words, parity], axis=0)
-    else:
-        all_words = data_words
-    rebuilt = words_to_bytes(all_words)
-    keep = np.asarray(present[: rebuilt.shape[0]])[:, None]
-    return jnp.where(keep, shards[: rebuilt.shape[0]], rebuilt)
-
-
 def reconstruct(
     shards: jax.Array | np.ndarray,
     present: "np.ndarray | list[bool]",
     data_shards: int,
     parity_shards: int,
     data_only: bool = True,
-    static_pattern: bool = True,
 ) -> jax.Array:
     """Device analogue of reedsolomon.ReconstructData / Reconstruct.
 
@@ -282,23 +257,13 @@ def reconstruct(
             f"need {data_shards} shards, have {len(idx)}"
         )
     shards = jnp.asarray(shards, dtype=jnp.uint8)
-    if static_pattern:
-        out = _reconstruct_static_jit(
-            shards,
-            tuple(bool(b) for b in present),
-            data_shards,
-            parity_shards,
-            not data_only,
-        )
-    else:
-        rm = gf.reconstruction_matrix(data_shards, parity_shards, idx)
-        mask = jnp.asarray(present.astype(np.uint8))
-        out = _reconstruct_jit(
-            shards,
-            mask,
-            jnp.asarray(rm),
-            data_shards,
-            parity_shards,
-            not data_only,
-        )
+    rm = gf.reconstruction_matrix(data_shards, parity_shards, idx)
+    out = _reconstruct_jit(
+        shards,
+        jnp.asarray(present.astype(np.uint8)),
+        jnp.asarray(rm),
+        data_shards,
+        parity_shards,
+        not data_only,
+    )
     return out[:data_shards] if data_only else out

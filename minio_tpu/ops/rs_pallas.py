@@ -9,6 +9,9 @@ kernels below (MINIO_TPU_CODEC_FORMULATION):
    xtime-powers decomposition with the generator matrix baked into the
    kernel at trace time, so each tile is a straight-line XOR chain over
    VMEM-resident vectors - no tables, no gathers, no dtype conversions.
+   Encode only: the generator matrix is one per geometry.  Decode takes
+   its matrix as an operand (the runtime-matrix kernels at the end: one
+   program per geometry, whatever the loss pattern).
 
 2. MXU bit-matrix (`_mxu_rows`): GF(2^8) mul-by-constant is an 8x8
    linear map over GF(2), so the whole codec lifts to one
@@ -188,8 +191,10 @@ def _mxu_rows(matrix: np.ndarray, data, mat=None) -> list:
     ``mat`` is the pre-lifted bit matrix when called inside a Pallas
     kernel (kernels cannot capture traced constants, so the caller
     threads it through an input ref); None rebuilds it from ``matrix``.
+    With ``mat`` given, ``matrix`` may be the bare (o, s) shape: the
+    runtime-matrix kernels have no constant to hand over.
     """
-    o, s = matrix.shape
+    o, s = matrix if isinstance(matrix, tuple) else matrix.shape
     if o == 0:
         return []
     t = data.shape[-1]
@@ -324,92 +329,158 @@ def encode_hash_fused(
     return parity, hacc
 
 
-def _vr_kernel_factory(
-    rmatrix: np.ndarray, idx: tuple, n: int, tw: int, formulation: str
+# ---------------------------------------------------------------------------
+# Runtime-matrix kernels: the decode matrix is an OPERAND, not a constant
+# ---------------------------------------------------------------------------
+#
+# Which k of n shards a read got is known only when it ends, and a hedged
+# read gets an arbitrary k (C(12, 8) = 495 sets for 8+4).  A kernel with
+# the matrix baked in is one program per set; these take the matrix as a
+# traced array, so one program serves every loss pattern of a geometry.
+#
+# Formulation (SWAR): the tile is (s, tw) with the shard rows on the
+# sublanes.  P_b = x^b * tile is seven dense xtimes; output row r is the
+# XOR over (c, b) of P_b[c] where bit b of matrix[r, c] is set.  The bits
+# arrive as 0 / 0xFFFFFFFF masks laid out (s * 8, o, 128): entry c * 8 + b
+# holds, on sublane r, the mask of matrix[r, c] bit b, one vreg for o <= 8.
+# A term is then: broadcast sublane c of P_b over the o output sublanes,
+# AND the mask, XOR into the (o, lanes) accumulator - every operand dense,
+# no gather (a row that must not contribute has a zero column).
+
+# lanes per inner step: one vreg per mask, a handful live per step
+_CH = 128
+
+
+def runtime_masks(matrix):
+    """Traced (o, s) uint8 GF matrix -> (s * 8, o, _CH) uint32 AND-masks
+    for the runtime SWAR kernels (a few KiB of XLA work per call)."""
+    o, s = matrix.shape
+    m32 = matrix.astype(jnp.uint32)
+    bits = (m32[:, :, None] >> jnp.arange(8, dtype=jnp.uint32)) & 1
+    masks = (jnp.uint32(0) - bits).transpose(1, 2, 0).reshape(s * 8, o, 1)
+    return jnp.broadcast_to(masks, (s * 8, o, _CH))
+
+
+def bit_matrix_traced(matrix):
+    """Traced twin of _bit_matrix: (o, s) uint8 -> (8o, 8s) f32 over
+    GF(2), for the MXU formulation with a runtime matrix."""
+    o, s = matrix.shape
+    v = matrix.astype(jnp.uint32)
+    prods = []
+    for _ in range(8):  # v * x^b, b = 0..7
+        prods.append(v)
+        v = ((v << 1) & 0xFF) ^ (((v >> 7) & 1) * jnp.uint32(rs._POLY_LOW))
+    prods = jnp.stack(prods, axis=-1)  # (o, s, 8[b])
+    t = jnp.arange(8, dtype=jnp.uint32)[None, :, None, None]
+    bits = (prods[:, None, :, :] >> t) & 1  # (o, 8[t], s, 8[b])
+    return bits.reshape(8 * o, 8 * s).astype(jnp.float32)
+
+
+def _runtime_rows(mask_ref, tile, o: int):
+    """(s, lanes) tile x the masks' matrix -> (o, lanes), lanes == _CH."""
+    s, lanes = tile.shape
+    accs = [jnp.zeros((o, lanes), jnp.uint32) for _ in range(4)]
+    p = tile
+    t = 0
+    for b in range(8):
+        for c in range(s):
+            row = jnp.broadcast_to(p[c : c + 1, :], (o, lanes))
+            accs[t % 4] = accs[t % 4] ^ (row & mask_ref[c * 8 + b])
+            t += 1
+        if b != 7:
+            p = rs._xtime(p)
+    return (accs[0] ^ accs[1]) ^ (accs[2] ^ accs[3])
+
+
+def _runtime_kernel_factory(
+    o: int, s: int, tw: int, formulation: str, with_hash: bool
 ):
+    """Kernel over one (1, s, tw) block of shard rows as read: out =
+    (operand matrix) GF@ rows and, ``with_hash``, the phash partials of
+    all s rows accumulated over the w-tiles."""
     mxu = _rows_fn(formulation) is _mxu_rows
 
-    def impl(sh_ref, data_ref, hacc_ref, mat):
+    def kernel(mat_ref, sh_ref, data_ref, *hacc_ref):
         i = pl.program_id(1)
+        if mxu:
+            rows = _mxu_rows((o, s), sh_ref[0], mat_ref[...])
+            data_ref[0] = jnp.stack(rows)
+        else:
 
-        @pl.when(i == 0)
-        def _zero():
-            hacc_ref[...] = jnp.zeros_like(hacc_ref)
+            def step(j, carry):
+                sl = pl.ds(pl.multiple_of(j * _CH, _CH), _CH)
+                data_ref[0, :, sl] = _runtime_rows(
+                    mat_ref, sh_ref[0, :, sl], o
+                )
+                return carry
 
-        sh = sh_ref[0]  # (n, tw), rows AS READ (absent rows: garbage)
-        surv = jnp.stack([sh[j, :] for j in idx])  # (k, tw) static gather
-        rows = (
-            _mxu_rows(rmatrix, surv, mat) if mxu else _swar_rows(rmatrix, surv)
-        )
-        data_ref[0] = jnp.stack(rows)
-        hacc_ref[0] = hacc_ref[0] ^ _tile_hash_partials(sh, i, tw)
+            jax.lax.fori_loop(0, tw // _CH, step, 0)
+        if with_hash:
+            (hacc,) = hacc_ref
 
-    if mxu:
+            @pl.when(i == 0)
+            def _zero():
+                hacc[...] = jnp.zeros_like(hacc)
 
-        def kernel(mat_ref, sh_ref, data_ref, hacc_ref):
-            impl(sh_ref, data_ref, hacc_ref, mat_ref[...])
-
-    else:
-
-        def kernel(sh_ref, data_ref, hacc_ref):
-            impl(sh_ref, data_ref, hacc_ref, None)
+            hacc[0] = hacc[0] ^ _tile_hash_partials(sh_ref[0], i, tw)
 
     return kernel
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "present_idx",
-        "data_shards",
-        "parity_shards",
-        "formulation",
-        "interpret",
-    ),
-)
-def verify_reconstruct_fused(
+def _runtime_call(rows, matrix, formulation, interpret, with_hash):
+    B, s, w = rows.shape
+    o = matrix.shape[0]
+    if matrix.shape != (o, s):
+        raise ValueError(f"matrix {matrix.shape} does not take {s} rows")
+    if w % _TW:
+        raise ValueError(f"words per shard ({w}) must be a multiple of {_TW}")
+    if formulation == "mxu":
+        mat = bit_matrix_traced(matrix)
+    else:
+        mat = runtime_masks(matrix)
+    zeros = (0,) * mat.ndim
+    out_shape = [jax.ShapeDtypeStruct((B, o, w), jnp.uint32)]
+    out_specs = [pl.BlockSpec((1, o, _TW), lambda b, i: (b, 0, i))]
+    if with_hash:
+        out_shape.append(jax.ShapeDtypeStruct((B, s, 8), jnp.uint32))
+        out_specs.append(pl.BlockSpec((1, s, 8), lambda b, i: (b, 0, 0)))
+    return pl.pallas_call(
+        _runtime_kernel_factory(o, s, _TW, formulation, with_hash),
+        out_shape=tuple(out_shape),
+        grid=(B, w // _TW),
+        in_specs=[
+            pl.BlockSpec(mat.shape, lambda b, i: zeros),
+            pl.BlockSpec((1, s, _TW), lambda b, i: (b, 0, i)),
+        ],
+        out_specs=tuple(out_specs),
+        interpret=interpret,
+    )(mat, rows)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def matmul_rows_runtime(rows, matrix, interpret: bool = False):
+    """(B, s, w) u32 shard rows x TRACED (o, s) uint8 GF matrix ->
+    (B, o, w), ONE pallas_call, one program whatever the matrix holds."""
+    (out,) = _runtime_call(rows, matrix, "swar", interpret, False)
+    return out
+
+
+@functools.partial(jax.jit, static_argnames=("formulation", "interpret"))
+def verify_reconstruct_runtime(
     shards,
-    present_idx: tuple,
-    data_shards: int,
-    parity_shards: int,
+    matrix,
     formulation: str = "swar",
     interpret: bool = False,
 ):
-    """One-kernel GET codec pass: bitrot partials for every shard row +
-    reconstruction from the static survivor set, ONE pallas_call.
+    """One-kernel GET codec pass with the decode matrix an operand:
+    bitrot partials for every shard row + reconstruction, ONE
+    pallas_call.
 
-    shards: (B, n, w) u32 as read; present_idx: the k survivor row
-    indices (static).  Returns (data (B, k, w) u32, partials (B, n, 8)
-    u32 un-finalized - finalize and compare against stored digests
-    outside; each shard byte is read from HBM exactly once for both).
+    shards: (B, n, w) u32 as read; matrix: traced (k, n) uint8, the
+    pattern's inverse scattered to its survivors' columns (zero columns
+    for the rows that must not contribute).  Returns (data (B, k, w)
+    u32, partials (B, n, 8) u32 un-finalized - finalize and compare
+    against stored digests outside; each shard byte is read from HBM
+    exactly once for both).
     """
-    B, n, w = shards.shape
-    k, m = data_shards, parity_shards
-    if n != k + m:
-        raise ValueError("shard rows must equal k + m")
-    idx = tuple(int(i) for i in present_idx)
-    if len(idx) != k:
-        raise ValueError(f"need exactly {k} survivor indices, got {len(idx)}")
-    if w % _TW:
-        raise ValueError(f"words per shard ({w}) must be a multiple of {_TW}")
-    rm = gf.reconstruction_matrix(k, m, idx)
-    kernel = _vr_kernel_factory(rm, idx, n, _TW, formulation)
-    extra_in, extra_specs = (
-        _mxu_operand(rm) if formulation == "mxu" else ([], [])
-    )
-    data, hacc = pl.pallas_call(
-        kernel,
-        out_shape=(
-            jax.ShapeDtypeStruct((B, k, w), jnp.uint32),
-            jax.ShapeDtypeStruct((B, n, 8), jnp.uint32),
-        ),
-        grid=(B, w // _TW),
-        in_specs=extra_specs
-        + [pl.BlockSpec((1, n, _TW), lambda b, i: (b, 0, i))],
-        out_specs=(
-            pl.BlockSpec((1, k, _TW), lambda b, i: (b, 0, i)),
-            pl.BlockSpec((1, n, 8), lambda b, i: (b, 0, 0)),
-        ),
-        interpret=interpret,
-    )(*extra_in, shards)
-    return data, hacc
+    return _runtime_call(shards, matrix, formulation, interpret, True)

@@ -242,25 +242,34 @@ def _encode_hash_local(mesh: Mesh, k: int, m: int, shard_len: int):
     return step
 
 
-def _reconstruct_local(mesh: Mesh, k: int, m: int, idx: tuple[int, ...]):
+def _reconstruct_local(
+    mesh: Mesh, k: int, m: int, use_pallas: bool = False,
+    interpret: bool = False,
+):
     shard_n = mesh.shape["shard"]
-    rm = gf.reconstruction_matrix(k, m, idx)  # (k, k) survivors -> data
     if shard_n == 1:
+        from ..ops import codec_step
 
-        def whole(local: jax.Array):
+        def whole(local: jax.Array, matrix: jax.Array):
             # local: (B_local, k, w) compacted survivor rows, whole
-            # stripes per device: the static product (the Pallas kernel
-            # on TPU) instead of the dynamic bit-walk
-            return rs._matmul_static_batch(local, rm)
+            # stripes per device; matrix: (k, k) traced, survivors ->
+            # data.  The runtime-matrix Pallas kernel on a TPU and a
+            # tile-aligned width, else the XLA bit-walk
+            return codec_step.matmul_rows(
+                local, matrix, use_pallas, interpret
+            )
 
         return whole
 
-    col_blocks = _col_blocks(rm, shard_n)
+    k_local = k // shard_n
 
-    def step(local: jax.Array):
-        # local: (B_local, k_local, w) compacted survivor rows
+    def step(local: jax.Array, matrix: jax.Array):
+        # local: (B_local, k_local, w) compacted survivor rows; this
+        # device's columns of the traced matrix
         dev = jax.lax.axis_index("shard")
-        my_cols = jnp.asarray(col_blocks)[dev]
+        my_cols = jax.lax.dynamic_slice_in_dim(
+            matrix, dev * k_local, k_local, axis=1
+        )
         partial = jax.vmap(
             lambda wds: rs._matmul_words_dynamic(wds, my_cols)
         )(local)
@@ -283,7 +292,6 @@ def _verify_reconstruct_local(
     mesh: Mesh,
     k: int,
     m: int,
-    present: tuple[bool, ...],
     shard_len: int,
     formulation: str = "swar",
     use_pallas: bool = False,
@@ -291,14 +299,17 @@ def _verify_reconstruct_local(
 ):
     from ..ops import codec_step
 
-    def step(words: jax.Array, digests: jax.Array):
+    def step(words, digests, present, survivors, matrix):
         # words: (B_local, n, w) quorum rows; whole stripes are
         # device-local on the stripe axis (and replicated over "shard"),
-        # so the fused GET step runs per device with no collective
+        # so the fused GET step runs per device with no collective.
+        # The pattern's three operands are whole on every device
         return codec_step.verify_and_reconstruct_words(
             words,
             digests,
             present,
+            survivors,
+            matrix,
             k,
             m,
             shard_len,
@@ -334,7 +345,7 @@ rules.register_kernel(
 )
 rules.register_kernel(
     "mesh_reconstruct",
-    in_names=("survivor_words",),
+    in_names=("survivor_words", "decode_matrix"),
     out_names=("recon_words",),
     build_local=_reconstruct_local,
 )
@@ -346,7 +357,10 @@ rules.register_kernel(
 )
 rules.register_kernel(
     "mesh_verify_reconstruct",
-    in_names=("quorum_words", "quorum_digests"),
+    in_names=(
+        "quorum_words", "quorum_digests",
+        "decode_present", "decode_survivors", "decode_matrix",
+    ),
     out_names=("recon_words", "ok_mask"),
     build_local=_verify_reconstruct_local,
 )
@@ -487,35 +501,43 @@ def mesh_encode_hash_end(handle):
 def mesh_reconstruct(
     mesh: Mesh,
     words: np.ndarray,
-    present: tuple[bool, ...],
+    survivors: np.ndarray,
+    matrix: np.ndarray,
     data_shards: int,
     parity_shards: int,
+    use_pallas: bool = False,
+    interpret: bool = False,
 ) -> np.ndarray:
-    """Mesh-parallel batched reconstruct: (B, n, w) + mask -> (B, k, w).
+    """Mesh-parallel batched reconstruct: (B, n, w) + pattern -> (B, k, w).
 
-    Survivor rows are compacted host-side (free fancy-index view) so the
-    device program is one partial-matmul + XOR all-reduce per device.
+    ``survivors`` (int32[k]) and ``matrix`` (uint8[k, k]) are the
+    pattern's operands (codec.backend.decode_plan).  Survivor rows are
+    compacted host-side (free fancy-index view) so the device program is
+    one partial-matmul + XOR all-reduce per device, with the matrix an
+    operand: one program per geometry, whatever the pattern.
     """
     k, m = data_shards, parity_shards
-    idx = tuple(i for i, p in enumerate(present) if p)[:k]
-    if len(idx) < k:
-        raise ValueError(f"need {k} shards, have {len(idx)}")
-    surv = np.ascontiguousarray(words[:, idx, :])  # (B, k, w)
+    if len(survivors) != k:
+        raise ValueError(f"need {k} shards, have {len(survivors)}")
+    surv = np.ascontiguousarray(words[:, np.asarray(survivors), :])
     B = surv.shape[0]
     stripe = mesh.shape["stripe"]
     surv = _pad_batch(surv, _bucket_batch(B, stripe))
     fn = rules.compile_kernel(
-        "mesh_reconstruct", mesh, k=k, m=m, idx=idx
+        "mesh_reconstruct", mesh, k=k, m=m,
+        use_pallas=use_pallas, interpret=interpret,
     )
     dd = put_sharded(mesh, surv, rules.spec_for("survivor_words"))
-    return np.asarray(fn(dd))[:B]
+    return np.asarray(fn(dd, np.asarray(matrix, dtype=np.uint8)))[:B]
 
 
 def mesh_verify_reconstruct(
     mesh: Mesh,
     words: np.ndarray,
     digests: np.ndarray,
-    present: tuple[bool, ...],
+    present: np.ndarray,
+    survivors: np.ndarray,
+    matrix: np.ndarray,
     data_shards: int,
     parity_shards: int,
     shard_len: int,
@@ -526,7 +548,9 @@ def mesh_verify_reconstruct(
     """Mesh-parallel fused GET step: verify digests + reconstruct, one program.
 
     words: (B, n, w) quorum rows, digests: (B, n, 8) expected phash256 -
-    both sharded over "stripe".  Returns ((B, k, w) data, (B, n) ok mask).
+    both sharded over "stripe"; present (bool[n]), survivors (int32[k])
+    and matrix (uint8[k, k]) are the pattern's operands, whole on every
+    device.  Returns ((B, k, w) data, (B, n) ok mask).
     Padded stripes hash to garbage and come back ok=False; the [:B] slice
     drops them before anyone looks.  ``use_pallas``/``interpret``/
     ``formulation`` are codec_step.pallas_dispatch's statics, threaded
@@ -543,7 +567,6 @@ def mesh_verify_reconstruct(
         mesh,
         k=k,
         m=m,
-        present=tuple(bool(p) for p in present),
         shard_len=shard_len,
         formulation=formulation,
         use_pallas=use_pallas,
@@ -551,7 +574,13 @@ def mesh_verify_reconstruct(
     )
     dw = put_sharded(mesh, words, rules.spec_for("quorum_words"))
     dg = put_sharded(mesh, digests, rules.spec_for("quorum_digests"))
-    data, ok = fn(dw, dg)
+    data, ok = fn(
+        dw,
+        dg,
+        np.asarray(present, dtype=bool),
+        np.asarray(survivors, dtype=np.int32),
+        np.asarray(matrix, dtype=np.uint8),
+    )
     return np.asarray(data)[:B], np.asarray(ok)[:B]
 
 

@@ -86,6 +86,10 @@ PARTITION_RULES: tuple[tuple[str, PartitionSpec], ...] = (
     # shard rows of a stripe stay together - the bitrot check is
     # row-local but the decode needs every survivor row
     (r"^quorum_(words|digests)$", PartitionSpec("stripe", None, None)),
+    # the loss pattern's operands (present bool[n], survivors int32[k],
+    # matrix uint8[k, k]): a few bytes, whole on every device
+    (r"^decode_(present|survivors)$", PartitionSpec(None)),
+    (r"^decode_matrix$", PartitionSpec(None, None)),
     # (B, n) per-shard verify verdicts
     (r"^ok_mask$", PartitionSpec("stripe", None)),
     # (R, w) flattened digest rows: spread over every device on both axes

@@ -139,6 +139,10 @@ class DiskHealth:
         self._probe_t0 = 0.0
         self.trips = 0
         self.recoveries = 0
+        # times this drive left HEALTHY, by what sent it: consecutive
+        # errors, or reads that outlay the pool (censored hedge losers
+        # and latency outliers both strike "slow")
+        self.demotions = {"error": 0, "outlier": 0}
         # per-disk shard-read latency (successful, non-censored reads)
         self._read_p50 = P2Quantile(0.50)
         self._read_p99 = P2Quantile(0.99)
@@ -240,8 +244,11 @@ class DiskHealth:
             self._on_success_locked(now)
 
     def _note_slow_locked(self, now: float) -> None:
+        was = self._state_locked(now)
         self._slow_strikes += 1
         self._slow_until = now + self._cfg.slow_decay_s
+        if was == HEALTHY and self._state_locked(now) != HEALTHY:
+            self.demotions["outlier"] += 1
         # slow strikes resolve a probe too: a probe read that had to be
         # abandoned is not a recovery
         if self._probing and self._state == TRIPPED:
@@ -271,6 +278,10 @@ class DiskHealth:
             if self._probing:
                 self._retrip_locked(now, api)
             return
+        if self._state == HEALTHY and (
+            self._consec_errors >= self._cfg.suspect_errors
+        ):
+            self.demotions["error"] += 1
         if self._consec_errors >= self._cfg.trip_errors:
             self._state = TRIPPED
             self._until = now + self._backoff_s
@@ -319,6 +330,7 @@ class DiskHealth:
                 "slow_strikes": self._slow_strikes,
                 "trips": self.trips,
                 "recoveries": self.recoveries,
+                "demotions": dict(self.demotions),
                 "probing": self._probing,
             }
             if self._state == TRIPPED:
@@ -413,6 +425,16 @@ class HealthRegistry:
                 ep: dh.snapshot() for ep, dh in sorted(disks.items())
             },
         }
+        return out
+
+    def demotions(self) -> "dict[str, int]":
+        """Demotions by cause (error | outlier), summed over the drives."""
+        with self._mu:
+            disks = list(self._disks.values())
+        out = {"error": 0, "outlier": 0}
+        for dh in disks:
+            for cause, n in dh.demotions.items():
+                out[cause] += n
         return out
 
     def states(self) -> "dict[str, int]":

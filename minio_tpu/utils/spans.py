@@ -108,6 +108,7 @@ BATCH_QUEUE_WAIT = "batch_queue_wait"
 BATCH_FLUSH = "batch_flush"
 FLUSH_TO_LAUNCH = "flush_to_launch"
 BATCH_RESULT_WAIT = "batch_result_wait"
+SEAM_MATRIX = "seam_matrix"
 SEAM_STAGE = "seam_stage"
 SEAM_LAUNCH = "seam_launch"
 SEAM_KERNEL_WAIT = "seam_kernel_wait"

@@ -150,10 +150,13 @@ def test_fused_get_matches_legacy_pair(use_pallas):
     present[0] = False  # lost
     shards[:, 0] = 0
     shards[1, 3, 5] ^= 0xDEAD  # bitrot on a non-survivor-critical row
+    survivors, matrix = codec_step.host_pattern(present, k, m)
     got_data, got_ok = codec_step.verify_and_reconstruct_words(
         jnp.asarray(shards),
         jnp.asarray(digests),
-        tuple(present),
+        np.asarray(present),
+        survivors,
+        matrix,
         k,
         m,
         L,
@@ -168,7 +171,7 @@ def test_fused_get_matches_legacy_pair(use_pallas):
     ) & np.asarray(present, bool)
     data_legacy = np.asarray(
         codec_step.reconstruct_words_batch(
-            jnp.asarray(shards), tuple(present), k, m
+            jnp.asarray(shards), survivors, matrix, k, m
         )
     )
     np.testing.assert_array_equal(np.asarray(got_ok), ok_legacy)
@@ -179,10 +182,15 @@ def test_fused_get_below_quorum_raises():
     k, m, L = 4, 2, 256
     present = (True, False, False, True, True, False)
     with pytest.raises(ValueError, match="shards"):
+        codec_step.host_pattern(present, k, m)
+    # and the device program refuses operands that are not a pattern's
+    with pytest.raises(ValueError, match="survivor"):
         codec_step.verify_and_reconstruct_words(
             jnp.zeros((1, 6, L // 4), jnp.uint32),
             jnp.zeros((1, 6, 8), jnp.uint32),
-            present,
+            np.asarray(present),
+            np.asarray([0, 3, 4], np.int32),
+            np.zeros((3, 3), np.uint8),
             k,
             m,
             L,

@@ -34,7 +34,8 @@ TABLE = [
     "xl_delete_version", "xl_delete_file", "iopool_queue_wait", "iopool_job",
     "xl_shard_write", "xl_shard_fsync", "xl_shard_read", "iopool_result_wait",
     "stream_assemble", "stream_codec_wait", "stream_disk", "batch_queue_wait",
-    "batch_flush", "flush_to_launch", "batch_result_wait", "seam_stage",
+    "batch_flush", "flush_to_launch", "batch_result_wait", "seam_matrix",
+    "seam_stage",
     "seam_launch", "seam_kernel_wait", "seam_d2h", "probe",
 ]
 

@@ -38,12 +38,6 @@ BLOCK = 10 * 1024 * 1024
 GRID = ((4, 2), (8, 4), (16, 4))
 
 
-def _present(k: int, m: int) -> tuple:
-    n = k + m
-    lost = {0, n - 1} if m >= 2 else {0}
-    return tuple(i not in lost for i in range(n))
-
-
 def _cases() -> "list[tuple[str, str, dict]]":
     """(name, kind, params) for every compile; names are the test ids."""
     out: "list[tuple[str, str, dict]]" = []
@@ -141,7 +135,9 @@ def _child() -> int:
         w = L // 4
         use_pallas, interpret = codec_step.pallas_dispatch(w)
         assert not interpret
-        pres = _present(k, m)
+        # the loss pattern's operands: abstract, like the shards - the
+        # program cannot depend on which rows survived
+        pat = [S((n,), jnp.bool_), S((k,), jnp.int32), S((k, k), jnp.uint8)]
         if kind == "put_fused1":
             return (
                 lambda x: codec_step.encode_words_fused1(
@@ -164,16 +160,20 @@ def _child() -> int:
             return (lambda x: codec_step.digest_words(x, L), [S((B, k, w))])
         if kind == "degraded_reconstruct":
             return (
-                lambda x: codec_step.reconstruct_words_batch(x, pres, k, m),
-                [S((B, n, w))],
+                lambda x, sv, mat: codec_step.reconstruct_words_batch(
+                    x, sv, mat, k, m, use_pallas=use_pallas
+                ),
+                [S((B, n, w))] + pat[1:],
             )
         if kind == "heal_verify_reconstruct":
             return (
-                lambda x, d: codec_step.verify_and_reconstruct_words(
-                    x, d, pres, k, m, L, formulation=p["formulation"],
-                    use_pallas=use_pallas,
+                lambda x, d, pr, sv, mat: (
+                    codec_step.verify_and_reconstruct_words(
+                        x, d, pr, sv, mat, k, m, L,
+                        formulation=p["formulation"], use_pallas=use_pallas,
+                    )
                 ),
-                [S((B, n, w)), S((B, n, 8))],
+                [S((B, n, w)), S((B, n, 8))] + pat,
             )
         if kind == "drain_group_flags":
             return (lambda x: codec_step.group_flags(x, 256), [S((B, m, w))])
@@ -193,12 +193,13 @@ def _child() -> int:
             )
         if kind == "heal_subchunk":
             return (
-                lambda c, a, d, o: (
+                lambda c, a, d, o, pr, sv, mat: (
                     codec_step.verify_reconstruct_subchunk_words(
-                        c, a, d, o, pres, k, m, L, finalize=p["finalize"]
+                        c, a, d, o, pr, sv, mat, k, m, L,
+                        finalize=p["finalize"],
                     )
                 ),
-                [S((B, n, cw)), S((B, n, 8)), S((B, n, 8)), S(())],
+                [S((B, n, cw)), S((B, n, 8)), S((B, n, 8)), S(())] + pat,
             )
         raise KeyError(kind)
 
@@ -218,24 +219,31 @@ def _child() -> int:
             rows, 4 if kind == "mesh_digest" else stripe
         )
 
-        def A(shape, plane):
-            return S(shape, u32, NamedSharding(mesh, prules.spec_for(plane)))
+        def A(shape, plane, dtype=u32):
+            return S(shape, dtype, NamedSharding(mesh, prules.spec_for(plane)))
 
         if kind == "mesh_encode_hash":
             fn = prules.compile_kernel(kind, mesh, k=k, m=m, shard_len=L)
             return fn, [A((bucket, k, w), "stripe_words")]
         if kind == "mesh_reconstruct":
-            idx = tuple(i for i, ok in enumerate(_present(k, m)) if ok)[:k]
-            fn = prules.compile_kernel(kind, mesh, k=k, m=m, idx=idx)
-            return fn, [A((bucket, k, w), "survivor_words")]
+            fn = prules.compile_kernel(
+                kind, mesh, k=k, m=m, use_pallas=shard == 1, interpret=False
+            )
+            return fn, [
+                A((bucket, k, w), "survivor_words"),
+                A((k, k), "decode_matrix", jnp.uint8),
+            ]
         if kind == "mesh_verify_reconstruct":
             fn = prules.compile_kernel(
-                kind, mesh, k=k, m=m, present=_present(k, m), shard_len=L,
+                kind, mesh, k=k, m=m, shard_len=L,
                 formulation="swar", use_pallas=True, interpret=False,
             )
             return fn, [
                 A((bucket, n, w), "quorum_words"),
                 A((bucket, n, 8), "quorum_digests"),
+                A((n,), "decode_present", jnp.bool_),
+                A((k,), "decode_survivors", jnp.int32),
+                A((k, k), "decode_matrix", jnp.uint8),
             ]
         if kind == "mesh_digest":
             fn = prules.compile_kernel(kind, mesh, shard_len=L)
