@@ -295,6 +295,7 @@ class KernelStats:
                     "matrix_cache": dict(self._plan),
                 },
                 "breaker": _breaker_demotions(),
+                "meta_read": _meta_read_counts(),
                 "stages": [
                     {
                         "op": op,
@@ -362,6 +363,9 @@ class KernelStats:
             self._submesh_depth_hwm.clear()
         if self is KERNEL_STATS:
             spans.reset()
+            from ..storage import xl
+
+            xl.META_READ[:] = [0, 0, 0]
 
 
 def _parity_cache_stats() -> dict:
@@ -385,6 +389,14 @@ def _breaker_demotions() -> dict:
     from ..storage import health
 
     return health.registry().demotions()
+
+
+def _meta_read_counts() -> dict:
+    """The drives' small-file reads (storage/xl.py counts them where
+    they are made): reads, refills, error_path."""
+    from ..storage import xl
+
+    return xl.meta_read_counts()
 
 
 # Process-wide singleton: one codec seam per process (backend.py caches

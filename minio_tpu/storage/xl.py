@@ -30,6 +30,22 @@ SYS_DIR = ".sys"
 TMP_DIR = f"{SYS_DIR}/tmp"
 XL_META = "xl.meta"
 
+# One os.read of read_all: well above any xl.meta (336 bytes for a 10 MiB
+# object at EC 8+4); a longer file takes more reads of the same size.
+READ_CHUNK = 64 << 10
+
+# read_all over every XLStorage of the process: [reads, refills (reads
+# that needed more than one os.read), error_path (opens that failed and
+# went to look at the volume)].  Plain adds under the GIL, no lock and no
+# clock; system calls per read = 3 + refills / reads.  kernel-stats
+# carries them as ``meta_read`` (codec/telemetry.py).
+META_READ = [0, 0, 0]
+
+
+def meta_read_counts() -> dict:
+    reads, refills, error_path = META_READ
+    return {"reads": reads, "refills": refills, "error_path": error_path}
+
 
 def _check_name(name: str) -> None:
     if not name or name.startswith("/") or ".." in name.split("/"):
@@ -212,14 +228,36 @@ class XLStorage(StorageAPI):
 
     @spans.spanned(spans.XL_READ_ALL)
     def read_all(self, volume: str, path: str) -> bytes:
-        self._require_vol(volume)
+        # open, read, close: three system calls for a file under
+        # READ_CHUNK and none before them.  Each one gives the GIL away
+        # and has to win it back from every other thread of the server,
+        # so their number is the wall time of a metadata round; the
+        # volume is looked at only once the open has failed
+        full = self._file_path(volume, path)
+        META_READ[0] += 1
         try:
-            with open(self._file_path(volume, path), "rb") as f:
-                return f.read()
-        except FileNotFoundError:
+            fd = os.open(full, os.O_RDONLY)
+        except (FileNotFoundError, NotADirectoryError) as e:
+            META_READ[2] += 1
+            self._require_vol(volume)
+            if isinstance(e, NotADirectoryError):
+                raise  # a parent component is a regular file
             raise errors.FileNotFound(path) from None
+        try:
+            data = os.read(fd, READ_CHUNK)
+            if len(data) == READ_CHUNK:
+                # came back full: a long version journal, a large
+                # format.json or bucket document; read on to the end
+                META_READ[1] += 1
+                chunks = [data]
+                while len(chunks[-1]) == READ_CHUNK:
+                    chunks.append(os.read(fd, READ_CHUNK))
+                data = b"".join(chunks)
+            return data
         except IsADirectoryError:
             raise errors.IsNotRegular(path) from None
+        finally:
+            os.close(fd)
 
     @spans.spanned(spans.XL_WRITE_ALL)
     def write_all(self, volume: str, path: str, data: bytes) -> None:

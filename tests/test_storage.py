@@ -4,11 +4,14 @@ Real temp-dir disks, no mocks - the reference's test style
 (newErasureTestSetup, cmd/erasure_test.go).
 """
 
+import builtins
 import os
+import shutil
 
 import pytest
 
 from minio_tpu.storage import errors
+from minio_tpu.storage import xl as xl_mod
 from minio_tpu.storage.meta import (
     ErasureInfo,
     FileInfo,
@@ -186,3 +189,188 @@ def test_append_file_offset_idempotent(disk):
 
     with _pytest.raises(_errors.FileCorrupt):
         disk.append_file("av", "f", b"dd", offset=99)
+
+
+# ---- read_all: three system calls, the volume looked at on the error path
+
+
+def _pattern(size: int) -> bytes:
+    return bytes(i % 251 for i in range(size))
+
+
+def _setup_missing_file(disk):
+    return "b", "nope/xl.meta"
+
+
+def _setup_missing_volume(disk):
+    return "nob", "o/xl.meta"
+
+
+def _setup_directory(disk):
+    disk.write_all("b", "o/xl.meta", b"m")
+    return "b", "o"
+
+
+def _setup_parent_is_file(disk):
+    disk.write_all("b", "o/xl.meta", b"m")
+    return "b", "o/xl.meta/xl.meta"
+
+
+def _setup_volume_is_file(disk):
+    open(os.path.join(disk.root, "volfile"), "w").close()
+    return "volfile", "o/xl.meta"
+
+
+def _lose_root(leave_file):
+    # the degraded cell's case: the drive's path stops being a directory
+    def setup(disk):
+        disk.write_all("b", "o/xl.meta", b"m")
+        shutil.rmtree(disk.root)
+        if leave_file:
+            open(disk.root, "w").close()
+        return "b", "o/xl.meta"
+
+    return setup
+
+
+N = xl_mod.READ_CHUNK
+
+# what read_all answers (the file's content, or a setup and the parent
+# commit's error class), refills, error_path
+READ_ALL_CASES = {
+    "present": (_pattern(336), 0, 0),
+    "empty": (b"", 0, 0),
+    "one-under-chunk": (_pattern(N - 1), 0, 0),
+    "exactly-chunk": (_pattern(N), 1, 0),
+    "chunk-plus-one": (_pattern(N + 1), 1, 0),
+    "three-chunks-plus-seven": (_pattern(3 * N + 7), 1, 0),
+    "missing-file": ((_setup_missing_file, errors.FileNotFound), 0, 1),
+    "missing-volume": ((_setup_missing_volume, errors.VolumeNotFound), 0, 1),
+    "directory": ((_setup_directory, errors.IsNotRegular), 0, 0),
+    "parent-is-a-file": ((_setup_parent_is_file, NotADirectoryError), 0, 1),
+    "volume-is-a-file": ((_setup_volume_is_file, errors.VolumeNotFound), 0, 1),
+    "root-became-a-file": ((_lose_root(True), errors.VolumeNotFound), 0, 1),
+    "root-gone": ((_lose_root(False), errors.VolumeNotFound), 0, 1),
+}
+
+
+@pytest.mark.parametrize("case", READ_ALL_CASES)
+def test_read_all_answers(disk, case):
+    want, refills, error_path = READ_ALL_CASES[case]
+    disk.make_vol("b")
+    if isinstance(want, bytes):
+        disk.write_all("b", "o/xl.meta", want)
+        before = xl_mod.meta_read_counts()
+        assert disk.read_all("b", "o/xl.meta") == want
+    else:
+        setup, error = want
+        volume, path = setup(disk)
+        before = xl_mod.meta_read_counts()
+        with pytest.raises(error) as caught:
+            disk.read_all(volume, path)
+        assert type(caught.value) is error
+    after = xl_mod.meta_read_counts()
+    assert {k: after[k] - before[k] for k in after} == {
+        "reads": 1, "refills": refills, "error_path": error_path,
+    }
+
+
+COUNTED = ("os.open", "os.read", "os.close", "os.stat", "os.path.isdir",
+           "builtins.open")
+
+
+@pytest.fixture
+def syscalls(monkeypatch):
+    """Counts of the calls a drive's read could make, while ``on``."""
+    calls = dict.fromkeys(COUNTED, 0)
+    state = {"on": False}
+
+    def counted(name, fn):
+        def wrapper(*a, **kw):
+            if state["on"]:
+                calls[name] += 1
+            return fn(*a, **kw)
+
+        return wrapper
+
+    for name in COUNTED:
+        mod, _, attr = name.rpartition(".")
+        owner = {"os": os, "os.path": os.path, "builtins": builtins}[mod]
+        monkeypatch.setattr(owner, attr, counted(name, getattr(owner, attr)))
+
+    class Counter:
+        def __enter__(self):
+            state["on"] = True
+            return calls
+
+        def __exit__(self, *exc):
+            state["on"] = False
+
+    return Counter()
+
+
+def _cell_fileinfo(pad: int = 0) -> FileInfo:
+    # the shape a 10 MiB object of the benchmark's cells leaves: EC 8+4,
+    # one part; the etag padded so that xl.meta is the cells' 336 bytes
+    return FileInfo(
+        version_id="",
+        data_dir=new_version_id(),
+        size=10 << 20,
+        mod_time_ns=now_ns(),
+        metadata={"etag": "0" * pad},
+        parts=[ObjectPartInfo(number=1, size=10 << 20, actual_size=10 << 20)],
+        erasure=ErasureInfo(
+            data_blocks=8, parity_blocks=4, block_size=10 << 20, index=1,
+            distribution=list(range(1, 13)),
+        ),
+    )
+
+
+def _write_336(disks, name="obj"):
+    fi = _cell_fileinfo()
+    xl = XLMeta()
+    xl.add_version(fi)
+    fi = _cell_fileinfo(pad=336 - len(xl.to_bytes()))
+    for d in disks:
+        d.write_metadata("b", name, fi)
+        assert os.path.getsize(
+            os.path.join(d.root, "b", name, "xl.meta")) == 336
+
+
+def test_read_version_is_three_system_calls(disk, syscalls):
+    disk.make_vol("b")
+    _write_336([disk])
+    with syscalls as calls:
+        fi = disk.read_version("b", "obj")
+    assert fi.size == 10 << 20
+    assert calls == {"os.open": 1, "os.read": 1, "os.close": 1,
+                     "os.stat": 0, "os.path.isdir": 0, "builtins.open": 0}
+
+
+@pytest.mark.parametrize("lost", [(), (2, 7)], ids=["healthy", "two-offline"])
+def test_metadata_round_system_calls(tmp_path, syscalls, lost):
+    from minio_tpu.codec.telemetry import KERNEL_STATS
+    from minio_tpu.objectlayer.metadata import (
+        find_fileinfo_in_quorum,
+        read_all_fileinfo,
+    )
+
+    disks = [XLStorage(str(tmp_path / f"d{i}")) for i in range(12)]
+    for d in disks:
+        d.make_vol("b")
+    _write_336(disks)
+    online = [None if i in lost else d for i, d in enumerate(disks)]
+    before = KERNEL_STATS.snapshot()["meta_read"]
+    with syscalls as calls:
+        fis, errs = read_all_fileinfo(online, "b", "obj")
+    after = KERNEL_STATS.snapshot()["meta_read"]
+    answered = 12 - len(lost)
+    assert calls == {"os.open": answered, "os.read": answered,
+                     "os.close": answered, "os.stat": 0,
+                     "os.path.isdir": 0, "builtins.open": 0}
+    assert sum(calls.values()) == 3 * answered  # 36 a round; 30 degraded
+    assert {k: after[k] - before[k] for k in after} == {
+        "reads": answered, "refills": 0, "error_path": 0,
+    }
+    assert [e is None for e in errs] == [i not in lost for i in range(12)]
+    assert find_fileinfo_in_quorum(fis, 8).size == 10 << 20
