@@ -150,7 +150,7 @@ RULES: "dict[str, str]" = {
     ),
     "MTPU601": (
         "resource lifecycle: leaked acquire — a registered resource "
-        "(staging-ledger reservation, admission token, parity ref, "
+        "(admission token, parity ref, "
         "io-pool future, rw-lock, fault hang) is acquired and a path "
         "reaches function exit without a matching release or a "
         "registered ownership transfer (the defer-less leak class: one "

@@ -85,19 +85,11 @@ KNOWN_ENTRY_POINTS = {
     ("rs_pallas", "matmul_rows_runtime"),
     ("rs_pallas", "verify_reconstruct_runtime"),
     ("codec_step", "encode_and_hash_words"),
-    ("codec_step", "encode_and_hash_words_digest"),
     ("codec_step", "encode_words_fused1"),
     ("codec_step", "verify_and_reconstruct_words"),
-    ("codec_step", "encode_subchunk_words"),
-    ("codec_step", "verify_reconstruct_subchunk_words"),
-    ("codec_step", "group_flags"),
-    ("codec_step", "pack_nonzero_groups"),
     ("codec_step", "verify_hashes_words"),
     ("codec_step", "digest_words"),
     ("codec_step", "reconstruct_words_batch"),
-    ("codec_step", "encode_throughput_probe"),
-    ("codec_step", "reconstruct_throughput_probe"),
-    ("codec_step", "verify_throughput_probe"),
     ("select_step", "screen_chunk"),
     ("select_step", "extract_positions"),
     ("select_step", "row_spans"),
@@ -111,13 +103,7 @@ KNOWN_ENTRY_POINTS = {
 # class, caught statically as MTPU501.  MTPU505 cross-checks this table
 # against the ``donate_argnums`` literals in the jit decorators.
 DONATING_ENTRY_POINTS = {
-    ("codec_step", "encode_and_hash_words_digest"): (0,),
     ("codec_step", "encode_words_fused1"): (0,),
-    # the async overlap sub-chunk chain donates BOTH the staging chunk
-    # (dies into the parity allocation) and the ping-pong hash
-    # accumulator (threads through the chunk chain)
-    ("codec_step", "encode_subchunk_words"): (0, 1),
-    ("codec_step", "verify_reconstruct_subchunk_words"): (0, 1),
 }
 
 # Mesh kernel kinds registered with the rules.py compile seam that
@@ -139,7 +125,6 @@ DRAIN_SEAMS = {
         "encode_end",
         "encode_digest_end",
         "drain",
-        "_drain_d2h",
         # the one read-back helper every seam above goes through:
         # block_until_ready (seam_kernel_wait) then np.asarray (seam_d2h)
         "_host_readback",
@@ -149,10 +134,6 @@ DRAIN_SEAMS = {
         "reconstruct_and_verify",
         "verify",
         "digest",
-        # sub-chunk overlap pipeline (MINIO_TPU_CODEC_OVERLAP=async):
-        # the GET-side chain that drains chunk s D2H while chunk s+1
-        # computes
-        "_drain_vr_subchunks",
     ),
     "minio_tpu/s3select/device.py": (
         # candidate row bytes are the only payload that crosses D2H,
@@ -290,7 +271,6 @@ def run() -> "list[Finding]":
     findings: "list[Finding]" = []
     S = jax.ShapeDtypeStruct
     u8, u32 = jnp.uint8, jnp.uint32
-    reps = S((), jnp.int32)  # dynamic trip count of the bench probes
 
     def ctx(fn, default_path):
         return _ContractContext(findings, fn, default_path)
@@ -383,64 +363,6 @@ def run() -> "list[Finding]":
         except Exception as e:
             c.fail(e)
 
-    covers("codec_step", "encode_and_hash_words_digest")
-    c = ctx(
-        codec_step.encode_and_hash_words_digest,
-        "minio_tpu/ops/codec_step.py",
-    )
-    for k, m, L in CONFIG_GRID:
-        w, n = L // 4, k + m
-        c.config = cfg_str(k, m, L)
-        try:
-            # identical contract to encode_and_hash_words: the digest
-            # variant only changes buffer lifetime (donated input,
-            # device-resident parity), never shapes or dtypes
-            parity, digests = (
-                codec_step.encode_and_hash_words_digest.eval_shape(
-                    S((_BATCH, k, w), u32), m, L
-                )
-            )
-            c.shape(parity, (_BATCH, m, w), "device-resident parity")
-            c.dtype(parity, "uint32", "device-resident parity")
-            c.shape(digests, (_BATCH, n, 8), "digests")
-            c.dtype(digests, "uint32", "digests")
-        except Exception as e:
-            c.fail(e)
-
-    # parity transport compression: group granularity must divide the
-    # words-per-shard of every grid config (smallest is 64B -> 16 words)
-    _GROUP = 8
-
-    covers("codec_step", "group_flags")
-    c = ctx(codec_step.group_flags, "minio_tpu/ops/codec_step.py")
-    for k, m, L in CONFIG_GRID:
-        w, g = L // 4, L // 4 // _GROUP
-        c.config = cfg_str(k, m, L)
-        try:
-            flags = codec_step.group_flags.eval_shape(
-                S((_BATCH, m, w), u32), _GROUP
-            )
-            c.shape(flags, (_BATCH, m, g), "group flags")
-            c.dtype(flags, "bool", "group flags")
-        except Exception as e:
-            c.fail(e)
-
-    covers("codec_step", "pack_nonzero_groups")
-    c = ctx(codec_step.pack_nonzero_groups, "minio_tpu/ops/codec_step.py")
-    for k, m, L in CONFIG_GRID:
-        w, g = L // 4, L // 4 // _GROUP
-        c.config = cfg_str(k, m, L)
-        try:
-            flags, packed = codec_step.pack_nonzero_groups.eval_shape(
-                S((_BATCH, m, w), u32), _GROUP
-            )
-            c.shape(flags, (_BATCH, m, g), "pack flags")
-            c.dtype(flags, "bool", "pack flags")
-            c.shape(packed, (_BATCH, m, w), "packed words")
-            c.dtype(packed, "uint32", "packed words")
-        except Exception as e:
-            c.fail(e)
-
     covers("codec_step", "verify_hashes_words")
     c = ctx(codec_step.verify_hashes_words, "minio_tpu/ops/codec_step.py")
     for k, m, L in CONFIG_GRID:
@@ -500,46 +422,12 @@ def run() -> "list[Finding]":
         except Exception as e:
             c.fail(e)
 
-    for name in (
-        "encode_throughput_probe",
-        "reconstruct_throughput_probe",
-        "verify_throughput_probe",
-    ):
-        covers("codec_step", name)
-    for k, m, L in CONFIG_GRID:
-        w, n = L // 4, k + m
-        probes = [
-            (
-                codec_step.encode_throughput_probe,
-                (S((_BATCH, k, w), u32), m, L, reps),
-            ),
-            (
-                codec_step.reconstruct_throughput_probe,
-                (S((_BATCH, n, w), u32), *pattern(k)[1:], k, m, reps),
-            ),
-            (
-                codec_step.verify_throughput_probe,
-                (S((_BATCH, n, w), u32), S((_BATCH, n, 8), u32), L, reps),
-            ),
-        ]
-        for fn, args in probes:
-            c = ctx(fn, "minio_tpu/ops/codec_step.py")
-            c.config = cfg_str(k, m, L)
-            try:
-                sample, acc = fn.eval_shape(*args)
-                c.shape(sample, (8,), "probe checksum sample")
-                c.dtype(sample, "uint32", "probe checksum sample")
-                c.shape(acc, (), "probe accumulator")
-                c.dtype(acc, "uint32", "probe accumulator")
-            except Exception as e:
-                c.fail(e)
-
-    # ---- codec_step.py: one-kernel codec (fused1) -----------------------
+    # ---- codec_step.py: the served one-pass codec ------------------------
     #
-    # The fused1 entries run encode+digest resp. verify+reconstruct as
-    # one pass.  The XLA formulation is checked over CONFIG_GRID; the
-    # Pallas path over FUSED_GRID in interpret mode, both formulations,
-    # so contract coverage matches everything the dispatcher can launch.
+    # encode+digest resp. verify+reconstruct as one pass.  The XLA form
+    # is checked over CONFIG_GRID; the Pallas path over FUSED_GRID in
+    # interpret mode, so contract coverage matches everything the
+    # dispatcher can launch.
 
     covers("codec_step", "encode_words_fused1")
     c = ctx(codec_step.encode_words_fused1, "minio_tpu/ops/codec_step.py")
@@ -558,21 +446,17 @@ def run() -> "list[Finding]":
             c.fail(e)
     for k, m, L in FUSED_GRID:
         w, n = L // 4, k + m
-        for formulation in ("swar", "mxu"):
-            c.config = cfg_str(k, m, L) + f" [pallas, {formulation}]"
-            try:
-                parity, digests = (
-                    codec_step.encode_words_fused1.eval_shape(
-                        S((_BATCH, k, w), u32), m, L,
-                        formulation, True, True,
-                    )
-                )
-                c.shape(parity, (_BATCH, m, w), "fused1 parity")
-                c.dtype(parity, "uint32", "fused1 parity")
-                c.shape(digests, (_BATCH, n, 8), "fused1 digests")
-                c.dtype(digests, "uint32", "fused1 digests")
-            except Exception as e:
-                c.fail(e)
+        c.config = cfg_str(k, m, L) + " [pallas]"
+        try:
+            parity, digests = codec_step.encode_words_fused1.eval_shape(
+                S((_BATCH, k, w), u32), m, L, True, True
+            )
+            c.shape(parity, (_BATCH, m, w), "fused1 parity")
+            c.dtype(parity, "uint32", "fused1 parity")
+            c.shape(digests, (_BATCH, n, 8), "fused1 digests")
+            c.dtype(digests, "uint32", "fused1 digests")
+        except Exception as e:
+            c.fail(e)
 
     covers("codec_step", "verify_and_reconstruct_words")
     c = ctx(
@@ -610,84 +494,18 @@ def run() -> "list[Finding]":
             c.fail(e)
     for k, m, L in FUSED_GRID:
         w, n = L // 4, k + m
-        for formulation in ("swar", "mxu"):
-            c.config = cfg_str(k, m, L) + f" [pallas, {formulation}]"
-            try:
-                data, ok = (
-                    codec_step.verify_and_reconstruct_words.eval_shape(
-                        S((_BATCH, n, w), u32), S((_BATCH, n, 8), u32),
-                        *pattern(k, n), k, m, L, formulation, True, True,
-                    )
-                )
-                c.shape(data, (_BATCH, k, w), "fused GET data words")
-                c.dtype(data, "uint32", "fused GET data words")
-                c.shape(ok, (_BATCH, n), "fused GET ok mask")
-                c.dtype(ok, "bool", "fused GET ok mask")
-            except Exception as e:
-                c.fail(e)
-
-    # ---- codec_step.py: async-overlap sub-chunk twins -------------------
-    #
-    # The MINIO_TPU_CODEC_OVERLAP=async chain: per-chunk parity/verify
-    # passes threading a donated (B, n, 8) hash-partial accumulator.
-    # Contracts run each entry as a mid-chain link (finalize=False) and
-    # as the chain tail (finalize=True) — shapes must agree so the
-    # backend's ping-pong reassignment stays well-typed, and the chunk
-    # width grid includes a NON-dividing width (the ragged tail chunk
-    # compiles as its own program).
-
-    covers("codec_step", "encode_subchunk_words")
-    c = ctx(codec_step.encode_subchunk_words, "minio_tpu/ops/codec_step.py")
-    for k, m, L in CONFIG_GRID:
-        w, n = L // 4, k + m
-        for cw in (w, w // 2 if w // 2 % 8 == 0 else w, 8):
-            for fin in (False, True):
-                c.config = cfg_str(k, m, L) + f" [cw={cw}, finalize={fin}]"
-                try:
-                    parity, acc = (
-                        codec_step.encode_subchunk_words.eval_shape(
-                            S((_BATCH, k, cw), u32),
-                            S((_BATCH, n, 8), u32),
-                            S((), u32),
-                            m, L, fin,
-                        )
-                    )
-                    c.shape(parity, (_BATCH, m, cw), "chunk parity")
-                    c.dtype(parity, "uint32", "chunk parity")
-                    c.shape(acc, (_BATCH, n, 8), "chunk partials")
-                    c.dtype(acc, "uint32", "chunk partials")
-                except Exception as e:
-                    c.fail(e)
-
-    covers("codec_step", "verify_reconstruct_subchunk_words")
-    c = ctx(
-        codec_step.verify_reconstruct_subchunk_words,
-        "minio_tpu/ops/codec_step.py",
-    )
-    for k, m, L in CONFIG_GRID:
-        w, n = L // 4, k + m
-        for cw in (w, 8):
-            for fin in (False, True):
-                c.config = cfg_str(k, m, L) + f" [cw={cw}, finalize={fin}]"
-                try:
-                    data, acc, ok = (
-                        codec_step
-                        .verify_reconstruct_subchunk_words.eval_shape(
-                            S((_BATCH, n, cw), u32),
-                            S((_BATCH, n, 8), u32),
-                            S((_BATCH, n, 8), u32),
-                            S((), u32),
-                            *pattern(k, n), k, m, L, fin,
-                        )
-                    )
-                    c.shape(data, (_BATCH, k, cw), "chunk data words")
-                    c.dtype(data, "uint32", "chunk data words")
-                    c.shape(acc, (_BATCH, n, 8), "chunk partials")
-                    c.dtype(acc, "uint32", "chunk partials")
-                    c.shape(ok, (_BATCH, n), "chunk ok mask")
-                    c.dtype(ok, "bool", "chunk ok mask")
-                except Exception as e:
-                    c.fail(e)
+        c.config = cfg_str(k, m, L) + " [pallas]"
+        try:
+            data, ok = codec_step.verify_and_reconstruct_words.eval_shape(
+                S((_BATCH, n, w), u32), S((_BATCH, n, 8), u32),
+                *pattern(k, n), k, m, L, True, True,
+            )
+            c.shape(data, (_BATCH, k, w), "fused GET data words")
+            c.dtype(data, "uint32", "fused GET data words")
+            c.shape(ok, (_BATCH, n), "fused GET ok mask")
+            c.dtype(ok, "bool", "fused GET ok mask")
+        except Exception as e:
+            c.fail(e)
 
     # ---- select_step.py: S3 Select scan kernels -------------------------
     #
@@ -847,18 +665,17 @@ def run() -> "list[Finding]":
     c = ctx(rs_pallas.encode_hash_fused, "minio_tpu/ops/rs_pallas.py")
     for k, m, L in FUSED_GRID:
         w, n = L // 4, k + m
-        for formulation in ("swar", "mxu"):
-            c.config = cfg_str(k, m, L) + f" [{formulation}]"
-            try:
-                parity, hacc = rs_pallas.encode_hash_fused.eval_shape(
-                    S((_BATCH, k, w), u32), m, formulation, True
-                )
-                c.shape(parity, (_BATCH, m, w), "fused parity")
-                c.dtype(parity, "uint32", "fused parity")
-                c.shape(hacc, (_BATCH, n, 8), "fused hash partials")
-                c.dtype(hacc, "uint32", "fused hash partials")
-            except Exception as e:
-                c.fail(e)
+        c.config = cfg_str(k, m, L)
+        try:
+            parity, hacc = rs_pallas.encode_hash_fused.eval_shape(
+                S((_BATCH, k, w), u32), m, True
+            )
+            c.shape(parity, (_BATCH, m, w), "fused parity")
+            c.dtype(parity, "uint32", "fused parity")
+            c.shape(hacc, (_BATCH, n, 8), "fused hash partials")
+            c.dtype(hacc, "uint32", "fused hash partials")
+        except Exception as e:
+            c.fail(e)
 
     covers("rs_pallas", "matmul_rows_runtime")
     c = ctx(rs_pallas.matmul_rows_runtime, "minio_tpu/ops/rs_pallas.py")
@@ -883,21 +700,17 @@ def run() -> "list[Finding]":
     )
     for k, m, L in FUSED_GRID:
         w, n = L // 4, k + m
-        for formulation in ("swar", "mxu"):
-            c.config = cfg_str(k, m, L) + f" [{formulation}]"
-            try:
-                data, hacc = (
-                    rs_pallas.verify_reconstruct_runtime.eval_shape(
-                        S((_BATCH, n, w), u32), S((k, n), u8),
-                        formulation, True,
-                    )
-                )
-                c.shape(data, (_BATCH, k, w), "fused GET data words")
-                c.dtype(data, "uint32", "fused GET data words")
-                c.shape(hacc, (_BATCH, n, 8), "fused GET hash partials")
-                c.dtype(hacc, "uint32", "fused GET hash partials")
-            except Exception as e:
-                c.fail(e)
+        c.config = cfg_str(k, m, L)
+        try:
+            data, hacc = rs_pallas.verify_reconstruct_runtime.eval_shape(
+                S((_BATCH, n, w), u32), S((k, n), u8), True
+            )
+            c.shape(data, (_BATCH, k, w), "fused GET data words")
+            c.dtype(data, "uint32", "fused GET data words")
+            c.shape(hacc, (_BATCH, n, 8), "fused GET hash partials")
+            c.dtype(hacc, "uint32", "fused GET hash partials")
+        except Exception as e:
+            c.fail(e)
 
     # ---- parallel/mesh.py: compile-seam mesh kernels --------------------
     #

@@ -21,7 +21,7 @@ the MTPU605 resolution check):
   only when the call returns truthy (``if not try_enter(t): return``
   refines the obligation away on the shed branch).
 * ``handle=True`` marks acquires whose return value IS the resource
-  (staging reservation, io-future, parity ref).  Release is the
+  (admission token, io-future, parity ref).  Release is the
   handle flowing into a ``release_calls`` function or one of
   ``release_methods`` invoked on it; returning/storing/passing the
   handle transfers ownership out of the local frame.
@@ -79,22 +79,6 @@ class Registry:
 
 
 _DEFAULT_RESOURCES: "tuple[ResourceClass, ...]" = (
-    # Device-budget staging ledger (codec/backend.py): _stage_reserve
-    # returns the byte count that _stage_release must give back; the
-    # reservation may instead ride into an _AsyncHandle payload, whose
-    # *_end drain releases it on the device side.
-    ResourceClass(
-        name="staging-ledger",
-        scope=("minio_tpu/codec/backend.py",),
-        acquire_calls=("_stage_reserve",),
-        release_calls=("_stage_release",),
-        transfer_calls=("_AsyncHandle",),
-        handle=True,
-        defs=(
-            ("minio_tpu/codec/backend.py", "_stage_reserve"),
-            ("minio_tpu/codec/backend.py", "_stage_release"),
-        ),
-    ),
     # Admission tokens (server/): the AdmissionController seams and
     # the TokenCounter reserve/undo primitives they are built from.
     # try_* acquires hold only on a truthy return; a seam returning
@@ -240,7 +224,7 @@ _DEFAULT_RESOURCES: "tuple[ResourceClass, ...]" = (
 # a registered scope must itself be registered or MTPU605 fires (the
 # other drift direction — code outrunning the registry).
 ACQUIRE_SHAPED_PREFIXES = ("try_enter_", "try_acquire", "acquire_")
-ACQUIRE_SHAPED_NAMES = ("reserve", "_stage_reserve", "admit")
+ACQUIRE_SHAPED_NAMES = ("reserve", "admit")
 
 
 def registered_call_names(registry: Registry) -> "set[str]":

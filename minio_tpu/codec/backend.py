@@ -87,16 +87,6 @@ def _record_h2d(plane: str, nbytes: int) -> None:
     KERNEL_STATS.record_h2d(plane, int(nbytes))
 
 
-def _record_overlap(plane: str, windows: int) -> None:
-    """Account completed overlap windows (plane = put|get): sub-chunks
-    whose transfer was dispatched while a neighbor's pass was in
-    flight — the snapshot-level evidence the
-    MINIO_TPU_CODEC_OVERLAP=async pipeline engaged."""
-    from .telemetry import KERNEL_STATS
-
-    KERNEL_STATS.record_overlap_windows(plane, int(windows))
-
-
 # ---------------------------------------------------------------------------
 # The loss pattern as operands: survivors + inverse, picked on the host
 # ---------------------------------------------------------------------------
@@ -221,42 +211,6 @@ def _ladder_chunks(arr: np.ndarray, row_bytes: int):
         yield lo, hi, part
 
 
-# Ping-pong staging ledger for the async sub-chunk pipeline: while a
-# batch is between encode_digest_begin and _end, TWO sub-chunk staging
-# buffers are live on device (the one computing and the one prefetching)
-# on top of the parity planes the ParityPlaneCache already accounts.
-# Posted to the shared device-byte budget so cache admission sees the
-# real headroom (cache/allocator.py).
-_staging_bytes = 0
-
-
-def _stage_reserve(nbytes: int) -> int:
-    global _staging_bytes
-    nbytes = int(nbytes)
-    with _lock:
-        _staging_bytes += nbytes
-        total = _staging_bytes
-    _post_staging(total)
-    return nbytes
-
-
-def _stage_release(nbytes: int) -> None:
-    global _staging_bytes
-    with _lock:
-        _staging_bytes = max(0, _staging_bytes - int(nbytes))
-        total = _staging_bytes
-    _post_staging(total)
-
-
-def _post_staging(total: int) -> None:
-    try:
-        from ..cache.allocator import device_budget
-
-        device_budget().set_usage("codec_staging", total)
-    except Exception as exc:  # noqa: BLE001 - must never fail I/O
-        _log.debug("staging budget accounting failed: %s", exc)
-
-
 # ---------------------------------------------------------------------------
 # Device-resident parity plane: refs + the bounded write-back cache
 # ---------------------------------------------------------------------------
@@ -357,47 +311,35 @@ class _EagerParityRef:
 
 
 class _DeviceParityRef:
-    """One batch's device-resident parity plane: (B, m, w) u32 words,
-    held whole or as the S sub-chunk arrays the async overlap pipeline
-    cut along the stripe-length axis (MINIO_TPU_CODEC_OVERLAP=async).
+    """One batch's device-resident parity plane: (B, m, w) u32 words.
 
     ``drain()`` is the single D2H seam: thread-safe and memoized, so
-    the m per-disk parity writers sharing this ref pay one transfer —
-    and when MINIO_TPU_DEVICE_COMPRESS screens a plane and finds it
-    sparse, the packed prefix (ops/codec_step.pack_nonzero_groups), not
-    the raw plane, crosses the bus.  Registered with the
-    ParityPlaneCache, which accounts every live chunk, until drained
-    or released.
+    the m per-disk parity writers sharing this ref pay one transfer.
+    Registered with the ParityPlaneCache, which accounts the plane
+    until it is drained or released.
     """
 
-    __slots__ = ("_lk", "_cache", "_planes", "_host", "nbytes")
+    __slots__ = ("_lk", "_cache", "_plane", "_host", "nbytes")
 
-    def __init__(self, cache: ParityPlaneCache, planes):
+    def __init__(self, cache: ParityPlaneCache, plane):
         self._lk = threading.Lock()
         self._cache = cache
-        self._planes = list(planes)
+        self._plane = plane
         self._host: "np.ndarray | None" = None
-        self.nbytes = sum(
-            int(p.shape[0]) * int(p.shape[1]) * int(p.shape[2]) * 4
-            for p in self._planes
-        )
+        self.nbytes = int(plane.nbytes)
         cache.add(self)
 
     def drain(self) -> np.ndarray:
-        """(B, m, L) uint8 parity bytes, materialized at most once."""
+        """(B, m, L) uint8 parity bytes, materialized at most once: the
+        one sanctioned eager readback of a parity plane."""
+        from ..ops import codec_step
+
         with self._lk:
-            if self._host is None and self._planes is not None:
-                # per-chunk D2H: reading chunk s overlaps the device
-                # work still in flight behind chunks s+1..; each chunk
-                # is screened on its own, so a sparse chunk of an
-                # otherwise dense plane still crosses the bus packed
-                parts = [self._drain_d2h(p) for p in self._planes]
-                self._host = (
-                    parts[0]
-                    if len(parts) == 1
-                    else np.concatenate(parts, axis=-1)
+            if self._host is None and self._plane is not None:
+                self._host = codec_step.host_words_to_bytes(
+                    _host_readback(self._plane, "parity")
                 )
-                self._planes = None
+                self._plane = None
                 self._cache.forget(self)
             return self._host
 
@@ -405,44 +347,9 @@ class _DeviceParityRef:
         """Drop an unused plane without the transfer (error-path
         cleanup of handles whose writers were never scheduled)."""
         with self._lk:
-            if self._planes is not None:
-                self._planes = None
+            if self._plane is not None:
+                self._plane = None
                 self._cache.forget(self)
-
-    @staticmethod
-    def _drain_d2h(parity_w) -> np.ndarray:
-        """The one sanctioned eager readback of a parity plane."""
-        from ..ops import codec_step
-        from . import compress as compmod
-
-        mode = compmod.device_compress_mode()
-        w = int(parity_w.shape[-1])
-        G = compmod.PARITY_GROUP_WORDS
-        g = w // G if w % G == 0 else 0
-        if mode != "off" and g >= 2:
-            _record_pass("group_flags")
-            flags = _host_readback(codec_step.group_flags(parity_w, G), None)
-            kept = int(flags.sum(axis=-1).max()) if flags.size else 0
-            if kept == 0:
-                _record_d2h("parity", flags.nbytes)
-                return np.zeros(
-                    parity_w.shape[:-1] + (w * 4,), dtype=np.uint8
-                )
-            if (
-                mode == "on"
-                or kept / g <= compmod.parity_fill_threshold()
-            ):
-                _record_pass("pack_nonzero_groups")
-                _f, packed = codec_step.pack_nonzero_groups(parity_w, G)
-                keep = compmod.prefix_keep(kept, g)
-                prefix = _host_readback(packed[..., : keep * G], None)
-                _record_d2h("parity", flags.nbytes + prefix.nbytes)
-                words = compmod.unpack_nonzero_groups(
-                    flags, prefix, G, w
-                )
-                return codec_step.host_words_to_bytes(words)
-        parity = _host_readback(parity_w, "parity")
-        return codec_step.host_words_to_bytes(parity)
 
 
 _PARITY_CACHE: "ParityPlaneCache | None" = None
@@ -662,7 +569,7 @@ class TpuBackend(CodecBackend):
 
     def __init__(self, devices=None):
         # devices=None -> every visible device; an explicit tuple pins
-        # the backend to a slice of the machine (bench chip sweeps)
+        # the backend to a slice of the machine
         self._devices = tuple(devices) if devices is not None else None
         self._meshes: dict[tuple, object] = {}
         self._router = None
@@ -801,48 +708,19 @@ class TpuBackend(CodecBackend):
 
     def encode_digest_begin(self, data, parity_shards):
         """Digest-only start: the fused donated kernel keeps parity on
-        device; only the 32-byte digests are scheduled for readback.
-        MINIO_TPU_CODEC_KERNEL picks the jitted entry (``legacy`` is the
-        bisection oracle); both park the plane behind a ParityRef."""
+        device behind a ParityRef; only the 32-byte digests are
+        scheduled for readback."""
         from ..ops import codec_step
 
         data = np.ascontiguousarray(data, dtype=np.uint8)
         B, k, L = data.shape
         if self._mesh_for(B, k) is not None:
-            if codec_step.codec_overlap_mode() != "off":
-                # overlap sub-chunking would fight the mesh "seq" axis
-                # for the stripe-length dimension: warn once, take the
-                # serialized (bit-identical) mesh path
-                from ..parallel import mesh as pm
-
-                pm.warn_overlap_fallback()
             # the mesh path has no device-resident cache (planes live
             # sharded across devices): compose the eager seam, still
             # async through the mesh begin/end split
             return _AsyncHandle(
                 "digest-eager", self.encode_begin(data, parity_shards)
             )
-        if codec_step.codec_kernel_mode() != "fused1":
-            words = self._stage(data)
-            with _launch():
-                parity_w, digests = (
-                    codec_step.encode_and_hash_words_digest(
-                        words, parity_shards, L
-                    )
-                )
-            _record_pass(
-                "encode_and_hash_words_digest",
-                pallas=parity_shards > 0
-                and codec_step.pallas_compiled(L // 4),
-            )
-            return _AsyncHandle("digest", (parity_w, digests))
-        if codec_step.codec_overlap_mode() == "async":
-            handle = self._encode_subchunk_begin(
-                codec_step.host_bytes_to_words(data), parity_shards, L
-            )
-            if handle is not None:
-                return handle
-            # batch too small for S >= 3 sub-chunks: serialized path
         use_pallas, interpret = codec_step.pallas_dispatch(L // 4)
         words = self._stage(data)
         with _launch():
@@ -850,75 +728,15 @@ class TpuBackend(CodecBackend):
                 words,
                 parity_shards,
                 L,
-                formulation=codec_step.codec_formulation(),
                 use_pallas=use_pallas,
                 interpret=interpret,
             )
         _record_pass("encode_words_fused1", pallas=use_pallas)
         return _AsyncHandle("digest", (parity_w, digests))
 
-    def _encode_subchunk_begin(self, words_h, parity_shards, shard_len):
-        """MINIO_TPU_CODEC_OVERLAP=async PUT: split the stripe batch
-        along w into S sub-chunks and double-buffer them through the
-        device — chunk s+1's H2D staging (async device_put dispatch)
-        overlaps chunk s's encode pass, whose donated ping-pong
-        accumulator carries the phash256 partials; the LAST chunk
-        finalizes the digests in its own program, so the chain launches
-        S passes and nothing extra for the digest.
-
-        Returns the in-flight handle, or None when the batch is too
-        small to cut S >= 3 chunks (caller takes the serialized path).
-        """
-        from ..ops import codec_step, rs
-        from . import compress as compmod
-        from .erasure import subchunk_words
-
-        B, k, w = words_h.shape
-        m = parity_shards
-        # cut on parity-group boundaries while the drain screens per
-        # chunk, on the hash partition stride otherwise
-        screened = compmod.device_compress_mode() != "off"
-        cw = subchunk_words(w, compmod.PARITY_GROUP_WORDS if screened else 8)
-        if not cw:
-            return None
-        offs = list(range(0, w, cw))
-        # ping-pong staging: two sub-chunk input buffers live at once
-        reserved = _stage_reserve(2 * B * k * cw * 4)
-        try:
-            acc = self._to_device(np.zeros((B, k + m, 8), np.uint32))
-            parity_c = []
-            for i, off in enumerate(offs):
-                end = min(off + cw, w)
-                with spans.span(spans.SEAM_STAGE):
-                    chunk = self._to_device(
-                        np.ascontiguousarray(words_h[:, :, off:end])
-                    )
-                _record_h2d("data", (end - off) * B * k * 4)
-                with _launch():
-                    p_c, acc = codec_step.encode_subchunk_words(
-                        chunk,
-                        acc,
-                        np.uint32(off),
-                        m,
-                        shard_len,
-                        finalize=i == len(offs) - 1,
-                    )
-                # the chunk's parity product is rs._matmul_static
-                _record_pass(
-                    "encode_subchunk_words",
-                    pallas=m > 0 and rs.lowering_for_tpu(),
-                )
-                parity_c.append(p_c)
-            _record_overlap("put", len(offs) - 1)
-        except BaseException:
-            _stage_release(reserved)
-            raise
-        return _AsyncHandle("digest-subchunk", (parity_c, acc, reserved))
-
     def encode_digest_end(self, handle):
         if not isinstance(handle, _AsyncHandle) or handle.kind not in (
             "digest",
-            "digest-subchunk",
             "digest-eager",
         ):
             return super().encode_digest_end(handle)
@@ -932,22 +750,6 @@ class TpuBackend(CodecBackend):
                     np.ascontiguousarray(parity, dtype=np.uint8)
                 ),
             )
-        elif handle.kind == "digest-subchunk":
-            # async-overlap twin: same digest-only eager readback; the
-            # staging ping-pong reservation drops here — the last
-            # chunk's pass has produced everything the ref holds
-            parity_c, digests_d, reserved = handle.payload
-            # the reservation must drop even when the digest D2H
-            # throws (device reset mid-drain): an exception here must
-            # not strand staging-ledger bytes for the process lifetime
-            try:
-                digests = _host_readback(digests_d, "data")
-            finally:
-                _stage_release(reserved)
-            result = (
-                digests,
-                _DeviceParityRef(parity_plane_cache(), parity_c),
-            )
         else:
             # digests are the ONLY eager readback (MTPU107); parity
             # stays device-resident behind the ref
@@ -955,7 +757,7 @@ class TpuBackend(CodecBackend):
             digests = _host_readback(digests_d, "data")
             result = (
                 digests,
-                _DeviceParityRef(parity_plane_cache(), [parity_w]),
+                _DeviceParityRef(parity_plane_cache(), parity_w),
             )
         handle.result = result
         handle.consumed = True
@@ -1038,36 +840,26 @@ class TpuBackend(CodecBackend):
     def reconstruct_and_verify(
         self, shards, digests, present, data_shards, parity_shards
     ):
-        """Fused GET-side pass (fused1): digest checks + survivor decode
-        in ONE device pass (codec_step.verify_and_reconstruct_words),
-        replacing the verify -> reconstruct pair on the heal path.
-        Optimistic like CpuBackend: decode from the first k present
-        rows while hashing all of them; on the rare digest mismatch
-        among the chosen survivors, re-pick survivors from the verified
-        mask and re-solve just the hit stripes.  The legacy mode
-        composes the separate passes (bisection oracle)."""
+        """Fused GET-side pass: digest checks + survivor decode in ONE
+        device pass (codec_step.verify_and_reconstruct_words) on the
+        heal path.  Optimistic like CpuBackend: decode from the first k
+        present rows while hashing all of them; on the rare digest
+        mismatch among the chosen survivors, re-pick survivors from the
+        verified mask and re-solve just the hit stripes."""
         from ..ops import codec_step
 
-        if codec_step.codec_kernel_mode() != "fused1":
-            return super().reconstruct_and_verify(
-                shards, digests, present, data_shards, parity_shards
-            )
         shards = np.ascontiguousarray(shards, dtype=np.uint8)
         pres = np.asarray(present, dtype=bool)
         B, n, L = shards.shape
         survivors, matrix = decode_plan(pres, data_shards, parity_shards)
         words = codec_step.host_bytes_to_words(shards)
         use_pallas, interpret = codec_step.pallas_dispatch(L // 4)
-        overlap = codec_step.codec_overlap_mode()
         mesh = self._mesh_for(B, data_shards)
-        got = None
         if mesh is not None:
             from ..parallel import mesh as pm
 
-            if overlap != "off":
-                pm.warn_overlap_fallback()
             with _launch():  # staging, kernel and read-back are inside
-                got = pm.mesh_verify_reconstruct(
+                dw, ok = pm.mesh_verify_reconstruct(
                     mesh,
                     words,
                     np.asarray(digests),
@@ -1077,20 +869,12 @@ class TpuBackend(CodecBackend):
                     data_shards,
                     parity_shards,
                     L,
-                    formulation=codec_step.codec_formulation(),
                     use_pallas=use_pallas,
                     interpret=interpret,
                 )
             _record_pass("mesh_verify_reconstruct", pallas=use_pallas)
             _record_h2d("data", words.nbytes)
-            _record_d2h("data", got[0].nbytes)
-        elif overlap == "async":
-            got = self._drain_vr_subchunks(
-                words, digests, (pres, survivors, matrix),
-                data_shards, parity_shards, L,
-            )
-        if got is not None:
-            dw, ok = got
+            _record_d2h("data", dw.nbytes)
         else:
             with spans.span(spans.SEAM_STAGE):
                 words_d = self._to_device(words)
@@ -1106,7 +890,6 @@ class TpuBackend(CodecBackend):
                     data_shards,
                     parity_shards,
                     L,
-                    formulation=codec_step.codec_formulation(),
                     use_pallas=use_pallas,
                     interpret=interpret,
                 )
@@ -1123,72 +906,6 @@ class TpuBackend(CodecBackend):
                 shards[idxs], ok[idxs], data_shards, parity_shards
             )
         return data, ok
-
-    def _drain_vr_subchunks(
-        self, words_h, digests, pattern, data_shards, parity_shards, shard_len
-    ):
-        """MINIO_TPU_CODEC_OVERLAP=async GET: the sub-chunked
-        verify+reconstruct chain, a registered drain seam — each
-        reconstructed chunk drains D2H here WHILE the next chunk's pass
-        runs (np.asarray of chunk s syncs only chunk s; chunks s+1.. are
-        still in flight behind it), with the digest partials threading
-        through the donated ping-pong accumulator and the LAST chunk's
-        program producing the verify mask.
-
-        ``pattern`` is decode_plan's pair behind the present mask:
-        (present, survivors, matrix), the chunk program's operands.
-        Returns (data words (B, k, w), ok (B, n) bool), or None when
-        the batch is too small to cut S >= 3 chunks.
-        """
-        from ..ops import codec_step
-        from .erasure import subchunk_words
-
-        B, n, w = words_h.shape
-        cw = subchunk_words(w, 8)
-        if not cw:
-            return None
-        offs = list(range(0, w, cw))
-        reserved = _stage_reserve(2 * B * n * cw * 4)
-        try:
-            digests_d = self._to_device(np.asarray(digests))
-            acc = self._to_device(np.zeros((B, n, 8), np.uint32))
-            parts: "list[np.ndarray]" = []
-            prev = None
-            ok_d = None
-            for i, off in enumerate(offs):
-                end = min(off + cw, w)
-                with spans.span(spans.SEAM_STAGE):
-                    chunk = self._to_device(
-                        np.ascontiguousarray(words_h[:, :, off:end])
-                    )
-                _record_h2d("data", (end - off) * B * n * 4)
-                with _launch():
-                    d_c, acc, ok_d = (
-                        codec_step.verify_reconstruct_subchunk_words(
-                            chunk,
-                            acc,
-                            digests_d,
-                            np.uint32(off),
-                            *pattern,
-                            data_shards,
-                            parity_shards,
-                            shard_len,
-                            finalize=i == len(offs) - 1,
-                        )
-                    )
-                # cut on hash strides, not kernel tiles: the XLA form
-                _record_pass("verify_reconstruct_subchunk_words")
-                if prev is not None:
-                    # drain chunk i-1 while chunk i computes: this is
-                    # the D2H leg of the three-deep overlap
-                    parts.append(_host_readback(prev, "data"))
-                prev = d_c
-            parts.append(_host_readback(prev, "data"))
-            ok = _host_readback(ok_d, None)
-            _record_overlap("get", len(offs) - 1)
-        finally:
-            _stage_release(reserved)
-        return np.concatenate(parts, axis=-1), ok
 
     def digest(self, shards):
         from ..ops import codec_step
@@ -1296,9 +1013,9 @@ class CpuBackend(CodecBackend):
     def encode_split(self, data, parity_shards):
         """Legacy split path: per-stripe native matmul round-trips plus
         a separate full-read digest pass over a concatenated copy.
-        Kept callable as the identity/bench baseline the fused kernel
-        is asserted bit-identical against (tests, bench --codec-micro);
-        not used by the erasure layer."""
+        Kept callable as the identity baseline the fused kernel is
+        asserted bit-identical against (tests/test_native.py); not used
+        by the erasure layer."""
         from ..utils import native
 
         data = np.ascontiguousarray(data, dtype=np.uint8)
@@ -1485,13 +1202,11 @@ def _make(name: str) -> CodecBackend:
 
 def backend_info() -> dict:
     """The resolved backend and the devices under it, for the boot log,
-    ``healthinfo`` and ``kernel-stats``: backend name, the knob values
-    that pick its kernels, and utils.jaxenv.device_info().  Resolves the
-    backend if nothing has yet, and raises if that fails."""
-    from ..ops import codec_step
+    ``healthinfo`` and ``kernel-stats``: backend name and
+    utils.jaxenv.device_info().  Resolves the backend if nothing has
+    yet, and raises if that fails."""
     from ..parallel import rules as prules
     from ..utils import jaxenv
-    from . import compress as compmod
 
     be = get_backend()
     inner = be
@@ -1503,10 +1218,6 @@ def backend_info() -> dict:
         doc["codec"] = "native" if native else "numpy"
         return doc
     doc.update(jaxenv.device_info())
-    doc["kernel"] = codec_step.codec_kernel_mode()
-    doc["formulation"] = codec_step.codec_formulation()
-    doc["overlap"] = codec_step.codec_overlap_mode()
-    doc["device_compress"] = compmod.device_compress_mode()
     n = doc["device_count"]
     if n > 1:
         doc["placement"] = (
@@ -1520,17 +1231,15 @@ def backend_info() -> dict:
 def reset_backend() -> None:
     """Testing aid: drop the cached backend (and the parity cache) so
     env changes take effect."""
-    global _backend, _PARITY_CACHE, _staging_bytes
+    global _backend, _PARITY_CACHE
     with _lock:
         _backend = None
         _PARITY_CACHE = None
-        _staging_bytes = 0
     _plans.clear()
     _patterns.clear()
     try:
         from ..cache.allocator import device_budget
 
         device_budget().set_usage("parity_plane", 0)
-        device_budget().set_usage("codec_staging", 0)
     except Exception as exc:  # noqa: BLE001
         _log.debug("parity budget reset failed: %s", exc)

@@ -18,8 +18,6 @@ from __future__ import annotations
 import os
 import zlib
 
-import numpy as np
-
 ALGORITHM = "deflate/v1"
 META_COMPRESSION = "x-internal-compression"
 META_ACTUAL_SIZE = "x-internal-actual-size"
@@ -85,89 +83,6 @@ def is_compressible(key: str, content_type: str, size: int) -> bool:
     if ct.startswith(EXCLUDED_TYPE_PREFIXES):
         return False
     return True
-
-
-# -- device parity transport (the fused on-device compression leg) -------
-#
-# The stored representation above is untouched: shard files hold the
-# exact same framed bytes either way.  What compresses here is the BUS
-# TRANSFER — the parity plane crossing device->host during the lazy
-# drain (codec/backend.py).  Parity of compressible/zero-padded objects
-# is mostly zero groups, so ops/codec_step.pack_nonzero_groups compacts
-# the nonzero groups to the front on device and only flags + the packed
-# prefix cross the bus; unpack_nonzero_groups below restores the full
-# plane host-side, bit-identically.
-
-# words per transport group (1 KiB of parity per flag bit)
-PARITY_GROUP_WORDS = 256
-
-
-def device_compress_mode() -> str:
-    """MINIO_TPU_DEVICE_COMPRESS = off|auto|on (default off).
-
-    off: every drain moves the full plane, and launches nothing.
-    auto: screen at drain with ops/codec_step.group_flags and pack only
-    when the nonzero fill is below parity_fill_threshold(); on: always
-    pack.  The screen costs one device pass and one small synchronous
-    readback before any parity byte moves - worth it only where the bus
-    is far slower than the device, so it is not the default.
-    """
-    v = os.environ.get("MINIO_TPU_DEVICE_COMPRESS", "off").lower()
-    return v if v in ("auto", "on", "off") else "off"
-
-
-def parity_fill_threshold() -> float:
-    """Max nonzero-group fill ratio at which auto mode still packs
-    (MINIO_TPU_DCOMP_MAX_FILL, default 0.75): past this the packed
-    prefix approaches the full plane and the extra device pass loses."""
-    try:
-        v = float(os.environ.get("MINIO_TPU_DCOMP_MAX_FILL") or 0.75)
-    except ValueError:
-        v = 0.75
-    return min(1.0, max(0.0, v))
-
-
-def prefix_keep(kept: int, groups: int) -> int:
-    """Packed-prefix length (in groups) to move over the bus.
-
-    Rounded up to a power of two so each distinct D2H slice shape is
-    its own compiled gather and the shape zoo stays O(log g).  Shared
-    by both drain paths (the legacy pack-at-drain kernel and the
-    fused1 precomputed planes), so the two can never round differently
-    and break bit-identity of the unpacked result.
-    """
-    if kept <= 0:
-        return 0
-    return min(1 << (kept - 1).bit_length(), groups)
-
-
-def unpack_nonzero_groups(
-    flags: np.ndarray, packed_prefix: np.ndarray, group: int, w: int
-) -> np.ndarray:
-    """Invert ops/codec_step.pack_nonzero_groups on the host.
-
-    ``flags`` is the (..., g) bool mask, ``packed_prefix`` the leading
-    (..., >=max_kept*group) u32 slice of the packed rows that actually
-    crossed the bus.  Returns the full (..., w) u32 rows: packed groups
-    scattered back to their np.nonzero(flags) positions, zeros elsewhere.
-    """
-    flags = np.asarray(flags, dtype=bool)
-    lead = flags.shape[:-1]
-    g = flags.shape[-1]
-    if g * group != w:
-        raise ValueError("flags width disagrees with w/group")
-    prefix = np.ascontiguousarray(packed_prefix, dtype=np.uint32)
-    out = np.zeros(lead + (g, group), dtype=np.uint32)
-    flat_flags = flags.reshape(-1, g)
-    flat_prefix = prefix.reshape(len(flat_flags), -1)
-    flat_out = out.reshape(-1, g, group)
-    for r in range(len(flat_flags)):
-        nz = np.nonzero(flat_flags[r])[0]
-        if nz.size:
-            flat_out[r, nz] = flat_prefix[
-                r, : nz.size * group
-            ].reshape(nz.size, group)
-    return out.reshape(lead + (w,))
 
 
 class CompressReader:

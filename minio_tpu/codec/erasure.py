@@ -66,7 +66,7 @@ def _staged(stage: str) -> "spans.span":
 def _codec_stage(be) -> str:
     """Stage key for encode time: backends whose encode() computes
     parity + digests in one fused pass (TPU device pass, native
-    single-pass CPU kernel) book under "codec_fused" so the bench stage
+    single-pass CPU kernel) book under "codec_fused" so the stage
     breakdown shows what the fusion bought; split/fallback encodes stay
     under "codec" alongside decode/verify time."""
     return "codec_fused" if getattr(be, "fused_encode", False) else "codec"
@@ -85,30 +85,6 @@ def _parity_plane_on() -> bool:
     the writers pull it (codec/backend.py).  "off" restores the legacy
     eager encode_end readback."""
     return os.environ.get("MINIO_TPU_PARITY_PLANE", "on") != "off"
-
-
-def subchunk_words(w: int, quantum: int) -> int:
-    """Sub-chunk size in uint32 words for the device overlap pipeline
-    (MINIO_TPU_CODEC_OVERLAP=async), or 0 when the batch is too small.
-
-    MINIO_TPU_CODEC_SUBCHUNK_KB (default 256 KiB of shard bytes per
-    sub-chunk) is rounded down to a multiple of ``quantum`` words —
-    the parity pack group when the pack leg is on, the hash partition
-    stride otherwise — so chunk cuts land on group AND partition
-    boundaries and the per-chunk math composes bit-identically.
-    Clamped so the pipeline only engages at S >= 3 full chunks: below
-    that the staging ping-pong cannot amortize its second buffer and
-    the serialized path is strictly better.
-    """
-    try:
-        kb = float(os.environ.get("MINIO_TPU_CODEC_SUBCHUNK_KB") or 256)
-    except ValueError:
-        kb = 256.0
-    q = max(int(quantum), 1)
-    cw = max(q, (int(kb * 256) // q) * q)  # KiB -> u32 words, quantized
-    if w // cw < 3:
-        return 0
-    return cw
 
 
 class _Begun:
@@ -1188,11 +1164,9 @@ class Erasure:
                 present[s] = True
             # fused GET-side pass: digest checks + survivor decode in
             # one memory pass over the frames (CpuBackend runs it as a
-            # single native call; TpuBackend under fused1 runs it as
-            # ONE device launch - codec_step.verify_and_reconstruct_
-            # words / mesh_verify_reconstruct - and composes the
-            # legacy verify + reconstruct pair only as the bisection
-            # oracle, MINIO_TPU_CODEC_KERNEL=legacy)
+            # single native call; TpuBackend runs it as ONE device
+            # launch - codec_step.verify_and_reconstruct_words /
+            # mesh_verify_reconstruct)
             try:
                 data, ok = be.reconstruct_and_verify(
                     shards, digests, present, k, m

@@ -83,16 +83,11 @@ class KernelStats:
         # The parity-plane PUT restructure exists to drive the parity
         # row of this table to the post-ack drain band only
         self._d2h: "dict[str, list]" = {}
-        # host->device staging by plane (mirror of _d2h), and sub-chunk
-        # overlap windows by plane: a "window" is one sub-chunk whose
-        # transfer was in flight while a neighbor's compute ran — the
-        # snapshot-level proof the DMA pipeline actually overlapped
-        # (PR 18), not an inference from wall clock
+        # host->device staging by plane (mirror of _d2h)
         self._h2d: "dict[str, list]" = {}
-        self._overlap: "dict[str, int]" = {}
         # device-program launches by jitted entry point, and the subset
-        # of them that ran a Pallas kernel (the rest ran the XLA
-        # formulation: ragged widths, non-TPU platforms, XLA-only passes)
+        # of them that ran a Pallas kernel (the rest ran the XLA form:
+        # ragged widths, non-TPU platforms, XLA-only passes)
         self._passes: "dict[str, int]" = {}
         self._pallas_passes: "dict[str, int]" = {}
         # submesh placement: outcome ("span"|"route") -> batches, and
@@ -144,13 +139,6 @@ class KernelStats:
             row = self._h2d.setdefault(plane, [0, 0])
             row[0] += 1
             row[1] += nbytes
-
-    def record_overlap_windows(self, plane: str, windows: int) -> None:
-        """``windows`` sub-chunks whose transfer overlapped a
-        neighbor's compute, keyed by direction:
-        plane = put (encode side) | get (verify/reconstruct side)."""
-        with self._mu:
-            self._overlap[plane] = self._overlap.get(plane, 0) + windows
 
     def record_pass(self, kernel: str, pallas: bool = False) -> None:
         """One device-program launch (jitted codec pass) by entry-point
@@ -228,7 +216,7 @@ class KernelStats:
     # -- reading ----------------------------------------------------------
 
     def snapshot(self) -> dict:
-        """JSON-friendly dump (admin kernel-stats, bench.py trajectory)."""
+        """JSON-friendly dump (admin kernel-stats)."""
         # the enqueue-side mark lives on the pool's queues (their own
         # lock is held there anyway); the dequeue-side one is ours
         from ..parallel import iopool
@@ -273,10 +261,6 @@ class KernelStats:
                     {"plane": plane, "transfers": n, "bytes": nbytes}
                     for plane, (n, nbytes) in sorted(self._h2d.items())
                 ],
-                "overlap_windows": {
-                    plane: self._overlap.get(plane, 0)
-                    for plane in ("put", "get")
-                },
                 "device_passes": dict(sorted(self._passes.items())),
                 "pallas_passes": dict(sorted(self._pallas_passes.items())),
                 "portable_passes": {
@@ -355,7 +339,6 @@ class KernelStats:
             self._plan = {"hit": 0, "miss": 0}
             self._d2h.clear()
             self._h2d.clear()
-            self._overlap.clear()
             self._passes.clear()
             self._pallas_passes.clear()
             self._placement.clear()

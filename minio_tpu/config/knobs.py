@@ -63,18 +63,6 @@ KNOBS: "dict[str, Knob]" = {
     "MINIO_TPU_KEY_FILE": Knob("", "TLS private key path"),
     "MINIO_TPU_CA_FILE": Knob("", "TLS client-verification CA path"),
     # -- codec / device plane ----------------------------------------------
-    "MINIO_TPU_CODEC_KERNEL": Knob(
-        "fused1", "codec entry points: fused1 | legacy"
-    ),
-    "MINIO_TPU_CODEC_FORMULATION": Knob(
-        "swar", "GF(2^8) product formulation: swar | mxu"
-    ),
-    "MINIO_TPU_CODEC_OVERLAP": Knob(
-        "off", "host-driven sub-chunk transfer overlap: async | off"
-    ),
-    "MINIO_TPU_CODEC_SUBCHUNK_KB": Knob(
-        "256", "sub-chunk size for the overlap pipeline (KiB)"
-    ),
     "MINIO_TPU_CODEC_INTERPRET": Knob(
         "0", "run Pallas kernels in interpret mode (debug)"
     ),
@@ -85,18 +73,12 @@ KNOBS: "dict[str, Knob]" = {
         "128", "parity-plane cache budget (MiB)"
     ),
     "MINIO_TPU_PARITY_ACK": Knob(
-        "settle", "PUT parity durability ack: settle | eager"
+        "settle", "PUT parity durability ack: settle | early"
     ),
     "MINIO_TPU_DEVICE_BUDGET_MB": Knob(
         "192", "device memory ledger capacity (MiB)"
     ),
     "MINIO_TPU_COMPRESS": Knob("off", "transparent object compression"),
-    "MINIO_TPU_DEVICE_COMPRESS": Knob(
-        "off", "drain-time parity transport compression: off | auto | on"
-    ),
-    "MINIO_TPU_DCOMP_MAX_FILL": Knob(
-        "0.75", "device-compression max output fill ratio"
-    ),
     "MINIO_TPU_PLACEMENT": Knob(
         "auto", "device placement policy for sharded ops"
     ),
@@ -108,7 +90,7 @@ KNOBS: "dict[str, Knob]" = {
     ),
     # -- caches ------------------------------------------------------------
     "MINIO_TPU_READ_CACHE": Knob(
-        "off", "tiered GET read cache: on | off"
+        "off", "tiered GET read cache: off | host | device | auto"
     ),
     "MINIO_TPU_READ_CACHE_MB": Knob("64", "read cache host tier (MiB)"),
     "MINIO_TPU_READ_CACHE_DEVICE_MB": Knob(

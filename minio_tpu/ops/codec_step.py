@@ -29,8 +29,8 @@ import numpy as np
 
 from . import gf, hash as phash, rs, rs_pallas
 
-# encode_and_hash_words_digest donates its input buffer so the device
-# reuses the H2D staging allocation for parity; on host-only platforms
+# encode_words_fused1 donates its input buffer so the device reuses
+# the H2D staging allocation for parity; on host-only platforms
 # (the CPU test backend) XLA cannot always honor the donation and says
 # so per call — that is expected there, not a bug worth a warning storm.
 warnings.filterwarnings(
@@ -87,120 +87,9 @@ def encode_and_hash_words(
     return parity.transpose(1, 0, 2), digests.transpose(1, 0, 2)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("parity_shards", "shard_len"),
-    donate_argnums=(0,),
-)
-def encode_and_hash_words_digest(
-    words: jax.Array, parity_shards: int, shard_len: int
-):
-    """Digest-only fused encode: the device-resident-parity variant.
-
-    Same math and same outputs as encode_and_hash_words, with two
-    contract differences the PUT pipeline builds on:
-
-    * ``words`` is DONATED — the H2D input buffer is dead after the
-      pass, so XLA may reuse it for parity instead of allocating, and
-      the caller must not touch its jax copy again.
-    * The caller materializes ONLY ``digests`` eagerly (32 bytes per
-      shard — all encode_end needs to frame bitrot metadata and ack);
-      ``parity`` stays a device array parked in the backend's parity
-      plane cache until the write path drains it D2H lazily.
-    """
-    return encode_and_hash_words(words, parity_shards, shard_len)
-
-
-@functools.partial(jax.jit, static_argnames=("group",))
-def group_flags(words: jax.Array, group: int):
-    """Per-group nonzero flags: (..., w) u32 -> (..., w//group) bool.
-
-    The cheap compressibility screen for the parity D2H transport:
-    reading the flags costs one bool per ``group`` words, and a mostly-
-    False mask means pack_nonzero_groups can shrink the bus transfer.
-    """
-    *lead, w = words.shape
-    if w % group:
-        raise ValueError("words per row must be a multiple of group")
-    g = w // group
-    return (words.reshape(*lead, g, group) != 0).any(axis=-1)
-
-
-@functools.partial(jax.jit, static_argnames=("group",))
-def pack_nonzero_groups(words: jax.Array, group: int):
-    """Compact nonzero groups to the front of each row (device side).
-
-    (..., w) u32 -> (flags (..., g) bool, packed (..., w) u32) where
-    g = w // group.  Within each row the nonzero groups keep their
-    original relative order at the front and the zero groups follow, so
-    the host only pulls ``flags`` plus the first ``flags.sum()`` groups
-    over the bus and scatters them back by np.nonzero(flags) — the
-    fused on-device compression leg of the parity transport
-    (codec/compress.py unpack_nonzero_groups is the inverse).
-    """
-    *lead, w = words.shape
-    if w % group:
-        raise ValueError("words per row must be a multiple of group")
-    g = w // group
-    grouped = words.reshape(*lead, g, group)
-    flags = (grouped != 0).any(axis=-1)
-    # unique, strictly ordered sort keys (nonzero group j -> j, zero
-    # group j -> g + j): the permutation is deterministic without
-    # leaning on argsort stability guarantees
-    idx = jnp.arange(g, dtype=jnp.int32)
-    key = jnp.where(flags, 0, jnp.int32(g)) + idx
-    order = jnp.argsort(key, axis=-1)
-    packed = jnp.take_along_axis(
-        grouped, order[..., None], axis=-2
-    ).reshape(*lead, w)
-    return flags, packed
-
-
 # ---------------------------------------------------------------------------
-# One-kernel codec (fused1): PUT and GET as one device pass per direction
+# The served codec: PUT and GET as one device pass per direction
 # ---------------------------------------------------------------------------
-
-
-def codec_kernel_mode() -> str:
-    """MINIO_TPU_CODEC_KERNEL: ``fused1`` (default) or ``legacy``.
-
-    ``legacy`` is the bisection oracle: the pre-fusion pass structure
-    (encode_and_hash_words_digest on PUT; verify then reconstruct on
-    heal) with byte-identical outputs.  Flip it to attribute a
-    regression to the fused entry points vs everything around them.
-    """
-    v = os.environ.get("MINIO_TPU_CODEC_KERNEL", "fused1").strip().lower()
-    return v if v in ("fused1", "legacy") else "fused1"
-
-
-def codec_formulation() -> str:
-    """MINIO_TPU_CODEC_FORMULATION: ``swar`` (default) or ``mxu``.
-
-    Picks the GF(2^8) matrix-product formulation inside the fused
-    kernels (see rs_pallas module doc); both are bit-exact.
-    """
-    v = os.environ.get(
-        "MINIO_TPU_CODEC_FORMULATION", "swar"
-    ).strip().lower()
-    return v if v in ("swar", "mxu") else "swar"
-
-
-def codec_overlap_mode() -> str:
-    """MINIO_TPU_CODEC_OVERLAP: ``async`` | ``off`` (default ``off``).
-
-    The host-driven transfer/compute overlap seam:
-
-    * ``async`` — the stripe batch splits along w into S sub-chunks
-      double-buffered through donated ping-pong device buffers
-      (encode_subchunk_words), so sub-chunk N+1's H2D overlaps N's pass
-      which overlaps N-1's drain.  Honest about launches: S passes per
-      direction.
-    * ``off`` — one pass per batch; inside it the fused Pallas kernels
-      still overlap HBM<->VMEM tile traffic with compute through their
-      BlockSpec pipeline.
-    """
-    v = os.environ.get("MINIO_TPU_CODEC_OVERLAP", "").strip().lower()
-    return v if v in ("async", "off") else "off"
 
 
 def pallas_compiled(words_per_shard: int) -> bool:
@@ -210,7 +99,7 @@ def pallas_compiled(words_per_shard: int) -> bool:
 
 
 def pallas_dispatch(words_per_shard: int) -> tuple[bool, bool]:
-    """(use_pallas, interpret) statics for the fused1 entry points.
+    """(use_pallas, interpret) statics for the fused entry points.
 
     Tile-aligned widths run the Pallas kernel compiled on TPU;
     MINIO_TPU_CODEC_INTERPRET=1 forces the interpreter on other
@@ -235,7 +124,6 @@ def pallas_dispatch(words_per_shard: int) -> tuple[bool, bool]:
     static_argnames=(
         "parity_shards",
         "shard_len",
-        "formulation",
         "use_pallas",
         "interpret",
     ),
@@ -245,17 +133,18 @@ def encode_words_fused1(
     words: jax.Array,
     parity_shards: int,
     shard_len: int,
-    formulation: str = "swar",
     use_pallas: bool = False,
     interpret: bool = False,
 ):
-    """fused1 PUT codec step: parity + digests in ONE device pass.
+    """PUT codec step: parity + digests in ONE device pass.
 
     On TPU (or under interpret) a tile-aligned batch is exactly one
     pallas_call (rs_pallas.encode_hash_fused); elsewhere it is one XLA
     program with the same math.
 
-    words: (B, k, w) u32, DONATED like encode_and_hash_words_digest.
+    words: (B, k, w) u32, DONATED - the H2D input buffer is dead after
+    the pass, so XLA may reuse it for parity instead of allocating, and
+    the caller must not touch its jax copy again.
     Returns (parity (B, m, w) u32, digests (B, n, 8) u32 finalized).
     Only ``digests`` may be materialized eagerly (MTPU107); parity
     parks in the parity plane cache until drain.
@@ -268,9 +157,7 @@ def encode_words_fused1(
         raise ValueError("words per shard must be a multiple of 8")
 
     if use_pallas and m > 0 and w % rs_pallas._TW == 0:
-        parity, partials = rs_pallas.encode_hash_fused(
-            words, m, formulation=formulation, interpret=interpret
-        )
+        parity, partials = rs_pallas.encode_hash_fused(words, m, interpret)
         return parity, phash.finalize_partials(partials, shard_len)
 
     # XLA single-program path (the bit-identity oracle for the kernel).
@@ -349,7 +236,6 @@ def reconstruct_rows(
         "data_shards",
         "parity_shards",
         "shard_len",
-        "formulation",
         "use_pallas",
         "interpret",
     ),
@@ -363,16 +249,13 @@ def verify_and_reconstruct_words(
     data_shards: int,
     parity_shards: int,
     shard_len: int,
-    formulation: str = "swar",
     use_pallas: bool = False,
     interpret: bool = False,
 ):
-    """fused1 GET codec step: digest-verify + reconstruct in ONE pass.
-
-    Replaces the verify_hashes_words -> reconstruct_words_batch pair on
-    the quorum-read/heal path: one pallas_call (or one portable XLA
-    program) reads each shard byte once for both the bitrot check and
-    the RS product.
+    """GET codec step: digest-verify + reconstruct in ONE pass on the
+    quorum-read/heal path: one pallas_call (or one portable XLA program)
+    reads each shard byte once for both the bitrot check and the RS
+    product.
 
     shards: (B, n, w) u32 as read (absent rows hold garbage); digests:
     (B, n, 8) u32 stored.  The loss pattern is three TRACED operands -
@@ -393,7 +276,6 @@ def verify_and_reconstruct_words(
         data, partials = rs_pallas.verify_reconstruct_runtime(
             shards,
             expand_matrix(survivors, matrix, n),
-            formulation=formulation,
             interpret=interpret,
         )
         got = phash.finalize_partials(partials, shard_len)
@@ -402,115 +284,6 @@ def verify_and_reconstruct_words(
         data = reconstruct_rows(shards, survivors, matrix, False, False)
     ok = jnp.all(got == digests, axis=-1) & present
     return data, ok
-
-
-# ---------------------------------------------------------------------------
-# Sub-chunked pipeline (MINIO_TPU_CODEC_OVERLAP=async): host-driven
-# double buffering of one stripe batch, on any backend
-# ---------------------------------------------------------------------------
-#
-# The stripe batch splits along w into S sub-chunks; the backend stages
-# chunk s+1 H2D (jax.device_put is async) while chunk s's pass runs and
-# chunk s-1's results drain.  RS parity is column-local, so per-chunk
-# parity is exact; the phash256 partials XOR-accumulate across chunks
-# through a DONATED (B, n, 8) ping-pong accumulator whose key uses the
-# GLOBAL word offset (hash.tile_partials_batched), and the LAST chunk
-# finalizes in the same program — zero extra launches for the digest.
-# ``word_offset`` is traced, so every equal-sized chunk of a stream
-# shares one compiled program.
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("parity_shards", "shard_len", "finalize"),
-    donate_argnums=(0, 1),
-)
-def encode_subchunk_words(
-    chunk: jax.Array,
-    acc: jax.Array,
-    word_offset,
-    parity_shards: int,
-    shard_len: int,
-    finalize: bool = False,
-):
-    """One PUT sub-chunk: parity + hash partials for a (B, k, cw) u32
-    slice of the stripe batch at global ``word_offset``.
-
-    ``chunk`` and ``acc`` are DONATED — the staging buffer dies into
-    the parity allocation and the partial accumulator ping-pongs
-    through the chunk chain.  Returns (parity (B, m, cw), acc' (B, n,
-    8) — FINALIZED digests when ``finalize``, raw partials otherwise).
-    ``shard_len`` is the FULL row byte length (the digest length-fold),
-    not the chunk's.
-    """
-    B, k, cw = chunk.shape
-    m = parity_shards
-    if cw % 8:
-        raise ValueError("chunk words must be a multiple of 8")
-    if m > 0:
-        matrix = gf.parity_matrix(k, m)
-        flat = chunk.transpose(1, 0, 2).reshape(k, B * cw)
-        parity = rs._matmul_static(flat, matrix).reshape(m, B, cw)
-        aw = jnp.concatenate([chunk.transpose(1, 0, 2), parity], axis=0)
-        parity = parity.transpose(1, 0, 2)
-    else:
-        parity = jnp.zeros((B, 0, cw), jnp.uint32)
-        aw = chunk.transpose(1, 0, 2)
-    acc = acc ^ phash.tile_partials_batched(aw, word_offset).transpose(
-        1, 0, 2
-    )
-    return parity, (
-        phash.finalize_partials(acc, shard_len) if finalize else acc
-    )
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "data_shards",
-        "parity_shards",
-        "shard_len",
-        "finalize",
-    ),
-    donate_argnums=(0, 1),
-)
-def verify_reconstruct_subchunk_words(
-    chunk: jax.Array,
-    acc: jax.Array,
-    digests: jax.Array,
-    word_offset,
-    present: jax.Array,
-    survivors: jax.Array,
-    matrix: jax.Array,
-    data_shards: int,
-    parity_shards: int,
-    shard_len: int,
-    finalize: bool = False,
-):
-    """One GET sub-chunk: reconstruct a (B, n, cw) slice of the shard
-    rows AND accumulate verify partials (donated ping-pong ``acc`` and
-    staging ``chunk``, like encode_subchunk_words).  The loss pattern is
-    traced (present bool[n], survivors int32[k], matrix uint8[k, k]) as
-    in verify_and_reconstruct_words; a sub-chunk is cut on hash strides,
-    not kernel tiles, so the product is the XLA bit-walk.
-
-    Returns (data (B, k, cw) u32, acc' (B, n, 8), ok (B, n) bool).
-    ``ok`` is meaningful only on the ``finalize`` call (digest match of
-    the WHOLE row AND present); earlier chunks return all-False — the
-    backend drains each data chunk D2H while the next one computes and
-    reads ``ok`` once from the last.
-    """
-    B, n, cw = chunk.shape
-    _check_pattern(chunk, survivors, matrix, data_shards, parity_shards)
-    acc = acc ^ phash.tile_partials_batched(
-        chunk.transpose(1, 0, 2), word_offset
-    ).transpose(1, 0, 2)
-    data = reconstruct_rows(chunk, survivors, matrix, False, False)
-    if finalize:
-        got = phash.finalize_partials(acc, shard_len)
-        ok = jnp.all(got == digests, axis=-1) & present
-        return data, acc, ok
-    return data, acc, jnp.zeros((B, n), bool)
 
 
 @functools.partial(jax.jit, static_argnames=("shard_len",))
@@ -646,84 +419,3 @@ def decode_and_verify(
     )[0]
     data = host_words_to_bytes(np.asarray(dw))
     return data, ok
-
-
-# ---------------------------------------------------------------------------
-# Benchmark probes (chained device passes, see bench.py)
-# ---------------------------------------------------------------------------
-
-
-@functools.partial(
-    jax.jit, static_argnames=("parity_shards", "shard_len")
-)
-def encode_throughput_probe(
-    words: jax.Array, parity_shards: int, shard_len: int, reps
-):
-    """Run `reps` dependent encode+hash passes inside ONE device program.
-
-    Chains iterations through a cheap XOR so XLA cannot elide work,
-    letting per-pass device time be measured without host launch
-    overhead.  `reps` is a DYNAMIC trip count
-    (fori_loop), so one compiled program serves every chain length the
-    adaptive bench harness probes.  Returns a small checksum array.
-    """
-    def body(_, carry):
-        words_c, acc = carry
-        parity, digests = encode_and_hash_words(
-            words_c, parity_shards, shard_len
-        )
-        return words_c ^ parity[:, :1], acc ^ digests[0, 0, 0]
-
-    final, acc = jax.lax.fori_loop(
-        0, reps, body, (words, jnp.uint32(0))
-    )
-    return final[0, 0, :8], acc
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("data_shards", "parity_shards"),
-)
-def reconstruct_throughput_probe(
-    shards: jax.Array,
-    survivors: jax.Array,
-    matrix: jax.Array,
-    data_shards: int,
-    parity_shards: int,
-    reps,
-):
-    """Chained batched reconstructs (see encode probe)."""
-    k = data_shards
-    use_pallas = pallas_compiled(shards.shape[-1])
-
-    def body(_, carry):
-        shards_c, acc = carry
-        data = reconstruct_words_batch(
-            shards_c, survivors, matrix, data_shards, parity_shards,
-            use_pallas=use_pallas,
-        )
-        nxt = shards_c.at[:, :k].set(shards_c[:, :k] ^ data)
-        return nxt, acc ^ data[0, 0, 0]
-
-    final, acc = jax.lax.fori_loop(
-        0, reps, body, (shards, jnp.uint32(0))
-    )
-    return final[0, 0, :8], acc
-
-
-@functools.partial(jax.jit, static_argnames=("shard_len",))
-def verify_throughput_probe(
-    shards: jax.Array, digests: jax.Array, shard_len: int, reps
-):
-    """Chained bitrot-verify passes: the HEALTHY read path (no RS math,
-    just the device hash + compare every streamed block pays)."""
-    def body(_, carry):
-        shards_c, acc = carry
-        ok = verify_hashes_words(shards_c, digests, shard_len)
-        nxt = shards_c ^ jnp.where(ok[0, 0], 0, 1).astype(shards_c.dtype)
-        return nxt, acc ^ ok.sum().astype(jnp.uint32)
-
-    final, acc = jax.lax.fori_loop(
-        0, reps, body, (shards, jnp.uint32(0))
-    )
-    return final[0, 0, :8], acc

@@ -1,24 +1,17 @@
 """Pallas TPU kernels for the GF(2^8) shard codec hot path.
 
-Two device formulations of "GF matrix @ shards" (the klauspost/reedsolomon
-role behind cmd/erasure-coding.go:54-64), selectable inside the fused
-kernels below (MINIO_TPU_CODEC_FORMULATION):
+"GF matrix @ shards" (the klauspost/reedsolomon role behind
+cmd/erasure-coding.go:54-64) in SWAR form on the VPU (`_swar_rows`):
+shards live as uint32 words (4 field elements per lane).
+Multiply-by-constant uses the xtime-powers decomposition with the
+generator matrix baked into the kernel at trace time, so each tile is a
+straight-line XOR chain over VMEM-resident vectors - no tables, no
+gathers, no dtype conversions.  Encode only: the generator matrix is one
+per geometry.  Decode takes its matrix as an operand (the runtime-matrix
+kernels at the end: one program per geometry, whatever the loss
+pattern).
 
-1. SWAR/VPU (`_swar_rows`, the default): shards live as uint32 words
-   (4 field elements per lane).  Multiply-by-constant uses the
-   xtime-powers decomposition with the generator matrix baked into the
-   kernel at trace time, so each tile is a straight-line XOR chain over
-   VMEM-resident vectors - no tables, no gathers, no dtype conversions.
-   Encode only: the generator matrix is one per geometry.  Decode takes
-   its matrix as an operand (the runtime-matrix kernels at the end: one
-   program per geometry, whatever the loss pattern).
-
-2. MXU bit-matrix (`_mxu_rows`): GF(2^8) mul-by-constant is an 8x8
-   linear map over GF(2), so the whole codec lifts to one
-   (8o x 8s) @ (8s x T) bf16 matmul per tile, mod 2.  Higher arithmetic
-   intensity but pays ~30 VPU ops/byte in bit unpack/repack.
-
-Throughput of either: not measured on this code (PERF.md).
+Throughput: PERF.md (`encode_roofline`, `reconstruct_roofline`).
 
 Every entry point takes ``interpret`` explicitly: False compiles the
 kernel with Mosaic (TPU only), True runs the Pallas interpreter (the
@@ -156,91 +149,15 @@ def _swar_rows(matrix: np.ndarray, data) -> list:
 
 
 # ---------------------------------------------------------------------------
-# MXU bit-matrix formulation of the same rows
-# ---------------------------------------------------------------------------
-
-
-@functools.lru_cache(maxsize=None)
-def _bit_matrix(matrix_bytes: bytes, o: int, s: int) -> np.ndarray:
-    """Lift an (o, s) GF(2^8) matrix to its (8o, 8s) GF(2) representation.
-
-    Row 8r+t, column 8c+b is bit t of matrix[r,c] * x^b: the contribution
-    of input-byte-c's bit b to output-byte-r's bit t.
-    """
-    matrix = np.frombuffer(matrix_bytes, dtype=np.uint8).reshape(o, s)
-    out = np.zeros((8 * o, 8 * s), dtype=np.float32)
-    for r in range(o):
-        for c in range(s):
-            v = int(matrix[r, c])
-            for b in range(8):
-                prod = gf.gf_mul(v, 1 << b)
-                for t in range(8):
-                    out[8 * r + t, 8 * c + b] = (prod >> t) & 1
-    return out
-
-
-def _mxu_rows(matrix: np.ndarray, data, mat=None) -> list:
-    """MXU formulation of _swar_rows: (s, t) u32 tile -> o output rows.
-
-    Lifts the bytewise GF(2^8) product to the (8o, 8s) GF(2) bit matrix
-    (_bit_matrix) and evaluates all four byte positions of every word in
-    ONE bf16 matmul mod 2: the codec is byte-local, so byte positions
-    stack on the lane dim.  Exact because every intermediate is a small
-    integer (bit-counts <= 8s < 2^8) carried in f32.
-
-    ``mat`` is the pre-lifted bit matrix when called inside a Pallas
-    kernel (kernels cannot capture traced constants, so the caller
-    threads it through an input ref); None rebuilds it from ``matrix``.
-    With ``mat`` given, ``matrix`` may be the bare (o, s) shape: the
-    runtime-matrix kernels have no constant to hand over.
-    """
-    o, s = matrix if isinstance(matrix, tuple) else matrix.shape
-    if o == 0:
-        return []
-    t = data.shape[-1]
-    if mat is None:
-        key = np.ascontiguousarray(matrix, dtype=np.uint8).tobytes()
-        mat = jnp.asarray(_bit_matrix(key, o, s))
-    mat = mat.astype(jnp.bfloat16)
-    # (s, 4t): byte plane j of every word, side by side on the lane dim
-    bts = jnp.concatenate(
-        [(data >> jnp.uint32(8 * j)) & jnp.uint32(0xFF) for j in range(4)],
-        axis=-1,
-    ).astype(jnp.int32)
-    bits = jnp.stack(
-        [(bts >> b) & 1 for b in range(8)], axis=1
-    )  # (s, 8, 4t): row order 8c+b after reshape
-    bits = bits.reshape(8 * s, 4 * t).astype(jnp.bfloat16)
-    counts = jnp.dot(mat, bits, preferred_element_type=jnp.float32)
-    pbits = (counts.astype(jnp.int32) & 1).reshape(o, 8, 4 * t)
-    acc8 = pbits[:, 0, :].astype(jnp.uint32)
-    for tbit in range(1, 8):
-        acc8 = acc8 | (pbits[:, tbit, :].astype(jnp.uint32) << tbit)
-    out = acc8[:, :t]
-    for j in range(1, 4):
-        out = out | (acc8[:, j * t : (j + 1) * t] << jnp.uint32(8 * j))
-    return [out[r] for r in range(o)]
-
-
-def _rows_fn(formulation: str):
-    if formulation == "swar":
-        return _swar_rows
-    if formulation == "mxu":
-        return _mxu_rows
-    raise ValueError(f"unknown codec formulation: {formulation!r}")
-
-
-# ---------------------------------------------------------------------------
 # One-kernel codec: a single pass per direction (PUT encode+hash, GET
 # verify+reconstruct)
 # ---------------------------------------------------------------------------
 
 
-def _encode_kernel_factory(matrix: np.ndarray, tw: int, formulation: str):
+def _encode_kernel_factory(matrix: np.ndarray, tw: int):
     m, k = matrix.shape
-    mxu = _rows_fn(formulation) is _mxu_rows
 
-    def impl(data_ref, parity_ref, hacc_ref, mat):
+    def kernel(data_ref, parity_ref, hacc_ref):
         i = pl.program_id(1)
 
         @pl.when(i == 0)
@@ -248,47 +165,17 @@ def _encode_kernel_factory(matrix: np.ndarray, tw: int, formulation: str):
             hacc_ref[...] = jnp.zeros_like(hacc_ref)
 
         data = data_ref[0]  # (k, tw)
-        parity_rows = (
-            _mxu_rows(matrix, data, mat) if mxu else _swar_rows(matrix, data)
-        )
         all_rows = jnp.concatenate(
-            [data, jnp.stack(parity_rows)], axis=0
+            [data, jnp.stack(_swar_rows(matrix, data))], axis=0
         )  # (n, tw)
         parity_ref[0] = all_rows[k:]
         hacc_ref[0] = hacc_ref[0] ^ _tile_hash_partials(all_rows, i, tw)
 
-    if mxu:
-
-        def kernel(mat_ref, data_ref, parity_ref, hacc_ref):
-            impl(data_ref, parity_ref, hacc_ref, mat_ref[...])
-
-    else:
-
-        def kernel(data_ref, parity_ref, hacc_ref):
-            impl(data_ref, parity_ref, hacc_ref, None)
-
     return kernel
 
 
-def _mxu_operand(matrix: np.ndarray):
-    """(bit-matrix input list, matching in_spec list) for an MXU kernel
-    on the (batch, w-tile) fused grids."""
-    o, s = matrix.shape
-    key = np.ascontiguousarray(matrix, dtype=np.uint8).tobytes()
-    mat = jnp.asarray(_bit_matrix(key, o, s))
-    return [mat], [pl.BlockSpec((8 * o, 8 * s), lambda b, i: (0, 0))]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("parity_shards", "formulation", "interpret"),
-)
-def encode_hash_fused(
-    words,
-    parity_shards: int,
-    formulation: str = "swar",
-    interpret: bool = False,
-):
+@functools.partial(jax.jit, static_argnames=("parity_shards", "interpret"))
+def encode_hash_fused(words, parity_shards: int, interpret: bool = False):
     """One-kernel PUT codec pass: (B, k, w) data words -> ((B, m, w)
     parity words, (B, n, 8) un-finalized phash partials covering data
     AND parity rows), ONE pallas_call.
@@ -307,25 +194,20 @@ def encode_hash_fused(
     if w % _TW:
         raise ValueError(f"words per shard ({w}) must be a multiple of {_TW}")
     matrix = gf.parity_matrix(k, m)
-    kernel = _encode_kernel_factory(matrix, _TW, formulation)
-    extra_in, extra_specs = (
-        _mxu_operand(matrix) if formulation == "mxu" else ([], [])
-    )
     parity, hacc = pl.pallas_call(
-        kernel,
+        _encode_kernel_factory(matrix, _TW),
         out_shape=(
             jax.ShapeDtypeStruct((B, m, w), jnp.uint32),
             jax.ShapeDtypeStruct((B, n, 8), jnp.uint32),
         ),
         grid=(B, w // _TW),
-        in_specs=extra_specs
-        + [pl.BlockSpec((1, k, _TW), lambda b, i: (b, 0, i))],
+        in_specs=[pl.BlockSpec((1, k, _TW), lambda b, i: (b, 0, i))],
         out_specs=(
             pl.BlockSpec((1, m, _TW), lambda b, i: (b, 0, i)),
             pl.BlockSpec((1, n, 8), lambda b, i: (b, 0, 0)),
         ),
         interpret=interpret,
-    )(*extra_in, words)
+    )(words)
     return parity, hacc
 
 
@@ -338,7 +220,7 @@ def encode_hash_fused(
 # the matrix baked in is one program per set; these take the matrix as a
 # traced array, so one program serves every loss pattern of a geometry.
 #
-# Formulation (SWAR): the tile is (s, tw) with the shard rows on the
+# The tile is (s, tw) with the shard rows on the
 # sublanes.  P_b = x^b * tile is seven dense xtimes; output row r is the
 # XOR over (c, b) of P_b[c] where bit b of matrix[r, c] is set.  The bits
 # arrive as 0 / 0xFFFFFFFF masks laid out (s * 8, o, 128): entry c * 8 + b
@@ -361,21 +243,6 @@ def runtime_masks(matrix):
     return jnp.broadcast_to(masks, (s * 8, o, _CH))
 
 
-def bit_matrix_traced(matrix):
-    """Traced twin of _bit_matrix: (o, s) uint8 -> (8o, 8s) f32 over
-    GF(2), for the MXU formulation with a runtime matrix."""
-    o, s = matrix.shape
-    v = matrix.astype(jnp.uint32)
-    prods = []
-    for _ in range(8):  # v * x^b, b = 0..7
-        prods.append(v)
-        v = ((v << 1) & 0xFF) ^ (((v >> 7) & 1) * jnp.uint32(rs._POLY_LOW))
-    prods = jnp.stack(prods, axis=-1)  # (o, s, 8[b])
-    t = jnp.arange(8, dtype=jnp.uint32)[None, :, None, None]
-    bits = (prods[:, None, :, :] >> t) & 1  # (o, 8[t], s, 8[b])
-    return bits.reshape(8 * o, 8 * s).astype(jnp.float32)
-
-
 def _runtime_rows(mask_ref, tile, o: int):
     """(s, lanes) tile x the masks' matrix -> (o, lanes), lanes == _CH."""
     s, lanes = tile.shape
@@ -392,29 +259,20 @@ def _runtime_rows(mask_ref, tile, o: int):
     return (accs[0] ^ accs[1]) ^ (accs[2] ^ accs[3])
 
 
-def _runtime_kernel_factory(
-    o: int, s: int, tw: int, formulation: str, with_hash: bool
-):
+def _runtime_kernel_factory(o: int, tw: int, with_hash: bool):
     """Kernel over one (1, s, tw) block of shard rows as read: out =
     (operand matrix) GF@ rows and, ``with_hash``, the phash partials of
     all s rows accumulated over the w-tiles."""
-    mxu = _rows_fn(formulation) is _mxu_rows
 
-    def kernel(mat_ref, sh_ref, data_ref, *hacc_ref):
+    def kernel(mask_ref, sh_ref, data_ref, *hacc_ref):
         i = pl.program_id(1)
-        if mxu:
-            rows = _mxu_rows((o, s), sh_ref[0], mat_ref[...])
-            data_ref[0] = jnp.stack(rows)
-        else:
 
-            def step(j, carry):
-                sl = pl.ds(pl.multiple_of(j * _CH, _CH), _CH)
-                data_ref[0, :, sl] = _runtime_rows(
-                    mat_ref, sh_ref[0, :, sl], o
-                )
-                return carry
+        def step(j, carry):
+            sl = pl.ds(pl.multiple_of(j * _CH, _CH), _CH)
+            data_ref[0, :, sl] = _runtime_rows(mask_ref, sh_ref[0, :, sl], o)
+            return carry
 
-            jax.lax.fori_loop(0, tw // _CH, step, 0)
+        jax.lax.fori_loop(0, tw // _CH, step, 0)
         if with_hash:
             (hacc,) = hacc_ref
 
@@ -427,51 +285,42 @@ def _runtime_kernel_factory(
     return kernel
 
 
-def _runtime_call(rows, matrix, formulation, interpret, with_hash):
+def _runtime_call(rows, matrix, interpret, with_hash):
     B, s, w = rows.shape
     o = matrix.shape[0]
     if matrix.shape != (o, s):
         raise ValueError(f"matrix {matrix.shape} does not take {s} rows")
     if w % _TW:
         raise ValueError(f"words per shard ({w}) must be a multiple of {_TW}")
-    if formulation == "mxu":
-        mat = bit_matrix_traced(matrix)
-    else:
-        mat = runtime_masks(matrix)
-    zeros = (0,) * mat.ndim
+    masks = runtime_masks(matrix)
     out_shape = [jax.ShapeDtypeStruct((B, o, w), jnp.uint32)]
     out_specs = [pl.BlockSpec((1, o, _TW), lambda b, i: (b, 0, i))]
     if with_hash:
         out_shape.append(jax.ShapeDtypeStruct((B, s, 8), jnp.uint32))
         out_specs.append(pl.BlockSpec((1, s, 8), lambda b, i: (b, 0, 0)))
     return pl.pallas_call(
-        _runtime_kernel_factory(o, s, _TW, formulation, with_hash),
+        _runtime_kernel_factory(o, _TW, with_hash),
         out_shape=tuple(out_shape),
         grid=(B, w // _TW),
         in_specs=[
-            pl.BlockSpec(mat.shape, lambda b, i: zeros),
+            pl.BlockSpec(masks.shape, lambda b, i: (0, 0, 0)),
             pl.BlockSpec((1, s, _TW), lambda b, i: (b, 0, i)),
         ],
         out_specs=tuple(out_specs),
         interpret=interpret,
-    )(mat, rows)
+    )(masks, rows)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def matmul_rows_runtime(rows, matrix, interpret: bool = False):
     """(B, s, w) u32 shard rows x TRACED (o, s) uint8 GF matrix ->
     (B, o, w), ONE pallas_call, one program whatever the matrix holds."""
-    (out,) = _runtime_call(rows, matrix, "swar", interpret, False)
+    (out,) = _runtime_call(rows, matrix, interpret, False)
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("formulation", "interpret"))
-def verify_reconstruct_runtime(
-    shards,
-    matrix,
-    formulation: str = "swar",
-    interpret: bool = False,
-):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def verify_reconstruct_runtime(shards, matrix, interpret: bool = False):
     """One-kernel GET codec pass with the decode matrix an operand:
     bitrot partials for every shard row + reconstruction, ONE
     pallas_call.
@@ -483,4 +332,4 @@ def verify_reconstruct_runtime(
     against stored digests outside; each shard byte is read from HBM
     exactly once for both).
     """
-    return _runtime_call(shards, matrix, formulation, interpret, True)
+    return _runtime_call(shards, matrix, interpret, True)

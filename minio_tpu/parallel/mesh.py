@@ -65,35 +65,6 @@ def make_mesh(
     return Mesh(arr, ("stripe", "shard"))
 
 
-_overlap_fallback_warned = False
-
-
-def warn_overlap_fallback() -> None:
-    """Warn once that MINIO_TPU_CODEC_OVERLAP=async is ignored on mesh.
-
-    The sub-chunk overlap pipeline double-buffers per-device staging
-    arrays; the mesh entry points shard one whole stripe batch across
-    devices with collective parity accumulation, so splitting the
-    stripe-length axis again underneath them would fight the "seq"
-    axis for the same dimension.  Mesh callers silently get the
-    serialized (bit-identical) path; this warning surfaces that the
-    overlap knob is being ignored so operators do not chase missing
-    overlap_windows counters on multi-device runs.
-    """
-    global _overlap_fallback_warned
-    if _overlap_fallback_warned:
-        return
-    _overlap_fallback_warned = True
-    import warnings
-
-    warnings.warn(
-        "MINIO_TPU_CODEC_OVERLAP is not supported on the device-mesh "
-        "codec path; falling back to the serialized (off) pipeline",
-        RuntimeWarning,
-        stacklevel=3,
-    )
-
-
 def xor_allreduce(x: jax.Array, axis_name: str) -> jax.Array:
     """All-reduce with XOR over a mesh axis via recursive doubling.
 
@@ -293,7 +264,6 @@ def _verify_reconstruct_local(
     k: int,
     m: int,
     shard_len: int,
-    formulation: str = "swar",
     use_pallas: bool = False,
     interpret: bool = False,
 ):
@@ -313,7 +283,6 @@ def _verify_reconstruct_local(
             k,
             m,
             shard_len,
-            formulation=formulation,
             use_pallas=use_pallas,
             interpret=interpret,
         )
@@ -541,7 +510,6 @@ def mesh_verify_reconstruct(
     data_shards: int,
     parity_shards: int,
     shard_len: int,
-    formulation: str = "swar",
     use_pallas: bool = False,
     interpret: bool = False,
 ) -> tuple[np.ndarray, np.ndarray]:
@@ -552,9 +520,9 @@ def mesh_verify_reconstruct(
     and matrix (uint8[k, k]) are the pattern's operands, whole on every
     device.  Returns ((B, k, w) data, (B, n) ok mask).
     Padded stripes hash to garbage and come back ok=False; the [:B] slice
-    drops them before anyone looks.  ``use_pallas``/``interpret``/
-    ``formulation`` are codec_step.pallas_dispatch's statics, threaded
-    to the per-device body.
+    drops them before anyone looks.  ``use_pallas``/``interpret`` are
+    codec_step.pallas_dispatch's statics, threaded to the per-device
+    body.
     """
     k, m = data_shards, parity_shards
     B = words.shape[0]
@@ -568,7 +536,6 @@ def mesh_verify_reconstruct(
         k=k,
         m=m,
         shard_len=shard_len,
-        formulation=formulation,
         use_pallas=use_pallas,
         interpret=interpret,
     )

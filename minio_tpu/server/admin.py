@@ -586,7 +586,7 @@ class AdminAPI:
         # shared admission budget: live per-tenant inflight plus the
         # high-water mark each tenant's token counter ever reached -
         # the out-of-process witness that the GLOBAL cap held exactly
-        # across loops (bench --concurrency asserts hwm <= cap here)
+        # across loops (hwm <= cap)
         admission = getattr(self.s3, "admission", None)
         if admission is not None:
             doc["admission"] = {
@@ -606,24 +606,11 @@ class AdminAPI:
         doc["select"] = dict(
             seldev.STATS.snapshot(), mode=seldev.select_mode()
         )
-        # device transfer/compute overlap: configured mode plus the
-        # windows the codec actually opened and the per-plane bus
-        # traffic backing them (codec/telemetry.py)
-        from ..codec.telemetry import KERNEL_STATS
-        from ..ops import codec_step
-
         # what the codec runs on, as JAX reports it: backend, platform,
         # device kind/count, versions, compile cache, per-device memory
         from ..codec import backend as codec_backend
 
         doc["device"] = codec_backend.backend_info()
-        ksnap = KERNEL_STATS.snapshot()
-        doc["codec_overlap"] = {
-            "mode": codec_step.codec_overlap_mode(),
-            "overlap_windows": ksnap["overlap_windows"],
-            "h2d": ksnap["h2d"],
-            "d2h": ksnap["d2h"],
-        }
         try:
             page = _os.sysconf("SC_PAGE_SIZE")
             doc["mem_total_bytes"] = page * _os.sysconf("SC_PHYS_PAGES")

@@ -111,7 +111,7 @@ class TokenCounter:
         self._res: "list[None]" = []
         self._adm: "list[None]" = []
         # benign-race max (may under-record a transient peak, never
-        # invents one): hwm <= cap is the bench's exactness witness
+        # invents one): hwm <= cap is the exactness witness
         self.hwm = 0
 
     def try_acquire(self, limit: int) -> bool:

@@ -555,13 +555,6 @@ class Metrics:
             [({"plane": p}, h2d.get(p, {}).get("transfers", 0))
              for p in ("data", "parity")],
         )
-        ow = snap.get("overlap_windows", {})
-        emit(
-            "miniotpu_codec_overlap_windows_total", "counter",
-            "Transfer/compute overlap windows opened by direction "
-            "(put = encode side, get = verify/reconstruct side)",
-            [({"direction": d}, ow.get(d, 0)) for d in ("put", "get")],
-        )
         pc = snap.get("parity_cache", {})
         emit(
             "miniotpu_codec_parity_cache_bytes", "gauge",

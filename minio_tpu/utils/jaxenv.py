@@ -5,10 +5,10 @@ Two things every entry point that touches the device needs and nothing
 else should reimplement:
 
 * ``setup_compile_cache()`` - called once, before the first JAX use, by
-  ``python -m minio_tpu.server``, ``bench.py`` and ``chip_smoke.py``'s
-  children.  Every distinct (batch, k, m, width, loss pattern) is its
-  own XLA program and a cold TPU compile runs from about a second to
-  over a minute, so a server that forgets them pays on every restart.
+  ``python -m minio_tpu.server`` and ``chip_smoke.py``'s children.
+  Every distinct (batch, k, m, width, loss pattern) is its own XLA
+  program and a cold TPU compile runs from about a second to over a
+  minute, so a server that forgets them pays on every restart.
 * ``device_info()`` - platform, device kind and count, versions, the
   cache directory in effect and per-device memory, as JAX reports them.
   The server logs it at boot and serves it in ``healthinfo`` and

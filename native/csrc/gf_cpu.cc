@@ -1,5 +1,5 @@
 // Native CPU GF(2^8) matrix codec: the host fallback for the TPU erasure
-// data plane, and the in-repo AVX2 baseline bench.py measures against.
+// data plane, and the reference the device codec is held bit-identical to.
 //
 // Implements the same technique as the reference's codec dependency
 // (klauspost/reedsolomon v1.9.9 AVX2 assembly, wrapped by

@@ -12,7 +12,7 @@ def _to_host(arr):
 
 
 def read_parity(words, parity_shards, shard_len):
-    parity, digests = codec_step.encode_and_hash_words_digest(
+    parity, digests = codec_step.encode_words_fused1(
         words, parity_shards, shard_len
     )
     return _to_host(parity)

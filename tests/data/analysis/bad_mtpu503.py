@@ -6,7 +6,7 @@ from minio_tpu.ops import codec_step
 
 
 def put_async(pool, words, parity_shards, shard_len):
-    parity, digests = codec_step.encode_and_hash_words_digest(
+    parity, digests = codec_step.encode_words_fused1(
         words, parity_shards, shard_len
     )
 
@@ -17,7 +17,7 @@ def put_async(pool, words, parity_shards, shard_len):
 
 
 def put_async_lambda(pool, words, parity_shards, shard_len):
-    parity, digests = codec_step.encode_and_hash_words_digest(
+    parity, digests = codec_step.encode_words_fused1(
         words, parity_shards, shard_len
     )
     pool.submit("stripe-1", lambda: digests.sum())  # VIOLATION: MTPU503

@@ -8,7 +8,7 @@ from minio_tpu.ops import codec_step
 
 def put_object(data, parity_shards, shard_len):
     words = jnp.asarray(data)
-    parity, digests = codec_step.encode_and_hash_words_digest(
+    parity, digests = codec_step.encode_words_fused1(
         words, parity_shards, shard_len
     )
     return parity, digests

@@ -7,7 +7,7 @@ from minio_tpu.s3select import device as sdevice
 
 
 def read_rows(words, parity_shards, shard_len, nbytes):
-    parity, digests = codec_step.encode_and_hash_words_digest(
+    parity, digests = codec_step.encode_words_fused1(
         words, parity_shards, shard_len
     )
     payload = sdevice.drain_plane(parity, nbytes)

@@ -854,7 +854,7 @@ def test_mtpu501_fires_on_seeded_codec_step_canary():
     src = _read_tree_source(rel)
     injected = (
         "\n\ndef _canary_reuse(words, parity_shards, shard_len):\n"
-        "    parity, digests = encode_and_hash_words_digest(\n"
+        "    parity, digests = encode_words_fused1(\n"
         "        words, parity_shards, shard_len\n"
         "    )\n"
         "    return words.sum(), parity\n"
@@ -880,7 +880,7 @@ def test_mtpu502_fires_on_seeded_backend_canary():
     src = _read_tree_source(rel)
     injected = (
         "\n\ndef _canary_peek(words, parity_shards, shard_len):\n"
-        "    parity_w, digests = codec_step.encode_and_hash_words_digest(\n"
+        "    parity_w, digests = codec_step.encode_words_fused1(\n"
         "        words, parity_shards, shard_len\n"
         "    )\n"
         "    return np.asarray(parity_w)\n"
@@ -1218,23 +1218,17 @@ def test_registry_resolves_every_def_in_tree_graph(tree_graph):
 
 
 def test_mtpu601_fires_on_seeded_backend_canary():
-    """Canary: a copy of the REAL codec/backend.py whose GET sub-chunk
-    path drops its finally-release strands the staging reservation —
-    caught with exact rule ids and lines (the unprotected hold and the
-    leaking exit)."""
+    """Canary: a copy of the REAL codec/backend.py with an exit that
+    neither drains, releases nor hands on the parity ref it admitted to
+    the plane cache - caught with exact rule id and line."""
     rel = "minio_tpu/codec/backend.py"
     src = _read_tree_source(rel)
-    target = (
-        "        finally:\n"
-        "            _stage_release(reserved)\n"
-        "        return np.concatenate(parts, axis=-1), ok\n"
-    )
-    assert src.count(target) == 1, "canary anchor drifted"
-    seeded = src.replace(
-        target,
-        "        finally:\n"
-        "            pass  # canary: release dropped\n"
-        "        return np.concatenate(parts, axis=-1), ok\n",
+    seeded = src + (
+        "\n\ndef _canary_peek(plane):\n"
+        "    ref = _DeviceParityRef(parity_plane_cache(), plane)\n"
+        "    if ref.nbytes > (1 << 20):\n"
+        "        return None  # canary: the plane stays in the cache\n"
+        "    return ref\n"
     )
     clean = lifecycle.analyze_sources(
         {rel: parse_source(rel, src)}
@@ -1243,23 +1237,14 @@ def test_mtpu601_fires_on_seeded_backend_canary():
     found = lifecycle.analyze_sources(
         {rel: parse_source(rel, seeded)}
     ).findings
-    slines = seeded.splitlines()
-    pass_line = (
-        slines.index("            pass  # canary: release dropped") + 1
-    )
-    leak_line = pass_line + 1  # the return after the gutted finally
-    reserve_line = (
-        next(
-            i
-            for i, ln in enumerate(slines)
-            if "2 * B * n * cw * 4" in ln
+    leak_line = (
+        seeded.splitlines().index(
+            "        return None  # canary: the plane stays in the cache"
         )
         + 1
     )
-    hold_line = reserve_line + 2  # first raisable call inside the try
     assert {(f.rule, f.line) for f in found} == {
-        ("MTPU603", hold_line),
-        ("MTPU601", leak_line),
+        ("MTPU601", leak_line)
     }, "\n".join(f.render() for f in found)
 
 

@@ -255,22 +255,3 @@ def test_range_read_still_flags_heal(layer):
     assert out.getvalue() == data[10:110]
     assert info.user_defined.get("x-internal-heal-required") == "true"
     assert healed_keys == [("zip", "rot")]
-
-
-def test_prefix_keep_power_of_two_and_bounds():
-    """Both drain paths (legacy pack-at-drain and fused1 precomputed)
-    share this rounding; it must be a power of two capped at g."""
-    from minio_tpu.codec.compress import prefix_keep
-
-    assert prefix_keep(0, 16) == 0
-    assert prefix_keep(1, 16) == 1
-    assert prefix_keep(3, 16) == 4
-    assert prefix_keep(5, 16) == 8
-    assert prefix_keep(9, 16) == 16
-    assert prefix_keep(16, 16) == 16
-    assert prefix_keep(11, 12) == 12  # capped at g, even off-power
-    for g in (2, 4, 16, 64):
-        for kept in range(1, g + 1):
-            keep = prefix_keep(kept, g)
-            assert kept <= keep <= g
-            assert keep == g or (keep & (keep - 1)) == 0

@@ -73,7 +73,12 @@ def test_backend_info_names_what_jax_reports(monkeypatch):
     assert info["device_kind"] == dev[0].device_kind
     assert info["device_count"] == len(dev) == len(info["devices"])
     assert info["jax"] == jax.__version__
-    assert {"kernel", "formulation", "overlap", "device_compress"} <= set(info)
+    # the resolved backend and what JAX reports: nothing here chooses a
+    # kernel, so no key names one
+    assert set(info) == {
+        "backend", "batched", "platform", "device_kind", "device_count",
+        "devices", "jax", "jaxlib", "libtpu", "compile_cache", "placement",
+    }
     assert "over 8 devices" in info["placement"]
     monkeypatch.setenv("MINIO_MESH", "0")
     assert "pinned to device 0" in backend_mod.backend_info()["placement"]

@@ -1,18 +1,18 @@
-"""One-kernel codec (fused1) tests.
+"""One-pass codec tests.
 
 Covers the single-pass PUT/GET codec kernels end to end:
 
 * bit-identity of ``encode_words_fused1`` (portable and Pallas
-  interpret, SWAR and MXU formulations) against the legacy entry AND
-  the CPU-native reference, across k/m geometries including k=1, m=0,
+  interpret) against the eager seam's ``encode_and_hash_words`` AND the
+  CPU-native reference, across k/m geometries including k=1, m=0,
   ragged tails, and all-zero stripes;
 * bit-identity of ``verify_and_reconstruct_words`` against the
   verify_hashes_words -> reconstruct_words_batch pair, with bitrot;
+* the seam against ``CpuBackend`` over the degenerate shapes (k=1, m=0,
+  a ragged row) and drop patterns, PUT and heal;
 * pass accounting through the backend seam: PUT is exactly ONE device
-  pass before the drain in both kernel modes, the drain launches
-  nothing with the transport screen off and the two screen passes with
-  it on; fused1 heal is one pass where legacy takes two (KERNEL_STATS
-  ``device_passes``, with the Pallas/portable split);
+  pass and the drain launches nothing; heal is one pass
+  (KERNEL_STATS ``device_passes``, with the Pallas/portable split);
 * the digest-only contract: ``encode_digest_end`` materializes digest
   bytes only, the parity plane crosses D2H at drain;
 * donation safety: ``donate_argnums`` on the data words never corrupts
@@ -51,13 +51,13 @@ def _stripes(batch, k, length, seed=0):
     return rng.integers(0, 256, (batch, k, length)).astype(np.uint8)
 
 
-def _legacy_encode(words, m, L):
-    """The legacy entry fused1 must match bit for bit."""
+def _eager_encode(words, m, L):
+    """The eager seam's entry (heal's re-encode): the same bits."""
     parity, digests = codec_step.encode_and_hash_words(words, m, L)
     return np.asarray(parity), np.asarray(digests)
 
 
-# -- bit-identity: fused1 vs legacy vs CPU native ------------------------
+# -- bit-identity: fused1 vs the eager seam vs CPU native ----------------
 
 # (k, m, L): k=1 degenerate, m=0 digest-only, ragged tail (w=24 words,
 # under one 128-lane hash row) and a width with a ragged hash tail
@@ -75,7 +75,7 @@ _GEOMETRIES = [
 
 
 @pytest.mark.parametrize("k,m,L", _GEOMETRIES)
-def test_fused1_portable_matches_legacy_and_native(k, m, L):
+def test_fused1_portable_matches_eager_and_native(k, m, L):
     B = 3
     data = _stripes(B, k, L, seed=k * 31 + m)
     data[1] = 0  # one all-zero stripe
@@ -83,7 +83,7 @@ def test_fused1_portable_matches_legacy_and_native(k, m, L):
     parity, digests = codec_step.encode_words_fused1(
         jnp.asarray(words), m, L
     )
-    lp, ld = _legacy_encode(jnp.asarray(words), m, L)
+    lp, ld = _eager_encode(jnp.asarray(words), m, L)
     np.testing.assert_array_equal(np.asarray(parity), lp)
     np.testing.assert_array_equal(np.asarray(digests), ld)
     # CPU-native reference: gf.encode_ref parity + phash256_host digests
@@ -99,40 +99,34 @@ def test_fused1_portable_matches_legacy_and_native(k, m, L):
             assert np.asarray(digests)[b, s].tobytes() == want
 
 
-@pytest.mark.parametrize("formulation", ["swar", "mxu"])
-def test_fused1_pallas_interpret_smoke(formulation):
+def test_fused1_pallas_interpret_smoke():
     """Fast tier-1 smoke: one Pallas tile through the interpreter."""
     k, m, L = 2, 1, 4 * rs_pallas._TW
     data = _stripes(2, k, L, seed=9)
     data[0, :, : L // 2] = 0
     words = jnp.asarray(codec_step.host_bytes_to_words(data))
-    got = codec_step.encode_words_fused1(
-        words, m, L, formulation, True, True
-    )
-    want = _legacy_encode(words, m, L)
+    got = codec_step.encode_words_fused1(words, m, L, True, True)
+    want = _eager_encode(words, m, L)
     for g, w_ in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), w_)
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("formulation", ["swar", "mxu"])
 @pytest.mark.parametrize("k,m", [(2, 1), (4, 2), (8, 4)])
-def test_fused1_pallas_interpret_full_grid(k, m, formulation):
+def test_fused1_pallas_interpret_full_grid(k, m):
     """The full FUSED_GRID geometry through the Pallas interpreter."""
     L = 4 * rs_pallas._TW
     data = _stripes(2, k, L, seed=k + m)
     data[1] = 0
     words = jnp.asarray(codec_step.host_bytes_to_words(data))
-    got = codec_step.encode_words_fused1(
-        words, m, L, formulation, True, True
-    )
-    want = _legacy_encode(words, m, L)
+    got = codec_step.encode_words_fused1(words, m, L, True, True)
+    want = _eager_encode(words, m, L)
     for g, w_ in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), w_)
 
 
 @pytest.mark.parametrize("use_pallas", [False, True])
-def test_fused_get_matches_legacy_pair(use_pallas):
+def test_fused_get_matches_verify_reconstruct_pair(use_pallas):
     """verify_and_reconstruct_words == verify -> reconstruct, bitrot."""
     k, m = 4, 2
     L = (4 * rs_pallas._TW) if use_pallas else 256
@@ -160,22 +154,21 @@ def test_fused_get_matches_legacy_pair(use_pallas):
         k,
         m,
         L,
-        "swar",
         use_pallas,
         use_pallas,  # interpret mode when exercising the Pallas path
     )
-    ok_legacy = np.asarray(
+    ok_pair = np.asarray(
         codec_step.verify_hashes_words(
             jnp.asarray(shards), jnp.asarray(digests), L
         )
     ) & np.asarray(present, bool)
-    data_legacy = np.asarray(
+    data_pair = np.asarray(
         codec_step.reconstruct_words_batch(
             jnp.asarray(shards), survivors, matrix, k, m
         )
     )
-    np.testing.assert_array_equal(np.asarray(got_ok), ok_legacy)
-    np.testing.assert_array_equal(np.asarray(got_data), data_legacy)
+    np.testing.assert_array_equal(np.asarray(got_ok), ok_pair)
+    np.testing.assert_array_equal(np.asarray(got_data), data_pair)
 
 
 def test_fused_get_below_quorum_raises():
@@ -200,48 +193,78 @@ def test_fused_get_below_quorum_raises():
 # -- the backend seam: pass accounting + digest-only contract ------------
 
 
-def _encode_passes(mode, compress, monkeypatch):
-    monkeypatch.setenv("MINIO_TPU_CODEC_KERNEL", mode)
-    monkeypatch.setenv("MINIO_TPU_DEVICE_COMPRESS", compress)
+# (B, k, m, L_bytes, dropped shards): k=1 (a parity-only survivor), m=0
+# (digest-only, nothing to drop), the ragged 11,264-byte row (w=2816, no
+# kernel tile divides it), multi-loss reconstruction.
+SEAM_GRID = [
+    (2, 4, 2, 4096, (1, 4)),
+    (1, 1, 1, 4096, (0,)),
+    (2, 3, 0, 4096, ()),
+    (1, 4, 2, 11264, (0, 5)),
+    (2, 2, 1, 3072, (2,)),
+]
+_SEAM_IDS = [f"B{B}-ec{k}+{m}-L{L}" for B, k, m, L, _ in SEAM_GRID]
+
+
+@pytest.mark.parametrize(
+    "B,k,m,L", [c[:4] for c in SEAM_GRID], ids=_SEAM_IDS
+)
+def test_put_seam_matches_cpu_backend(single_device, B, k, m, L):
+    """PUT through the digest seam: digests at _end, parity at drain,
+    one launch, and the drain launches nothing."""
+    data = _stripes(B, k, L, seed=L + k)
     be = TpuBackend()
-    data = _stripes(2, 4, 4096, seed=2)
-    data[:, :, : 4096 // 2] = 0  # sparse: the pack pass must run
     KERNEL_STATS.reset()
-    dig, ref = be.encode_digest_end(be.encode_digest_begin(data, 2))
+    dig, ref = be.encode_digest_end(be.encode_digest_begin(data, m))
     pre = dict(KERNEL_STATS.snapshot()["device_passes"])
-    par = ref.drain()
+    par = be.drain(ref)
     ref.release()
-    post = dict(KERNEL_STATS.snapshot()["device_passes"])
-    want_par, want_dig = CpuBackend().encode(data, 2)
+    assert pre == {"encode_words_fused1": 1}
+    assert KERNEL_STATS.snapshot()["device_passes"] == pre
+    want_par, want_dig = CpuBackend().encode(data, m)
     np.testing.assert_array_equal(dig, want_dig)
     np.testing.assert_array_equal(par, want_par)
-    return pre, post
 
 
-_PUT_ENTRY = {
-    "fused1": "encode_words_fused1",
-    "legacy": "encode_and_hash_words_digest",
-}
+@pytest.mark.parametrize("B,k,m,L,drop", SEAM_GRID, ids=_SEAM_IDS)
+def test_heal_seam_matches_cpu_backend(single_device, B, k, m, L, drop):
+    """GET side of the same shapes: reconstruct_and_verify and
+    reconstruct with the dropped rows gone, against CpuBackend."""
+    data = _stripes(B, k, L, seed=L + k)
+    tb, cb = TpuBackend(), CpuBackend()
+    par, dig = cb.encode(data, m)
+    shards = np.concatenate([data, par], axis=1)
+    present = np.array([i not in drop for i in range(k + m)])
+    shards[:, ~present] = 0x5A  # garbage where the shard is gone
+    got, ok = tb.reconstruct_and_verify(shards, dig, present, k, m)
+    want, wok = cb.reconstruct_and_verify(shards, dig, present, k, m)
+    np.testing.assert_array_equal(ok, wok)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(got, data)
+    np.testing.assert_array_equal(
+        tb.reconstruct(shards, present, k, m), data
+    )
 
 
-@pytest.mark.parametrize("mode", ["fused1", "legacy"])
-def test_put_is_one_device_pass_unscreened(single_device, monkeypatch, mode):
-    """Default transport (MINIO_TPU_DEVICE_COMPRESS=off): one launch per
-    batch, and the drain launches nothing."""
-    pre, post = _encode_passes(mode, "off", monkeypatch)
-    assert pre == {_PUT_ENTRY[mode]: 1}
-    assert post == pre, f"drain launched kernels: {post}"
-
-
-@pytest.mark.parametrize("mode", ["fused1", "legacy"])
-def test_put_screened_drain_is_two_more_passes(
-    single_device, monkeypatch, mode
+@pytest.mark.parametrize("B", [2, 3, 4])
+def test_put_seam_pallas_batches_match_cpu_backend(
+    single_device, monkeypatch, B
 ):
-    pre, post = _encode_passes(mode, "on", monkeypatch)
-    assert pre == {_PUT_ENTRY[mode]: 1}
-    assert sum(post.values()) == 3, post
-    assert post["group_flags"] == 1
-    assert post["pack_nonzero_groups"] == 1
+    """A coalesced flush of 2-4 blocks (what the benchmark's traffic
+    makes) through the kernel's (batch, w-tile) grid, interpreted."""
+    monkeypatch.setenv("MINIO_TPU_CODEC_INTERPRET", "1")
+    k, m, L = 4, 2, 4 * rs_pallas._TW
+    data = _stripes(B, k, L, seed=B)
+    be = TpuBackend()
+    KERNEL_STATS.reset()
+    dig, ref = be.encode_digest_end(be.encode_digest_begin(data, m))
+    par = be.drain(ref)
+    assert KERNEL_STATS.snapshot()["pallas_passes"] == {
+        "encode_words_fused1": 1
+    }
+    want_par, want_dig = CpuBackend().encode(data, m)
+    np.testing.assert_array_equal(dig, want_dig)
+    np.testing.assert_array_equal(par, want_par)
 
 
 def test_pallas_and_portable_passes_counted_apart(
@@ -267,10 +290,9 @@ def test_pallas_and_portable_passes_counted_apart(
     }
 
 
-def test_fused1_digest_only_before_drain(single_device, monkeypatch):
+def test_fused1_digest_only_before_drain(single_device):
     """MTPU107 contract at runtime: only digest bytes cross D2H at the
     end seam; the parity plane waits for drain."""
-    monkeypatch.setenv("MINIO_TPU_CODEC_KERNEL", "fused1")
     be = TpuBackend()
     data = _stripes(2, 4, 4096, seed=6)
     KERNEL_STATS.reset()
@@ -289,13 +311,17 @@ def test_fused1_digest_only_before_drain(single_device, monkeypatch):
     np.testing.assert_array_equal(par, CpuBackend().encode(data, 2)[0])
 
 
-@pytest.mark.parametrize("mode", ["legacy", "fused1"])
+@pytest.mark.parametrize("form", ["portable", "pallas"])
 def test_backend_reconstruct_and_verify_modes_agree(
-    single_device, monkeypatch, mode
+    single_device, monkeypatch, form
 ):
-    monkeypatch.setenv("MINIO_TPU_CODEC_KERNEL", mode)
+    """Both forms of the one heal pass agree with CpuBackend, the
+    re-pick after a rotted survivor included."""
+    if form == "pallas":
+        monkeypatch.setenv("MINIO_TPU_CODEC_INTERPRET", "1")
     tb, cb = TpuBackend(), CpuBackend()
-    k, m, L = 4, 2, 1024
+    k, m = 4, 2
+    L = 4 * rs_pallas._TW if form == "pallas" else 1024
     data = _stripes(3, k, L, seed=8)
     par, dig = cb.encode(data, m)
     shards = np.concatenate([data, par], axis=1).copy()
@@ -305,16 +331,14 @@ def test_backend_reconstruct_and_verify_modes_agree(
     shards[:, 2, 7] ^= 0x80  # bitrot on a chosen survivor: re-pick path
     KERNEL_STATS.reset()
     got, ok = tb.reconstruct_and_verify(shards, dig, tuple(present), k, m)
-    passes = KERNEL_STATS.snapshot()["device_passes"]
+    snap = KERNEL_STATS.snapshot()
     want, wok = cb.reconstruct_and_verify(shards, dig, tuple(present), k, m)
     np.testing.assert_array_equal(ok, wok)
     np.testing.assert_array_equal(got, want)
     np.testing.assert_array_equal(got, data)
-    if mode == "fused1":
-        assert passes.get("verify_and_reconstruct_words") == 1
-    else:
-        assert passes.get("digest_words") == 1
-        assert passes.get("reconstruct_words_batch", 0) >= 1
+    name = "verify_and_reconstruct_words"
+    assert snap["device_passes"].get(name) == 1
+    assert snap["pallas_passes"].get(name, 0) == (form == "pallas")
 
 
 # -- donation safety -----------------------------------------------------
@@ -335,14 +359,3 @@ def test_donated_words_never_corrupt_retained_reference():
     out2 = codec_step.encode_words_fused1(jnp.asarray(words_np), m, L)
     for a, b in zip(out1, out2):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_fused1_is_default_and_legacy_oracle_selectable(monkeypatch):
-    monkeypatch.delenv("MINIO_TPU_CODEC_KERNEL", raising=False)
-    assert codec_step.codec_kernel_mode() == "fused1"
-    monkeypatch.setenv("MINIO_TPU_CODEC_KERNEL", "legacy")
-    assert codec_step.codec_kernel_mode() == "legacy"
-    # unknown values fall back to the default, matching the other
-    # codec knobs (device_compress_mode et al.)
-    monkeypatch.setenv("MINIO_TPU_CODEC_KERNEL", "bogus")
-    assert codec_step.codec_kernel_mode() == "fused1"

@@ -41,10 +41,12 @@ def test_resource_balances_converge_to_zero(leakcheck, tmp_path):
     traffic plus a forced admission shed, every statically-proved
     balance is empirically zero — admission tokens (tenant and
     select), the plane inflight gauge, and the codec's device-byte
-    staging account."""
+    parity-plane account."""
     from minio_tpu.cache.allocator import device_budget
+    from minio_tpu.codec.backend import reset_backend
     from minio_tpu.server.admission import TokenCounter
 
+    reset_backend()  # the account is the process's: start from its zero
     disks = [XLStorage(str(tmp_path / f"d{i}")) for i in range(4)]
     ol = ErasureObjects(disks, block_size=4096, min_part_size=1)
     srv = S3Server(ol, address="127.0.0.1:0").start()
@@ -77,7 +79,7 @@ def test_resource_balances_converge_to_zero(leakcheck, tmp_path):
         assert srv.plane_stats.inflight == 0
     finally:
         srv.shutdown()
-    assert device_budget().usage("codec_staging") == 0
+    assert device_budget().usage("parity_plane") == 0
 
 
 def test_detector_catches_a_deliberate_leak():

@@ -432,17 +432,6 @@ def test_admin_healthinfo_includes_api_stats(server, client):
         assert stats["read_all"]["calls"] >= 1
 
 
-def test_admin_healthinfo_codec_overlap_block(server, client):
-    """OBD carries the transfer-overlap posture: configured mode plus
-    the windows/bus counters, shape-stable even with zero traffic."""
-    r = client.request("GET", f"{ADMIN}/healthinfo")
-    assert r.status == 200, r.body
-    ov = json.loads(r.body)["nodes"][0]["codec_overlap"]
-    assert ov["mode"] in ("off", "async", "pipeline")
-    assert set(ov["overlap_windows"]) == {"put", "get"}
-    assert isinstance(ov["h2d"], list) and isinstance(ov["d2h"], list)
-
-
 def test_batcher_occupancy_counters():
     """Jobs routed through the BatchingBackend land in the flush
     telemetry: flushes, job count, and queue wait accumulate."""
@@ -564,10 +553,9 @@ def test_select_families_zero_filled():
         STATS.device_time(saved["device_seconds"])
 
 
-def test_overlap_families_zero_filled():
-    """The round-18 transfer-overlap families render with a stable,
-    zero-filled label set (both planes, both directions) before any
-    codec traffic."""
+def test_h2d_families_zero_filled():
+    """The host->device staging families render with a stable,
+    zero-filled label set (both planes) before any codec traffic."""
     KERNEL_STATS.reset()
     families = parse_exposition(Metrics().render().decode())
     for name in (
@@ -579,18 +567,11 @@ def test_overlap_families_zero_filled():
         planes = {lab["plane"]: v for _n, lab, v in fam["samples"]}
         assert set(planes) == {"data", "parity"}, name
         assert all(v == 0.0 for v in planes.values()), name
-    fam = get_family(families, "miniotpu_codec_overlap_windows_total")
-    assert fam["type"] == "counter"
-    dirs = {lab["direction"]: v for _n, lab, v in fam["samples"]}
-    assert set(dirs) == {"put", "get"}
-    assert all(v == 0.0 for v in dirs.values())
 
 
-def test_overlap_families_reflect_live_counters():
+def test_h2d_families_reflect_live_counters():
     KERNEL_STATS.record_h2d("data", 4096)
     KERNEL_STATS.record_h2d("data", 4096)
-    KERNEL_STATS.record_overlap_windows("put", 3)
-    KERNEL_STATS.record_overlap_windows("get", 5)
     families = parse_exposition(Metrics().render().decode())
     fam = get_family(families, "miniotpu_codec_h2d_bytes_total")
     planes = {lab["plane"]: v for _n, lab, v in fam["samples"]}
@@ -598,9 +579,6 @@ def test_overlap_families_reflect_live_counters():
     fam = get_family(families, "miniotpu_codec_h2d_transfers_total")
     planes = {lab["plane"]: v for _n, lab, v in fam["samples"]}
     assert planes["data"] >= 2.0
-    fam = get_family(families, "miniotpu_codec_overlap_windows_total")
-    dirs = {lab["direction"]: v for _n, lab, v in fam["samples"]}
-    assert dirs["put"] >= 3.0 and dirs["get"] >= 5.0
 
 
 def test_select_families_reflect_live_counters():

@@ -5,8 +5,7 @@ Covers the tentpole's moving parts in isolation and end to end:
 * ParityPlaneCache - bounded occupancy under concurrent adds, FIFO
   write-back eviction order, forget accounting;
 * digest-only encode (TpuBackend/CpuBackend/batcher) - bit-identical
-  parity + digests vs the legacy eager path, including the fused
-  on-device transport compression leg;
+  parity + digests vs the eager path;
 * encode_end/encode_digest_end idempotency (the satellite fix: error-
   path cleanup can never double-consume a handle);
 * quorum-early ParityBand - drain failures are heal-flagged, never
@@ -23,19 +22,16 @@ import numpy as np
 import pytest
 
 from minio_tpu.codec import backend as backend_mod
-from minio_tpu.codec import compress
 from minio_tpu.codec.backend import (
     CpuBackend,
     ParityPlaneCache,
     TpuBackend,
-    _DeviceParityRef,
     parity_plane_cache,
     reset_backend,
 )
 from minio_tpu.codec.batcher import BatchingBackend
 from minio_tpu.codec.erasure import Erasure
 from minio_tpu.codec.telemetry import KERNEL_STATS
-from minio_tpu.ops import codec_step
 from minio_tpu.parallel import iopool
 
 
@@ -186,25 +182,8 @@ def test_tpu_digest_path_bit_identical_and_lazy(single_device):
     } == planes
 
 
-def test_tpu_digest_path_with_transport_compression(
-    single_device, monkeypatch
-):
-    """Sparse planes cross the bus packed; bytes must still be exact."""
-    monkeypatch.setenv("MINIO_TPU_DEVICE_COMPRESS", "on")
-    be = TpuBackend()
-    k, L = 4, 4096  # 1024 words -> 4 groups of PARITY_GROUP_WORDS
-    data = np.zeros((2, k, L), dtype=np.uint8)
-    data[0, 1, 100:160] = 7  # a few nonzero groups
-    data[1, 3, -8:] = 91
-    parity, digests = CpuBackend().encode(data, 2)
-    dig, ref = be.encode_digest_end(be.encode_digest_begin(data, 2))
-    np.testing.assert_array_equal(dig, digests)
-    np.testing.assert_array_equal(ref.drain(), parity)
-
-
-def test_tpu_digest_path_all_zero_plane(single_device, monkeypatch):
-    """Degenerate screen result: zero parity never crosses the bus."""
-    monkeypatch.setenv("MINIO_TPU_DEVICE_COMPRESS", "auto")
+def test_tpu_digest_path_all_zero_plane(single_device):
+    """An all-zero plane drains as zeros, the whole plane one transfer."""
     be = TpuBackend()
     data = np.zeros((1, 4, 2048), dtype=np.uint8)
     KERNEL_STATS.reset()
@@ -214,27 +193,7 @@ def test_tpu_digest_path_all_zero_plane(single_device, monkeypatch):
     planes = {
         d["plane"]: d["bytes"] for d in KERNEL_STATS.snapshot()["d2h"]
     }
-    # only the group-flags screen was read back, not the plane
-    assert 0 < planes["parity"] < par.nbytes
-
-
-def test_pack_unpack_roundtrip_is_exact():
-    G = compress.PARITY_GROUP_WORDS
-    rng = np.random.default_rng(11)
-    w = 8 * G
-    words = rng.integers(0, 2**32, (3, 2, w), dtype=np.uint64).astype(
-        np.uint32
-    )
-    # zero out most groups so packing actually moves things
-    grouped = words.reshape(3, 2, 8, G)
-    grouped[:, :, [0, 2, 3, 5, 6], :] = 0
-    words = grouped.reshape(3, 2, w)
-    flags, packed = codec_step.pack_nonzero_groups(words, G)
-    flags = np.asarray(flags)
-    kept = int(flags.sum(axis=-1).max())
-    prefix = np.asarray(packed[..., : kept * G])
-    out = compress.unpack_nonzero_groups(flags, prefix, G, w)
-    np.testing.assert_array_equal(out, words)
+    assert planes["parity"] == par.nbytes
 
 
 def test_release_drops_plane_without_transfer(single_device):

@@ -261,13 +261,17 @@ def test_concurrent_put_lookup_stays_bounded():
     assert st["entries"] * per_entry == st["occupancy_bytes"]
 
 
-def test_device_tier_respects_shared_budget():
-    """With the parity plane holding most of the device budget, device
-    admissions overflow to the host tier instead of double-booking."""
+@pytest.mark.parametrize("short_by", ["everything", "one-byte"])
+def test_device_tier_respects_shared_budget(short_by):
+    """With the parity plane holding the device budget - all of it, or
+    all but one byte less than an entry needs - device admissions
+    overflow to the host tier instead of double-booking, and the
+    parity plane's bytes are never an eviction victim."""
     data, digests = _group()
     per_entry = data.nbytes + digests.nbytes
     budget = DeviceBudget(per_entry * 2)
-    budget.set_usage("parity_plane", per_entry * 2)  # ledger exhausted
+    held = per_entry * 2 if short_by == "everything" else per_entry + 1
+    budget.set_usage("parity_plane", held)
     c = TieredReadCache(
         TIER_DEVICE,
         host_capacity=1 << 20,
@@ -278,39 +282,13 @@ def test_device_tier_respects_shared_budget():
     st = c.stats()["tiers"]
     assert st[TIER_DEVICE]["entries"] == 0
     assert st[TIER_HOST]["entries"] == 1
+    assert budget.usage("parity_plane") == held
+    assert budget.usage("read_cache") == 0
     # the parity plane drains: device tier opens up and reports usage
     budget.set_usage("parity_plane", 0)
     assert c.put(_key("o2"), "bucket/o2", data, digests)
     assert c.stats()["tiers"][TIER_DEVICE]["entries"] == 1
     assert budget.usage("read_cache") == per_entry
-
-
-def test_device_tier_yields_to_codec_staging():
-    """The async overlap pipeline's ping-pong staging (PR 18) posts to
-    the same device-byte ledger as the parity plane: while a
-    sub-chunked encode is in flight, device cache admissions overflow
-    to the host tier — the cache yields; staging bytes are never an
-    eviction victim."""
-    data, digests = _group()
-    per_entry = data.nbytes + digests.nbytes
-    budget = DeviceBudget(per_entry * 2)
-    budget.set_usage("codec_staging", per_entry * 2)  # encode in flight
-    c = TieredReadCache(
-        TIER_DEVICE,
-        host_capacity=1 << 20,
-        device_capacity=1 << 20,
-        budget=budget,
-    )
-    assert c.put(_key("o"), "bucket/o", data, digests)
-    st = c.stats()["tiers"]
-    assert st[TIER_DEVICE]["entries"] == 0
-    assert st[TIER_HOST]["entries"] == 1
-    # the contest left the staging reservation untouched
-    assert budget.usage("codec_staging") == per_entry * 2
-    # encode_digest_end released the ping-pong: device tier reopens
-    budget.set_usage("codec_staging", 0)
-    assert c.put(_key("o2"), "bucket/o2", data, digests)
-    assert c.stats()["tiers"][TIER_DEVICE]["entries"] == 1
 
 
 def test_device_eviction_demotes_to_host():
@@ -520,14 +498,22 @@ class _LocalityShard:
         return bytes(self.buf[off : off + length])
 
 
-def test_admits_from_reconstructed_rows_when_parity_preferred(cache_env):
+def test_admits_from_reconstructed_rows_when_parity_preferred(
+    cache_env, monkeypatch
+):
     """The preference order is local-before-data: a node whose local
     drives hold parity never reads the data slots directly, and the
     cache must still populate from the reconstructed rows (with
     freshly computed digest words) — otherwise such a node misses
     forever and the hot-key chaos cell sees disk calls on every GET."""
     from minio_tpu.codec.erasure import Erasure
+    from minio_tpu.storage import health
 
+    # the order is under test, not hedging (MINIO_TPU_HEDGE=0, on the
+    # registry this process already has): once earlier reads have armed
+    # the hedge deadline, 2 ms of scheduling delay duplicates a read
+    # onto the next shard in order, a data shard
+    monkeypatch.setattr(health.registry().cfg, "hedge_enabled", False)
     cache_env("host")
     k, m, size = 3, 3, 40_000
     er = Erasure(k, m, 4096)

@@ -123,6 +123,61 @@ def test_every_pattern_decodes_to_what_was_encoded(
     assert pallas == (ran if form == "pallas" else 0)
 
 
+HEAL_CASES = [
+    # geometry, sample of loss patterns, form: the heal pass over what
+    # the grid above decodes plus a spare row, one chosen row rotted
+    ((4, 2), 15, "portable"),
+    ((8, 4), 24, "portable"),
+    ((16, 4), 24, "portable"),
+    ((4, 2), 6, "pallas"),
+    ((8, 4), 6, "pallas"),
+    ((16, 4), 3, "pallas"),
+]
+
+
+@pytest.mark.parametrize(
+    "geometry,sample,form",
+    HEAL_CASES,
+    ids=[f"ec{k}+{m}-{s}-{f}" for (k, m), s, f in HEAL_CASES],
+)
+def test_heal_pass_verifies_and_decodes_past_a_rotted_survivor(
+    geometry, sample, form, monkeypatch
+):
+    """``reconstruct_and_verify``, what heal runs: one fused pass a
+    call, the verdict names the rotted row and the lost ones, and where
+    the rot hit a row the decode chose, the stripe is re-solved from the
+    rows that verified - the data comes back as encoded either way."""
+    k, m = geometry
+    n = k + m
+    L = ALIGNED if form == "pallas" else SMALL
+    if form == "pallas":
+        monkeypatch.setenv("MINIO_TPU_CODEC_INTERPRET", "1")
+    be = _one_device()
+    shards, data = _stripes(2, k, m, L, seed=k * 10 + m)
+    digests = be.digest(shards)
+    name = "verify_and_reconstruct_words"
+    before = KERNEL_STATS.snapshot()
+    patterns = _patterns(k, m, sample, seed=n)
+    for i, survivors in enumerate(patterns):
+        # one row more than k was read: the spare to decode round the rot
+        spare = next(r for r in range(n) if r not in survivors)
+        held, present = _lose(shards, survivors + (spare,))
+        want_ok = np.tile(present, (2, 1))
+        rotted = np.flatnonzero(present)[i % k]  # a row the decode chose
+        held[1, rotted, i % L] ^= 0x40
+        want_ok[1, rotted] = False
+        got, ok = be.reconstruct_and_verify(held, digests, present, k, m)
+        assert np.array_equal(ok, want_ok), f"survivors={survivors}"
+        assert np.array_equal(got, data), f"survivors={survivors}"
+    after = KERNEL_STATS.snapshot()
+    ran = after["device_passes"][name] - before["device_passes"].get(name, 0)
+    assert ran == len(patterns)
+    pallas = after["pallas_passes"].get(name, 0) - before[
+        "pallas_passes"
+    ].get(name, 0)
+    assert pallas == (ran if form == "pallas" else 0)
+
+
 def test_a_ninth_row_and_a_mask_order_change_nothing():
     """A hedged read may hold more than k rows: the decode uses the
     first k present, and a mask that differs only past them is the same
@@ -188,6 +243,29 @@ def test_a_launch_holds_a_power_of_two_of_rows_within_its_bytes():
     assert backend_mod.launch_rows(12 * row) == 2  # reconstruct: 1, 2
     assert backend_mod.launch_rows(64 << 20) == 1  # never none
     assert backend_mod.launch_rows(512) == backend_mod.LADDER_CAP
+
+
+@pytest.mark.parametrize("rows", [1, 2, 3, 4, 5, 6, 7, 8, 16])
+def test_digest_at_every_rung_is_one_launch_of_the_cpu_codecs_digests(rows):
+    """The rungs a healthy read of EC 8+4 settles on (1-8 shards of a
+    block, 16 of a coalesced flush): one launch each, no padding, the
+    digests the host codec computes."""
+    be = _one_device()
+    L = 2048
+    shards = np.random.default_rng(rows).integers(
+        0, 256, (1, rows, L), dtype=np.uint8
+    )
+    before = KERNEL_STATS.snapshot()
+    got = be.digest(shards)
+    after = KERNEL_STATS.snapshot()
+    assert np.array_equal(got, backend_mod.CpuBackend().digest(shards))
+    assert (
+        after["device_passes"]["digest_words"]
+        - before["device_passes"].get("digest_words", 0)
+    ) == 1
+    h2d = {r["plane"]: r["bytes"] for r in after["h2d"]}
+    h2d0 = {r["plane"]: r["bytes"] for r in before["h2d"]}
+    assert h2d["data"] - h2d0.get("data", 0) == shards.nbytes  # no pad
 
 
 def test_digest_rows_walk_the_ladder_and_split_above_a_launch(monkeypatch):

@@ -8,13 +8,16 @@ v5e topology and run the real XLA:TPU + Mosaic compilers against it:
     jax.jit(f).lower(ShapeDtypeStruct(..., sharding=SingleDeviceSharding(
         topo.devices[0]))).compile()
 
-This module compiles, that way, every kernel a TPU default reaches and
-every value of a codec selector knob that stays selectable
-(MINIO_TPU_CODEC_KERNEL / _FORMULATION / _OVERLAP, _DEVICE_COMPRESS) at
-EC 4+2 / 8+4 / 16+4 with full 10 MiB blockSizeV1 blocks, the ragged
-width of EC 12+4 and a 4 KiB object, and the mesh kernels on the four
-topology devices at B = 1, 4, 8.  A variant Mosaic refuses fails here,
-on the CPU, before anyone spends chip time on it.
+This module compiles, that way, every program the codec seam
+(``codec/backend.py``) can launch on a TPU, at EC 4+2 / 8+4 / 16+4 with
+full 10 MiB blockSizeV1 blocks: the PUT pass at the batch sizes a flush
+makes, the digest pass at every rung of the seam's ladder, the
+reconstruct pass at every stripe count a launch takes, heal's two
+passes; then the ragged width of EC 12+4 and a 4 KiB object, and the
+mesh kernels on the four topology devices at B = 1, 4, 8.  The leading
+dimensions come from ``backend.ladder`` / ``launch_rows``, so the list
+cannot drift from the seam.  A program Mosaic refuses fails here, on the
+CPU, before anyone spends chip time on it.
 
 The compiles run in a child process (``python tests/test_tpu_compile.py``
 prints one JSON line per case): trace-time dispatch asks
@@ -34,52 +37,58 @@ import time
 import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+if __name__ == "__main__":  # the child: before the seam is imported
+    sys.path.insert(0, os.path.dirname(HERE))
+    os.environ["JAX_PLATFORMS"] = "cpu"
+
+from minio_tpu.codec import backend  # noqa: E402
+from minio_tpu.codec.erasure import Erasure  # noqa: E402
+
 BLOCK = 10 * 1024 * 1024
 GRID = ((4, 2), (8, 4), (16, 4))
 
 
+def _rungs(row_bytes: int) -> "list[int]":
+    """Every leading dimension the seam launches for rows this long."""
+    cap = backend.launch_rows(row_bytes)
+    return sorted({backend.ladder(r) for r in range(1, cap + 1)})
+
+
 def _cases() -> "list[tuple[str, str, dict]]":
-    """(name, kind, params) for every compile; names are the test ids."""
+    """(name, kind, params) for every compile; names are the test ids,
+    and a single-device kind is the jitted entry point it compiles."""
     out: "list[tuple[str, str, dict]]" = []
 
-    def add(kind, k, m, B, block=BLOCK, **kw):
+    def add(kind, k, m, B, block=BLOCK):
         tag = "" if block == BLOCK else f"-{block}B"
-        extra = "".join(f"-{v}" for v in kw.values())
         out.append(
-            (f"{kind}-ec{k}+{m}-B{B}{tag}{extra}", kind,
-             dict(k=k, m=m, B=B, block=block, **kw))
+            (f"{kind}-ec{k}+{m}-B{B}{tag}", kind,
+             dict(k=k, m=m, B=B, block=block))
         )
 
     for k, m in GRID:
-        for B in (1, 8):
-            # what the defaults reach: PUT, healthy read, degraded read
-            add("put_fused1", k, m, B, formulation="swar")
-            add("read_digest", k, m, B)
-            add("degraded_reconstruct", k, m, B)
+        L = Erasure(k, m).shard_size_padded(BLOCK)
+        # PUT: a flush of the batcher is 1-4 blocks under the
+        # benchmark's traffic (batch_fill 1.1-4.5), 8 under a burst
+        for B in (1, 2, 3, 4, 8):
+            add("encode_words_fused1", k, m, B)
+        # healthy read: the rows of a flush lie flat, (1, rows, w)
+        for rows in _rungs(L):
+            add("digest_words", k, m, rows)
+        # degraded read: stripes of n rows
+        for stripes in _rungs((k + m) * L):
+            add("reconstruct_words_batch", k, m, stripes)
         # heal: verify+reconstruct, then the re-encode
-        add("heal_verify_reconstruct", k, m, 1, formulation="swar")
-        add("heal_encode", k, m, 1)
-        # MINIO_TPU_CODEC_KERNEL=legacy
-        add("put_legacy", k, m, 1)
-        # MINIO_TPU_CODEC_FORMULATION=mxu
-        add("put_fused1", k, m, 1, formulation="mxu")
-        add("heal_verify_reconstruct", k, m, 1, formulation="mxu")
-        # MINIO_TPU_DEVICE_COMPRESS=auto|on: the drain-time screen
-        add("drain_group_flags", k, m, 1)
-        add("drain_pack", k, m, 1)
-    add("heal_verify_reconstruct", 8, 4, 8, formulation="swar")
-    # MINIO_TPU_CODEC_OVERLAP=async engages at >= 3 sub-chunks per row
-    for k, m in ((4, 2), (8, 4)):
-        for fin in (False, True):
-            add("put_subchunk", k, m, 1, finalize=fin)
-            add("heal_subchunk", k, m, 1, finalize=fin)
+        add("verify_and_reconstruct_words", k, m, 1)
+        add("encode_and_hash_words", k, m, 1)
+    add("verify_and_reconstruct_words", 8, 4, 8)
     # ragged widths leave the fused kernel: EC 12+4's 10 MiB block, and
     # a 4 KiB object at EC 8+4 (512-byte shards)
     for k, m, block in ((12, 4, BLOCK), (8, 4, 4096)):
-        add("put_fused1", k, m, 1, block, formulation="swar")
-        add("heal_verify_reconstruct", k, m, 1, block, formulation="swar")
-        add("read_digest", k, m, 1, block)
-        add("degraded_reconstruct", k, m, 1, block)
+        add("encode_words_fused1", k, m, 1, block)
+        add("verify_and_reconstruct_words", k, m, 1, block)
+        add("digest_words", k, m, backend.ladder(k), block)
+        add("reconstruct_words_batch", k, m, 1, block)
     # a four-chip host: stripe axis at B >= 4, shard axis at B = 1
     for B in (1, 4, 8):
         for kind in ("mesh_encode_hash", "mesh_verify_reconstruct",
@@ -99,8 +108,6 @@ CASES = _cases()
 
 
 def _child() -> int:
-    sys.path.insert(0, os.path.dirname(HERE))
-    os.environ["JAX_PLATFORMS"] = "cpu"
     from concurrent.futures import ThreadPoolExecutor
 
     import jax
@@ -116,7 +123,6 @@ def _child() -> int:
         print(json.dumps({"skip": f"{type(e).__name__}: {e}"[:300]}))
         return 0
 
-    from minio_tpu.codec.erasure import Erasure
     from minio_tpu.ops import codec_step, rs, select_step
     from minio_tpu.parallel import mesh as pm, rules as prules
 
@@ -138,68 +144,35 @@ def _child() -> int:
         # the loss pattern's operands: abstract, like the shards - the
         # program cannot depend on which rows survived
         pat = [S((n,), jnp.bool_), S((k,), jnp.int32), S((k, k), jnp.uint8)]
-        if kind == "put_fused1":
+        if kind == "encode_words_fused1":
             return (
                 lambda x: codec_step.encode_words_fused1(
-                    x, m, L, formulation=p["formulation"],
-                    use_pallas=use_pallas,
+                    x, m, L, use_pallas=use_pallas
                 ),
                 [S((B, k, w))],
             )
-        if kind == "put_legacy":
-            return (
-                lambda x: codec_step.encode_and_hash_words_digest(x, m, L),
-                [S((B, k, w))],
-            )
-        if kind == "heal_encode":
+        if kind == "encode_and_hash_words":
             return (
                 lambda x: codec_step.encode_and_hash_words(x, m, L),
                 [S((B, k, w))],
             )
-        if kind == "read_digest":
-            return (lambda x: codec_step.digest_words(x, L), [S((B, k, w))])
-        if kind == "degraded_reconstruct":
+        if kind == "digest_words":  # B counts rows here
+            return (lambda x: codec_step.digest_words(x, L), [S((1, B, w))])
+        if kind == "reconstruct_words_batch":
             return (
                 lambda x, sv, mat: codec_step.reconstruct_words_batch(
                     x, sv, mat, k, m, use_pallas=use_pallas
                 ),
                 [S((B, n, w))] + pat[1:],
             )
-        if kind == "heal_verify_reconstruct":
+        if kind == "verify_and_reconstruct_words":
             return (
                 lambda x, d, pr, sv, mat: (
                     codec_step.verify_and_reconstruct_words(
-                        x, d, pr, sv, mat, k, m, L,
-                        formulation=p["formulation"], use_pallas=use_pallas,
+                        x, d, pr, sv, mat, k, m, L, use_pallas=use_pallas
                     )
                 ),
                 [S((B, n, w)), S((B, n, 8))] + pat,
-            )
-        if kind == "drain_group_flags":
-            return (lambda x: codec_step.group_flags(x, 256), [S((B, m, w))])
-        if kind == "drain_pack":
-            return (
-                lambda x: codec_step.pack_nonzero_groups(x, 256),
-                [S((B, m, w))],
-            )
-        cw = 65536  # MINIO_TPU_CODEC_SUBCHUNK_KB default, in words
-        assert w // cw >= 3
-        if kind == "put_subchunk":
-            return (
-                lambda c, a, o: codec_step.encode_subchunk_words(
-                    c, a, o, m, L, finalize=p["finalize"]
-                ),
-                [S((B, k, cw)), S((B, n, 8)), S(())],
-            )
-        if kind == "heal_subchunk":
-            return (
-                lambda c, a, d, o, pr, sv, mat: (
-                    codec_step.verify_reconstruct_subchunk_words(
-                        c, a, d, o, pr, sv, mat, k, m, L,
-                        finalize=p["finalize"],
-                    )
-                ),
-                [S((B, n, cw)), S((B, n, 8)), S((B, n, 8)), S(())] + pat,
             )
         raise KeyError(kind)
 
@@ -236,7 +209,7 @@ def _child() -> int:
         if kind == "mesh_verify_reconstruct":
             fn = prules.compile_kernel(
                 kind, mesh, k=k, m=m, shard_len=L,
-                formulation="swar", use_pallas=True, interpret=False,
+                use_pallas=True, interpret=False,
             )
             return fn, [
                 A((bucket, n, w), "quorum_words"),
@@ -283,7 +256,7 @@ def _child() -> int:
         return doc
 
     # XLA and Mosaic compile outside the GIL: a few threads cut the wall
-    # time of the ~70 compiles several-fold; the one x64 case runs alone
+    # time of the ~75 compiles several-fold; the one x64 case runs alone
     # (enable_x64 is thread-local, but keep the tracing context simple)
     plain = [c for c in CASES if c[1] != "select_screen"]
     with ThreadPoolExecutor(max_workers=min(8, os.cpu_count() or 1)) as ex:
@@ -324,42 +297,33 @@ def test_compiles_for_v5e(verdicts, name):
     assert doc["ok"], f"{name} does not compile for v5e: {doc['error']}"
 
 
-def test_selector_values_are_all_covered(monkeypatch):
-    """Every value the codec selector knobs still accept has a case."""
-    from minio_tpu.codec import compress
-    from minio_tpu.ops import codec_step
+def test_selector_values_are_all_covered():
+    """Every jitted entry point and mesh kernel the seam can reach has
+    a compile case, and no knob selects among them: what runs is picked
+    from the platform and the width (codec_step.pallas_dispatch)."""
+    import ast
 
-    kinds = {(c[1], c[2].get("formulation")) for c in CASES}
-    accepted = {}
-    for knob, fn, probes in (
-        ("MINIO_TPU_CODEC_KERNEL", codec_step.codec_kernel_mode,
-         ("fused1", "legacy", "pipeline")),
-        ("MINIO_TPU_CODEC_FORMULATION", codec_step.codec_formulation,
-         ("swar", "mxu", "vpu")),
-        ("MINIO_TPU_CODEC_OVERLAP", codec_step.codec_overlap_mode,
-         ("off", "async", "pipeline")),
-        ("MINIO_TPU_DEVICE_COMPRESS", compress.device_compress_mode,
-         ("off", "auto", "on", "fused")),
-    ):
-        accepted[knob] = set()
-        for v in probes:
-            monkeypatch.setenv(knob, v)
-            if fn() == v:
-                accepted[knob].add(v)
-    assert accepted == {
-        "MINIO_TPU_CODEC_KERNEL": {"fused1", "legacy"},
-        "MINIO_TPU_CODEC_FORMULATION": {"swar", "mxu"},
-        "MINIO_TPU_CODEC_OVERLAP": {"off", "async"},
-        "MINIO_TPU_DEVICE_COMPRESS": {"off", "auto", "on"},
+    from minio_tpu.analysis.kernel_contracts import KNOWN_ENTRY_POINTS
+    from minio_tpu.config.knobs import KNOBS
+
+    jitted = {name for mod, name in KNOWN_ENTRY_POINTS if mod == "codec_step"}
+    with open(os.path.join(HERE, "..", "minio_tpu", "codec", "backend.py")) as f:
+        tree = ast.parse(f.read())
+    reached = set()
+    for node in ast.walk(tree):
+        if not (isinstance(node, ast.Attribute)
+                and isinstance(node.value, ast.Name)):
+            continue
+        if node.value.id == "codec_step" and node.attr in jitted:
+            reached.add(node.attr)
+        elif node.value.id == "pm" and node.attr.startswith("mesh_"):
+            reached.add(node.attr.removesuffix("_begin").removesuffix("_end"))
+    assert {"encode_words_fused1", "digest_words", "mesh_digest"} <= reached
+    kinds = {c[1] for c in CASES}
+    assert reached <= kinds, f"no compile case for {sorted(reached - kinds)}"
+    assert {k for k in KNOBS if k.startswith("MINIO_TPU_CODEC_")} == {
+        "MINIO_TPU_CODEC_INTERPRET"
     }
-    for needed in (
-        ("put_fused1", "swar"), ("put_fused1", "mxu"), ("put_legacy", None),
-        ("heal_verify_reconstruct", "swar"),
-        ("heal_verify_reconstruct", "mxu"),
-        ("put_subchunk", None), ("heal_subchunk", None),
-        ("drain_group_flags", None), ("drain_pack", None),
-    ):
-        assert needed in kinds, f"no compile case for {needed}"
 
 
 if __name__ == "__main__":
