@@ -1187,8 +1187,10 @@ class Erasure:
         KERNEL_STATS.record_stream("heal", total_length)
 
 
-def _read_full(reader, size: int) -> bytes:
-    """Read exactly size bytes unless EOF (io.ReadFull semantics)."""
+def _read_full(reader, size: int):
+    """Read exactly size bytes unless EOF (io.ReadFull semantics).  A
+    reader that reads full itself (the request plane's) gives one piece,
+    which passes through as it is: a join would copy the block."""
     chunks = []
     got = 0
     while got < size:
@@ -1197,4 +1199,4 @@ def _read_full(reader, size: int) -> bytes:
             break
         chunks.append(chunk)
         got += len(chunk)
-    return b"".join(chunks)
+    return chunks[0] if len(chunks) == 1 else b"".join(chunks)

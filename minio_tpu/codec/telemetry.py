@@ -280,6 +280,7 @@ class KernelStats:
                 },
                 "breaker": _breaker_demotions(),
                 "meta_read": _meta_read_counts(),
+                "body_read": _body_read_counts(),
                 "stages": [
                     {
                         "op": op,
@@ -349,6 +350,9 @@ class KernelStats:
             from ..storage import xl
 
             xl.META_READ[:] = [0, 0, 0]
+            from ..server import aio
+
+            aio.BODY_READ[:] = [0, 0, 0]
 
 
 def _parity_cache_stats() -> dict:
@@ -380,6 +384,15 @@ def _meta_read_counts() -> dict:
     from ..storage import xl
 
     return xl.meta_read_counts()
+
+
+def _body_read_counts() -> dict:
+    """Request bodies handed from the event loops to their handlers
+    (server/aio.py counts them where they cross): handovers, bytes,
+    loop_reads."""
+    from ..server import aio
+
+    return aio.body_read_counts()
 
 
 # Process-wide singleton: one codec seam per process (backend.py caches

@@ -35,10 +35,17 @@ thin bridges:
 
 ``_LoopReader``
     Blocking file-like over the connection's ``asyncio.StreamReader``.
-    Each ``read(n)`` is one ``run_coroutine_threadsafe`` round-trip, so
-    a PUT body streams chunk-by-chunk from the loop straight into
-    ``HashReader`` -> ``encode_begin`` with bounded memory — the loop
-    never holds a full body and the worker never touches the socket.
+    Each ``read(n)`` is ONE ``run_coroutine_threadsafe`` round-trip and
+    reads full (``n`` bytes, fewer only at EOF, as a ``BufferedReader``
+    does): the loop collects the pieces the transport delivers into
+    the buffer the worker brought and hands it over once, so a PUT
+    body streams block-by-block into ``HashReader`` -> ``encode_begin``
+    with bounded memory (one hand-over fills at most ``_MAX_HANDOVER``;
+    the connection reads ahead ``_BODY_READAHEAD`` to twice that) and
+    the worker never touches the socket.  A 10 MiB block used to cross
+    in 40-42 pieces of one ``recv`` each, every one a wake-up of the
+    loop and of the worker; ``kernel-stats.body_read`` counts what
+    crosses now.
 
 ``_LoopWriter``
     Blocking writes through ``transport.write`` + ``drain()``.  A
@@ -85,6 +92,42 @@ _MAX_HEAD = 1 << 16
 
 # listen(2) backlog for sharded/fallback sockets (asyncio's default)
 _LISTEN_BACKLOG = 100
+
+# The most one hand-over of a body fills: an erasure block of the largest
+# block size a handler reads with, and http.py's _ChunkedReader.MAX_CHUNK.
+# A caller that asks for more gets this much and asks again (_read_full
+# and _LimitedReader loop), so no request can make a worker allocate more.
+_MAX_HANDOVER = 16 << 20
+
+# Flow control while a body is being read.  asyncio pauses the transport
+# when the StreamReader buffers more than twice its limit and resumes it
+# at the limit; under the head's 64 KiB that was a pause and a resume
+# (two epoll_ctl) around every recv of a body.  With this the connection
+# reads ahead 1-2 MiB while its handler hashes and encodes the block
+# before, and a fill that keeps the buffer drained is never paused.
+_BODY_READAHEAD = 1 << 20
+
+# Bodies handed from the loops to their handlers, over the process:
+# [handovers (cross-thread calls of a _LoopReader), bytes handed over,
+# loop_reads (reads of the StreamReader the loop made for them that gave
+# bytes: the pieces it collected)].  Plain adds under the GIL on the
+# handler's thread, no lock and no clock; kernel-stats carries them as
+# ``body_read`` (codec/telemetry.py).  Hand-overs a MiB and pieces a
+# hand-over are read from it; handovers is body_read_wait's count.
+BODY_READ = [0, 0, 0]
+
+
+def body_read_counts() -> dict:
+    handovers, nbytes, loop_reads = BODY_READ
+    return {"handovers": handovers, "bytes": nbytes, "loop_reads": loop_reads}
+
+
+def _set_flow_limit(reader: asyncio.StreamReader, limit: int) -> None:
+    """The threshold ``feed_data`` pauses the transport by and
+    ``readuntil`` caps a head by.  asyncio takes it at construction only
+    and keeps it in ``_limit``; a connection needs one value for a head
+    and another for a body."""
+    reader._limit = limit
 
 
 def _env_float(name: str, default: float) -> float:
@@ -139,25 +182,66 @@ class _LoopReader:
         self._reader = reader
 
     def _call(self, coro):
+        """One hand-over: ``coro`` runs on the loop and gives (bytes-like,
+        reads of the StreamReader it took)."""
         try:
             # the handler blocked on the loop (and, behind it, the client)
             with spans.span(spans.BODY_READ_WAIT):
                 fut = asyncio.run_coroutine_threadsafe(
                     coro, self._owner.loop
                 )
-                return fut.result()
+                data, loop_reads = fut.result()
         except asyncio.TimeoutError:
             raise socket.timeout("body read timed out") from None
         except (RuntimeError, ConnectionError, asyncio.CancelledError) as e:
             raise OSError(f"connection lost: {e}") from None
+        BODY_READ[0] += 1
+        BODY_READ[1] += len(data)
+        BODY_READ[2] += loop_reads
+        return data
 
-    def read(self, n: int = -1) -> bytes:
+    def read(self, n: int = -1):
+        """``n`` bytes, fewer only at EOF (io.ReadFull; what the
+        threaded plane's ``rfile`` gives) or above ``_MAX_HANDOVER``, in
+        one hand-over from the loop; ``read(-1)`` reads to EOF.  The
+        buffer is allocated here, on the worker, and comes back as it
+        is: a ``bytearray``, so no copy of the block is made for a
+        ``bytes``."""
+        if n == 0:
+            return b""
+        reader = self._reader
         timeout = self._owner.body_timeout
 
-        async def _rd():
-            return await asyncio.wait_for(self._reader.read(n), timeout)
+        if n < 0:
+            async def _rd():
+                return await asyncio.wait_for(reader.read(-1), timeout), 1
 
-        return self._call(_rd())
+            return self._call(_rd())
+
+        buf = bytearray(min(n, _MAX_HANDOVER))
+
+        async def _fill():
+            # never more than was asked for: what follows the body in
+            # the StreamReader is the next request's.  The timeout
+            # bounds the wait for the NEXT bytes, not the hand-over: a
+            # slow client that keeps sending is not cut
+            _set_flow_limit(reader, _BODY_READAHEAD)
+            want = len(buf)
+            got = pieces = 0
+            while got < want:
+                piece = await asyncio.wait_for(
+                    reader.read(want - got), timeout
+                )
+                if not piece:
+                    break
+                # same length on both sides: a copy in place, no resize
+                buf[got:got + len(piece)] = piece
+                got += len(piece)
+                pieces += 1
+            del buf[got:]
+            return buf, pieces
+
+        return self._call(_fill())
 
     def readline(self, limit: int = -1) -> bytes:
         """Bounded line read (internode chunked framing uses 1024)."""
@@ -173,7 +257,7 @@ class _LoopReader:
                 out += b
                 if b == b"\n":
                     break
-            return bytes(out)
+            return bytes(out), len(out)
 
         return self._call(_rl())
 
@@ -616,6 +700,8 @@ class _ServerLoop:
                 )
                 return False
         await done
+        # the next head is capped as the first was, whatever the body read
+        _set_flow_limit(reader, _MAX_HEAD)
         return not h.close_connection and not writer.is_closing()
 
     # -- helpers -----------------------------------------------------------

@@ -38,7 +38,7 @@ class _Srv:
         self.saved_env = saved_env
 
 
-def _boot(root, mode, **env):
+def _boot(root, mode, block_size=BLOCK, **env):
     env = {"MINIO_TPU_SERVER": mode, **env}
     # pin the loop count unless a test opts into multi-loop: the
     # single-pool tests (exact shed counts, backlog=1 semantics)
@@ -48,7 +48,7 @@ def _boot(root, mode, **env):
     for k, v in env.items():
         os.environ[k] = str(v)
     disks = [XLStorage(str(root / f"d{i}")) for i in range(4)]
-    ol = ErasureObjects(disks, block_size=BLOCK, min_part_size=1)
+    ol = ErasureObjects(disks, block_size=block_size, min_part_size=1)
     srv = S3Server(ol, address="127.0.0.1:0").start()
     return _Srv(srv, saved)
 
@@ -520,6 +520,351 @@ def test_put_body_streams_to_codec(leakcheck, tmp_path):
         assert g.status == 200 and g.body == body
     finally:
         srv.object_layer.put_object = orig
+        _teardown(booted)
+
+
+# -- the reader bridge: one hand-over a read(n), filled on the loop --------
+
+
+def _body_read():
+    from minio_tpu.codec.telemetry import KERNEL_STATS
+
+    snap = KERNEL_STATS.snapshot()
+    waits = sum(
+        r["count"] for r in snap["spans"] if r["name"] == "body_read_wait"
+    )
+    return {**snap["body_read"], "waits": waits}
+
+
+def _moved(before):
+    after = _body_read()
+    return {k: after[k] - before[k] for k in after}
+
+
+def test_put_10mib_crosses_in_one_handover(leakcheck, tmp_path):
+    """A signed 10 MiB PUT read with the reference's 10 MiB block: the
+    body crosses from the loop to its handler once (40-42 times when a
+    read(n) returned one recv), every byte of it, and reads back."""
+    size = 10 << 20
+    booted = _boot(tmp_path, "async", block_size=size)
+    try:
+        c = S3Client(booted.srv.endpoint)
+        assert c.make_bucket("once").status == 200
+        body = _pay(size, seed=30)
+        before = _body_read()
+        assert c.put_object("once", "block", body).status == 200
+        moved = _moved(before)
+        assert moved["handovers"] == moved["waits"] == 1
+        assert moved["bytes"] == size
+        assert 1 <= moved["loop_reads"] <= size // 4096
+        before = _body_read()
+        g = c.get_object("once", "block")
+        assert g.status == 200 and g.body == body
+        assert _moved(before) == {
+            "handovers": 0, "bytes": 0, "loop_reads": 0, "waits": 0,
+        }
+    finally:
+        _teardown(booted)
+
+
+@pytest.mark.parametrize(
+    "gap_s,stall_s,ok",
+    [(0.12, 0.0, True), (0.0, 1.5, False)],
+    ids=["trickle", "stall"],
+)
+def test_body_timeout_bounds_the_wait_for_the_next_bytes(
+    leakcheck, tmp_path, gap_s, stall_s, ok
+):
+    """MINIO_TPU_BODY_TIMEOUT_S is per wait, not per hand-over: 1 KiB
+    every 0.12 s keeps an 8 KiB read alive for twice the timeout; a
+    client that goes silent for longer than it is cut, as before."""
+    booted = _boot(
+        tmp_path, "async", block_size=1 << 20,
+        MINIO_TPU_BODY_TIMEOUT_S="0.5",
+    )
+    try:
+        c = S3Client(booted.srv.endpoint)
+        assert c.make_bucket("slow").status == 200
+        body = _pay(8192, seed=31)
+        s = _connect(booted.srv)
+        try:
+            s.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+            s.sendall(_signed_head(c, "PUT", "/slow/obj", body=body))
+            before = _body_read()
+            t0 = time.monotonic()
+            for off in range(0, len(body) // 2, 1024):
+                s.sendall(body[off:off + 1024])
+                time.sleep(gap_s)
+            time.sleep(stall_s)
+            f = s.makefile("rb")
+            if ok:
+                for off in range(len(body) // 2, len(body), 1024):
+                    s.sendall(body[off:off + 1024])
+                    time.sleep(gap_s)
+                status, _, _ = _read_response(f)
+                assert status == 200
+                assert time.monotonic() - t0 > 2 * 0.5
+                moved = _moved(before)
+                assert moved["handovers"] == 1 and moved["bytes"] == 8192
+                assert moved["loop_reads"] >= 4
+            else:
+                # as before the change: cut without a reply
+                assert _read_response(f) == (None, {}, b"")
+        finally:
+            s.close()
+        g = c.get_object("slow", "obj")
+        assert (g.status, g.body) == ((200, body) if ok else (404, g.body))
+    finally:
+        _teardown(booted)
+
+
+def test_short_body_is_incomplete_and_closes(leakcheck, tmp_path):
+    """Fewer bytes than Content-Length, then EOF: the full read comes
+    back short, the PUT fails with IncompleteBody, nothing is stored."""
+    booted = _boot(tmp_path, "async", block_size=1 << 20)
+    try:
+        c = S3Client(booted.srv.endpoint)
+        assert c.make_bucket("short").status == 200
+        body = _pay(300_000, seed=32)
+        s = _connect(booted.srv)
+        try:
+            s.sendall(_signed_head(c, "PUT", "/short/obj", body=body))
+            s.sendall(body[:-100])
+            s.shutdown(socket.SHUT_WR)
+            f = s.makefile("rb")
+            status, _, rbody = _read_response(f)
+            assert status == 400 and b"IncompleteBody" in rbody
+            assert f.read(1) == b""
+        finally:
+            s.close()
+        assert c.get_object("short", "obj").status == 404
+    finally:
+        _teardown(booted)
+
+
+@pytest.mark.parametrize(
+    "sizes", [(3000, 5000), (300_000, 200_000)], ids=["small", "pieces"]
+)
+def test_pipelined_puts_each_get_their_own_bytes(
+    leakcheck, tmp_path, sizes
+):
+    """Two PUTs written back to back: the first body's fill stops at
+    its n, so the second head and body are still in the StreamReader."""
+    booted = _boot(tmp_path, "async", block_size=1 << 20)
+    try:
+        c = S3Client(booted.srv.endpoint)
+        assert c.make_bucket("pipe2").status == 200
+        bodies = {
+            f"o{i}": _pay(n, seed=40 + i) for i, n in enumerate(sizes)
+        }
+        s = _connect(booted.srv)
+        try:
+            before = _body_read()
+            s.sendall(b"".join(
+                _signed_head(c, "PUT", f"/pipe2/{k}", body=v) + v
+                for k, v in bodies.items()
+            ))
+            f = s.makefile("rb")
+            for _ in bodies:
+                status, _, _ = _read_response(f)
+                assert status == 200
+            moved = _moved(before)
+            assert moved["handovers"] == 2
+            assert moved["bytes"] == sum(sizes)
+        finally:
+            s.close()
+        for k, v in bodies.items():
+            g = c.get_object("pipe2", k)
+            assert g.status == 200 and g.body == v
+    finally:
+        _teardown(booted)
+
+
+@pytest.mark.parametrize(
+    "head,status",
+    [
+        (b"GET /big HTTP/1.1\r\nX-Pad: " + b"a" * (70 << 10), 431),
+        (b"GET /loris HTTP/1.1\r\nHost: x", 408),
+    ],
+    ids=["oversize-431", "slow-408"],
+)
+def test_head_limits_hold_after_a_body(leakcheck, tmp_path, head, status):
+    """The body's read-ahead threshold is the body's alone: the next
+    head on the same connection is still capped at 64 KiB (431 at once,
+    not a 408 a timeout later) and still timed."""
+    booted = _boot(
+        tmp_path, "async", block_size=1 << 20,
+        MINIO_TPU_HEADER_TIMEOUT_S="1.0",
+    )
+    try:
+        c = S3Client(booted.srv.endpoint)
+        assert c.make_bucket("heads").status == 200
+        body = _pay(300_000, seed=33)
+        s = _connect(booted.srv)
+        try:
+            s.sendall(_signed_head(c, "PUT", "/heads/obj", body=body) + body)
+            f = s.makefile("rb")
+            assert _read_response(f)[0] == 200
+            t0 = time.monotonic()
+            s.sendall(head)
+            got, _, _ = _read_response(f)
+            assert got == status
+            if status == 431:
+                assert time.monotonic() - t0 < 0.9
+            assert f.read(1) == b""
+        finally:
+            s.close()
+    finally:
+        _teardown(booted)
+
+
+class _Wire:
+    """A _LoopReader over a StreamReader that the test feeds by hand: the
+    bridge alone, with no socket and no server around it."""
+
+    def __init__(self, timeout=5.0):
+        import asyncio
+
+        from minio_tpu.server import aio
+
+        self.loop = asyncio.new_event_loop()
+        self.body_timeout = timeout
+        self._thread = threading.Thread(
+            target=self.loop.run_forever, name="wire-loop", daemon=True
+        )
+        self._thread.start()
+        self.stream = asyncio.StreamReader(limit=aio._MAX_HEAD, loop=self.loop)
+        self.reader = aio._LoopReader(self, self.stream)
+
+    def feed(self, data):
+        """None is EOF."""
+        if data is None:
+            self.loop.call_soon_threadsafe(self.stream.feed_eof)
+        else:
+            self.loop.call_soon_threadsafe(self.stream.feed_data, data)
+
+    def close(self):
+        self.loop.call_soon_threadsafe(self.loop.stop)
+        self._thread.join(timeout=5)
+        assert not self._thread.is_alive()
+        self.loop.close()
+
+
+DATA = _pay(3000, seed=60)
+# fed (None is EOF), then [(n asked, bytes that come back)], then what
+# body_read moved by: hand-overs, bytes, reads on the loop
+WIRE_CASES = {
+    "reads-full": ([DATA[:1000], DATA[1000:]], [(3000, DATA)], (1, 3000, None)),
+    "stops-at-n": ([DATA], [(1000, DATA[:1000]), (2000, DATA[1000:])],
+                   (2, 3000, 2)),
+    "short-at-eof": ([DATA[:500], None], [(3000, DATA[:500]), (10, b"")],
+                     (2, 500, None)),
+    "capped": ([DATA], [(3000, DATA[:1024]), (1976, DATA[1024:2048]),
+                        (952, DATA[2048:])], (3, 3000, 3)),
+    "zero-is-no-handover": ([DATA], [(0, b"")], (0, 0, 0)),
+    "minus-one-reads-to-eof": ([DATA[:700], None], [(-1, DATA[:700])],
+                               (1, 700, 1)),
+}
+
+
+@pytest.mark.parametrize("case", WIRE_CASES)
+def test_loop_reader_reads_full(leakcheck, monkeypatch, case):
+    from minio_tpu.server import aio
+
+    fed, reads, (handovers, nbytes, loop_reads) = WIRE_CASES[case]
+    if case == "capped":
+        monkeypatch.setattr(aio, "_MAX_HANDOVER", 1024)
+    wire = _Wire()
+    try:
+        for piece in fed:
+            wire.feed(piece)
+        before = aio.body_read_counts()
+        for n, want in reads:
+            got = wire.reader.read(n)
+            assert got == want and len(got) == len(want)
+        after = aio.body_read_counts()
+        moved = {k: after[k] - before[k] for k in after}
+        assert (moved["handovers"], moved["bytes"]) == (handovers, nbytes)
+        if loop_reads is not None:
+            assert moved["loop_reads"] == loop_reads
+        # the head's cap is the connection's again only once the request
+        # is over (_handle_one): a read leaves the body's threshold set
+        if any(n > 0 for n, _ in reads):
+            assert wire.stream._limit == aio._BODY_READAHEAD
+    finally:
+        wire.close()
+
+
+def test_loop_reader_times_out_on_the_next_bytes_only(leakcheck):
+    """Bytes every 0.1 s for 0.6 s under a timeout of 0.3 s: the
+    hand-over outlasts the timeout; silence for longer raises."""
+    wire = _Wire(timeout=0.3)
+    try:
+        def trickle():
+            for off in range(0, 600, 100):
+                wire.feed(DATA[off:off + 100])
+                time.sleep(0.1)
+
+        t = threading.Thread(target=trickle, name="wire-trickle")
+        t.start()
+        try:
+            assert wire.reader.read(600) == DATA[:600]
+        finally:
+            t.join(timeout=5)
+        assert not t.is_alive()
+        with pytest.raises(socket.timeout):
+            wire.reader.read(10)
+        # and a line of chunk framing still comes a byte at a time
+        wire.feed(b"1f\r\nrest")
+        assert wire.reader.readline(1024) == b"1f\r\n"
+    finally:
+        wire.close()
+
+
+class _StreamPlane:
+    """An internode plane that takes a chunked body as a stream."""
+
+    def __init__(self):
+        self.got = None
+
+    def handle(self, tail, query, body, headers):
+        return 200, b"", {}
+
+    def handle_stream(self, tail, query, reader, headers):
+        self.got = reader.read(-1)
+        return 200, b"ok", {}
+
+
+@pytest.mark.parametrize("step", [1, 7, 1 << 16], ids=lambda n: f"step{n}")
+def test_chunked_framing_delivered_in_small_steps(leakcheck, tmp_path, step):
+    """_ChunkedReader compares raw.read(2) with CRLF and reads a chunk
+    with raw.read(want): both need a read that reads full.  Delivered a
+    byte at a time the old bridge returned one byte of the two."""
+    booted = _boot(tmp_path, "async")
+    plane = _StreamPlane()
+    booted.srv.register_internode("/testplane", plane.handle)
+    try:
+        chunks = [_pay(n, seed=50 + n) for n in (1, 300, 17)]
+        wire = b"".join(
+            b"%x\r\n%s\r\n" % (len(ch), ch) for ch in chunks
+        ) + b"0\r\n\r\n"
+        s = _connect(booted.srv)
+        try:
+            s.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+            s.sendall(
+                b"POST /testplane/v1/put HTTP/1.1\r\nHost: x\r\n"
+                b"Transfer-Encoding: chunked\r\n\r\n"
+            )
+            for off in range(0, len(wire), step):
+                s.sendall(wire[off:off + step])
+                if step < 64:
+                    time.sleep(0.001)
+            status, _, rbody = _read_response(s.makefile("rb"))
+            assert (status, rbody) == (200, b"ok")
+        finally:
+            s.close()
+        assert plane.got == b"".join(chunks)
+    finally:
         _teardown(booted)
 
 

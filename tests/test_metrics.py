@@ -419,6 +419,32 @@ def test_admin_kernel_stats_route(server, client):
     assert doc["device"]["backend"] == "tpu"
 
 
+@pytest.mark.parametrize("size", [1, 4096, 3 * 4096 + 5])
+def test_admin_kernel_stats_body_read_adds_up_over_a_put(server, client, size):
+    """``body_read`` (server/aio.py::BODY_READ, beside ``meta_read``): a PUT's
+    body crosses from the loop to its handler once a 4096-byte block of this
+    server, every byte of it, and each crossing is one ``body_read_wait``."""
+
+    def counts():
+        r = client.request("GET", f"{ADMIN}/kernel-stats")
+        assert r.status == 200, r.body
+        doc = json.loads(r.body)
+        assert set(doc["body_read"]) == {"handovers", "bytes", "loop_reads"}
+        waits = sum(
+            s["count"] for s in doc["spans"] if s["name"] == "body_read_wait"
+        )
+        return {**doc["body_read"], "waits": waits}
+
+    before = counts()
+    assert client.put_object("metrbkt", "body", b"b" * size).status == 200
+    after = counts()
+    moved = {k: after[k] - before[k] for k in after}
+    blocks = -(-size // 4096)
+    assert moved["handovers"] == moved["waits"] == blocks
+    assert moved["bytes"] == size
+    assert blocks <= moved["loop_reads"] <= size
+
+
 def test_admin_healthinfo_includes_api_stats(server, client):
     r = client.request("GET", f"{ADMIN}/healthinfo")
     assert r.status == 200, r.body
