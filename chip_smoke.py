@@ -241,13 +241,31 @@ def codec_child(seed: int, rehearse: bool) -> int:
         pallas_passes=snap["pallas_passes"],
         portable_passes=snap["portable_passes"],
     )
-    # the ragged branch: EC 12+4 cuts a 10 MiB block into 873,824-byte
-    # shards, not a multiple of the 16 KiB Pallas tile
+    # a ragged width: EC 12+4 cuts a 10 MiB block into 873,824-byte
+    # shards, 53.3 of the 16 KiB Pallas tiles.  The seam stages them at
+    # the 56-tile rung with their length an operand, so they too take
+    # the kernels (the portable form is what another platform runs)
     drive(one, 12, 4, 1, BLOCK, "ec12+4 B=1 10MiB ragged")
-    snap = KERNEL_STATS.snapshot()
+    ragged = KERNEL_STATS.snapshot()
+    if rehearse:
+        check(
+            ragged["portable_passes"].get("encode_words_fused1", 0) > 0,
+            "the rehearsal's passes were not counted as portable",
+        )
+    else:
+        for name in fused:
+            check(
+                ragged["pallas_passes"].get(name, 0)
+                > snap["pallas_passes"].get(name, 0),
+                f"the ragged width ran no Pallas-compiled pass of {name}",
+            )
+            check(
+                not ragged["portable_passes"].get(name),
+                f"the ragged width ran the portable branch of {name}",
+            )
     check(
-        snap["portable_passes"].get("encode_words_fused1", 0) > 0,
-        "the ragged width was not counted as a portable pass",
+        "917504" in ragged["ragged"]["staged_rows"],
+        f"873,824-byte rows were not staged at 56 tiles: {ragged['ragged']}",
     )
 
     if ndev > 1:
@@ -401,6 +419,21 @@ class Server:
         self._log.close()
 
 
+def width_ladder(full_shard: int) -> "list[int]":
+    """The staged widths the codec seam may launch for shards of up to
+    ``full_shard`` bytes, worked out here from the rule and not taken
+    from the program: whole 16 KiB tiles, every count up to 8, then
+    steps of an eighth of the next power of two."""
+    tile, out, t = 16384, [], 0
+    while t * tile < full_shard:
+        t += 1
+        if t > 8:
+            step = (1 << (t - 1).bit_length()) >> 3
+            t = -(-t // step) * step
+        out.append(t * tile)
+    return out
+
+
 def _free_port() -> int:
     import socket
 
@@ -495,6 +528,49 @@ def served_phases(seed: int, rehearse: bool, env: dict, workdir: str,
             bytes=sum(sizes.values()), load_seconds=round(load_s, 2),
             first_put_seconds=round(first_put1, 3),
             first_get_seconds=round(first_get1, 3), healthy_reads="identical")
+
+        # objects of every size: the 16 sizes of the benchmark's cell
+        # `mixed-randsize` (log2-uniform over 40 KiB-10 MiB, none a
+        # whole number of kernel tiles), PUT and GET once each.  Their
+        # shards are staged at rungs of the seam's width ladder with
+        # their lengths as operands, so the Pallas kernels run on them
+        # and no width outside the ladder is ever launched.
+        ragged = {
+            f"rs-{j:02d}": round(40960 * 256 ** ((j + 0.5) / 16))
+            // (16 if rehearse else 1)
+            for j in range(16)
+        }
+        before = c.admin("GET", "kernel-stats")
+
+        def put_get(key):
+            body = payload(seed, key, ragged[key])
+            r = c.request("PUT", f"/{BUCKET}/{key}", body=body)
+            check(r.status == 200, f"PUT {key} -> {r.status} {r.body[:200]!r}")
+            r = c.request("GET", f"/{BUCKET}/{key}")
+            check(r.status == 200 and r.body == body,
+                  f"ragged: GET {key} ({ragged[key]} bytes) differs from its PUT")
+
+        _parallel(put_get, list(ragged), 8)
+        after = c.admin("GET", "kernel-stats")
+        rungs = width_ladder(BLOCK // 8)
+        staged = {int(w) for w in after["ragged"]["staged_rows"]}
+        check(staged <= set(rungs),
+              f"widths launched off the ladder: {sorted(staged - set(rungs))}")
+        rose = (after["pallas_passes"].get("encode_words_fused1", 0)
+                - before["pallas_passes"].get("encode_words_fused1", 0))
+        if not rehearse:
+            check(rose >= 1, "ragged PUTs ran no Pallas encode pass: "
+                  f"{after['pallas_passes']} {after['portable_passes']}")
+            check(not after["portable_passes"].get("encode_words_fused1"),
+                  f"an encode took the portable form: {after['portable_passes']}")
+        r0, r1 = before["ragged"], after["ragged"]
+        say(event="ragged", objects=len(ragged), bytes=sum(ragged.values()),
+            pallas_encode_passes=rose, widths_true=r1["widths_true"],
+            widths_staged=sorted(staged), ladder_rungs=len(rungs),
+            pad_ratio=round((r1["staged_bytes"] - r0["staged_bytes"])
+                            / max(1, r1["true_bytes"] - r0["true_bytes"]), 4),
+            mixed_launches=r1["mixed_launches"] - r0["mixed_launches"],
+            compile_cache=after["device"]["compile_cache"])
 
         for key in ("big-01", "multi-tail", "small-4k"):
             r = c.request("HEAD", f"/{BUCKET}/{key}")

@@ -270,7 +270,11 @@ def run() -> "list[Finding]":
 
     findings: "list[Finding]" = []
     S = jax.ShapeDtypeStruct
-    u8, u32 = jnp.uint8, jnp.uint32
+    u8, u32, i32 = jnp.uint8, jnp.uint32, jnp.int32
+    # a shard's byte length is an OPERAND of every codec program, one a
+    # stripe (one a row for the digests): abstract, so one evaluation
+    # stands for every length a staged width can hold
+    LENS = S((_BATCH,), i32)
 
     def ctx(fn, default_path):
         return _ContractContext(findings, fn, default_path)
@@ -354,7 +358,7 @@ def run() -> "list[Finding]":
         c.config = cfg_str(k, m, L)
         try:
             parity, digests = codec_step.encode_and_hash_words.eval_shape(
-                S((_BATCH, k, w), u32), m, L
+                S((_BATCH, k, w), u32), m, LENS
             )
             c.shape(parity, (_BATCH, m, w), "parity")
             c.dtype(parity, "uint32", "parity")
@@ -370,7 +374,8 @@ def run() -> "list[Finding]":
         c.config = cfg_str(k, m, L)
         try:
             ok = codec_step.verify_hashes_words.eval_shape(
-                S((_BATCH, n, w), u32), S((_BATCH, n, 8), u32), L
+                S((_BATCH, n, w), u32), S((_BATCH, n, 8), u32),
+                S((_BATCH, n), i32),
             )
             c.shape(ok, (_BATCH, n), "ok mask")
             c.dtype(ok, "bool", "ok mask")
@@ -384,7 +389,7 @@ def run() -> "list[Finding]":
         c.config = cfg_str(k, m, L)
         try:
             got = codec_step.digest_words.eval_shape(
-                S((_BATCH, n, w), u32), L
+                S((_BATCH, n, w), u32), S((_BATCH, n), i32)
             )
             c.shape(got, (_BATCH, n, 8), "digests")
             c.dtype(got, "uint32", "digests")
@@ -405,7 +410,7 @@ def run() -> "list[Finding]":
             # MTPU203: word-domain round-trip — encode a batch, drop m
             # shards, reconstruct; shapes must close.
             parity, _ = codec_step.encode_and_hash_words.eval_shape(
-                S((_BATCH, k, w), u32), m, L
+                S((_BATCH, k, w), u32), m, LENS
             )
             rt = codec_step.reconstruct_words_batch.eval_shape(
                 S((_BATCH, k + parity.shape[1], w), parity.dtype),
@@ -436,7 +441,7 @@ def run() -> "list[Finding]":
         c.config = cfg_str(k, m, L) + " [portable]"
         try:
             parity, digests = codec_step.encode_words_fused1.eval_shape(
-                S((_BATCH, k, w), u32), m, L
+                S((_BATCH, k, w), u32), m, LENS
             )
             c.shape(parity, (_BATCH, m, w), "fused1 parity")
             c.dtype(parity, "uint32", "fused1 parity")
@@ -449,7 +454,7 @@ def run() -> "list[Finding]":
         c.config = cfg_str(k, m, L) + " [pallas]"
         try:
             parity, digests = codec_step.encode_words_fused1.eval_shape(
-                S((_BATCH, k, w), u32), m, L, True, True
+                S((_BATCH, k, w), u32), m, LENS, True, True
             )
             c.shape(parity, (_BATCH, m, w), "fused1 parity")
             c.dtype(parity, "uint32", "fused1 parity")
@@ -469,7 +474,7 @@ def run() -> "list[Finding]":
         try:
             data, ok = codec_step.verify_and_reconstruct_words.eval_shape(
                 S((_BATCH, n, w), u32), S((_BATCH, n, 8), u32),
-                *pattern(k, n), k, m, L,
+                *pattern(k, n), k, m, LENS,
             )
             c.shape(data, (_BATCH, k, w), "fused GET data words")
             c.dtype(data, "uint32", "fused GET data words")
@@ -477,12 +482,12 @@ def run() -> "list[Finding]":
             c.dtype(ok, "bool", "fused GET ok mask")
             # MTPU203: fused1 encode -> fused1 verify+reconstruct closes
             parity, digests = codec_step.encode_words_fused1.eval_shape(
-                S((_BATCH, k, w), u32), m, L
+                S((_BATCH, k, w), u32), m, LENS
             )
             rt, _ = codec_step.verify_and_reconstruct_words.eval_shape(
                 S((_BATCH, k + parity.shape[1], w), parity.dtype),
                 S(tuple(digests.shape), digests.dtype),
-                *pattern(k, n), k, m, L,
+                *pattern(k, n), k, m, LENS,
             )
             c.expect(
                 "MTPU203",
@@ -498,7 +503,7 @@ def run() -> "list[Finding]":
         try:
             data, ok = codec_step.verify_and_reconstruct_words.eval_shape(
                 S((_BATCH, n, w), u32), S((_BATCH, n, 8), u32),
-                *pattern(k, n), k, m, L, True, True,
+                *pattern(k, n), k, m, LENS, True, True,
             )
             c.shape(data, (_BATCH, k, w), "fused GET data words")
             c.dtype(data, "uint32", "fused GET data words")
@@ -668,7 +673,7 @@ def run() -> "list[Finding]":
         c.config = cfg_str(k, m, L)
         try:
             parity, hacc = rs_pallas.encode_hash_fused.eval_shape(
-                S((_BATCH, k, w), u32), m, True
+                S((_BATCH, k, w), u32), LENS, m, True
             )
             c.shape(parity, (_BATCH, m, w), "fused parity")
             c.dtype(parity, "uint32", "fused parity")
@@ -703,7 +708,7 @@ def run() -> "list[Finding]":
         c.config = cfg_str(k, m, L)
         try:
             data, hacc = rs_pallas.verify_reconstruct_runtime.eval_shape(
-                S((_BATCH, n, w), u32), S((k, n), u8), True
+                S((_BATCH, n, w), u32), S((k, n), u8), LENS, True
             )
             c.shape(data, (_BATCH, k, w), "fused GET data words")
             c.dtype(data, "uint32", "fused GET data words")
@@ -785,8 +790,8 @@ def run() -> "list[Finding]":
             try:
                 parity, ddig, pdig = mesh_eval(
                     "mesh_encode_hash", mode,
-                    (S((_BATCH, k, w), u32),),
-                    dict(k=k, m=m, shard_len=L),
+                    (S((_BATCH, k, w), u32), LENS),
+                    dict(k=k, m=m),
                 )
                 c.shape(parity, (_BATCH, m, w), "mesh parity words")
                 c.dtype(parity, "uint32", "mesh parity words")
@@ -814,8 +819,8 @@ def run() -> "list[Finding]":
                 # MTPU203: mesh encode -> reconstruct round-trip
                 parity, _, _ = mesh_eval(
                     "mesh_encode_hash", mesh_modes("mesh_encode_hash")[0],
-                    (S((_BATCH, k, w), u32),),
-                    dict(k=k, m=m, shard_len=L),
+                    (S((_BATCH, k, w), u32), LENS),
+                    dict(k=k, m=m),
                 )
                 surv = S((_BATCH, parity.shape[1] + (k - m), w), parity.dtype)
                 rt = mesh_eval(
@@ -840,7 +845,7 @@ def run() -> "list[Finding]":
             try:
                 out = mesh_eval(
                     "mesh_digest", mode,
-                    (S((_BATCH, w), u32),), dict(shard_len=L),
+                    (S((_BATCH, w), u32), LENS), {},
                 )
                 c.shape(out, (_BATCH, 8), "mesh digests")
                 c.dtype(out, "uint32", "mesh digests")
@@ -856,9 +861,9 @@ def run() -> "list[Finding]":
             try:
                 data, ok = mesh_eval(
                     "mesh_verify_reconstruct", mode,
-                    (S((_BATCH, n, w), u32), S((_BATCH, n, 8), u32))
+                    (S((_BATCH, n, w), u32), S((_BATCH, n, 8), u32), LENS)
                     + pattern(k, n),
-                    dict(k=k, m=m, shard_len=L),
+                    dict(k=k, m=m),
                 )
                 c.shape(data, (_BATCH, k, w), "mesh fused GET data words")
                 c.dtype(data, "uint32", "mesh fused GET data words")

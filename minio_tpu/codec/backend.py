@@ -17,7 +17,9 @@ backend verify and decode under the other.
 
 from __future__ import annotations
 
+import itertools
 import os
+import queue
 import subprocess
 import threading
 
@@ -85,6 +87,16 @@ def _record_h2d(plane: str, nbytes: int) -> None:
     from .telemetry import KERNEL_STATS
 
     KERNEL_STATS.record_h2d(plane, int(nbytes))
+
+
+def _record_ragged(lengths: np.ndarray, width: int) -> None:
+    """Account one launch of a served entry point that takes lengths
+    (encode_words_fused1, digest_words): its rows' true lengths (a
+    padding row's is 0 and is left out) and the width they were staged
+    at (kernel-stats ``ragged``)."""
+    from .telemetry import KERNEL_STATS
+
+    KERNEL_STATS.record_ragged(lengths, width)
 
 
 # ---------------------------------------------------------------------------
@@ -156,9 +168,73 @@ def patterns_seen() -> int:
 # stripes.  Padding rows are the seam's own cost: they cross the bus,
 # count in h2d, and never reach a caller.
 
+#
+# The width is the other dimension.  A shard's byte length is an OPERAND
+# of every codec program (one length a row), and the width a launch is
+# staged at is whole Pallas tiles off a ladder of its own: every tile
+# count up to WIDTH_UNIT, then steps of an eighth of the next power of
+# two (10, 12, 14, 16, 20, ... 64, 80: a full 10 MiB block of EC 8+4 is
+# 80 tiles, a rung, staged as it lies).  So the programs a geometry can
+# ever trace are width rungs x row rungs however many object sizes an
+# application PUTs, rows of different true lengths on one rung share a
+# launch, the Pallas kernels take every width, and a row is staged at
+# most a quarter wider than its tiles (the words past its length are
+# padding: the hash masks them, Reed-Solomon of zero columns is zero).
+
 LAUNCH_BYTES = 32 << 20
 LADDER_CAP = 256  # the batcher's max_batch_blocks: no launch is longer
 LADDER_UNIT = 8  # sizes up to here are their own rung
+TILE_BYTES = 16384  # of one shard row: ops/rs_pallas._TW uint32 words
+WIDTH_UNIT = 8  # tile counts up to here are their own rung
+
+
+def width_rung(nbytes: int) -> int:
+    """The staged width, in bytes, of shard rows of ``nbytes``: the
+    rung of the width ladder that holds them."""
+    tiles = max(1, -(-int(nbytes) // TILE_BYTES))
+    if tiles > WIDTH_UNIT:
+        step = (1 << (tiles - 1).bit_length()) >> 3
+        tiles = -(-tiles // step) * step
+    return tiles * TILE_BYTES
+
+
+def width_rungs(max_bytes: int) -> "list[int]":
+    """Every rung up to the one that holds ``max_bytes``, ascending: the
+    widths a geometry whose full shard is ``max_bytes`` can ever stage."""
+    out, w = [], 0
+    while w < max_bytes:
+        w = width_rung(w + 1)
+        out.append(w)
+    return out
+
+
+def at_width(arr: np.ndarray, width: int) -> np.ndarray:
+    """``arr`` with its last axis ``width`` bytes wide.  The stream
+    assembles at its backend's stage_width already and passes through;
+    a caller that did not is copied into fresh zeros (not a thread's
+    padding buffer: an encode still reads its input when begin
+    returns)."""
+    L = arr.shape[-1]
+    if width == L:
+        return arr
+    wide = np.zeros(arr.shape[:-1] + (width,), dtype=arr.dtype)
+    wide[..., :L] = arr
+    return wide
+
+
+def stripe_lengths(arr: np.ndarray, lengths) -> np.ndarray:
+    """The true shard bytes of each stripe of a (B, rows, L) array as
+    int32[B]: ``lengths`` where the caller staged wider than its rows,
+    else L for all."""
+    B, L = arr.shape[0], arr.shape[-1]
+    if lengths is None:
+        return np.full(B, L, dtype=np.int32)
+    lens = np.asarray(lengths, dtype=np.int32).reshape(-1)
+    if lens.shape != (B,) or (lens > L).any() or (lens < 0).any():
+        raise ValueError(
+            f"need {B} lengths of at most {L} bytes, got {lens.tolist()}"
+        )
+    return lens
 
 
 def launch_rows(row_bytes: int) -> int:
@@ -193,22 +269,132 @@ def _pad_buffer(shape: tuple, dtype) -> np.ndarray:
     return buf
 
 
-def _ladder_chunks(arr: np.ndarray, row_bytes: int):
-    """Cut the leading axis into launches and pad each to the ladder:
-    yields (lo, hi, host array of ladder(hi - lo) rows).  Only the last
-    launch of a call can be short of its rung, so a call fills at most
-    one padding buffer."""
+def _ladder_chunks(arr: np.ndarray, lengths: np.ndarray, row_bytes: int):
+    """Cut the leading axis into launches and pad each to the ladders:
+    yields (lo, hi, host array of ladder(hi - lo) rows at the width's
+    rung, its int32 lengths with 0 for the padding rows).  ``row_bytes``
+    counts one leading row at its staged width.  Only the last launch
+    of a call can be short of its row rung, so a call that came staged
+    at its width (codec/erasure.py stages there) fills at most one
+    padding buffer."""
     cap = launch_rows(row_bytes)
-    total = arr.shape[0]
+    total, L = arr.shape[0], arr.shape[-1]
+    width = width_rung(L)
     for lo in range(0, total, cap):
         hi = min(lo + cap, total)
         rows = ladder(hi - lo)
-        part = arr[lo:hi]
-        if rows != hi - lo:
-            padded = _pad_buffer((rows,) + arr.shape[1:], arr.dtype)
-            padded[: hi - lo] = part
+        part, lens = arr[lo:hi], lengths[lo:hi]
+        if rows != hi - lo or width != L:
+            shape = (rows,) + arr.shape[1:-1] + (width,)
+            # the thread's buffer serves the one short launch of a call;
+            # a caller that did not stage at its width is copied launch
+            # by launch, each into an array of its own (the launches of
+            # a call are read back together, after the last is issued)
+            padded = (
+                _pad_buffer(shape, arr.dtype)
+                if width == L
+                else np.zeros(shape, dtype=arr.dtype)
+            )
+            padded[: hi - lo, ..., :L] = part
             part = padded
-        yield lo, hi, part
+            lens = np.zeros(rows, dtype=np.int32)
+            lens[: hi - lo] = lengths[lo:hi]
+        yield lo, hi, part, lens
+
+
+# ---------------------------------------------------------------------------
+# Warming: a width's programs are loaded off the request's path
+# ---------------------------------------------------------------------------
+#
+# The ladders make the set of programs finite, so a server need not meet
+# each inside somebody's request.  Once it is serving (server/__main__
+# starts this after ``ready``; nothing else does, so tests and tools
+# trace only what they launch), the first launch at a staged width
+# queues that width's family - the encode at 1, 2 and 4 stripes, the
+# digest at every row rung a read settles on, the reconstruct at 1 and 2
+# stripes - and one background thread runs each on zeros, narrowest
+# width first: a load from the compile cache where the cache has it, a
+# compile where not, on a thread no request waits for.  It adapts to the
+# traffic: a deployment of one object size warms one width.
+
+_WARM_ENCODE = (1, 2, 4)  # stripes: the batcher pads a flush to a power of two
+_WARM_DIGEST = tuple(range(1, LADDER_UNIT + 1)) + (2 * LADDER_UNIT,)
+_WARM_RECONSTRUCT = (1, 2)
+
+
+class _Warmer:
+    def __init__(self, backend: "TpuBackend"):
+        self._backend = backend
+        self._seen: "set[tuple]" = set()
+        self._queue: "queue.PriorityQueue" = queue.PriorityQueue()
+        self._seq = itertools.count()
+        self._stopping = threading.Event()
+        self.loaded = 0
+        self._thread = threading.Thread(
+            target=self._run, name="codec-warmer", daemon=True
+        )
+        self._thread.start()
+
+    def note(self, *families: tuple) -> None:
+        """Families (kind, ..., width) a launch has just been made of."""
+        for family in families:
+            if family not in self._seen:
+                self._seen.add(family)
+                self._queue.put((family[-1], next(self._seq), family))
+
+    def _run(self) -> None:
+        while not self._stopping.is_set():
+            try:
+                family = self._queue.get(timeout=0.2)[2]
+            except queue.Empty:
+                continue
+            try:
+                for program in self._backend._family(family):
+                    if self._stopping.is_set():
+                        return
+                    program()
+                    self.loaded += 1
+            except Exception as exc:  # noqa: BLE001 - a request will say
+                _log.warning(
+                    "codec warm-up failed",
+                    extra=kv(family=repr(family), err=str(exc)),
+                )
+
+    def stop(self) -> None:
+        """Before the interpreter goes: a compile may not be cut short."""
+        self._stopping.set()
+        self._thread.join(timeout=120)
+
+
+def _innermost(be):
+    """The concrete backend under the batching and telemetry wrappers."""
+    while hasattr(be, "inner"):
+        be = be.inner
+    return be
+
+
+def _device_backend() -> "TpuBackend | None":
+    be = _innermost(get_backend())
+    return be if isinstance(be, TpuBackend) else None
+
+
+def start_warming() -> bool:
+    """Load the programs of every width the traffic shows, behind it
+    (see above).  False on a host codec, which compiles nothing."""
+    be = _device_backend()
+    if be is None or len(be._base_devices()) > 1:
+        return False  # a mesh builds its programs a placement
+    if be._warmer is None:
+        be._warmer = _Warmer(be)
+    return True
+
+
+def stop_warming() -> None:
+    be = _innermost(_backend)  # never resolves one just to stop it
+    warmer = getattr(be, "_warmer", None)
+    if warmer is not None:
+        be._warmer = None
+        warmer.stop()
 
 
 # ---------------------------------------------------------------------------
@@ -319,26 +505,28 @@ class _DeviceParityRef:
     until it is drained or released.
     """
 
-    __slots__ = ("_lk", "_cache", "_plane", "_host", "nbytes")
+    __slots__ = ("_lk", "_cache", "_plane", "_host", "_width", "nbytes")
 
-    def __init__(self, cache: ParityPlaneCache, plane):
+    def __init__(self, cache: ParityPlaneCache, plane, width: int):
         self._lk = threading.Lock()
         self._cache = cache
         self._plane = plane
         self._host: "np.ndarray | None" = None
+        self._width = width  # of the caller's rows, at most the plane's
         self.nbytes = int(plane.nbytes)
         cache.add(self)
 
     def drain(self) -> np.ndarray:
-        """(B, m, L) uint8 parity bytes, materialized at most once: the
-        one sanctioned eager readback of a parity plane."""
+        """(B, m, L) uint8 parity bytes at the caller's width,
+        materialized at most once: the one sanctioned eager readback of
+        a parity plane."""
         from ..ops import codec_step
 
         with self._lk:
             if self._host is None and self._plane is not None:
                 self._host = codec_step.host_words_to_bytes(
                     _host_readback(self._plane, "parity")
-                )
+                )[..., : self._width]
                 self._plane = None
                 self._cache.forget(self)
             return self._host
@@ -429,7 +617,22 @@ class CodecBackend:
     # time shows up as "codec_fused" in put_stages breakdowns.
     fused_encode = False
 
-    def encode(self, data: np.ndarray, parity_shards: int):
+    # Every op that hashes takes ``lengths``: None, or int32[B], the
+    # true shard bytes of each stripe where the caller staged its rows
+    # wider than they are (at ``stage_width``, zero past the length).
+    # ``reconstruct`` takes none: Reed-Solomon is column-wise, and a
+    # zero padding column decodes to zero.  Results come back at the
+    # width the rows came in; what lies past a stripe's length in them
+    # is padding.
+
+    def stage_width(self, nbytes: int) -> int:
+        """The width to lay shard rows of ``nbytes`` out at so that this
+        backend takes them as they lie (the stream assembles its blocks
+        there, so staging costs no second copy).  Host codecs work at
+        the exact width."""
+        return nbytes
+
+    def encode(self, data: np.ndarray, parity_shards: int, lengths=None):
         """(B, k, L) u8 -> (parity (B, m, L) u8, digests (B, k+m, 8) u32).
 
         L must be a multiple of 32.  Digest order: data rows then parity.
@@ -446,13 +649,17 @@ class CodecBackend:
         """(B, n, L) u8 + survivor mask -> (B, k, L) u8 data rows."""
         raise NotImplementedError
 
-    def digest(self, shards: np.ndarray) -> np.ndarray:
+    def digest(self, shards: np.ndarray, lengths=None) -> np.ndarray:
         """(B, n, L) u8 -> (B, n, 8) u32 phash256 digests."""
         raise NotImplementedError
 
-    def verify(self, shards: np.ndarray, digests: np.ndarray) -> np.ndarray:
+    def verify(
+        self, shards: np.ndarray, digests: np.ndarray, lengths=None
+    ) -> np.ndarray:
         """(B, n, L) u8 + (B, n, 8) digests -> (B, n) bool intact mask."""
-        return (self.digest(shards) == np.asarray(digests)).all(axis=-1)
+        return (
+            self.digest(shards, lengths) == np.asarray(digests)
+        ).all(axis=-1)
 
     def reconstruct_and_verify(
         self,
@@ -461,6 +668,7 @@ class CodecBackend:
         present: "tuple[bool, ...] | np.ndarray",
         data_shards: int,
         parity_shards: int,
+        lengths=None,
     ) -> "tuple[np.ndarray, np.ndarray]":
         """Verify digests of present shards AND decode the data rows.
 
@@ -473,7 +681,7 @@ class CodecBackend:
         """
         shards = np.ascontiguousarray(shards, dtype=np.uint8)
         pres = np.asarray(present, dtype=bool)
-        ok = self.verify(shards, digests) & pres
+        ok = self.verify(shards, digests, lengths) & pres
         return (
             self._reconstruct_from_ok(
                 shards, ok, data_shards, parity_shards
@@ -511,8 +719,10 @@ class CodecBackend:
     # backends fall back to eager evaluation - the handle IS the
     # result, and end() is free.
 
-    def encode_begin(self, data: np.ndarray, parity_shards: int):
-        return self.encode(data, parity_shards)
+    def encode_begin(
+        self, data: np.ndarray, parity_shards: int, lengths=None
+    ):
+        return self.encode(data, parity_shards, lengths)
 
     def encode_end(self, handle):
         return handle
@@ -527,8 +737,10 @@ class CodecBackend:
     # parity that is already host-resident; device backends override to
     # keep the plane on device (TpuBackend).
 
-    def encode_digest_begin(self, data: np.ndarray, parity_shards: int):
-        return self.encode_begin(data, parity_shards)
+    def encode_digest_begin(
+        self, data: np.ndarray, parity_shards: int, lengths=None
+    ):
+        return self.encode_begin(data, parity_shards, lengths)
 
     def encode_digest_end(self, handle):
         """handle -> (digests (B, k+m, 8) u32, parity ref)."""
@@ -574,6 +786,7 @@ class TpuBackend(CodecBackend):
         self._meshes: dict[tuple, object] = {}
         self._router = None
         self._router_mu = threading.Lock()
+        self._warmer: "_Warmer | None" = None  # start_warming()
 
     def _base_devices(self) -> tuple:
         import jax
@@ -641,10 +854,58 @@ class TpuBackend(CodecBackend):
                 self._router = prules.PlacementRouter(devices)
             return self._router
 
-    def encode(self, data, parity_shards):
-        return self.encode_end(self.encode_begin(data, parity_shards))
+    def stage_width(self, nbytes: int) -> int:
+        return width_rung(nbytes)
 
-    def encode_begin(self, data, parity_shards):
+    def _family(self, family: tuple):
+        """The programs of one family of a staged width, as thunks that
+        launch each on zeros exactly as the seam launches it (the same
+        statics, shapes and placement, so the program a request finds is
+        this one); launches above LAUNCH_BYTES are not the seam's."""
+        from ..ops import codec_step
+
+        kind, width = family[0], family[-1]
+        use_pallas, interpret = codec_step.pallas_dispatch(width // 4)
+
+        def zeros(*shape):
+            return self._to_device(np.zeros(shape, dtype=np.uint32))
+
+        if kind == "encode":
+            _, k, m, _ = family
+            for B in _WARM_ENCODE:
+                if B * k * width <= LAUNCH_BYTES:
+                    yield lambda B=B: codec_step.encode_words_fused1(
+                        zeros(B, k, width // 4), m, np.zeros(B, np.int32),
+                        use_pallas=use_pallas, interpret=interpret,
+                    )
+        elif kind == "digest":
+            for rows in _WARM_DIGEST:
+                if rows <= launch_rows(width):
+                    yield lambda rows=rows: codec_step.digest_words(
+                        zeros(1, rows, width // 4),
+                        np.zeros((1, rows), np.int32),
+                    )
+        elif kind == "reconstruct":
+            _, k, m, _ = family
+            survivors = np.arange(k, dtype=np.int32)
+            matrix = np.eye(k, dtype=np.uint8)
+            for B in _WARM_RECONSTRUCT:
+                if B <= launch_rows((k + m) * width):
+                    yield lambda B=B: codec_step.reconstruct_words_batch(
+                        zeros(B, k + m, width // 4), survivors, matrix, k, m,
+                        use_pallas=use_pallas, interpret=interpret,
+                    )
+
+    @staticmethod
+    def _at_rung(data: np.ndarray) -> np.ndarray:
+        return at_width(data, width_rung(data.shape[-1]))
+
+    def encode(self, data, parity_shards, lengths=None):
+        return self.encode_end(
+            self.encode_begin(data, parity_shards, lengths)
+        )
+
+    def encode_begin(self, data, parity_shards, lengths=None):
         """Asynchronous start: JAX dispatch is async, so the returned
         device arrays are futures - the H2D copy and the fused pass
         run while the caller streams the PREVIOUS batch to disk."""
@@ -652,7 +913,12 @@ class TpuBackend(CodecBackend):
 
         data = np.ascontiguousarray(data, dtype=np.uint8)
         B, k, L = data.shape
-        compiled = parity_shards > 0 and codec_step.pallas_compiled(L // 4)
+        lens = stripe_lengths(data, lengths)
+        data = self._at_rung(data)
+        width = data.shape[-1]
+        compiled = parity_shards > 0 and codec_step.pallas_compiled(
+            width // 4
+        )
         mesh = self._mesh_for(B, k)
         if mesh is not None:
             # shard_map dispatch is as async as plain jit: the mesh
@@ -664,7 +930,7 @@ class TpuBackend(CodecBackend):
             with _launch():
                 h = pm.mesh_encode_hash_begin(
                     mesh, codec_step.host_bytes_to_words(data),
-                    parity_shards, L,
+                    parity_shards, lens,
                 )
             _record_h2d("data", data.nbytes)
             # k-sharded meshes run the dynamic XLA bit-walk + all-reduce
@@ -672,14 +938,14 @@ class TpuBackend(CodecBackend):
                 "mesh_encode_hash",
                 pallas=compiled and mesh.shape["shard"] == 1,
             )
-            return _AsyncHandle("async-mesh", h)
+            return _AsyncHandle("async-mesh", (h, L))
         words = self._stage(data)
         with _launch():
             parity_w, digests = codec_step.encode_and_hash_words(
-                words, parity_shards, L
+                words, parity_shards, lens
             )
         _record_pass("encode_and_hash_words", pallas=compiled)
-        return _AsyncHandle("async", (parity_w, digests))
+        return _AsyncHandle("async", ((parity_w, digests), L))
 
     def encode_end(self, handle):
         if not isinstance(handle, _AsyncHandle):
@@ -688,25 +954,26 @@ class TpuBackend(CodecBackend):
             return handle.result
         from ..ops import codec_step
 
+        payload, L = handle.payload
         if handle.kind == "async-mesh":
             from ..parallel import mesh as pm
 
-            parity_w, digests = pm.mesh_encode_hash_end(handle.payload)
+            parity_w, digests = pm.mesh_encode_hash_end(payload)
         elif handle.kind == "async":
-            parity_w, digests = handle.payload
+            parity_w, digests = payload
         else:
             raise ValueError(
                 f"encode_end: unknown handle kind {handle.kind!r}"
             )
         parity_w = _host_readback(parity_w, "parity")
         digests = _host_readback(digests, "data")
-        result = codec_step.host_words_to_bytes(parity_w), digests
+        result = codec_step.host_words_to_bytes(parity_w)[..., :L], digests
         handle.result = result
         handle.consumed = True
         handle.payload = None  # drop the device refs
         return result
 
-    def encode_digest_begin(self, data, parity_shards):
+    def encode_digest_begin(self, data, parity_shards, lengths=None):
         """Digest-only start: the fused donated kernel keeps parity on
         device behind a ParityRef; only the 32-byte digests are
         scheduled for readback."""
@@ -719,20 +986,31 @@ class TpuBackend(CodecBackend):
             # sharded across devices): compose the eager seam, still
             # async through the mesh begin/end split
             return _AsyncHandle(
-                "digest-eager", self.encode_begin(data, parity_shards)
+                "digest-eager",
+                self.encode_begin(data, parity_shards, lengths),
             )
-        use_pallas, interpret = codec_step.pallas_dispatch(L // 4)
+        lens = stripe_lengths(data, lengths)
+        data = self._at_rung(data)
+        width = data.shape[-1]
+        use_pallas, interpret = codec_step.pallas_dispatch(width // 4)
         words = self._stage(data)
         with _launch():
             parity_w, digests = codec_step.encode_words_fused1(
                 words,
                 parity_shards,
-                L,
+                lens,
                 use_pallas=use_pallas,
                 interpret=interpret,
             )
         _record_pass("encode_words_fused1", pallas=use_pallas)
-        return _AsyncHandle("digest", (parity_w, digests))
+        _record_ragged(np.repeat(lens, k), width)
+        if self._warmer is not None and parity_shards > 0:
+            self._warmer.note(
+                ("encode", k, parity_shards, width),
+                ("digest", width),
+                ("reconstruct", k, parity_shards, width),
+            )
+        return _AsyncHandle("digest", (parity_w, digests, L))
 
     def encode_digest_end(self, handle):
         if not isinstance(handle, _AsyncHandle) or handle.kind not in (
@@ -753,11 +1031,11 @@ class TpuBackend(CodecBackend):
         else:
             # digests are the ONLY eager readback (MTPU107); parity
             # stays device-resident behind the ref
-            parity_w, digests_d = handle.payload
+            parity_w, digests_d, L = handle.payload
             digests = _host_readback(digests_d, "data")
             result = (
                 digests,
-                _DeviceParityRef(parity_plane_cache(), parity_w),
+                _DeviceParityRef(parity_plane_cache(), parity_w, L),
             )
         handle.result = result
         handle.consumed = True
@@ -779,7 +1057,8 @@ class TpuBackend(CodecBackend):
         shards = np.ascontiguousarray(shards, dtype=np.uint8)
         B, n, L = shards.shape
         survivors, matrix = decode_plan(present, data_shards, parity_shards)
-        use_pallas, interpret = codec_step.pallas_dispatch(L // 4)
+        width = width_rung(L)
+        use_pallas, interpret = codec_step.pallas_dispatch(width // 4)
         mesh = self._mesh_for(B, data_shards)
         if mesh is not None:
             from ..parallel import mesh as pm
@@ -787,7 +1066,7 @@ class TpuBackend(CodecBackend):
             with _launch():  # staging, kernel and read-back are inside
                 dw = pm.mesh_reconstruct(
                     mesh,
-                    codec_step.host_bytes_to_words(shards),
+                    codec_step.host_bytes_to_words(self._at_rung(shards)),
                     survivors,
                     matrix,
                     data_shards,
@@ -802,9 +1081,12 @@ class TpuBackend(CodecBackend):
             # k compacted survivor rows go up, k data rows come back
             _record_h2d("data", dw.nbytes)
             _record_d2h("data", dw.nbytes)
-            return codec_step.host_words_to_bytes(dw)
+            return codec_step.host_words_to_bytes(dw)[..., :L]
         launched = []
-        for lo, hi, part in _ladder_chunks(shards, n * L):
+        # the decode takes no length (column-wise), so its launches are
+        # not in ``ragged``: their rows lie at the same rungs
+        no_lengths = np.zeros(B, dtype=np.int32)
+        for lo, hi, part, _ in _ladder_chunks(shards, no_lengths, n * width):
             words = self._stage(part)
             with _launch():
                 dw = codec_step.reconstruct_words_batch(
@@ -818,27 +1100,34 @@ class TpuBackend(CodecBackend):
                 )
             _record_pass("reconstruct_words_batch", pallas=use_pallas)
             launched.append((lo, hi, dw))
+        if self._warmer is not None:
+            self._warmer.note(
+                ("reconstruct", data_shards, parity_shards, width)
+            )
         return self._gather(launched, (B, data_shards, L))
 
     @staticmethod
     def _gather(launched, shape):
         """Read back the launches of one seam call: (lo, hi, device
-        words) each, padding rows dropped.  One launch (the common
-        case) hands its buffer through as a view."""
+        words) each, padding rows and the staged width's padding
+        dropped.  One launch (the common case) hands its buffer through
+        as a view."""
         from ..ops import codec_step
 
+        L = shape[-1]
         if len(launched) == 1:
             lo, hi, dw = launched[0]
             got = codec_step.host_words_to_bytes(_host_readback(dw, "data"))
-            return got[: hi - lo]
+            return got[: hi - lo, ..., :L]
         out = np.empty(shape, dtype=np.uint8)
         for lo, hi, dw in launched:
             got = codec_step.host_words_to_bytes(_host_readback(dw, "data"))
-            out[lo:hi] = got[: hi - lo]
+            out[lo:hi] = got[: hi - lo, ..., :L]
         return out
 
     def reconstruct_and_verify(
-        self, shards, digests, present, data_shards, parity_shards
+        self, shards, digests, present, data_shards, parity_shards,
+        lengths=None,
     ):
         """Fused GET-side pass: digest checks + survivor decode in ONE
         device pass (codec_step.verify_and_reconstruct_words) on the
@@ -851,9 +1140,10 @@ class TpuBackend(CodecBackend):
         shards = np.ascontiguousarray(shards, dtype=np.uint8)
         pres = np.asarray(present, dtype=bool)
         B, n, L = shards.shape
+        lens = stripe_lengths(shards, lengths)
         survivors, matrix = decode_plan(pres, data_shards, parity_shards)
-        words = codec_step.host_bytes_to_words(shards)
-        use_pallas, interpret = codec_step.pallas_dispatch(L // 4)
+        words = codec_step.host_bytes_to_words(self._at_rung(shards))
+        use_pallas, interpret = codec_step.pallas_dispatch(words.shape[-1])
         mesh = self._mesh_for(B, data_shards)
         if mesh is not None:
             from ..parallel import mesh as pm
@@ -868,7 +1158,7 @@ class TpuBackend(CodecBackend):
                     matrix,
                     data_shards,
                     parity_shards,
-                    L,
+                    lens,
                     use_pallas=use_pallas,
                     interpret=interpret,
                 )
@@ -889,14 +1179,14 @@ class TpuBackend(CodecBackend):
                     matrix,
                     data_shards,
                     parity_shards,
-                    L,
+                    lens,
                     use_pallas=use_pallas,
                     interpret=interpret,
                 )
             _record_pass("verify_and_reconstruct_words", pallas=use_pallas)
             dw = _host_readback(dw_d, "data")
             ok = _host_readback(ok_d, None)
-        data = codec_step.host_words_to_bytes(dw)
+        data = codec_step.host_words_to_bytes(dw)[..., :L]
         bad = ~ok[:, survivors].all(axis=1)
         if bad.any():
             idxs = np.nonzero(bad)[0]
@@ -907,36 +1197,42 @@ class TpuBackend(CodecBackend):
             )
         return data, ok
 
-    def digest(self, shards):
+    def digest(self, shards, lengths=None):
         from ..ops import codec_step
 
         shards = np.ascontiguousarray(shards, dtype=np.uint8)
         B, n, L = shards.shape
+        # digests are row-local: the rows of the whole batch lie flat,
+        # each with its own length
+        rows = shards.reshape(B * n, L)
+        lens = np.repeat(stripe_lengths(shards, lengths), n)
         mesh = self._mesh_for(B * n, 1)
         if mesh is not None:
             from ..parallel import mesh as pm
 
-            words = codec_step.host_bytes_to_words(shards)
-            flat = words.reshape(B * n, -1)
+            words = codec_step.host_bytes_to_words(self._at_rung(rows))
             _record_pass("mesh_digest")
             with _launch():  # staging, kernel and read-back are inside
-                got = pm.mesh_digest(mesh, flat, L).reshape(B, n, 8)
+                got = pm.mesh_digest(mesh, words, lens).reshape(B, n, 8)
             _record_h2d("data", words.nbytes)
             _record_d2h("data", got.nbytes)
             return got
-        # digests are row-local: the rows of the whole batch lie flat,
         # (1, rows, w), and the row count walks the ladder - one
-        # program per ladder size whatever (B, n) a flush came in
-        rows = shards.reshape(B * n, L)
+        # program per (row rung, width rung) whatever (B, n) a flush
+        # came in and whatever lengths its rows have
+        width = width_rung(L)
         out = np.empty((B * n, 8), dtype=np.uint32)
         launched = []
-        for lo, hi, part in _ladder_chunks(rows, L):
+        for lo, hi, part, plens in _ladder_chunks(rows, lens, width):
             words = self._stage(part[None])
             # the healthy-read digest has no Pallas kernel: one XLA pass
             with _launch():
-                got = codec_step.digest_words(words, L)
+                got = codec_step.digest_words(words, plens[None])
             _record_pass("digest_words")
+            _record_ragged(plens, width)
             launched.append((lo, hi, got))
+        if self._warmer is not None:
+            self._warmer.note(("digest", width))
         for lo, hi, got in launched:
             out[lo:hi] = _host_readback(got, "data")[0, : hi - lo]
         return out.reshape(B, n, 8)
@@ -966,6 +1262,18 @@ class CpuBackend(CodecBackend):
     def fused_encode(self):  # type: ignore[override]
         return CpuBackend._native_ok is not False
 
+    @staticmethod
+    def _exact(arr: np.ndarray, lengths) -> None:
+        """The host codec works at the exact width (stage_width is the
+        identity here): rows staged wider than they are have no taker."""
+        if lengths is not None and (
+            stripe_lengths(arr, lengths) != arr.shape[-1]
+        ).any():
+            raise ValueError(
+                "the host codec takes rows at their exact width; got "
+                f"lengths below {arr.shape[-1]} bytes"
+            )
+
     @classmethod
     def _native_fused(cls):
         """The native module, or None after a failed build (warn-once)."""
@@ -987,10 +1295,11 @@ class CpuBackend(CodecBackend):
                 return None
         return native
 
-    def encode(self, data, parity_shards):
+    def encode(self, data, parity_shards, lengths=None):
         """Fused single-pass batch encode: ONE native call, no Python
         per-stripe loop, no full-batch concatenate copy."""
         data = np.ascontiguousarray(data, dtype=np.uint8)
+        self._exact(data, lengths)
         native = self._native_fused()
         if native is not None:
             try:
@@ -1046,7 +1355,8 @@ class CpuBackend(CodecBackend):
         return _numpy_reconstruct(shards, pres, data_shards, parity_shards)
 
     def reconstruct_and_verify(
-        self, shards, digests, present, data_shards, parity_shards
+        self, shards, digests, present, data_shards, parity_shards,
+        lengths=None,
     ):
         """Fused GET-side pass: digest checks + survivor decode in one
         native memory pass.  Optimistic: decodes from the first k
@@ -1054,6 +1364,7 @@ class CpuBackend(CodecBackend):
         mismatch among the chosen survivors, re-picks survivors from
         the verified mask and reconstructs just the hit stripes."""
         shards = np.ascontiguousarray(shards, dtype=np.uint8)
+        self._exact(shards, lengths)
         pres = np.asarray(present, dtype=bool)
         native = self._native_fused()
         if native is None:
@@ -1085,8 +1396,9 @@ class CpuBackend(CodecBackend):
             )
         return data, ok
 
-    def digest(self, shards):
+    def digest(self, shards, lengths=None):
         shards = np.ascontiguousarray(shards, dtype=np.uint8)
+        self._exact(shards, lengths)
         L = shards.shape[-1]
         words = shards.view(np.uint32)
         if CpuBackend._native_hash_ok is not False:
@@ -1209,9 +1521,7 @@ def backend_info() -> dict:
     from ..utils import jaxenv
 
     be = get_backend()
-    inner = be
-    while hasattr(inner, "inner"):
-        inner = inner.inner
+    inner = _innermost(be)
     doc = {"backend": inner.name, "batched": be is not inner}
     if isinstance(inner, CpuBackend):
         native = CpuBackend._native_fused() is not None

@@ -29,20 +29,24 @@ import time
 import numpy as np
 
 from ..utils import spans
-from .backend import CodecBackend
+from .backend import CodecBackend, at_width, stripe_lengths
 from .telemetry import KERNEL_STATS
 
 
 class _Job:
     __slots__ = (
-        "op", "key", "arrays", "result", "error", "done", "created_ns",
-        "client", "ended", "ctx",
+        "op", "key", "arrays", "lengths", "width", "result", "error",
+        "done", "created_ns", "client", "ended", "ctx",
     )
 
-    def __init__(self, op: str, key: tuple, arrays: tuple):
+    def __init__(
+        self, op: str, key: tuple, arrays: tuple, lengths, width: int
+    ):
         self.op = op
         self.key = key
-        self.arrays = arrays
+        self.arrays = arrays  # rows at the key's staged width
+        self.lengths = lengths  # int32[B]: each stripe's true bytes
+        self.width = width  # of the caller's rows: results go back at it
         self.result = None
         self.error: "BaseException | None" = None
         self.done = threading.Event()
@@ -60,22 +64,24 @@ class _Job:
 class _SlicedParityRef:
     """View of a coalesced batch's parity ref: drain pulls the PARENT
     (one shared D2H for the whole merged flush) and hands back this
-    job's rows.  release is a no-op — sibling jobs may still need the
-    parent, which stays governed by the write-back cache either way."""
+    job's rows at its width.  release is a no-op — sibling jobs may
+    still need the parent, which stays governed by the write-back cache
+    either way."""
 
-    __slots__ = ("_parent", "_lo", "_hi")
+    __slots__ = ("_parent", "_lo", "_hi", "_width")
 
-    def __init__(self, parent, lo: int, hi: int):
+    def __init__(self, parent, lo: int, hi: int, width: int):
         self._parent = parent
         self._lo = lo
         self._hi = hi
+        self._width = width
 
     @property
     def nbytes(self) -> int:
         return 0  # the parent ref carries the cache accounting
 
     def drain(self):
-        return self._parent.drain()[self._lo : self._hi]
+        return self._parent.drain()[self._lo : self._hi, :, : self._width]
 
     def release(self) -> None:
         return None
@@ -175,8 +181,21 @@ class BatchingBackend(CodecBackend):
         else:
             self._active[client] = left
 
-    def _submit(self, op: str, key: tuple, arrays: tuple):
-        job = _Job(op, key, arrays)
+    def stage_width(self, nbytes: int) -> int:
+        return self.inner.stage_width(nbytes)
+
+    def _job(self, op: str, arr, lengths, key_of) -> _Job:
+        """A job on the rung of its rows' width, so that rows of
+        different true lengths coalesce: the stream stages there itself
+        (stage_width); another caller's rows are copied there now, on
+        the caller's thread.  ``key_of(width)`` is the coalescing key."""
+        arr = np.ascontiguousarray(arr, dtype=np.uint8)
+        L = arr.shape[-1]
+        lens = stripe_lengths(arr, lengths)
+        width = self.inner.stage_width(L)
+        return _Job(op, key_of(width), (at_width(arr, width),), lens, L)
+
+    def _submit(self, job: _Job):
         with self._cv:
             self._jobs.append(job)
             self._cv.notify_all()
@@ -186,10 +205,12 @@ class BatchingBackend(CodecBackend):
             raise job.error
         return job.result
 
-    def encode(self, data, parity_shards):
-        return self.encode_end(self.encode_begin(data, parity_shards))
+    def encode(self, data, parity_shards, lengths=None):
+        return self.encode_end(
+            self.encode_begin(data, parity_shards, lengths)
+        )
 
-    def encode_begin(self, data, parity_shards):
+    def encode_begin(self, data, parity_shards, lengths=None):
         """Non-blocking submit: the job coalesces and runs on the
         dispatcher while the caller flushes its PREVIOUS batch; the
         handle resolves in encode_end (double-buffered PUT pipeline).
@@ -199,9 +220,10 @@ class BatchingBackend(CodecBackend):
         decrement NOTIFIES the dispatcher, which then flushes as soon
         as every remaining active client has submitted instead of
         sleeping out the coalesce deadline."""
-        data = np.ascontiguousarray(data, dtype=np.uint8)
-        B, k, L = data.shape
-        job = _Job("encode", (k, L, parity_shards), (data,))
+        k = data.shape[1]
+        job = self._job(
+            "encode", data, lengths, lambda w: (k, w, parity_shards)
+        )
         with self._cv:
             self._enter(job.client)
             self._jobs.append(job)
@@ -225,15 +247,16 @@ class BatchingBackend(CodecBackend):
             raise job.error
         return job.result
 
-    def encode_digest_begin(self, data, parity_shards):
+    def encode_digest_begin(self, data, parity_shards, lengths=None):
         """Digest-only twin of encode_begin: coalesces across requests
         like encode, and admission BACKS OFF while the inner backend's
         parity cache is over budget — the flush policy's cache-pressure
         term, bounding device-resident parity under concurrency."""
         self._cache_backoff()
-        data = np.ascontiguousarray(data, dtype=np.uint8)
-        B, k, L = data.shape
-        job = _Job("encode_digest", (k, L, parity_shards), (data,))
+        k = data.shape[1]
+        job = self._job(
+            "encode_digest", data, lengths, lambda w: (k, w, parity_shards)
+        )
         with self._cv:
             self._enter(job.client)
             self._jobs.append(job)
@@ -262,33 +285,36 @@ class BatchingBackend(CodecBackend):
         ):
             time.sleep(0.002)
 
-    def digest(self, shards):
-        shards = np.ascontiguousarray(shards, dtype=np.uint8)
-        B, n, L = shards.shape
+    def digest(self, shards, lengths=None):
+        n = shards.shape[1]
+        job = self._job("digest", shards, lengths, lambda w: (n, w))
         client = threading.get_ident()
         with self._cv:
             self._enter(client)
         try:
-            return self._submit("digest", (n, L), (shards,))
+            return self._submit(job)
         finally:
             with self._cv:
                 self._exit(client)
                 self._cv.notify_all()
 
     def reconstruct(self, shards, present, data_shards, parity_shards):
-        shards = np.ascontiguousarray(shards, dtype=np.uint8)
-        B, n, L = shards.shape
+        n = shards.shape[1]
         # jobs coalesce by what they decode FROM: the first k present
         # rows (a hedged read may hold a ninth, which no decode uses)
         first_k = np.flatnonzero(np.asarray(present, dtype=bool))
         pres = np.zeros(n, dtype=bool)
         pres[first_k[:data_shards]] = True
-        key = (n, L, tuple(pres.tolist()), data_shards, parity_shards)
+        pat = tuple(pres.tolist())
+        job = self._job(
+            "reconstruct", shards, None,
+            lambda w: (n, w, pat, data_shards, parity_shards),
+        )
         client = threading.get_ident()
         with self._cv:
             self._enter(client)
         try:
-            return self._submit("reconstruct", key, (shards,))
+            return self._submit(job)
         finally:
             with self._cv:
                 self._exit(client)
@@ -299,7 +325,8 @@ class BatchingBackend(CodecBackend):
         return getattr(self.inner, "fused_encode", False)
 
     def reconstruct_and_verify(
-        self, shards, digests, present, data_shards, parity_shards
+        self, shards, digests, present, data_shards, parity_shards,
+        lengths=None,
     ):
         # straight delegation, no coalescing: this op serves heal and
         # degraded reads - rare, latency-insensitive, and keyed by a
@@ -307,7 +334,7 @@ class BatchingBackend(CodecBackend):
         # The default composition would route through self.verify/
         # self.reconstruct and lose the inner fused pass.
         return self.inner.reconstruct_and_verify(
-            shards, digests, present, data_shards, parity_shards
+            shards, digests, present, data_shards, parity_shards, lengths
         )
 
     def placement_router(self):
@@ -462,11 +489,18 @@ class BatchingBackend(CodecBackend):
     def _run_group(self, op: str, key: tuple, group: "list[_Job]") -> None:
         if len(group) == 1:
             j = group[0]
-            j.result = self._call(op, key, j.arrays[0])
+            arr = j.arrays[0]
+            out = self._call(op, key, arr, j.lengths)
+            if j.width != arr.shape[-1]:  # not staged by its caller
+                out = self._rows_of(op, out, 0, arr.shape[0], j)
+            j.result = out
             j.done.set()
             return
         rows = [j.arrays[0].shape[0] for j in group]
+        # every job of a group lies at the key's staged width, whatever
+        # the true lengths of its rows: they travel beside it
         merged = np.concatenate([j.arrays[0] for j in group], axis=0)
+        lengths = np.concatenate([j.lengths for j in group])
         total = merged.shape[0]
         # device backends jit-compile per batch shape: arbitrary merged
         # sizes would each pay a fresh XLA compile (seconds).  Pad the
@@ -484,32 +518,39 @@ class BatchingBackend(CodecBackend):
                     (padded - total,) + merged.shape[1:], merged.dtype
                 )
                 merged = np.concatenate([merged, pad], axis=0)
-        out = self._call(op, key, merged)
+                lengths = np.concatenate(
+                    [lengths, np.zeros(padded - total, np.int32)]
+                )
+        out = self._call(op, key, merged, lengths)
         # split along the batch axis and fulfill each job
         offsets = np.cumsum([0] + rows)
         for i, j in enumerate(group):
-            lo, hi = offsets[i], offsets[i + 1]
-            if op == "encode":
-                parity, digests = out
-                j.result = (parity[lo:hi], digests[lo:hi])
-            elif op == "encode_digest":
-                digests, pref = out
-                j.result = (
-                    digests[lo:hi], _SlicedParityRef(pref, lo, hi)
-                )
-            else:
-                j.result = out[lo:hi]
+            j.result = self._rows_of(op, out, offsets[i], offsets[i + 1], j)
             j.done.set()
 
-    def _call(self, op: str, key: tuple, arr):
+    @staticmethod
+    def _rows_of(op: str, out, lo: int, hi: int, j: _Job):
+        """Job ``j``'s rows [lo, hi) of a flush's result, at the width
+        its caller's rows came in."""
         if op == "encode":
-            return self.inner.encode(arr, key[2])
+            parity, digests = out
+            return parity[lo:hi, :, : j.width], digests[lo:hi]
+        if op == "encode_digest":
+            digests, pref = out
+            return digests[lo:hi], _SlicedParityRef(pref, lo, hi, j.width)
+        if op == "reconstruct":
+            return out[lo:hi, :, : j.width]
+        return out[lo:hi]
+
+    def _call(self, op: str, key: tuple, arr, lengths):
+        if op == "encode":
+            return self.inner.encode(arr, key[2], lengths)
         if op == "encode_digest":
             return self.inner.encode_digest_end(
-                self.inner.encode_digest_begin(arr, key[2])
+                self.inner.encode_digest_begin(arr, key[2], lengths)
             )
         if op == "digest":
-            return self.inner.digest(arr)
+            return self.inner.digest(arr, lengths)
         if op == "reconstruct":
             n, L, present, k, m = key
             return self.inner.reconstruct(arr, present, k, m)

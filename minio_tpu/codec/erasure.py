@@ -100,13 +100,17 @@ class _Begun:
     """
 
     __slots__ = (
-        "handle", "batch", "digest_mode", "consumed", "start_block"
+        "handle", "batch", "shard_len", "digest_mode", "consumed",
+        "start_block",
     )
 
-    def __init__(self, handle, batch, digest_mode: bool,
+    def __init__(self, handle, batch, shard_len: int, digest_mode: bool,
                  start_block: int = 0):
         self.handle = handle
+        # (B, k, W): the rows at the backend's staged width W, each
+        # ``shard_len`` bytes of shard and zeros past them
         self.batch = batch
+        self.shard_len = shard_len
         self.digest_mode = digest_mode
         self.consumed = False
         # absolute index of the group's first object block: the read
@@ -410,7 +414,16 @@ class Erasure:
         started = []
         for shard_len, group_block, group in groups:
             with _staged("assemble") as sp:
-                batch = np.zeros((len(group), k, shard_len), dtype=np.uint8)
+                # laid out at the width the backend launches at (whole
+                # tiles off its ladder on a device, the exact width on
+                # the host): this fresh array is the only copy a block
+                # gets, so staging costs none; the true length travels
+                # beside it and only shard_len bytes a row reach a drive
+                batch = np.zeros(
+                    (len(group), k, be.stage_width(shard_len)),
+                    dtype=np.uint8,
+                )
+                lengths = np.full(len(group), shard_len, dtype=np.int32)
                 for bi, block in enumerate(group):
                     # one reshape scatters the whole block across its k
                     # shard rows (the per-shard slice loop was O(k) tiny
@@ -427,12 +440,14 @@ class Erasure:
             stages["assemble"] += sp.seconds
             with _staged(_codec_stage(be)) as sp:
                 handle = (
-                    be.encode_digest_begin(batch, m)
+                    be.encode_digest_begin(batch, m, lengths)
                     if digest_mode
-                    else be.encode_begin(batch, m)
+                    else be.encode_begin(batch, m, lengths)
                 )
                 started.append(
-                    _Begun(handle, batch, digest_mode, group_block)
+                    _Begun(
+                        handle, batch, shard_len, digest_mode, group_block
+                    )
                 )
             stages[_codec_stage(be)] += sp.seconds
         return started
@@ -458,7 +473,7 @@ class Erasure:
             raise
 
     @staticmethod
-    def _run_writer(w, dig_s, src, col, ds, stages):
+    def _run_writer(w, dig_s, src, col, ds, shard_len, stages):
         """Build the write job for one disk's byte run.  The interleave
         itself executes ON the iopool worker, and the closure pins only
         what this disk actually reads — its digest column plus EITHER
@@ -466,9 +481,9 @@ class Erasure:
         costs one shared array, never per-disk copies."""
         def _job():
             with _staged("assemble") as sp:
-                shard = src[:, col, :]
+                shard = src[:, col, :shard_len]
                 B = shard.shape[0]
-                run = np.empty((B, ds + shard.shape[1]), dtype=np.uint8)
+                run = np.empty((B, ds + shard_len), dtype=np.uint8)
                 run[:, :ds] = dig_s
                 run[:, ds:] = shard
             with _STAGE_LK:
@@ -480,7 +495,7 @@ class Erasure:
         return _job
 
     @staticmethod
-    def _run_parity_writer(w, dig_s, pref, col, ds, stages):
+    def _run_parity_writer(w, dig_s, pref, col, ds, shard_len, stages):
         """Parity twin of _run_writer for the digest-only path: the
         closure pins the ParityRef, not host bytes.  The first parity
         job to run pays the (memoized, possibly device-compressed) lazy
@@ -492,9 +507,9 @@ class Erasure:
             with _STAGE_LK:
                 stages["codec_drain"] += sp.seconds
             with _staged("assemble") as sp:
-                shard = par[:, col, :]
+                shard = par[:, col, :shard_len]
                 B = shard.shape[0]
-                run = np.empty((B, ds + shard.shape[1]), dtype=np.uint8)
+                run = np.empty((B, ds + shard_len), dtype=np.uint8)
                 run[:, :ds] = dig_s
                 run[:, ds:] = shard
             with _STAGE_LK:
@@ -527,7 +542,7 @@ class Erasure:
                     pref = None
             stages[_codec_stage(be)] += sp.seconds
             with _staged("assemble") as sp:
-                B, shard_len = batch.shape[0], batch.shape[2]
+                B, shard_len = batch.shape[0], rec.shard_len
                 ds = bitrot.DIGEST_SIZE
                 # digest words -> 32B frames, all (block, shard) cells at
                 # once; byte layout matches bitrot.digest_to_bytes
@@ -539,7 +554,8 @@ class Erasure:
                 # words, before any disk write settles — the next GET
                 # for this object never touches the quorum path
                 cache_ctx.populate_from_encode(
-                    rec.start_block, batch,
+                    rec.start_block,
+                    np.ascontiguousarray(batch[:, :, :shard_len]),
                     dig_u32.reshape(B, n, 8)[:, :k],
                 )
             for s in range(n):
@@ -548,7 +564,8 @@ class Erasure:
                     continue
                 if s >= k and pref is not None:
                     fn = self._run_parity_writer(
-                        w, dig[:, s, :], pref, s - k, ds, stages
+                        w, dig[:, s, :], pref, s - k, ds, shard_len,
+                        stages,
                     )
                 else:
                     fn = self._run_writer(
@@ -557,6 +574,7 @@ class Erasure:
                         batch if s < k else par,
                         s if s < k else s - k,
                         ds,
+                        shard_len,
                         stages,
                     )
                 jobs.append((s, _io_key(w), fn, B * (ds + shard_len)))
@@ -826,7 +844,7 @@ class Erasure:
                     datas = shards[:, :k, :]
                 else:
                     datas = np.zeros(
-                        (len(group), k, shard_len), dtype=np.uint8
+                        (len(group), k, shards.shape[2]), dtype=np.uint8
                     )
                     for pat, gis in patterns.items():
                         if all(pat[:k]):
@@ -851,15 +869,19 @@ class Erasure:
                 # digest-verified shards, so recompute their words —
                 # the cache only needs digests self-consistent with
                 # the rows it stores to catch in-cache rot on hit
+                rows = datas[:, :, :shard_len]
                 if bool(ok[:, :k].all()):
                     cache_ctx.admit_from_decode(
                         group[0], len(group), shard_len,
-                        datas, digests[:, :k, :],
+                        rows, digests[:, :k, :],
                     )
                 else:
                     cache_ctx.admit_from_decode(
-                        group[0], len(group), shard_len,
-                        datas, be.digest(datas),
+                        group[0], len(group), shard_len, rows,
+                        be.digest(
+                            datas,
+                            np.full(len(group), shard_len, np.int32),
+                        ),
                     )
             # raw frames die before blocks copy out
             shards = digests = ok = None
@@ -910,7 +932,11 @@ class Erasure:
         # whole group is one contiguous byte range; the tail block's
         # shorter frame is its own group and reads individually
         contiguous = frame == bitrot.frame_size(self.shard_size())
-        shards = np.zeros((g, n, shard_len), dtype=np.uint8)
+        # the frames land at the width the backend launches at (see
+        # _encode_begin_batch): zeros past shard_len, the true length
+        # beside every codec call
+        shards = np.zeros((g, n, be.stage_width(shard_len)), dtype=np.uint8)
+        lengths = np.full(g, shard_len, dtype=np.int32)
         digests = np.zeros((g, n, 8), dtype=np.uint32)
         present = np.zeros((g, n), dtype=bool)
         ok = np.zeros((g, n), dtype=bool)
@@ -1057,7 +1083,7 @@ class Erasure:
                             digests[gi, s] = bitrot.digest_from_bytes(
                                 c[: bitrot.DIGEST_SIZE]
                             )
-                            shards[gi, s] = np.frombuffer(
+                            shards[gi, s, :shard_len] = np.frombuffer(
                                 c[bitrot.DIGEST_SIZE :], dtype=np.uint8
                             )
                             present[gi, s] = True
@@ -1082,7 +1108,10 @@ class Erasure:
                     else:
                         sh_cols = shards[:, bcols]
                         dg_cols = digests[:, bcols]
-                    okb = be.verify(sh_cols, dg_cols) & present[:, bcols]
+                    okb = (
+                        be.verify(sh_cols, dg_cols, lengths)
+                        & present[:, bcols]
+                    )
                     sh_cols = dg_cols = None
                     if (okb != present[:, bcols]).any():
                         heal = True  # bitrot detected somewhere
@@ -1132,7 +1161,10 @@ class Erasure:
             shard_len = self.shard_size_padded(block_len)
             frame = bitrot.DIGEST_SIZE + shard_len
             off = self.shard_block_offset(b)
-            shards = np.zeros((1, n, shard_len), dtype=np.uint8)
+            shards = np.zeros(
+                (1, n, be.stage_width(shard_len)), dtype=np.uint8
+            )
+            lengths = np.full(1, shard_len, dtype=np.int32)
             digests = np.zeros((1, n, 8), dtype=np.uint32)
             present = np.zeros(n, dtype=bool)
 
@@ -1158,7 +1190,7 @@ class Erasure:
                 digests[0, s] = bitrot.digest_from_bytes(
                     buf[: bitrot.DIGEST_SIZE]
                 )
-                shards[0, s] = np.frombuffer(
+                shards[0, s, :shard_len] = np.frombuffer(
                     buf[bitrot.DIGEST_SIZE :], dtype=np.uint8
                 )
                 present[s] = True
@@ -1169,15 +1201,15 @@ class Erasure:
             # mesh_verify_reconstruct)
             try:
                 data, ok = be.reconstruct_and_verify(
-                    shards, digests, present, k, m
-                )  # data (1, k, L)
+                    shards, digests, present, k, m, lengths
+                )  # data (1, k, W)
             except ValueError:
-                ok = (be.verify(shards, digests)[0]) & present
+                ok = (be.verify(shards, digests, lengths)[0]) & present
                 raise QuorumError(
                     f"heal: {int(ok.sum())}/{n} shards intact, need {k}"
                 ) from None
-            parity, new_digests = be.encode(data, m)
-            full = np.concatenate([data, parity], axis=1)[0]
+            parity, new_digests = be.encode(data, m, lengths)
+            full = np.concatenate([data, parity], axis=1)[0, :, :shard_len]
             for s in range(n):
                 w = writers[s] if s < len(writers) else None
                 if w is None:

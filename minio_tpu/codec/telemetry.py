@@ -39,6 +39,8 @@ from __future__ import annotations
 import threading
 import time
 
+import numpy as np
+
 from ..utils import spans
 from .backend import CodecBackend
 
@@ -90,6 +92,18 @@ class KernelStats:
         # ragged widths, non-TPU platforms, XLA-only passes)
         self._passes: "dict[str, int]" = {}
         self._pallas_passes: "dict[str, int]" = {}
+        # launches of the served entry points that take lengths
+        # (encode_words_fused1, digest_words): real rows, the
+        # callers' shard bytes, the bytes of the width they were staged
+        # at, launches that held rows of more than one true length; and
+        # the distinct true widths seen since boot and the rows launched
+        # at each staged width (its keys are bounded by the width ladder)
+        self._ragged = dict.fromkeys(
+            ("launches", "rows", "true_bytes", "staged_bytes",
+             "mixed_launches"), 0
+        )
+        self._widths_true: "set[int]" = set()
+        self._staged_rows: "dict[int, int]" = {}
         # submesh placement: outcome ("span"|"route") -> batches, and
         # per-submesh in-flight depth (current + high-water mark)
         self._placement: "dict[str, int]" = {}
@@ -139,6 +153,25 @@ class KernelStats:
             row = self._h2d.setdefault(plane, [0, 0])
             row[0] += 1
             row[1] += nbytes
+
+    def record_ragged(self, lengths, width: int) -> None:
+        """One launch of a served entry point: the true bytes of its
+        real rows (a padding row has length 0 and is left out: it is in
+        ``h2d``) and the width they were staged at."""
+        lengths = np.asarray(lengths)
+        lengths = lengths[lengths > 0]
+        distinct = set(np.unique(lengths).tolist())
+        with self._mu:
+            r = self._ragged
+            r["launches"] += 1
+            r["rows"] += int(lengths.size)
+            r["true_bytes"] += int(lengths.sum())
+            r["staged_bytes"] += int(lengths.size) * width
+            r["mixed_launches"] += len(distinct) > 1
+            self._widths_true |= distinct
+            self._staged_rows[width] = (
+                self._staged_rows.get(width, 0) + int(lengths.size)
+            )
 
     def record_pass(self, kernel: str, pallas: bool = False) -> None:
         """One device-program launch (jitted codec pass) by entry-point
@@ -278,6 +311,17 @@ class KernelStats:
                     "patterns_seen": _patterns_seen(),
                     "matrix_cache": dict(self._plan),
                 },
+                "ragged": {
+                    **self._ragged,
+                    "widths_true": len(self._widths_true),
+                    "widths_staged": len(self._staged_rows),
+                    # rows launched by staged width: a window's widths
+                    # are those whose count moved between two snapshots
+                    "staged_rows": {
+                        str(w): n
+                        for w, n in sorted(self._staged_rows.items())
+                    },
+                },
                 "breaker": _breaker_demotions(),
                 "meta_read": _meta_read_counts(),
                 "body_read": _body_read_counts(),
@@ -342,6 +386,9 @@ class KernelStats:
             self._h2d.clear()
             self._passes.clear()
             self._pallas_passes.clear()
+            self._ragged = dict.fromkeys(self._ragged, 0)
+            self._widths_true.clear()
+            self._staged_rows.clear()
             self._placement.clear()
             self._submesh_depth.clear()
             self._submesh_depth_hwm.clear()
@@ -400,6 +447,15 @@ def _body_read_counts() -> dict:
 KERNEL_STATS = KernelStats()
 
 
+def _true_bytes(arr, lengths) -> int:
+    """The caller's bytes of a (B, rows, L) array: its rows at their
+    true lengths where the caller staged them wider (``ops.bytes`` is
+    what was asked for; ``ragged.staged_bytes`` what was launched)."""
+    if lengths is None:
+        return arr.nbytes
+    return int(np.sum(lengths)) * arr.shape[1]
+
+
 class InstrumentedBackend(CodecBackend):
     """CodecBackend decorator feeding a KernelStats registry.
 
@@ -433,19 +489,25 @@ class InstrumentedBackend(CodecBackend):
                 op, self.name, nbytes, time.monotonic() - t0
             )
 
-    def encode(self, data, parity_shards):
+    def stage_width(self, nbytes: int) -> int:
+        return self.inner.stage_width(nbytes)
+
+    def encode(self, data, parity_shards, lengths=None):
         return self._timed(
             "encode",
-            data.nbytes,
-            lambda: self.inner.encode(data, parity_shards),
+            _true_bytes(data, lengths),
+            lambda: self.inner.encode(data, parity_shards, lengths),
         )
 
-    def encode_begin(self, data, parity_shards):
+    def encode_begin(self, data, parity_shards, lengths=None):
         # async pair: dispatch time here, materialization time in
         # encode_end; recorded once, at end, as one encode call
         t0 = time.monotonic()
-        handle = self.inner.encode_begin(data, parity_shards)
-        return ("ktel", handle, time.monotonic() - t0, data.nbytes)
+        handle = self.inner.encode_begin(data, parity_shards, lengths)
+        return (
+            "ktel", handle, time.monotonic() - t0,
+            _true_bytes(data, lengths),
+        )
 
     def encode_end(self, handle):
         if not (
@@ -466,13 +528,18 @@ class InstrumentedBackend(CodecBackend):
                 dispatch_s + (time.monotonic() - t0),
             )
 
-    def encode_digest_begin(self, data, parity_shards):
+    def encode_digest_begin(self, data, parity_shards, lengths=None):
         # digest-only twin of the encode pair: same one-call recording
         # at end, under the op name "encode_digest" so the readback
         # restructure shows up as its own series next to "encode"
         t0 = time.monotonic()
-        handle = self.inner.encode_digest_begin(data, parity_shards)
-        return ("ktel", handle, time.monotonic() - t0, data.nbytes)
+        handle = self.inner.encode_digest_begin(
+            data, parity_shards, lengths
+        )
+        return (
+            "ktel", handle, time.monotonic() - t0,
+            _true_bytes(data, lengths),
+        )
 
     def encode_digest_end(self, handle):
         if not (
@@ -501,9 +568,11 @@ class InstrumentedBackend(CodecBackend):
         # batcher feature-detects the routing seam through it
         return self.inner.placement_router()
 
-    def digest(self, shards):
+    def digest(self, shards, lengths=None):
         return self._timed(
-            "digest", shards.nbytes, lambda: self.inner.digest(shards)
+            "digest",
+            _true_bytes(shards, lengths),
+            lambda: self.inner.digest(shards, lengths),
         )
 
     def reconstruct(self, shards, present, data_shards, parity_shards):
@@ -516,16 +585,18 @@ class InstrumentedBackend(CodecBackend):
         )
 
     def reconstruct_and_verify(
-        self, shards, digests, present, data_shards, parity_shards
+        self, shards, digests, present, data_shards, parity_shards,
+        lengths=None,
     ):
         # explicit delegation: the CodecBackend default would compose
         # self.verify + self.reconstruct and silently bypass the
         # inner backend's fused single-pass implementation
         return self._timed(
             "reconstruct_and_verify",
-            shards.nbytes,
+            _true_bytes(shards, lengths),
             lambda: self.inner.reconstruct_and_verify(
-                shards, digests, present, data_shards, parity_shards
+                shards, digests, present, data_shards, parity_shards,
+                lengths,
             ),
         )
 

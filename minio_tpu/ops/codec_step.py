@@ -51,13 +51,21 @@ def host_words_to_bytes(a: np.ndarray) -> np.ndarray:
     return np.ascontiguousarray(a).view(np.uint8)
 
 
-@functools.partial(jax.jit, static_argnames=("parity_shards", "shard_len"))
-def encode_and_hash_words(
-    words: jax.Array, parity_shards: int, shard_len: int
-):
+def _per_stripe(lengths, batch: int):
+    """The TRACED length operand of every codec program as int32[batch]
+    bytes, one a stripe.  A scalar stands for every stripe (a batch of
+    one width); the seam passes one length a stripe, so that rows of
+    different true lengths share a launch at their staged width."""
+    return jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (batch,))
+
+
+@functools.partial(jax.jit, static_argnames=("parity_shards",))
+def encode_and_hash_words(words: jax.Array, parity_shards: int, lengths):
     """Encode + bitrot-hash a batch of stripes in one fused pass.
 
-    words: (batch, k, w) uint32 data shards; shard_len = 4*w (bytes).
+    words: (batch, k, w) uint32 data shards at their staged width;
+    lengths: TRACED int32[batch] (or a scalar), each stripe's true shard
+    bytes - the words past them are padding, left out of the hash.
     Returns (parity, digests):
       parity:  (batch, m, w) uint32 parity shards
       digests: (batch, k+m, 8) uint32 finalized phash256 per shard
@@ -66,15 +74,14 @@ def encode_and_hash_words(
     """
     batch, k, w = words.shape
     m = parity_shards
-    if shard_len != 4 * w:
-        raise ValueError("shard_len must equal 4 * words-per-shard")
     if w % 8:
         raise ValueError("words per shard must be a multiple of 8")
+    lengths = _per_stripe(lengths, batch)
     matrix = gf.parity_matrix(k, m)
 
     if m > 0 and pallas_compiled(w):
-        parity, partials = rs_pallas.encode_hash_fused(words, m)
-        return parity, phash.finalize_partials(partials, shard_len)
+        parity, partials = rs_pallas.encode_hash_fused(words, lengths, m)
+        return parity, phash.finalize_partials(partials, lengths[:, None])
 
     # Portable path: RS is column-local, so a batch is ONE flat encode of
     # (k, B*w) - no vmap-of-small-ops - and hashing is one batched pass.
@@ -83,7 +90,9 @@ def encode_and_hash_words(
     aw = jnp.concatenate(
         [words.transpose(1, 0, 2), parity], axis=0
     )  # (n, B, w)
-    digests = phash.phash256_words_batched(aw, shard_len)  # (n, B, 8)
+    digests = phash.phash256_words_batched(
+        aw, jnp.broadcast_to(lengths, (k + m, batch))
+    )  # (n, B, 8)
     return parity.transpose(1, 0, 2), digests.transpose(1, 0, 2)
 
 
@@ -101,13 +110,15 @@ def pallas_compiled(words_per_shard: int) -> bool:
 def pallas_dispatch(words_per_shard: int) -> tuple[bool, bool]:
     """(use_pallas, interpret) statics for the fused entry points.
 
-    Tile-aligned widths run the Pallas kernel compiled on TPU;
-    MINIO_TPU_CODEC_INTERPRET=1 forces the interpreter on other
-    backends (the CI kernel-regression mode, mirroring
-    MINIO_TPU_SANITIZE).  Everything else - ragged widths, other
-    backends - takes the XLA formulation of the same math inside the
-    same jit program, and the backend counts it apart (KERNEL_STATS
-    ``portable_passes``).
+    ``words_per_shard`` is the STAGED width.  The seam stages every
+    launch at whole tiles (codec.backend.width_rung) and passes the true
+    lengths as an operand, so on a TPU every served pass is a Pallas
+    one, whatever the objects' sizes.  MINIO_TPU_CODEC_INTERPRET=1
+    forces the interpreter on other backends (the CI kernel-regression
+    mode, mirroring MINIO_TPU_SANITIZE).  Everything else - other
+    backends, a direct caller's odd width - takes the XLA formulation
+    of the same math inside the same jit program, and the backend counts
+    it apart (KERNEL_STATS ``portable_passes``).
     """
     if pallas_compiled(words_per_shard):
         return True, False
@@ -121,18 +132,13 @@ def pallas_dispatch(words_per_shard: int) -> tuple[bool, bool]:
 
 @functools.partial(
     jax.jit,
-    static_argnames=(
-        "parity_shards",
-        "shard_len",
-        "use_pallas",
-        "interpret",
-    ),
+    static_argnames=("parity_shards", "use_pallas", "interpret"),
     donate_argnums=(0,),
 )
 def encode_words_fused1(
     words: jax.Array,
     parity_shards: int,
-    shard_len: int,
+    lengths,
     use_pallas: bool = False,
     interpret: bool = False,
 ):
@@ -142,32 +148,40 @@ def encode_words_fused1(
     pallas_call (rs_pallas.encode_hash_fused); elsewhere it is one XLA
     program with the same math.
 
-    words: (B, k, w) u32, DONATED - the H2D input buffer is dead after
-    the pass, so XLA may reuse it for parity instead of allocating, and
-    the caller must not touch its jax copy again.
+    words: (B, k, w) u32 at the staged width, DONATED - the H2D input
+    buffer is dead after the pass, so XLA may reuse it for parity
+    instead of allocating, and the caller must not touch its jax copy
+    again.  lengths: TRACED int32[B] (or a scalar), each stripe's true
+    shard bytes: one program serves every length a width can hold, and
+    a stripe digests as its exact-width form does.
     Returns (parity (B, m, w) u32, digests (B, n, 8) u32 finalized).
     Only ``digests`` may be materialized eagerly (MTPU107); parity
     parks in the parity plane cache until drain.
     """
     batch, k, w = words.shape
     m = parity_shards
-    if shard_len != 4 * w:
-        raise ValueError("shard_len must equal 4 * words-per-shard")
     if w % 8:
         raise ValueError("words per shard must be a multiple of 8")
+    lengths = _per_stripe(lengths, batch)
 
     if use_pallas and m > 0 and w % rs_pallas._TW == 0:
-        parity, partials = rs_pallas.encode_hash_fused(words, m, interpret)
-        return parity, phash.finalize_partials(partials, shard_len)
+        parity, partials = rs_pallas.encode_hash_fused(
+            words, lengths, m, interpret
+        )
+        return parity, phash.finalize_partials(partials, lengths[:, None])
 
     # XLA single-program path (the bit-identity oracle for the kernel).
     # Data and parity rows hash separately: concatenating them first
     # would copy the whole batch for the sake of two tiny digest arrays
-    ddig = phash.phash256_words_batched(words, shard_len)  # (B, k, 8)
+    ddig = phash.phash256_words_batched(
+        words, jnp.broadcast_to(lengths[:, None], (batch, k))
+    )  # (B, k, 8)
     if m == 0:
         return jnp.zeros((batch, 0, w), jnp.uint32), ddig
     parity = rs._matmul_static_batch(words, gf.parity_matrix(k, m))
-    pdig = phash.phash256_words_batched(parity, shard_len)
+    pdig = phash.phash256_words_batched(
+        parity, jnp.broadcast_to(lengths[:, None], (batch, m))
+    )
     return parity, jnp.concatenate([ddig, pdig], axis=1)
 
 
@@ -233,11 +247,7 @@ def reconstruct_rows(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "data_shards",
-        "parity_shards",
-        "shard_len",
-        "use_pallas",
-        "interpret",
+        "data_shards", "parity_shards", "use_pallas", "interpret"
     ),
 )
 def verify_and_reconstruct_words(
@@ -248,7 +258,7 @@ def verify_and_reconstruct_words(
     matrix: jax.Array,
     data_shards: int,
     parity_shards: int,
-    shard_len: int,
+    lengths,
     use_pallas: bool = False,
     interpret: bool = False,
 ):
@@ -257,8 +267,10 @@ def verify_and_reconstruct_words(
     reads each shard byte once for both the bitrot check and the RS
     product.
 
-    shards: (B, n, w) u32 as read (absent rows hold garbage); digests:
-    (B, n, 8) u32 stored.  The loss pattern is three TRACED operands -
+    shards: (B, n, w) u32 as read, at the staged width (absent rows
+    hold garbage); digests: (B, n, 8) u32 stored; lengths: TRACED
+    int32[B] (or a scalar), each stripe's true shard bytes.  The loss
+    pattern is three more TRACED operands -
     present: bool[n] availability, survivors: int32[k] the rows to
     decode from, matrix: uint8[k, k] their inverse
     (gf.reconstruction_matrix) - so one program serves every pattern.
@@ -269,45 +281,63 @@ def verify_and_reconstruct_words(
     """
     k, m = data_shards, parity_shards
     B, n, w = shards.shape
-    if shard_len != 4 * w:
-        raise ValueError("shard_len must equal 4 * words-per-shard")
     _check_pattern(shards, survivors, matrix, k, m)
+    rows = jnp.broadcast_to(_per_stripe(lengths, B)[:, None], (B, n))
     if use_pallas and w % rs_pallas._TW == 0:
         data, partials = rs_pallas.verify_reconstruct_runtime(
             shards,
             expand_matrix(survivors, matrix, n),
+            rows[:, 0],
             interpret=interpret,
         )
-        got = phash.finalize_partials(partials, shard_len)
+        got = phash.finalize_partials(partials, rows)
     else:
-        got = phash.phash256_words_batched(shards, shard_len)
+        got = phash.phash256_words_batched(shards, rows)
         data = reconstruct_rows(shards, survivors, matrix, False, False)
     ok = jnp.all(got == digests, axis=-1) & present
     return data, ok
 
 
-@functools.partial(jax.jit, static_argnames=("shard_len",))
-def verify_hashes_words(
-    shards: jax.Array, digests: jax.Array, shard_len: int
-):
+def _per_row(lengths, lead: tuple):
+    """The TRACED length operand of the row-wise programs as int32 of
+    the rows' leading shape (B, n): a scalar is every row's, int32[B]
+    one a stripe, int32[B, n] one a row."""
+    ln = jnp.asarray(lengths, jnp.int32)
+    if ln.ndim == 1:
+        ln = ln[:, None]
+    return jnp.broadcast_to(ln, lead)
+
+
+@jax.jit
+def verify_hashes_words(shards: jax.Array, digests: jax.Array, lengths):
     """Recompute phash256 for (batch, n, w) uint32 shards, compare.
 
-    Returns (batch, n) bool - True where the shard is intact.  This is the
+    ``lengths`` as in digest_words.  Returns (batch, n) bool - True
+    where the shard is intact.  This is the
     read-side bitrot verification (cmd/bitrot-streaming.go:130-146 /
     xl-storage.go bitrotVerify) as one device pass over all shards.
     """
-    got = phash.phash256_words_batched(shards, shard_len)  # (B, n, 8)
+    got = phash.phash256_words_batched(
+        shards, _per_row(lengths, shards.shape[:-1])
+    )  # (B, n, 8)
     return jnp.all(got == digests, axis=-1)
 
 
-@functools.partial(jax.jit, static_argnames=("shard_len",))
-def digest_words(shards: jax.Array, shard_len: int):
+@jax.jit
+def digest_words(shards: jax.Array, lengths):
     """phash256 of (batch, n, w) uint32 shard rows -> (batch, n, 8).
+
+    lengths: TRACED true bytes of the rows (see _per_row): w is the
+    staged width, and a row padded past its length digests to the same
+    32 bytes as its exact-width form - one program a (rows, width)
+    whatever the lengths.
 
     The healthy-read bitrot pass (TpuBackend.digest/verify) as ONE XLA
     program; there is no Pallas digest-only kernel.
     """
-    return phash.phash256_words_batched(shards, shard_len)
+    return phash.phash256_words_batched(
+        shards, _per_row(lengths, shards.shape[:-1])
+    )
 
 
 @functools.partial(

@@ -142,17 +142,15 @@ def _mix_jnp(x):
     return x
 
 
-def phash256_words(words, nbytes: int):
+def phash256_words(words, nbytes):
     """Device digest of a (w,) uint32 word array -> (8,) uint32.
 
-    ``nbytes`` is the true byte length represented (static).  Word count
-    must already be a multiple of 4 (the erasure layer pads shards to
-    32-byte multiples, mirroring how the reference pads shards to
+    ``nbytes`` is the true byte length represented: an int, or a traced
+    scalar (words past it are padding and count for nothing).  Word
+    count must already be a multiple of 4 (the erasure layer pads shards
+    to 32-byte multiples, mirroring how the reference pads shards to
     ShardSize, cmd/erasure-coding.go:115-117).
     """
-    import jax
-    import jax.numpy as jnp
-
     (n,) = words.shape
     if n % _PARTS:
         raise ValueError(f"word count {n} must be a multiple of {_PARTS}")
@@ -200,16 +198,34 @@ def _fold_parts(mix):
     return acc
 
 
-def phash256_words_batched(words, nbytes: int):
+def row_words(nbytes):
+    """Per-row byte lengths -> the (..., 1) uint32 word counts that
+    ``tile_partials_batched`` masks by (a length is a whole number of
+    words: shards are padded to 32 bytes)."""
+    import jax.numpy as jnp
+
+    return (jnp.asarray(nbytes).astype(jnp.uint32) >> 2)[..., None]
+
+
+def phash256_words_batched(words, nbytes):
     """Device digest over the LAST axis: (..., w) uint32 -> (..., 8).
 
     Vectorized over leading axes with no vmap - every op is a full-size
     array op, so hashing (n_shards, batch, w) is one VPU pass.
+
+    ``nbytes`` is an int (every row is 4 * w bytes of shard, the exact-
+    width form) or an array of the leading shape, TRACED: row r holds
+    nbytes[r] bytes and is padding past them, and digests to the same
+    32 bytes as its exact-width form.
     """
-    return finalize_partials(tile_partials_batched(words, 0), nbytes)
+    if isinstance(nbytes, (int, np.integer)):
+        return finalize_partials(tile_partials_batched(words, 0), nbytes)
+    return finalize_partials(
+        tile_partials_batched(words, 0, row_words(nbytes)), nbytes
+    )
 
 
-def tile_partials_batched(words, offset):
+def tile_partials_batched(words, offset, nwords=None):
     """XOR partials of one contiguous sub-chunk over the LAST axis.
 
     words: (..., w) uint32 with w a multiple of _PARTS; offset: scalar
@@ -217,8 +233,11 @@ def tile_partials_batched(words, offset):
     sub-chunk of a stream reuses one compiled program.  offset must be
     a multiple of _PARTS (the strided word-index-mod-4 partitions must
     stay aligned across chunks); the codec sub-chunk sizing guarantees
-    this by cutting on hash-partition boundaries.  Returns (..., 8)
-    partials — XOR-fold the chunks in any order, then apply
+    this by cutting on hash-partition boundaries.  nwords: None, or
+    (..., 1) uint32 TRACED, each row's true length in words: a word at
+    or past it contributes nothing (unmasked, a zero padding word would
+    contribute ``mix((0 ^ key) * M1)``).  Returns (..., 8)
+    partials - XOR-fold the chunks in any order, then apply
     finalize_partials to obtain phash256_words_batched output.
     """
     import jax
@@ -231,16 +250,21 @@ def tile_partials_batched(words, offset):
     key = _mix_jnp(idx * _C1 + jnp.uint32(1))
     m1 = _mix_jnp((words ^ key) * _M1)
     m2 = _mix_jnp((words + key) * _M2)
+    if nwords is not None:
+        live = idx < nwords
+        m1 = jnp.where(live, m1, jnp.uint32(0))
+        m2 = jnp.where(live, m2, jnp.uint32(0))
     return jnp.concatenate([_fold_parts(m1), _fold_parts(m2)], axis=-1)
 
 
-def finalize_partials(partials, nbytes: int):
-    """Length-fold of XOR-combined tile partials: (..., 8) -> (..., 8)."""
+def finalize_partials(partials, nbytes):
+    """Length-fold of XOR-combined tile partials: (..., 8) -> (..., 8).
+    ``nbytes``: an int, or a traced array of the leading shape."""
     import jax
     import jax.numpy as jnp
 
-    return _mix_jnp(
-        partials
-        ^ jnp.uint32(nbytes) * _C1
-        + jax.lax.iota(jnp.uint32, 8)
-    )
+    if isinstance(nbytes, (int, np.integer)):
+        lenmix = jnp.uint32(nbytes) * _C1
+    else:
+        lenmix = (jnp.asarray(nbytes).astype(jnp.uint32) * _C1)[..., None]
+    return _mix_jnp(partials ^ lenmix + jax.lax.iota(jnp.uint32, 8))

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import gf, rs
 
@@ -89,19 +90,25 @@ def matmul_words(matrix: np.ndarray, words, interpret: bool):
 # ---------------------------------------------------------------------------
 
 
-def _tile_hash_partials(all_rows, i, tw: int):
+def _tile_hash_partials(all_rows, i, tw: int, nwords):
     """phash256 partials of (rows, tw) shard words at w-tile index i.
 
     Shared by every fused kernel; XOR-accumulate the (rows, 8) result
     into a revisited output block and finalize with
-    hash.finalize_partials outside the kernel.
+    hash.finalize_partials outside the kernel.  ``nwords`` is the
+    stripe's true length in words, a scalar read from SMEM: a word at or
+    past it is padding of the staged width and contributes nothing, so
+    the partials are those of the exact-width shard.
     """
     from . import hash as phash
 
-    gidx = i * tw + jax.lax.broadcasted_iota(jnp.uint32, (1, tw), 1)
+    gidx = (i * tw).astype(jnp.uint32) + jax.lax.broadcasted_iota(
+        jnp.uint32, (1, tw), 1
+    )
     key = phash._mix_jnp(gidx * phash._C1 + jnp.uint32(1))  # (1, tw)
-    m1 = phash._mix_jnp((all_rows ^ key) * phash._M1)
-    m2 = phash._mix_jnp((all_rows + key) * phash._M2)
+    live = gidx < nwords.astype(jnp.uint32)
+    m1 = jnp.where(live, phash._mix_jnp((all_rows ^ key) * phash._M1), 0)
+    m2 = jnp.where(live, phash._mix_jnp((all_rows + key) * phash._M2), 0)
 
     def red(x):
         # XOR-fold the lane dim down to 4: every halving step keeps
@@ -157,8 +164,8 @@ def _swar_rows(matrix: np.ndarray, data) -> list:
 def _encode_kernel_factory(matrix: np.ndarray, tw: int):
     m, k = matrix.shape
 
-    def kernel(data_ref, parity_ref, hacc_ref):
-        i = pl.program_id(1)
+    def kernel(nwords_ref, data_ref, parity_ref, hacc_ref):
+        b, i = pl.program_id(0), pl.program_id(1)
 
         @pl.when(i == 0)
         def _zero():
@@ -169,22 +176,41 @@ def _encode_kernel_factory(matrix: np.ndarray, tw: int):
             [data, jnp.stack(_swar_rows(matrix, data))], axis=0
         )  # (n, tw)
         parity_ref[0] = all_rows[k:]
-        hacc_ref[0] = hacc_ref[0] ^ _tile_hash_partials(all_rows, i, tw)
+        hacc_ref[0] = hacc_ref[0] ^ _tile_hash_partials(
+            all_rows, i, tw, nwords_ref[b]
+        )
 
     return kernel
 
 
+def _nwords(lengths, batch: int):
+    """Traced byte lengths int32[batch] -> the kernels' SMEM operand."""
+    if lengths.shape != (batch,):
+        raise ValueError(
+            f"need one length a stripe ({batch}), got {lengths.shape}"
+        )
+    return lengths.astype(jnp.int32) >> 2
+
+
 @functools.partial(jax.jit, static_argnames=("parity_shards", "interpret"))
-def encode_hash_fused(words, parity_shards: int, interpret: bool = False):
+def encode_hash_fused(
+    words, lengths, parity_shards: int, interpret: bool = False
+):
     """One-kernel PUT codec pass: (B, k, w) data words -> ((B, m, w)
     parity words, (B, n, 8) un-finalized phash partials covering data
     AND parity rows), ONE pallas_call.
+
+    ``lengths``: int32[B] TRACED, the true shard bytes of each stripe;
+    w is the staged width, whole tiles, and the words past a stripe's
+    length are padding the hash leaves out (scalar prefetch: the lengths
+    sit in SMEM before the grid starts).  Reed-Solomon is column-wise,
+    so the parity of zero padding columns is zero and needs no mask.
 
     Grid is (batch, w-tiles); the hash-partial output block for a stripe
     is revisited across its w-tiles and XOR-accumulated in VMEM, so HBM
     traffic is exactly data-in + parity-out (data shards never
     round-trip: the host already holds their bytes).  Finalize partials
-    with hash.finalize_partials(partials, shard_len_bytes).
+    with hash.finalize_partials(partials, lengths).
     """
     B, k, w = words.shape
     m = parity_shards
@@ -200,14 +226,17 @@ def encode_hash_fused(words, parity_shards: int, interpret: bool = False):
             jax.ShapeDtypeStruct((B, m, w), jnp.uint32),
             jax.ShapeDtypeStruct((B, n, 8), jnp.uint32),
         ),
-        grid=(B, w // _TW),
-        in_specs=[pl.BlockSpec((1, k, _TW), lambda b, i: (b, 0, i))],
-        out_specs=(
-            pl.BlockSpec((1, m, _TW), lambda b, i: (b, 0, i)),
-            pl.BlockSpec((1, n, 8), lambda b, i: (b, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, w // _TW),
+            in_specs=[pl.BlockSpec((1, k, _TW), lambda b, i, nw: (b, 0, i))],
+            out_specs=(
+                pl.BlockSpec((1, m, _TW), lambda b, i, nw: (b, 0, i)),
+                pl.BlockSpec((1, n, 8), lambda b, i, nw: (b, 0, 0)),
+            ),
         ),
         interpret=interpret,
-    )(words)
+    )(_nwords(lengths, B), words)
     return parity, hacc
 
 
@@ -264,8 +293,11 @@ def _runtime_kernel_factory(o: int, tw: int, with_hash: bool):
     (operand matrix) GF@ rows and, ``with_hash``, the phash partials of
     all s rows accumulated over the w-tiles."""
 
-    def kernel(mask_ref, sh_ref, data_ref, *hacc_ref):
-        i = pl.program_id(1)
+    def kernel(*refs):
+        # with_hash: the stripes' lengths come first (scalar prefetch)
+        # and the partials' accumulator last
+        mask_ref, sh_ref, data_ref = refs[with_hash : with_hash + 3]
+        b, i = pl.program_id(0), pl.program_id(1)
 
         def step(j, carry):
             sl = pl.ds(pl.multiple_of(j * _CH, _CH), _CH)
@@ -274,62 +306,85 @@ def _runtime_kernel_factory(o: int, tw: int, with_hash: bool):
 
         jax.lax.fori_loop(0, tw // _CH, step, 0)
         if with_hash:
-            (hacc,) = hacc_ref
+            nwords_ref, hacc = refs[0], refs[-1]
 
             @pl.when(i == 0)
             def _zero():
                 hacc[...] = jnp.zeros_like(hacc)
 
-            hacc[0] = hacc[0] ^ _tile_hash_partials(sh_ref[0], i, tw)
+            hacc[0] = hacc[0] ^ _tile_hash_partials(
+                sh_ref[0], i, tw, nwords_ref[b]
+            )
 
     return kernel
 
 
-def _runtime_call(rows, matrix, interpret, with_hash):
+def _runtime_call(rows, matrix, interpret, lengths=None):
+    """``lengths`` (int32[B] TRACED, each stripe's true shard bytes)
+    asks for the hash partials too; the product alone needs none (zero
+    padding columns decode to zero)."""
     B, s, w = rows.shape
+    with_hash = lengths is not None
     o = matrix.shape[0]
     if matrix.shape != (o, s):
         raise ValueError(f"matrix {matrix.shape} does not take {s} rows")
     if w % _TW:
         raise ValueError(f"words per shard ({w}) must be a multiple of {_TW}")
     masks = runtime_masks(matrix)
+    # *_: the scalar-prefetch ref, where the call has one
     out_shape = [jax.ShapeDtypeStruct((B, o, w), jnp.uint32)]
-    out_specs = [pl.BlockSpec((1, o, _TW), lambda b, i: (b, 0, i))]
-    if with_hash:
-        out_shape.append(jax.ShapeDtypeStruct((B, s, 8), jnp.uint32))
-        out_specs.append(pl.BlockSpec((1, s, 8), lambda b, i: (b, 0, 0)))
+    out_specs = [pl.BlockSpec((1, o, _TW), lambda b, i, *_: (b, 0, i))]
+    in_specs = [
+        pl.BlockSpec(masks.shape, lambda b, i, *_: (0, 0, 0)),
+        pl.BlockSpec((1, s, _TW), lambda b, i, *_: (b, 0, i)),
+    ]
+    grid = (B, w // _TW)
+    if not with_hash:
+        return pl.pallas_call(
+            _runtime_kernel_factory(o, _TW, False),
+            out_shape=tuple(out_shape),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=tuple(out_specs),
+            interpret=interpret,
+        )(masks, rows)
+    out_shape.append(jax.ShapeDtypeStruct((B, s, 8), jnp.uint32))
+    out_specs.append(pl.BlockSpec((1, s, 8), lambda b, i, *_: (b, 0, 0)))
     return pl.pallas_call(
-        _runtime_kernel_factory(o, _TW, with_hash),
+        _runtime_kernel_factory(o, _TW, True),
         out_shape=tuple(out_shape),
-        grid=(B, w // _TW),
-        in_specs=[
-            pl.BlockSpec(masks.shape, lambda b, i: (0, 0, 0)),
-            pl.BlockSpec((1, s, _TW), lambda b, i: (b, 0, i)),
-        ],
-        out_specs=tuple(out_specs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=tuple(out_specs),
+        ),
         interpret=interpret,
-    )(masks, rows)
+    )(_nwords(lengths, B), masks, rows)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def matmul_rows_runtime(rows, matrix, interpret: bool = False):
     """(B, s, w) u32 shard rows x TRACED (o, s) uint8 GF matrix ->
     (B, o, w), ONE pallas_call, one program whatever the matrix holds."""
-    (out,) = _runtime_call(rows, matrix, interpret, False)
+    (out,) = _runtime_call(rows, matrix, interpret)
     return out
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def verify_reconstruct_runtime(shards, matrix, interpret: bool = False):
+def verify_reconstruct_runtime(
+    shards, matrix, lengths, interpret: bool = False
+):
     """One-kernel GET codec pass with the decode matrix an operand:
     bitrot partials for every shard row + reconstruction, ONE
     pallas_call.
 
-    shards: (B, n, w) u32 as read; matrix: traced (k, n) uint8, the
-    pattern's inverse scattered to its survivors' columns (zero columns
-    for the rows that must not contribute).  Returns (data (B, k, w)
-    u32, partials (B, n, 8) u32 un-finalized - finalize and compare
-    against stored digests outside; each shard byte is read from HBM
-    exactly once for both).
+    shards: (B, n, w) u32 as read, w the staged width; matrix: traced
+    (k, n) uint8, the pattern's inverse scattered to its survivors'
+    columns (zero columns for the rows that must not contribute);
+    lengths: int32[B] TRACED, each stripe's true shard bytes.  Returns
+    (data (B, k, w) u32, partials (B, n, 8) u32 un-finalized - finalize
+    and compare against stored digests outside; each shard byte is read
+    from HBM exactly once for both).
     """
-    return _runtime_call(shards, matrix, interpret, True)
+    return _runtime_call(shards, matrix, interpret, lengths)

@@ -179,18 +179,20 @@ def _encode_seq_global(mesh: Mesh, k: int, m: int):
     return step
 
 
-def _encode_hash_local(mesh: Mesh, k: int, m: int, shard_len: int):
+def _encode_hash_local(mesh: Mesh, k: int, m: int):
     from ..ops import codec_step, hash as phash
 
     shard_n = mesh.shape["shard"]
     if shard_n == 1:
 
-        def whole(local: jax.Array):
+        def whole(local: jax.Array, lengths: jax.Array):
             # whole stripes are device-local on a stripe-only mesh: run
             # the fused single-device step (static matrix -> the Pallas
-            # kernel on TPU) instead of the dynamic bit-walk
+            # kernel on TPU) instead of the dynamic bit-walk.  lengths:
+            # (B_local,) the stripes' true shard bytes, the same traced
+            # operand the one-chip seam passes
             parity, digests = codec_step.encode_and_hash_words(
-                local, m, shard_len
+                local, m, lengths
             )
             return parity, digests[:, :k], digests[:, k:]
 
@@ -198,16 +200,21 @@ def _encode_hash_local(mesh: Mesh, k: int, m: int, shard_len: int):
 
     col_blocks = _col_blocks(gf.parity_matrix(k, m), shard_n)
 
-    def step(local: jax.Array):
-        # local: (B_local, k_local, w)
+    def step(local: jax.Array, lengths: jax.Array):
+        # local: (B_local, k_local, w); lengths: (B_local,)
         idx = jax.lax.axis_index("shard")
         my_cols = jnp.asarray(col_blocks)[idx]
         partial = jax.vmap(
             lambda wds: rs._matmul_words_dynamic(wds, my_cols)
         )(local)
         parity = xor_allreduce(partial, "shard")  # (B_local, m, w)
-        ddig = phash.phash256_words_batched(local, shard_len)
-        pdig = phash.phash256_words_batched(parity, shard_len)
+        rows = lengths[:, None]
+        ddig = phash.phash256_words_batched(
+            local, jnp.broadcast_to(rows, local.shape[:2])
+        )
+        pdig = phash.phash256_words_batched(
+            parity, jnp.broadcast_to(rows, parity.shape[:2])
+        )
         return parity, ddig, pdig
 
     return step
@@ -249,12 +256,13 @@ def _reconstruct_local(
     return step
 
 
-def _digest_global(mesh: Mesh, shard_len: int):
+def _digest_global(mesh: Mesh):
     from ..ops import hash as phash
 
-    def step(rows: jax.Array):
-        # rows: (R, w) flattened shard rows; embarrassingly parallel
-        return phash.phash256_words_batched(rows, shard_len)
+    def step(rows: jax.Array, lengths: jax.Array):
+        # rows: (R, w) flattened shard rows at their staged width,
+        # lengths: (R,) their true bytes; embarrassingly parallel
+        return phash.phash256_words_batched(rows, lengths)
 
     return step
 
@@ -263,13 +271,12 @@ def _verify_reconstruct_local(
     mesh: Mesh,
     k: int,
     m: int,
-    shard_len: int,
     use_pallas: bool = False,
     interpret: bool = False,
 ):
     from ..ops import codec_step
 
-    def step(words, digests, present, survivors, matrix):
+    def step(words, digests, lengths, present, survivors, matrix):
         # words: (B_local, n, w) quorum rows; whole stripes are
         # device-local on the stripe axis (and replicated over "shard"),
         # so the fused GET step runs per device with no collective.
@@ -282,7 +289,7 @@ def _verify_reconstruct_local(
             matrix,
             k,
             m,
-            shard_len,
+            lengths,
             use_pallas=use_pallas,
             interpret=interpret,
         )
@@ -305,7 +312,7 @@ rules.register_kernel(
 )
 rules.register_kernel(
     "mesh_encode_hash",
-    in_names=("stripe_words",),
+    in_names=("stripe_words", "stripe_lengths"),
     out_names=("parity_words", "data_digests", "parity_digests"),
     build_local=_encode_hash_local,
     # the data-words buffer is a fresh device_put per batch; donating it
@@ -320,14 +327,14 @@ rules.register_kernel(
 )
 rules.register_kernel(
     "mesh_digest",
-    in_names=("digest_rows",),
+    in_names=("digest_rows", "digest_lengths"),
     out_names=("digest_out",),
     build_global=_digest_global,
 )
 rules.register_kernel(
     "mesh_verify_reconstruct",
     in_names=(
-        "quorum_words", "quorum_digests",
+        "quorum_words", "quorum_digests", "stripe_lengths",
         "decode_present", "decode_survivors", "decode_matrix",
     ),
     out_names=("recon_words", "ok_mask"),
@@ -418,22 +425,34 @@ def _bucket_batch(batch: int, stripe: int) -> int:
     return stripe * p
 
 
+def _lengths(lengths, rows: int, words_per_row: int) -> np.ndarray:
+    """The host side of the length operand: int32[rows], a scalar (or
+    None: the rows' full width) standing for every row."""
+    if lengths is None:
+        lengths = 4 * words_per_row
+    return np.ascontiguousarray(
+        np.broadcast_to(np.asarray(lengths, dtype=np.int32), (rows,))
+    )
+
+
 def mesh_encode_hash(
-    mesh: Mesh, words: np.ndarray, parity_shards: int, shard_len: int
+    mesh: Mesh, words: np.ndarray, parity_shards: int, lengths=None
 ):
     """Mesh-parallel fused encode+digest over a batch of stripes.
 
-    words: (B, k, w) uint32 host array.  Returns (parity (B, m, w),
+    words: (B, k, w) uint32 host array at the staged width; lengths:
+    int32[B] (or one int for all, or None: 4 * w), the stripes' true
+    shard bytes.  Returns (parity (B, m, w),
     digests (B, k+m, 8)) as numpy, digest rows in data-then-parity order
     (the contract of ops.codec_step.encode_and_hash_words).
     """
     return mesh_encode_hash_end(
-        mesh_encode_hash_begin(mesh, words, parity_shards, shard_len)
+        mesh_encode_hash_begin(mesh, words, parity_shards, lengths)
     )
 
 
 def mesh_encode_hash_begin(
-    mesh: Mesh, words: np.ndarray, parity_shards: int, shard_len: int
+    mesh: Mesh, words: np.ndarray, parity_shards: int, lengths=None
 ):
     """Dispatch the mesh encode+digest WITHOUT synchronizing.
 
@@ -447,14 +466,15 @@ def mesh_encode_hash_begin(
     The device copy of ``words`` is donated to the kernel (the host
     array is untouched; only the fresh on-device buffer is recycled).
     """
-    B, k, _ = words.shape
+    B, k, w = words.shape
     stripe = mesh.shape["stripe"]
-    words = _pad_batch(words, _bucket_batch(B, stripe))
-    fn = rules.compile_kernel(
-        "mesh_encode_hash", mesh, k=k, m=parity_shards, shard_len=shard_len
-    )
+    rows = _bucket_batch(B, stripe)
+    lens = _pad_batch(_lengths(lengths, B, w), rows)
+    words = _pad_batch(words, rows)
+    fn = rules.compile_kernel("mesh_encode_hash", mesh, k=k, m=parity_shards)
     dd = put_sharded(mesh, words, rules.spec_for("stripe_words"))
-    parity, ddig, pdig = fn(dd)
+    dl = put_sharded(mesh, lens, rules.spec_for("stripe_lengths"))
+    parity, ddig, pdig = fn(dd, dl)
     return parity, ddig, pdig, B
 
 
@@ -509,14 +529,15 @@ def mesh_verify_reconstruct(
     matrix: np.ndarray,
     data_shards: int,
     parity_shards: int,
-    shard_len: int,
+    lengths=None,
     use_pallas: bool = False,
     interpret: bool = False,
 ) -> tuple[np.ndarray, np.ndarray]:
     """Mesh-parallel fused GET step: verify digests + reconstruct, one program.
 
-    words: (B, n, w) quorum rows, digests: (B, n, 8) expected phash256 -
-    both sharded over "stripe"; present (bool[n]), survivors (int32[k])
+    words: (B, n, w) quorum rows, digests: (B, n, 8) expected phash256,
+    lengths: int32[B] the stripes' true shard bytes (one int for all;
+    None: 4 * w) - all sharded over "stripe"; present (bool[n]), survivors (int32[k])
     and matrix (uint8[k, k]) are the pattern's operands, whole on every
     device.  Returns ((B, k, w) data, (B, n) ok mask).
     Padded stripes hash to garbage and come back ok=False; the [:B] slice
@@ -528,6 +549,7 @@ def mesh_verify_reconstruct(
     B = words.shape[0]
     stripe = mesh.shape["stripe"]
     rows = _bucket_batch(B, stripe)
+    lens = _pad_batch(_lengths(lengths, B, words.shape[-1]), rows)
     words = _pad_batch(words, rows)
     digests = _pad_batch(digests, rows)
     fn = rules.compile_kernel(
@@ -535,7 +557,6 @@ def mesh_verify_reconstruct(
         mesh,
         k=k,
         m=m,
-        shard_len=shard_len,
         use_pallas=use_pallas,
         interpret=interpret,
     )
@@ -544,6 +565,7 @@ def mesh_verify_reconstruct(
     data, ok = fn(
         dw,
         dg,
+        put_sharded(mesh, lens, rules.spec_for("stripe_lengths")),
         np.asarray(present, dtype=bool),
         np.asarray(survivors, dtype=np.int32),
         np.asarray(matrix, dtype=np.uint8),
@@ -551,15 +573,20 @@ def mesh_verify_reconstruct(
     return np.asarray(data)[:B], np.asarray(ok)[:B]
 
 
-def mesh_digest(mesh: Mesh, words: np.ndarray, shard_len: int) -> np.ndarray:
+def mesh_digest(mesh: Mesh, words: np.ndarray, lengths=None) -> np.ndarray:
     """Mesh-parallel phash256: (R, w) uint32 rows -> (R, 8) digests.
 
-    Rows (any flattened batch of shards) are spread over every device on
-    both axes - digesting is embarrassingly parallel.
+    Rows (any flattened batch of shards, at their staged width; lengths:
+    int32[R] their true bytes, one int for all, None: 4 * w) are spread
+    over every device on both axes - digesting is embarrassingly
+    parallel.
     """
-    R = words.shape[0]
+    R, w = words.shape
     n_dev = mesh.devices.size
-    words = _pad_batch(words, _bucket_batch(R, n_dev))
-    fn = rules.compile_kernel("mesh_digest", mesh, shard_len=shard_len)
+    rows = _bucket_batch(R, n_dev)
+    lens = _pad_batch(_lengths(lengths, R, w), rows)
+    words = _pad_batch(words, rows)
+    fn = rules.compile_kernel("mesh_digest", mesh)
     dd = put_sharded(mesh, words, rules.spec_for("digest_rows"))
-    return np.asarray(fn(dd))[:R]
+    dl = put_sharded(mesh, lens, rules.spec_for("digest_lengths"))
+    return np.asarray(fn(dd, dl))[:R]

@@ -86,6 +86,11 @@ PARTITION_RULES: tuple[tuple[str, PartitionSpec], ...] = (
     # shard rows of a stripe stay together - the bitrot check is
     # row-local but the decode needs every survivor row
     (r"^quorum_(words|digests)$", PartitionSpec("stripe", None, None)),
+    # (B,) the stripes' true shard bytes follow their stripes; (R,) the
+    # digest rows' follow their rows.  A length is an operand: the
+    # planes lie at a staged width, the programs are one a width
+    (r"^stripe_lengths$", PartitionSpec("stripe")),
+    (r"^digest_lengths$", PartitionSpec(("stripe", "shard"))),
     # the loss pattern's operands (present bool[n], survivors int32[k],
     # matrix uint8[k, k]): a few bytes, whole on every device
     (r"^decode_(present|survivors)$", PartitionSpec(None)),

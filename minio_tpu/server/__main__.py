@@ -649,8 +649,14 @@ def main(argv=None) -> int:
         "online",
         extra=log.kv(endpoint=srv.endpoint, zones=zcount),
     )
+    # serving: from here the codec loads the programs of every shard
+    # width the traffic shows behind it, off the requests' path
+    from ..codec import backend as backend_mod
+
+    backend_mod.start_warming()
     stop = signal.sigwait([signal.SIGINT, signal.SIGTERM])
     print(f"signal {stop}, shutting down")
+    backend_mod.stop_warming()
     # graceful teardown order: drain in-flight requests first (their
     # handlers release their own locks), stop heal/crawler/monitor
     # threads (inside srv.shutdown), THEN unwind whatever dsync grants

@@ -569,14 +569,15 @@ def test_put_10mib_crosses_in_one_handover(leakcheck, tmp_path):
 
 @pytest.mark.parametrize(
     "gap_s,stall_s,ok",
-    [(0.12, 0.0, True), (0.0, 1.5, False)],
+    [(0.13, 0.0, True), (0.0, 1.5, False)],
     ids=["trickle", "stall"],
 )
 def test_body_timeout_bounds_the_wait_for_the_next_bytes(
     leakcheck, tmp_path, gap_s, stall_s, ok
 ):
     """MINIO_TPU_BODY_TIMEOUT_S is per wait, not per hand-over: 1 KiB
-    every 0.12 s keeps an 8 KiB read alive for twice the timeout; a
+    every 0.13 s (eight sleeps: 1.04 s, whatever the scheduler adds)
+    keeps an 8 KiB read alive for twice the timeout; a
     client that goes silent for longer than it is cut, as before."""
     booted = _boot(
         tmp_path, "async", block_size=1 << 20,

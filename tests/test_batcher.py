@@ -18,13 +18,13 @@ class _CountingBackend(CpuBackend):
         self.digest_calls = 0
         self.reconstruct_calls = 0
 
-    def encode(self, data, m):
+    def encode(self, data, m, lengths=None):
         self.encode_calls += 1
-        return super().encode(data, m)
+        return super().encode(data, m, lengths)
 
-    def digest(self, shards):
+    def digest(self, shards, lengths=None):
         self.digest_calls += 1
-        return super().digest(shards)
+        return super().digest(shards, lengths)
 
     def reconstruct(self, shards, present, k, m):
         self.reconstruct_calls += 1

@@ -279,9 +279,12 @@ def test_encode_pipeline_overlaps_batches():
         def __init__(self):
             self.inner = backend_mod.get_backend()
 
-        def encode_begin(self, data, parity_shards):
+        def stage_width(self, nbytes):
+            return self.inner.stage_width(nbytes)
+
+        def encode_begin(self, data, parity_shards, lengths=None):
             events.append(("begin", data.shape[0]))
-            return self.inner.encode(data, parity_shards)
+            return self.inner.encode(data, parity_shards, lengths)
 
         def encode_end(self, handle):
             events.append(("end",))

@@ -270,8 +270,9 @@ def test_put_seam_pallas_batches_match_cpu_backend(
 def test_pallas_and_portable_passes_counted_apart(
     single_device, monkeypatch
 ):
-    """A tile-aligned batch under the interpreter counts as a Pallas
-    pass; a ragged one (and the XLA-only digest) as portable."""
+    """Under the interpreter every width counts as a Pallas pass - the
+    seam stages a ragged one at whole tiles and passes its length - and
+    the XLA-only digest as portable."""
     monkeypatch.setenv("MINIO_TPU_CODEC_INTERPRET", "1")
     be = TpuBackend()
     KERNEL_STATS.reset()
@@ -283,11 +284,11 @@ def test_pallas_and_portable_passes_counted_apart(
     be.digest(aligned)
     snap = KERNEL_STATS.snapshot()
     assert snap["device_passes"]["encode_words_fused1"] == 2
-    assert snap["pallas_passes"] == {"encode_words_fused1": 1}
-    assert snap["portable_passes"] == {
-        "digest_words": 1,
-        "encode_words_fused1": 1,
-    }
+    assert snap["pallas_passes"] == {"encode_words_fused1": 2}
+    assert snap["portable_passes"] == {"digest_words": 1}
+    # one program: the two lengths shared the one-tile rung
+    assert snap["ragged"]["widths_true"] == 2
+    assert snap["ragged"]["staged_rows"] == {str(4 * rs_pallas._TW): 6}
 
 
 def test_fused1_digest_only_before_drain(single_device):

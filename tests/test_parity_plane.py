@@ -185,7 +185,7 @@ def test_tpu_digest_path_bit_identical_and_lazy(single_device):
 def test_tpu_digest_path_all_zero_plane(single_device):
     """An all-zero plane drains as zeros, the whole plane one transfer."""
     be = TpuBackend()
-    data = np.zeros((1, 4, 2048), dtype=np.uint8)
+    data = np.zeros((1, 4, 16384), dtype=np.uint8)  # a rung: no padding
     KERNEL_STATS.reset()
     dig, ref = be.encode_digest_end(be.encode_digest_begin(data, 2))
     par = ref.drain()

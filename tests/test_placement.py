@@ -284,10 +284,10 @@ class _BlockingBackend(CpuBackend):
     def placement_router(self):
         return self._router
 
-    def encode(self, data, m):
+    def encode(self, data, m, lengths=None):
         self.started.release()
         assert self.unblock.wait(10), "test never released the encode"
-        return super().encode(data, m)
+        return super().encode(data, m, lengths)
 
 
 def test_two_batches_overlap_on_disjoint_submeshes():

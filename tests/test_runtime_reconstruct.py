@@ -248,10 +248,11 @@ def test_a_launch_holds_a_power_of_two_of_rows_within_its_bytes():
 @pytest.mark.parametrize("rows", [1, 2, 3, 4, 5, 6, 7, 8, 16])
 def test_digest_at_every_rung_is_one_launch_of_the_cpu_codecs_digests(rows):
     """The rungs a healthy read of EC 8+4 settles on (1-8 shards of a
-    block, 16 of a coalesced flush): one launch each, no padding, the
-    digests the host codec computes."""
+    block, 16 of a coalesced flush): one launch each, no padding (the
+    rows lie at a rung of the width ladder), the digests the host codec
+    computes."""
     be = _one_device()
-    L = 2048
+    L = ALIGNED
     shards = np.random.default_rng(rows).integers(
         0, 256, (1, rows, L), dtype=np.uint8
     )
@@ -293,7 +294,9 @@ def test_digest_rows_walk_the_ladder_and_split_above_a_launch(monkeypatch):
         assert np.array_equal(be.digest(sh), ref)
         assert dw._cache_size() == size  # the seam added no program
     # sixteen rows a launch: 41 rows = 16 + 16 + 9 (padded to 16)
-    monkeypatch.setattr(backend_mod, "LAUNCH_BYTES", 16 * L)
+    monkeypatch.setattr(
+        backend_mod, "LAUNCH_BYTES", 16 * backend_mod.width_rung(L)
+    )
     before = KERNEL_STATS.snapshot()["device_passes"]["digest_words"]
     size = dw._cache_size()
     sh = rng.integers(0, 256, (1, 41, L), dtype=np.uint8)
@@ -312,7 +315,9 @@ def test_reconstruct_splits_above_a_launch(monkeypatch):
     be = _one_device()
     shards, data = _stripes(5, k, m, L, seed=21)
     held, present = _lose(shards, (1, 2, 4, 5))
-    monkeypatch.setattr(backend_mod, "LAUNCH_BYTES", 2 * 6 * L)
+    monkeypatch.setattr(
+        backend_mod, "LAUNCH_BYTES", 2 * 6 * backend_mod.width_rung(L)
+    )
     before = KERNEL_STATS.snapshot()["device_passes"].get(
         "reconstruct_words_batch", 0
     )

@@ -253,10 +253,10 @@ class _SeesRequests(backend_mod.CpuBackend):
     def __init__(self):
         self.seen = []
 
-    def digest(self, shards):
+    def digest(self, shards, lengths=None):
         self.seen.append((threading.current_thread().name, spans.request_id()))
         with spans.span(spans.SEAM_LAUNCH):
-            return super().digest(shards)
+            return super().digest(shards, lengths)
 
 
 def test_request_id_rides_a_real_batcher_flush_and_is_restored():

@@ -82,9 +82,12 @@ def _cases() -> "list[tuple[str, str, dict]]":
         add("verify_and_reconstruct_words", k, m, 1)
         add("encode_and_hash_words", k, m, 1)
     add("verify_and_reconstruct_words", 8, 4, 8)
-    # ragged widths leave the fused kernel: EC 12+4's 10 MiB block, and
-    # a 4 KiB object at EC 8+4 (512-byte shards)
-    for k, m, block in ((12, 4, BLOCK), (8, 4, 4096)):
+    # ragged widths are staged at a rung of the width ladder and take
+    # the Pallas kernels with their length an operand: EC 12+4's 10 MiB
+    # block (54 tiles -> 56), a 4 KiB object at EC 8+4 (512-byte shards
+    # -> one tile), and objects of 390 KB (3 tiles) and 2.2 MB (17 -> 20)
+    for k, m, block in ((12, 4, BLOCK), (8, 4, 4096), (8, 4, 389679),
+                        (8, 4, 2204359)):
         add("encode_words_fused1", k, m, 1, block)
         add("verify_and_reconstruct_words", k, m, 1, block)
         add("digest_words", k, m, backend.ladder(k), block)
@@ -137,27 +140,32 @@ def _child() -> int:
         """(function of arrays, abstract args) on one topology device."""
         k, m, B = p["k"], p["m"], p["B"]
         n = k + m
+        # as the seam stages it: the width's rung, the lengths abstract
         L = Erasure(k, m).shard_size_padded(p["block"])
-        w = L // 4
+        w = backend.width_rung(L) // 4
         use_pallas, interpret = codec_step.pallas_dispatch(w)
-        assert not interpret
+        assert use_pallas and not interpret
+        lens = S((B,), jnp.int32)
         # the loss pattern's operands: abstract, like the shards - the
         # program cannot depend on which rows survived
         pat = [S((n,), jnp.bool_), S((k,), jnp.int32), S((k, k), jnp.uint8)]
         if kind == "encode_words_fused1":
             return (
-                lambda x: codec_step.encode_words_fused1(
-                    x, m, L, use_pallas=use_pallas
+                lambda x, ln: codec_step.encode_words_fused1(
+                    x, m, ln, use_pallas=use_pallas
                 ),
-                [S((B, k, w))],
+                [S((B, k, w)), lens],
             )
         if kind == "encode_and_hash_words":
             return (
-                lambda x: codec_step.encode_and_hash_words(x, m, L),
-                [S((B, k, w))],
+                lambda x, ln: codec_step.encode_and_hash_words(x, m, ln),
+                [S((B, k, w)), lens],
             )
         if kind == "digest_words":  # B counts rows here
-            return (lambda x: codec_step.digest_words(x, L), [S((1, B, w))])
+            return (
+                codec_step.digest_words,
+                [S((1, B, w)), S((1, B), jnp.int32)],
+            )
         if kind == "reconstruct_words_batch":
             return (
                 lambda x, sv, mat: codec_step.reconstruct_words_batch(
@@ -167,12 +175,12 @@ def _child() -> int:
             )
         if kind == "verify_and_reconstruct_words":
             return (
-                lambda x, d, pr, sv, mat: (
+                lambda x, d, pr, sv, mat, ln: (
                     codec_step.verify_and_reconstruct_words(
-                        x, d, pr, sv, mat, k, m, L, use_pallas=use_pallas
+                        x, d, pr, sv, mat, k, m, ln, use_pallas=use_pallas
                     )
                 ),
-                [S((B, n, w)), S((B, n, 8))] + pat,
+                [S((B, n, w)), S((B, n, 8))] + pat + [lens],
             )
         raise KeyError(kind)
 
@@ -196,8 +204,11 @@ def _child() -> int:
             return S(shape, dtype, NamedSharding(mesh, prules.spec_for(plane)))
 
         if kind == "mesh_encode_hash":
-            fn = prules.compile_kernel(kind, mesh, k=k, m=m, shard_len=L)
-            return fn, [A((bucket, k, w), "stripe_words")]
+            fn = prules.compile_kernel(kind, mesh, k=k, m=m)
+            return fn, [
+                A((bucket, k, w), "stripe_words"),
+                A((bucket,), "stripe_lengths", jnp.int32),
+            ]
         if kind == "mesh_reconstruct":
             fn = prules.compile_kernel(
                 kind, mesh, k=k, m=m, use_pallas=shard == 1, interpret=False
@@ -208,19 +219,22 @@ def _child() -> int:
             ]
         if kind == "mesh_verify_reconstruct":
             fn = prules.compile_kernel(
-                kind, mesh, k=k, m=m, shard_len=L,
-                use_pallas=True, interpret=False,
+                kind, mesh, k=k, m=m, use_pallas=True, interpret=False,
             )
             return fn, [
                 A((bucket, n, w), "quorum_words"),
                 A((bucket, n, 8), "quorum_digests"),
+                A((bucket,), "stripe_lengths", jnp.int32),
                 A((n,), "decode_present", jnp.bool_),
                 A((k,), "decode_survivors", jnp.int32),
                 A((k, k), "decode_matrix", jnp.uint8),
             ]
         if kind == "mesh_digest":
-            fn = prules.compile_kernel(kind, mesh, shard_len=L)
-            return fn, [A((bucket, w), "digest_rows")]
+            fn = prules.compile_kernel(kind, mesh)
+            return fn, [
+                A((bucket, w), "digest_rows"),
+                A((bucket,), "digest_lengths", jnp.int32),
+            ]
         raise KeyError(kind)
 
     def run(case):
