@@ -324,6 +324,7 @@ class KernelStats:
                 },
                 "breaker": _breaker_demotions(),
                 "meta_read": _meta_read_counts(),
+                "remove": _remove_counts(),
                 "body_read": _body_read_counts(),
                 "stages": [
                     {
@@ -431,6 +432,15 @@ def _meta_read_counts() -> dict:
     from ..storage import xl
 
     return xl.meta_read_counts()
+
+
+def _remove_counts() -> dict:
+    """The drives' removals that were told what they remove
+    (storage/xl.py counts them where they are made): named, walked,
+    calls."""
+    from ..storage import xl
+
+    return xl.remove_counts()
 
 
 def _body_read_counts() -> dict:

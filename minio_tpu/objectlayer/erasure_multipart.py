@@ -433,10 +433,10 @@ class MultipartMixin:
 
         with self.nslock.write(bucket, object_name):
             version_id = new_version_id() if versioned else ""
-            old_data_dir = (
-                ""
+            old_null = (
+                None
                 if versioned
-                else self._old_null_data_dir(bucket, object_name)
+                else self._old_null_version(bucket, object_name)
             )
             errs = []
             staged: list[tuple] = []  # (disk, tmp) that moved parts out
@@ -508,18 +508,9 @@ class MultipartMixin:
             # mutation seam: the completed upload is the object's new
             # generation — cached groups of the old one die everywhere
             self._invalidate_read_cache(bucket, object_name)
-            if old_data_dir and old_data_dir != data_dir:
-                for d in disks:
-                    if d is None:
-                        continue
-                    try:
-                        d.delete_file(
-                            bucket,
-                            f"{object_name}/{old_data_dir}",
-                            recursive=True,
-                        )
-                    except Exception as exc:
-                        _log.debug("replaced data dir cleanup failed", extra=kv(err=str(exc)))
+            self._reap_data_dir(
+                disks, errs, bucket, object_name, old_null, data_dir
+            )
         # drop the upload dir
         for d in self._online_disks():
             if d is None:

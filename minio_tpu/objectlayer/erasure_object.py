@@ -255,17 +255,42 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
                 compress, sse,
             )
 
-    def _old_null_data_dir(self, bucket, object_name) -> str:
-        """Data dir of the existing *null* version, if any - the only
+    def _old_null_version(self, bucket, object_name) -> "FileInfo | None":
+        """The existing *null* version, if it has a data dir - the only
         version an unversioned overwrite replaces (and so the only data
         dir safe to reap; real versions keep theirs)."""
         try:
             fi, _ = self._read_quorum_fileinfo(
                 bucket, object_name, "null"
             )
-            return fi.data_dir
+            return fi if fi.data_dir else None
         except Exception:  # noqa: BLE001
-            return ""
+            return None
+
+    @staticmethod
+    def _reap_data_dir(
+        disks, errs, bucket, object_name, old: "FileInfo | None", new_dir=""
+    ) -> None:
+        """Drop the data dir of the version a write has just replaced
+        (best effort, before the acknowledgement).  A drive whose commit
+        went through (``errs`` slot None) holds the object's journal for
+        certain, so it is told the names and walks no tree; the others
+        keep the walk, which also prunes an object left empty.  Every
+        caller has been through the invalidation seam by now."""
+        if old is None or old.data_dir == new_dir:
+            return
+        for d, err in zip(disks, errs):
+            if d is None:
+                continue
+            try:
+                d.delete_file(  # noqa: MTPU110
+                    bucket,
+                    f"{object_name}/{old.data_dir}",
+                    recursive=True,
+                    fi=old if err is None else None,
+                )
+            except Exception as exc:
+                _log.debug("replaced data dir cleanup failed", extra=kv(err=str(exc)))
 
     def _put_object(
         self, bucket, object_name, reader, size, metadata,
@@ -390,8 +415,8 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
         # unversioned/suspended PUT overwrites the null version only
         # (xl-storage-format-v2 version journal semantics)
         version_id = new_version_id() if versioned else ""
-        old_data_dir = (
-            "" if versioned else self._old_null_data_dir(bucket, object_name)
+        old_null = (
+            None if versioned else self._old_null_version(bucket, object_name)
         )
 
         # rename_data commits the version journal with its own fsync
@@ -467,18 +492,9 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
 
             band.finish(on_done=_on_settled)
         # overwrite cleanup: drop the replaced data dir (best effort)
-        if old_data_dir and old_data_dir != data_dir:
-            for d in disks:
-                if d is None:
-                    continue
-                try:
-                    d.delete_file(
-                        bucket,
-                        f"{object_name}/{old_data_dir}",
-                        recursive=True,
-                    )
-                except Exception as exc:
-                    _log.debug("replaced data dir cleanup failed", extra=kv(err=str(exc)))
+        self._reap_data_dir(
+            disks, errs, bucket, object_name, old_null, data_dir
+        )
         return ObjectInfo(
             bucket=bucket,
             name=object_name,
@@ -1058,7 +1074,11 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
                         # directory must survive (advisor finding r1)
                         d.delete_version(bucket, object_name, fi)
                     else:
-                        d.delete_file(bucket, object_name, recursive=True)
+                        # fi names every file of the object: the drive
+                        # removes them one by one and walks no tree
+                        d.delete_file(
+                            bucket, object_name, recursive=True, fi=fi
+                        )
                     errs.append(None)
                 except (serrors.FileNotFound, serrors.VersionNotFound):
                     errs.append(None)
@@ -1082,8 +1102,8 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
         buckets write the *null* marker, replacing the null version."""
         marker_vid = new_version_id() if versioned else ""
         mod_time = now_ns()
-        old_null_dir = (
-            "" if versioned else self._old_null_data_dir(bucket, object_name)
+        old_null = (
+            None if versioned else self._old_null_version(bucket, object_name)
         )
         fi = FileInfo(
             volume=bucket,
@@ -1105,19 +1125,8 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
                 errs.append(e)
         reduce_errs(errs, self.write_quorum, WriteQuorumError)
         self._invalidate_read_cache(bucket, object_name)
-        if old_null_dir:
-            # the replaced null version's data is unreferenced now
-            for d in disks:
-                if d is None:
-                    continue
-                try:
-                    d.delete_file(
-                        bucket,
-                        f"{object_name}/{old_null_dir}",
-                        recursive=True,
-                    )
-                except Exception as exc:
-                    _log.debug("null-version data dir cleanup failed", extra=kv(err=str(exc)))
+        # the replaced null version's data is unreferenced now
+        self._reap_data_dir(disks, errs, bucket, object_name, old_null)
         return ObjectInfo(
             bucket=bucket,
             name=object_name,

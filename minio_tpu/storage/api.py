@@ -111,7 +111,26 @@ class StorageAPI:
     def write_all(self, volume: str, path: str, data: bytes) -> None:
         raise NotImplementedError
 
-    def delete_file(self, volume: str, path: str, recursive: bool = False) -> None:
+    def delete_file(
+        self,
+        volume: str,
+        path: str,
+        recursive: bool = False,
+        fi: "FileInfo | None" = None,
+    ) -> None:
+        """Remove ``path``: a file, an empty directory or, with
+        ``recursive``, a directory and all under it; then its parents as
+        far as they are empty, never the volume.
+
+        ``fi`` (with ``recursive``) is a hint, for the caller that
+        already holds the version whose files lie there: ``path`` is
+        that object's directory (its journal, ``fi.data_dir`` and the
+        ``part.<n>`` of ``fi.parts`` under it), or is the data dir
+        ``<object>/<fi.data_dir>`` alone, of an object whose journal
+        stays.  A drive may then remove those names one by one and list
+        nothing, as long as it walks the tree wherever it finds anything
+        else; it may as well ignore the hint and walk.  What is left on
+        the drive and the error raised are the same either way."""
         raise NotImplementedError
 
     def rename_file(

@@ -396,7 +396,15 @@ class StorageRESTClient(StorageAPI):
     def write_all(self, volume: str, path: str, data: bytes) -> None:
         self._call("writeall", {"vol": volume, "path": path}, data)
 
-    def delete_file(self, volume: str, path: str, recursive: bool = False) -> None:
+    def delete_file(
+        self,
+        volume: str,
+        path: str,
+        recursive: bool = False,
+        fi: "FileInfo | None" = None,
+    ) -> None:
+        # one RPC either way; the names ride in the body so that the
+        # drive on the other side need not walk
         self._call(
             "deletefile",
             {
@@ -404,6 +412,7 @@ class StorageRESTClient(StorageAPI):
                 "path": path,
                 "recursive": "1" if recursive else "0",
             },
+            wire.pack(wire.fileinfo_to_wire(fi)) if fi is not None else b"",
         )
 
     def rename_file(

@@ -149,7 +149,12 @@ class StorageRESTServer:
             disk.write_all(vol, path, body)
             return b""
         if m == "deletefile":
-            disk.delete_file(vol, path, recursive=q.get("recursive") == "1")
+            disk.delete_file(
+                vol,
+                path,
+                recursive=q.get("recursive") == "1",
+                fi=wire.fileinfo_from_wire(wire.unpack(body)) if body else None,
+            )
             return b""
         if m == "renamefile":
             disk.rename_file(vol, path, q["dstvol"], q["dstpath"])

@@ -47,6 +47,21 @@ def meta_read_counts() -> dict:
     return {"reads": reads, "refills": refills, "error_path": error_path}
 
 
+# Removals that were told the names of what they remove, over every
+# XLStorage of the process: [named (went by name), walked (found
+# something else and fell back to the tree walk), calls (the os calls
+# made by name, a fall-back's attempts included; what its walk then
+# makes is not counted)].  Plain adds like META_READ; kernel-stats
+# carries them as ``remove``.  named / (named + walked) is the share of
+# removals that walked no tree.
+REMOVE = [0, 0, 0]
+
+
+def remove_counts() -> dict:
+    named, walked, calls = REMOVE
+    return {"named": named, "walked": walked, "calls": calls}
+
+
 def _check_name(name: str) -> None:
     if not name or name.startswith("/") or ".." in name.split("/"):
         raise errors.FileAccessDenied(name)
@@ -276,7 +291,26 @@ class XLStorage(StorageAPI):
         os.replace(tmp, full)
 
     @spans.spanned(spans.XL_DELETE_FILE)
-    def delete_file(self, volume: str, path: str, recursive: bool = False) -> None:
+    def delete_file(
+        self,
+        volume: str,
+        path: str,
+        recursive: bool = False,
+        fi: "FileInfo | None" = None,
+    ) -> None:
+        if recursive and fi is not None:
+            # the caller holds the names: no stat in front and no walk.
+            # Whatever is not as named goes to the walk below, which
+            # also finds the error class a caller is to see
+            full = self._file_path(volume, path)
+            data_only = (
+                bool(fi.data_dir) and os.path.basename(full) == fi.data_dir
+            )
+            if self._remove_named(full, fi, data_only):
+                if not data_only:
+                    # a data dir's parent is its object, which lives on
+                    REMOVE[2] += self._prune_parents(volume, full)
+                return
         self._require_vol(volume)
         full = self._file_path(volume, path)
         try:
@@ -291,16 +325,63 @@ class XLStorage(StorageAPI):
             raise errors.FileNotFound(path) from None
         except OSError as e:
             raise errors.FaultyDisk(str(e)) from e
-        # prune now-empty parents up to the volume root (deleteFile,
-        # xl-storage.go parent cleanup)
+        self._prune_parents(volume, full)
+
+    def _remove_named(self, full: str, fi: FileInfo, data_only: bool) -> bool:
+        """Remove the object directory ``full`` - or, with ``data_only``,
+        the data dir of a living object that ``full`` is - by the names
+        ``fi`` gives its files: the journal first (a crash leaves data
+        without a journal, never a journal that names missing data),
+        the parts, the data dir, the directory itself.  One system call
+        a name, each of which gives the GIL away once; the walk makes
+        seven for every one of these.  False once anything is not as
+        named (another version's data dir, a stray file, no journal, no
+        such object or volume): what is left is the walk's."""
+        data_dir = full if data_only else (
+            os.path.join(full, fi.data_dir) if fi.data_dir else ""
+        )
+        calls = 0
+        try:
+            if not data_only:
+                calls += 1
+                os.unlink(os.path.join(full, XL_META))
+            if data_dir:
+                for part in fi.parts:
+                    calls += 1
+                    try:
+                        os.unlink(
+                            os.path.join(data_dir, f"part.{part.number}")
+                        )
+                    except FileNotFoundError:
+                        pass  # already gone: a heal may be in flight
+                if not data_only:
+                    calls += 1
+                    os.rmdir(data_dir)
+            calls += 1
+            os.rmdir(full)
+        except OSError:
+            went = False
+        else:
+            went = True
+        REMOVE[0 if went else 1] += 1
+        REMOVE[2] += calls
+        return went
+
+    def _prune_parents(self, volume: str, full: str) -> int:
+        """Remove the now-empty parents of ``full`` up to the volume
+        root (deleteFile, xl-storage.go parent cleanup); the number of
+        rmdir calls it took."""
         parent = os.path.dirname(full)
         vol = self._vol_path(volume)
+        calls = 0
         while parent != vol:
+            calls += 1
             try:
                 os.rmdir(parent)
             except OSError:
                 break
             parent = os.path.dirname(parent)
+        return calls
 
     def rename_file(
         self, src_volume: str, src_path: str, dst_volume: str, dst_path: str
@@ -413,7 +494,15 @@ class XLStorage(StorageAPI):
         self.write_all(
             dst_volume, f"{dst_path}/{XL_META}", xl.to_bytes()
         )
-        shutil.rmtree(src_dir, ignore_errors=True)
+        # the staging dir's one entry was moved out above: one rmdir, and
+        # the walk only where something else is still in it
+        REMOVE[2] += 1
+        try:
+            os.rmdir(src_dir)
+            REMOVE[0] += 1
+        except OSError:
+            REMOVE[1] += 1
+            shutil.rmtree(src_dir, ignore_errors=True)
 
     # ---- maintenance ----------------------------------------------------
 

@@ -447,3 +447,47 @@ def test_internode_preauth_rejects_before_body(tmp_path):
         conn.close()
     finally:
         srv.shutdown()
+
+
+# how the object lies on the remote drive -> (REMOVE's [named, walked] on
+# the far side, error class on the near side)
+REMOTE_REMOVALS = {
+    "as-named": (None, True, [1, 0], None),
+    "stray-file": ("leftover", True, [0, 1], None),
+    "object-missing": (False, True, [0, 1], serrors.FileNotFound),
+    "no-names-sent": (None, False, [0, 0], None),
+}
+
+
+@pytest.mark.parametrize("case", REMOTE_REMOVALS)
+def test_remote_delete_file_carries_the_names(remote_pair, case):
+    """``fi`` rides the one RPC of a removal, so the drive on the far side
+    removes by name too; what it leaves and what the caller is told are
+    the walk's."""
+    from minio_tpu.storage import xl as xl_mod
+    from minio_tpu.storage.meta import FileInfo, ObjectPartInfo
+
+    lay, send, (named, walked), error = REMOTE_REMOVALS[case]
+    local, rc = remote_pair
+    rc.make_vol("vol")
+    fi = FileInfo(
+        volume="vol", name="a/obj", data_dir="0123abcd" * 4, size=8,
+        parts=[ObjectPartInfo(1, 8, 8), ObjectPartInfo(2, 8, 8)],
+    )
+    if lay is not False:
+        for part in fi.parts:
+            rc.write_all("vol", f"a/obj/{fi.data_dir}/part.{part.number}", b"12345678")
+        rc.write_metadata("vol", "a/obj", fi)
+    if lay:
+        rc.write_all("vol", f"a/obj/{lay}", b"stray")
+    before = xl_mod.remove_counts()
+    try:
+        rc.delete_file("vol", "a/obj", recursive=True, fi=fi if send else None)
+        got = None
+    except Exception as e:  # noqa: BLE001 - the class is what is compared
+        got = type(e)
+    after = xl_mod.remove_counts()
+    assert got is error
+    assert [after[k] - before[k] for k in ("named", "walked")] == [named, walked]
+    # the object, and its parent "a" with it; the volume stays
+    assert os.listdir(os.path.join(local.root, "vol")) == []

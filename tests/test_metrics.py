@@ -445,6 +445,53 @@ def test_admin_kernel_stats_body_read_adds_up_over_a_put(server, client, size):
     assert blocks <= moved["loop_reads"] <= size
 
 
+# what a client does to a key that holds an object -> kernel-stats.remove
+# moves by [named, walked, calls] over this server's 4 drives
+REMOVALS_SERVED = {
+    # 4 staging dirs, one rmdir each; 4 replaced data dirs: a part, the dir
+    "overwrite-put": ("PUT", [8, 0, 12]),
+    # the journal, the part, the data dir, the object's directory: 4 a drive
+    "delete": ("DELETE", [4, 0, 16]),
+    "get": ("GET", [0, 0, 0]),
+}
+
+
+@pytest.mark.parametrize("case", REMOVALS_SERVED)
+def test_admin_kernel_stats_remove_counts_a_served_removal(server, client, case):
+    """``remove`` (storage/xl.py::REMOVE, beside ``meta_read``): the drives'
+    removals that were told the names of what they remove, and the system
+    calls they made; ``walked`` stays 0 where every drive finds what the
+    quorum's FileInfo names."""
+    method, want = REMOVALS_SERVED[case]
+
+    def counts():
+        r = client.request("GET", f"{ADMIN}/kernel-stats")
+        assert r.status == 200, r.body
+        doc = json.loads(r.body)
+        assert set(doc["remove"]) == {"named", "walked", "calls"}
+        spans = sum(
+            s["count"] for s in doc["spans"] if s["name"] == "xl_delete_file"
+        )
+        return {**doc["remove"], "spans": spans}
+
+    key = f"removed-{case}"
+    assert client.put_object("metrbkt", key, b"r" * 5000).status == 200
+    before = counts()
+    if method == "PUT":
+        assert client.put_object("metrbkt", key, b"s" * 5000).status == 200
+    elif method == "DELETE":
+        assert client.request("DELETE", f"/metrbkt/{key}").status == 204
+    else:
+        assert client.get_object("metrbkt", key).body == b"r" * 5000
+    after = counts()
+    moved = {k: after[k] - before[k] for k in after}
+    assert [moved["named"], moved["walked"], moved["calls"]] == want
+    # the span stays round a drive's removal, whichever way it goes
+    assert moved["spans"] == (0 if method == "GET" else 4)
+    if method == "DELETE":
+        assert client.get_object("metrbkt", key).status == 404
+
+
 def test_admin_healthinfo_includes_api_stats(server, client):
     r = client.request("GET", f"{ADMIN}/healthinfo")
     assert r.status == 200, r.body

@@ -276,12 +276,22 @@ def test_read_all_answers(disk, case):
 
 
 COUNTED = ("os.open", "os.read", "os.close", "os.stat", "os.path.isdir",
-           "builtins.open")
+           "builtins.open", "os.unlink", "os.rmdir", "os.lstat", "os.scandir",
+           "os.fstat")
+
+
+def _calls(**made):
+    # every counted name at 0 but those given, as os_open=1 for "os.open"
+    want = dict.fromkeys(COUNTED, 0)
+    for name, n in made.items():
+        want[name.replace("_", ".", 1)] = n
+    return want
 
 
 @pytest.fixture
 def syscalls(monkeypatch):
-    """Counts of the calls a drive's read could make, while ``on``."""
+    """Counts of the calls a drive's read or removal could make, while
+    ``on`` (shutil.rmtree looks its own up in ``os``, so a walk shows)."""
     calls = dict.fromkeys(COUNTED, 0)
     state = {"on": False}
 
@@ -343,8 +353,7 @@ def test_read_version_is_three_system_calls(disk, syscalls):
     with syscalls as calls:
         fi = disk.read_version("b", "obj")
     assert fi.size == 10 << 20
-    assert calls == {"os.open": 1, "os.read": 1, "os.close": 1,
-                     "os.stat": 0, "os.path.isdir": 0, "builtins.open": 0}
+    assert calls == _calls(os_open=1, os_read=1, os_close=1)
 
 
 @pytest.mark.parametrize("lost", [(), (2, 7)], ids=["healthy", "two-offline"])
@@ -365,12 +374,526 @@ def test_metadata_round_system_calls(tmp_path, syscalls, lost):
         fis, errs = read_all_fileinfo(online, "b", "obj")
     after = KERNEL_STATS.snapshot()["meta_read"]
     answered = 12 - len(lost)
-    assert calls == {"os.open": answered, "os.read": answered,
-                     "os.close": answered, "os.stat": 0,
-                     "os.path.isdir": 0, "builtins.open": 0}
+    assert calls == _calls(os_open=answered, os_read=answered,
+                           os_close=answered)
     assert sum(calls.values()) == 3 * answered  # 36 a round; 30 degraded
     assert {k: after[k] - before[k] for k in after} == {
         "reads": answered, "refills": 0, "error_path": 0,
     }
     assert [e is None for e in errs] == [i not in lost for i in range(12)]
     assert find_fileinfo_in_quorum(fis, 8).size == 10 << 20
+
+
+# ---- removal by name (PR 32) ---------------------------------------------
+# A drive that is told which files an object has removes them one system
+# call a name and walks the tree only where it finds something else.  Every
+# case runs twice, by name and by the parent commit's walk on a twin drive,
+# and the two drives have to end alike, error class included.
+
+
+def _parent_delete_file(disk, volume, path):
+    # XLStorage.delete_file(recursive=True) as the parent commit had it
+    disk._require_vol(volume)
+    full = disk._file_path(volume, path)
+    try:
+        if os.path.isdir(full):
+            shutil.rmtree(full)
+        else:
+            os.remove(full)
+    except FileNotFoundError:
+        raise errors.FileNotFound(path) from None
+    except OSError as e:
+        raise errors.FaultyDisk(str(e)) from e
+    parent = os.path.dirname(full)
+    vol = disk._vol_path(volume)
+    while parent != vol:
+        try:
+            os.rmdir(parent)
+        except OSError:
+            break
+        parent = os.path.dirname(parent)
+
+
+def _tree(root):
+    """{relative path: content, or None for a directory} of a drive."""
+    if not os.path.isdir(root):
+        with open(root, "rb") as f:
+            return {"": f.read()}
+    out = {}
+    for dirpath, dirnames, filenames in os.walk(root):
+        rel = os.path.relpath(dirpath, root)
+        for nm in dirnames:
+            out[os.path.normpath(os.path.join(rel, nm))] = None
+        for nm in filenames:
+            with open(os.path.join(dirpath, nm), "rb") as f:
+                out[os.path.normpath(os.path.join(rel, nm))] = f.read()
+    return out
+
+
+def _parts_fileinfo(nparts=1, data_dir=None, version_id=""):
+    fi = _cell_fileinfo()
+    fi.version_id = version_id
+    fi.data_dir = new_version_id() if data_dir is None else data_dir
+    fi.parts = [
+        ObjectPartInfo(number=n, size=64, actual_size=64)
+        for n in range(1, nparts + 1)
+    ]
+    return fi
+
+
+def _lay(disk, name, fi, journal=True):
+    """The object as a PUT leaves it on one drive: the journal's entry
+    and ``<data_dir>/part.<n>``."""
+    obj = os.path.join(disk.root, "b", *name.split("/"))
+    if fi.data_dir:
+        os.makedirs(os.path.join(obj, fi.data_dir))
+        for part in fi.parts:
+            with open(os.path.join(obj, fi.data_dir, f"part.{part.number}"), "wb") as f:
+                f.write(_pattern(part.size))
+    if journal:
+        disk.write_metadata("b", name, fi)
+
+
+def _stray(disk, *rel):
+    with open(os.path.join(disk.root, "b", *rel), "wb") as f:
+        f.write(b"stray")
+
+
+# name -> (build(disk, fis) -> (path, fi), os calls by name or None where the
+# count is the walk's, REMOVE's [named, walked], error class)
+def _case_one_part(disk, fis):
+    fi = fis["one"]
+    _lay(disk, "obj-00012", fi)
+    return "obj-00012", fi
+
+
+def _case_three_parts(disk, fis):
+    fi = fis["three"]
+    _lay(disk, "obj", fi)
+    return "obj", fi
+
+
+def _case_no_data_dir(disk, fis):
+    fi = fis["marker"]
+    _lay(disk, "obj", fi)
+    return "obj", fi
+
+
+def _case_two_versions(disk, fis):
+    old, fi = fis["v1"], fis["v2"]
+    _lay(disk, "obj", old)
+    _lay(disk, "obj", fi)
+    return "obj", fi
+
+
+def _case_stray_in_object(disk, fis):
+    fi = fis["one"]
+    _lay(disk, "obj", fi)
+    _stray(disk, "obj", "leftover")
+    return "obj", fi
+
+
+def _case_stray_in_data_dir(disk, fis):
+    fi = fis["one"]
+    _lay(disk, "obj", fi)
+    _stray(disk, "obj", fi.data_dir, "part.7")
+    return "obj", fi
+
+
+def _case_part_is_a_directory(disk, fis):
+    fi = fis["one"]
+    _lay(disk, "obj", fi)
+    part = os.path.join(disk.root, "b", "obj", fi.data_dir, "part.1")
+    os.remove(part)
+    os.makedirs(os.path.join(part, "deeper"))
+    return "obj", fi
+
+
+def _case_part_gone(disk, fis):
+    fi = fis["three"]
+    _lay(disk, "obj", fi)
+    os.remove(os.path.join(disk.root, "b", "obj", fi.data_dir, "part.2"))
+    return "obj", fi
+
+
+def _case_data_dir_gone(disk, fis):
+    fi = fis["one"]
+    _lay(disk, "obj", fi)
+    shutil.rmtree(os.path.join(disk.root, "b", "obj", fi.data_dir))
+    return "obj", fi
+
+
+def _case_no_journal(disk, fis):
+    fi = fis["one"]
+    _lay(disk, "obj", fi, journal=False)
+    return "obj", fi
+
+
+def _case_object_missing(disk, fis):
+    _lay(disk, "other", fis["three"])
+    return "obj", fis["one"]
+
+
+def _case_volume_missing(disk, fis):
+    os.rmdir(os.path.join(disk.root, "b"))
+    return "obj", fis["one"]
+
+
+def _case_root_a_file(disk, fis):
+    fi = fis["one"]
+    _lay(disk, "obj", fi)
+    shutil.rmtree(disk.root)
+    with open(disk.root, "w") as f:
+        f.write("not a drive")
+    return "obj", fi
+
+
+def _case_nested_sibling_stays(disk, fis):
+    fi = fis["one"]
+    _lay(disk, "a/b/obj", fi)
+    _lay(disk, "a/c/other", fis["three"])
+    return "a/b/obj", fi
+
+
+def _case_nested_all_empty(disk, fis):
+    fi = fis["one"]
+    _lay(disk, "a/b/obj", fi)
+    return "a/b/obj", fi
+
+
+def _replaced(disk, fis, name="obj"):
+    # after an overwriting PUT's commit: the journal names the new data
+    # dir, the old one is still beside it
+    old, new = fis["one"], fis["three"]
+    _lay(disk, name, old, journal=False)
+    _lay(disk, name, new)
+    return f"{name}/{old.data_dir}", old
+
+
+def _case_replaced_data_dir(disk, fis):
+    return _replaced(disk, fis)
+
+
+def _case_replaced_nested(disk, fis):
+    return _replaced(disk, fis, "a/b/obj")
+
+
+def _case_replaced_stray(disk, fis):
+    path, old = _replaced(disk, fis)
+    _stray(disk, *path.split("/"), "part.9")
+    return path, old
+
+
+def _case_replaced_part_gone(disk, fis):
+    path, old = _replaced(disk, fis)
+    os.remove(os.path.join(disk.root, "b", *path.split("/"), "part.1"))
+    return path, old
+
+
+def _case_replaced_gone(disk, fis):
+    path, old = _replaced(disk, fis)
+    shutil.rmtree(os.path.join(disk.root, "b", *path.split("/")))
+    return path, old
+
+
+def _case_replaced_no_journal(disk, fis):
+    # a drive that holds the old data dir and nothing else of the object:
+    # the walk prunes the object's directory, by name it would stay.  The
+    # object layer names the files only to a drive whose commit went
+    # through (_reap_data_dir), so this drive is asked without them
+    old = fis["one"]
+    _lay(disk, "obj", old, journal=False)
+    return f"obj/{old.data_dir}", None
+
+
+REMOVE_CASES = {
+    "one-part": (_case_one_part, {"os.unlink": 2, "os.rmdir": 2}, [1, 0], None),
+    "three-parts": (_case_three_parts, {"os.unlink": 4, "os.rmdir": 2}, [1, 0], None),
+    "no-data-dir": (_case_no_data_dir, {"os.unlink": 1, "os.rmdir": 1}, [1, 0], None),
+    "two-versions": (_case_two_versions, None, [0, 1], None),
+    "stray-in-object": (_case_stray_in_object, None, [0, 1], None),
+    "stray-in-data-dir": (_case_stray_in_data_dir, None, [0, 1], None),
+    "part-is-a-directory": (_case_part_is_a_directory, None, [0, 1], None),
+    "part-already-gone": (_case_part_gone, {"os.unlink": 4, "os.rmdir": 2}, [1, 0], None),
+    "data-dir-already-gone": (_case_data_dir_gone, None, [0, 1], None),
+    "no-journal": (_case_no_journal, None, [0, 1], None),
+    "object-missing": (_case_object_missing, None, [0, 1], errors.FileNotFound),
+    "volume-missing": (_case_volume_missing, None, [0, 1], errors.VolumeNotFound),
+    "root-became-a-file": (_case_root_a_file, None, [0, 1], errors.VolumeNotFound),
+    "nested-sibling-stays": (_case_nested_sibling_stays, {"os.unlink": 2, "os.rmdir": 4}, [1, 0], None),
+    "nested-all-empty": (_case_nested_all_empty, {"os.unlink": 2, "os.rmdir": 4}, [1, 0], None),
+    "replaced-data-dir": (_case_replaced_data_dir, {"os.unlink": 1, "os.rmdir": 1}, [1, 0], None),
+    "replaced-nested": (_case_replaced_nested, {"os.unlink": 1, "os.rmdir": 1}, [1, 0], None),
+    "replaced-stray": (_case_replaced_stray, None, [0, 1], None),
+    "replaced-part-gone": (_case_replaced_part_gone, {"os.unlink": 1, "os.rmdir": 1}, [1, 0], None),
+    "replaced-already-gone": (_case_replaced_gone, None, [0, 1], errors.FileNotFound),
+    "replaced-no-journal-unnamed": (_case_replaced_no_journal, None, [0, 0], None),
+}
+
+
+def _outcome(fn):
+    try:
+        fn()
+    except Exception as e:  # noqa: BLE001 - the class is what is compared
+        return type(e)
+    return None
+
+
+@pytest.mark.parametrize("case", REMOVE_CASES)
+def test_remove_by_name_leaves_what_the_walk_leaves(tmp_path, syscalls, case):
+    build, by_name, (named, walked), error = REMOVE_CASES[case]
+    fis = dict(
+        one=_parts_fileinfo(1), three=_parts_fileinfo(3),
+        marker=_parts_fileinfo(0, data_dir=""),
+        v1=_parts_fileinfo(1, version_id=new_version_id()),
+        v2=_parts_fileinfo(1, version_id=new_version_id()),
+    )
+    fis["marker"].deleted = True
+    drive, twin = XLStorage(str(tmp_path / "d")), XLStorage(str(tmp_path / "t"))
+    for d in (drive, twin):
+        d.make_vol("b")
+    path, fi = build(drive, fis)
+    assert build(twin, fis) == (path, fi)
+    assert _tree(drive.root) == _tree(twin.root)
+
+    before = xl_mod.remove_counts()
+    with syscalls as calls:
+        got = _outcome(lambda: drive.delete_file("b", path, recursive=True, fi=fi))
+    made = {k: v for k, v in calls.items() if v}
+    after = xl_mod.remove_counts()
+    want = _outcome(lambda: _parent_delete_file(twin, "b", path))
+
+    assert got is want is error
+    assert _tree(drive.root) == _tree(twin.root)
+    moved = {k: after[k] - before[k] for k in after}
+    assert [moved["named"], moved["walked"]] == [named, walked]
+    if by_name is not None:
+        # 4 calls for the cells' object, 2 for a replaced data dir, and
+        # one more for every parent of a nested key it tries to prune
+        assert made == by_name
+        assert moved["calls"] == sum(by_name.values())
+    elif walked:
+        # the attempt by name and then the walk, which stats first
+        assert made.get("os.stat", 0) >= 1
+        assert 1 <= moved["calls"] <= 4
+    if path.startswith("a/b/") and error is None:
+        kept = os.path.isdir(os.path.join(drive.root, "b", "a"))
+        assert kept == (case in ("nested-sibling-stays", "replaced-nested"))
+    assert os.path.isdir(os.path.join(drive.root, "b")) or error is not None
+
+
+# staging dir's leftovers -> (REMOVE's [named, walked], os.rmdir calls)
+STAGING_CASES = {
+    "emptied": ((), [1, 0], 1),
+    "leftover-file": (("junk",), [0, 1], None),
+    "leftover-dir": (("more/junk",), [0, 1], None),
+    "no-data-dir": (None, [1, 0], 1),
+}
+
+
+@pytest.mark.parametrize("case", STAGING_CASES)
+def test_rename_data_removes_its_staging_dir_with_one_rmdir(disk, syscalls, case):
+    left, (named, walked), rmdirs = STAGING_CASES[case]
+    disk.make_vol("b")
+    fi = _parts_fileinfo(1) if left is not None else _parts_fileinfo(0, data_dir="")
+    tmp = disk.new_tmp_dir()
+    staged = os.path.join(disk.root, *tmp.split("/"))
+    os.makedirs(os.path.join(staged, fi.data_dir))
+    if fi.data_dir:
+        with open(os.path.join(staged, fi.data_dir, "part.1"), "wb") as f:
+            f.write(_pattern(64))
+    for rel in left or ():
+        os.makedirs(os.path.dirname(os.path.join(staged, rel)), exist_ok=True)
+        with open(os.path.join(staged, rel), "wb") as f:
+            f.write(b"stray")
+    before = xl_mod.remove_counts()
+    with syscalls as calls:
+        disk.rename_data(".sys", tmp[len(".sys/"):], fi, "b", "obj")
+    after = xl_mod.remove_counts()
+    # the parent's rmtree left no staging dir behind in any case
+    assert os.listdir(os.path.join(disk.root, ".sys", "tmp")) == []
+    got = _tree(os.path.join(disk.root, "b"))
+    assert sorted(got) == sorted(
+        ["obj", "obj/xl.meta"]
+        + ([f"obj/{fi.data_dir}", f"obj/{fi.data_dir}/part.1"] if fi.data_dir else [])
+    )
+    moved = {k: after[k] - before[k] for k in after}
+    assert [moved["named"], moved["walked"], moved["calls"]] == [named, walked, 1]
+    if rmdirs is not None:
+        assert calls["os.rmdir"] == rmdirs
+        assert calls["os.scandir"] == calls["os.lstat"] == calls["os.unlink"] == 0
+    else:
+        assert calls["os.scandir"] >= 1  # the walk, for what was left
+
+
+# ---- the same through the object layer, on twelve drives ------------------
+
+
+class _WalkingDrive(XLStorage):
+    """A drive that takes no notice of the names, as the StorageAPI allows
+    and as every drive of the parent commit did."""
+
+    def delete_file(self, volume, path, recursive=False, fi=None):
+        return super().delete_file(volume, path, recursive)
+
+
+_UUID = "[0-9a-f]{32}|[0-9a-f]{8}(-[0-9a-f]{4}){3}-[0-9a-f]{12}"
+
+
+def _bucket_shape(drive):
+    """What a drive holds of the bucket, with the data dirs' names (fresh
+    UUIDs) and the journals' stamps taken out."""
+    import re
+
+    shape = []
+    for rel, content in _tree(os.path.join(drive.root, "bkt")).items():
+        rel = re.sub(_UUID, "<data_dir>", rel)
+        if content is not None and rel.endswith("xl.meta"):
+            content = [
+                (v.deleted, v.size, bool(v.data_dir), [p.number for p in v.parts])
+                for v in XLMeta.from_bytes(content, "bkt", rel).versions
+            ]
+        shape.append((rel, content))
+    return sorted(shape, key=repr)
+
+
+def _put(ol, key, seed, size=20000, **kw):
+    import io
+
+    body = bytes((seed + i) % 251 for i in range(size))
+    return ol.put_object("bkt", key, io.BytesIO(body), size, **kw)
+
+
+def _scene_delete(ol, drives):
+    _put(ol, "obj-00012", 1)
+    return lambda: ol.delete_object("bkt", "obj-00012")
+
+
+def _scene_delete_nested(ol, drives):
+    _put(ol, "a/b/obj", 1)
+    _put(ol, "a/c/obj", 2)
+    return lambda: ol.delete_object("bkt", "a/b/obj")
+
+
+def _scene_overwrite(ol, drives):
+    _put(ol, "obj", 1)
+    return lambda: _put(ol, "obj", 2)
+
+
+def _scene_versioned_delete(ol, drives):
+    v1 = _put(ol, "obj", 1, versioned=True).version_id
+    _put(ol, "obj", 2, versioned=True)
+    return lambda: ol.delete_object("bkt", "obj", version_id=v1)
+
+
+def _scene_delete_all_versions(ol, drives):
+    # an unversioned DELETE of a key with two versions removes the whole
+    # directory; the quorum FileInfo names one data dir of the two
+    _put(ol, "obj", 1, versioned=True)
+    _put(ol, "obj", 2, versioned=True)
+    return lambda: ol.delete_object("bkt", "obj")
+
+
+def _scene_suspended_marker(ol, drives):
+    _put(ol, "obj", 1)
+    return lambda: ol.delete_object("bkt", "obj", version_suspended=True)
+
+
+def _scene_drive_offline(ol, drives):
+    _put(ol, "obj", 1)
+    ol.disks[3] = None
+    return lambda: ol.delete_object("bkt", "obj")
+
+
+def _scene_copy_gone(ol, drives):
+    _put(ol, "obj", 1)
+    shutil.rmtree(os.path.join(drives[5].root, "bkt", "obj"))
+    return lambda: ol.delete_object("bkt", "obj")
+
+
+def _scene_overwrite_copy_gone(ol, drives):
+    _put(ol, "obj", 1)
+    shutil.rmtree(os.path.join(drives[5].root, "bkt", "obj"))
+    return lambda: _put(ol, "obj", 2)
+
+
+# scene -> (setup, REMOVE's [named, walked, calls] over the act, the key
+# that is a 404 afterwards)
+SCENES = {
+    "delete": (_scene_delete, [12, 0, 48], "obj-00012"),
+    "delete-nested-key": (_scene_delete_nested, [12, 0, 72], "a/b/obj"),
+    # a PUT's 12 staging dirs, one rmdir each, then 12 old data dirs, two
+    "overwrite-put": (_scene_overwrite, [24, 0, 36], None),
+    "versioned-delete": (_scene_versioned_delete, [0, 0, 0], None),
+    "delete-of-two-versions": (_scene_delete_all_versions, [0, 12, 48], "obj"),
+    "suspended-delete-marker": (_scene_suspended_marker, [12, 0, 24], None),
+    "delete-drive-offline": (_scene_drive_offline, [11, 0, 44], "obj"),
+    "delete-one-copy-gone": (_scene_copy_gone, [11, 1, 45], "obj"),
+    "overwrite-one-copy-gone": (_scene_overwrite_copy_gone, [23, 1, 36], None),
+}
+
+
+@pytest.mark.parametrize("scene", SCENES)
+def test_object_layer_removes_by_name_what_the_parent_walked(tmp_path, scene):
+    from minio_tpu.objectlayer.erasure_object import ErasureObjects
+
+    setup, want, gone = SCENES[scene]
+    sets = []
+    for cls in (XLStorage, _WalkingDrive):
+        drives = [cls(str(tmp_path / cls.__name__ / f"d{i}")) for i in range(12)]
+        ol = ErasureObjects(drives, parity_blocks=4, block_size=4096)
+        ol.make_bucket("bkt")
+        sets.append((ol, drives, setup(ol, drives)))
+    (ol, drives, act), (_, twins, twin_act) = sets
+    twin_act()
+    before = xl_mod.remove_counts()
+    act()
+    after = xl_mod.remove_counts()
+    assert [after[k] - before[k] for k in ("named", "walked", "calls")] == want
+    for drive, twin in zip(drives, twins):
+        assert _bucket_shape(drive) == _bucket_shape(twin)
+        assert os.listdir(os.path.join(drive.root, ".sys", "tmp")) == []
+    if gone is not None:
+        from minio_tpu.objectlayer import api
+
+        with pytest.raises(api.ObjectNotFound):
+            ol.get_object_info("bkt", gone)
+        for d, online in zip(drives, ol.disks):
+            # a 404 from every drive that was asked, not from a quorum
+            assert online is None or not os.path.exists(
+                os.path.join(d.root, "bkt", gone))
+
+
+def test_names_reach_the_drive_through_its_wrappers(tmp_path, syscalls):
+    """DiskIDCheck(MeteredDisk(XLStorage)), the stack every erasure set
+    runs: the names pass, the disk-id check still runs first, the meter
+    still counts the call."""
+    from minio_tpu.objectlayer.format import wait_for_format
+    from minio_tpu.storage import metered
+    from minio_tpu.storage.diskcheck import DiskIDCheck
+
+    drives = [XLStorage(str(tmp_path / f"d{i}")) for i in range(4)]
+    ref, ordered = wait_for_format(drives, 1, 4, timeout_s=5)
+    raw = ordered[0]
+    raw.make_vol("b")
+    fi = _parts_fileinfo(1)
+    _lay(raw, "obj", fi)
+    laid = _tree(raw.root)
+
+    intruder = DiskIDCheck(metered.wrap(raw), "another-drive", check_interval_s=0.0)
+    with pytest.raises(errors.DiskNotFound, match="mismatch"):
+        intruder.delete_file("b", "obj", recursive=True, fi=fi)
+    assert _tree(raw.root) == laid
+
+    chain = DiskIDCheck(metered.wrap(raw), ref.sets[0][0], check_interval_s=0.0)
+    before = xl_mod.remove_counts()
+    with syscalls as calls:
+        chain.delete_file("b", "obj", recursive=True, fi=fi)
+    after = xl_mod.remove_counts()
+    assert {k: after[k] - before[k] for k in after} == {
+        "named": 1, "walked": 0, "calls": 4}
+    # the check's read of format.json and the four removals, nothing else
+    assert calls == _calls(os_open=1, os_read=1, os_close=1, os_unlink=2, os_rmdir=2)
+    assert chain.api_stats()["delete_file"]["calls"] == 1
+    assert chain.api_stats()["delete_file"]["errors"] == 0
+    assert "obj" not in os.listdir(os.path.join(raw.root, "b"))
