@@ -18,7 +18,10 @@ it runs three children one after another and checks what they report:
    process (tests/s3client.py, no JAX): >= 256 MiB loaded as 10 MiB
    objects by 8 concurrent clients, a multi-block object with a short
    tail, a 4 KiB object and an odd-length one; every GET compared byte
-   for byte with its PUT payload; STAT and DELETE; the shard files of two
+   for byte with its PUT payload; the 16 sizes of `mixed-randsize`; one
+   64 MiB object (seven blocks in one stream: two batches a direction,
+   no launch above the seam's 32 MiB), read whole and as a range across
+   its fourth block's end; STAT and DELETE; the shard files of two
    drives removed - one pair of drives under half the objects, another
    pair under the rest - and everything read again (device reconstruct,
    several loss patterns, one program: the codec child counts it); one
@@ -571,6 +574,50 @@ def served_phases(seed: int, rehearse: bool, env: dict, workdir: str,
                             / max(1, r1["true_bytes"] - r0["true_bytes"]), 4),
             mixed_launches=r1["mixed_launches"] - r0["mixed_launches"],
             compile_cache=after["device"]["compile_cache"])
+
+        # one object of many blocks: 64 MiB is six full blocks and a
+        # 4 MiB tail in one stream (the size of the benchmark's cell
+        # `mixed-64m`), so the PUT is two batches through the
+        # double-buffered encode, the whole GET two through the
+        # read-ahead, a batch of four full blocks goes out as launches
+        # of two, and a range across the fourth block's end (where the
+        # PUT's first batch ended) reads blocks four and five
+        big = 64 << 20
+        body = payload(seed, "blocks-7", big)
+        before = c.admin("GET", "kernel-stats")
+        r = c.request("PUT", f"/{BUCKET}/blocks-7", body=body)
+        check(r.status == 200, f"PUT blocks-7 -> {r.status} {r.body[:200]!r}")
+        r = c.request("GET", f"/{BUCKET}/blocks-7")
+        check(r.status == 200 and r.body == body,
+              "64 MiB: whole GET differs from its PUT")
+        lo, hi = 4 * BLOCK - 1000, 4 * BLOCK + 999
+        r = c.request("GET", f"/{BUCKET}/blocks-7",
+                      headers={"Range": f"bytes={lo}-{hi}"})
+        check(r.status == 206 and r.body == body[lo:hi + 1],
+              f"64 MiB: range GET across the fourth block's end -> {r.status}")
+        check(c.request("DELETE", f"/{BUCKET}/blocks-7").status == 204,
+              "DELETE blocks-7")
+        after = c.admin("GET", "kernel-stats")
+        moved = {
+            d: {f: after["stream"][d][f] - before["stream"][d][f]
+                for f in after["stream"][d]}
+            for d in ("encode", "decode")
+        }
+        check(moved["encode"]["blocks"] >= 7 and moved["encode"]["batches"] >= 2
+              and moved["encode"]["tail_groups"] >= 1,
+              f"the 64 MiB PUT was not two batches of seven blocks: {moved}")
+        check(moved["decode"]["blocks"] >= 9 and moved["decode"]["batches"] >= 3,
+              f"the 64 MiB GETs did not read seven blocks in two batches and "
+              f"two in one: {moved}")
+        peak = max((int(s) for s, n in after["launch"]["sizes"].items()
+                    if n != before["launch"]["sizes"].get(s, 0)), default=0)
+        check(0 < peak <= 32 << 20,
+              f"a launch of {peak} bytes: over the seam's 32 MiB")
+        say(event="multiblock", bytes=big, stream=moved, launch_peak_bytes=peak,
+            launches=after["launch"]["count"] - before["launch"]["count"],
+            split_calls=(after["launch"]["split_calls"]
+                         - before["launch"]["split_calls"]))
+        del body
 
         for key in ("big-01", "multi-tail", "small-4k"):
             r = c.request("HEAD", f"/{BUCKET}/{key}")

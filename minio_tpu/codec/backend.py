@@ -99,6 +99,16 @@ def _record_ragged(lengths: np.ndarray, width: int) -> None:
     KERNEL_STATS.record_ragged(lengths, width)
 
 
+def _record_launches(sizes: "list[int]") -> None:
+    """Account the launches one call of a served entry point
+    (encode_words_fused1, digest_words, reconstruct_words_batch) went
+    out as, by the bytes of each one's staged input (kernel-stats
+    ``launch``)."""
+    from .telemetry import KERNEL_STATS
+
+    KERNEL_STATS.record_launches(sizes)
+
+
 # ---------------------------------------------------------------------------
 # The loss pattern as operands: survivors + inverse, picked on the host
 # ---------------------------------------------------------------------------
@@ -150,14 +160,15 @@ def patterns_seen() -> int:
 
 
 # ---------------------------------------------------------------------------
-# The ladder: the leading dimension of a read-side program is one of a few
+# The ladder: the leading dimension of a program is one of a few
 # ---------------------------------------------------------------------------
 #
 # A jitted program is compiled per shape, about a second each on the one
-# dispatcher thread.  Coalesced flushes and settle batches come in any
-# size, so the seam rounds the leading dimension (stripes of a
-# reconstruct, shard rows of a digest) up a ladder with padding rows,
-# and above ``LAUNCH_BYTES`` of input launches more than once.  The
+# dispatcher thread.  Coalesced flushes, settle batches and the batches
+# of a many-block stream come in any size, so the seam rounds the
+# leading dimension (stripes of an encode or a reconstruct, shard rows
+# of a digest) up a ladder with padding rows, and above
+# ``LAUNCH_BYTES`` of input launches more than once.  The
 # ladder: every size up to LADDER_UNIT (a healthy read settles its k
 # shards in batches of 1 to k rows, so any traffic meets those sizes
 # within seconds, and padding them would copy every batch on the one
@@ -165,8 +176,16 @@ def patterns_seen() -> int:
 # powers of two (sizes only a coalesced flush reaches, met late or
 # never, which is where a compile lands inside somebody's request).  At
 # 10 MiB blocks of EC 8+4: digest 1-8 and 16 rows, reconstruct 1-2
-# stripes.  Padding rows are the seam's own cost: they cross the bus,
-# count in h2d, and never reach a caller.
+# stripes.  Two entry points stop short of the powers of two, so that
+# the warm-up loads every program they can launch (``_family``) and no
+# flush, however many requests it coalesces and however many blocks a
+# stream's batch holds, meets one that was not loaded behind the first:
+# an encode keeps to 1, 2 and 4 stripes (``encode_rungs``: 1-2 at a
+# full block's width), a digest to sixteen rows (``digest_rungs``; the
+# tails of three 64 MiB GETs in one flush are 24 rows of 512 KiB - 16 +
+# 8, not a 32-row program met once a day, inside somebody's window:
+# PERF.md PR 33).  Padding rows are the seam's own cost: they cross the
+# bus, count in h2d, and never reach a caller.
 
 #
 # The width is the other dimension.  A shard's byte length is an OPERAND
@@ -184,6 +203,7 @@ def patterns_seen() -> int:
 LAUNCH_BYTES = 32 << 20
 LADDER_CAP = 256  # the batcher's max_batch_blocks: no launch is longer
 LADDER_UNIT = 8  # sizes up to here are their own rung
+ENCODE_STRIPES = 4  # most stripes of one encode launch (a stream's batch)
 TILE_BYTES = 16384  # of one shard row: ops/rs_pallas._TW uint32 words
 WIDTH_UNIT = 8  # tile counts up to here are their own rung
 
@@ -250,6 +270,46 @@ def ladder(rows: int) -> int:
     return rows if rows <= LADDER_UNIT else 1 << (rows - 1).bit_length()
 
 
+def encode_rungs(stripe_bytes: int) -> "tuple[int, ...]":
+    """The stripe counts an encode of ``stripe_bytes`` a stripe (k rows
+    at their staged width) is launched at, ascending: the powers of two
+    up to ENCODE_STRIPES that fit LAUNCH_BYTES."""
+    cap = min(ENCODE_STRIPES, launch_rows(stripe_bytes))
+    return tuple(1 << i for i in range(cap.bit_length()))
+
+
+def encode_rung(stripes: int) -> int:
+    """The rung that holds ``stripes`` (>= 1) of one encode launch."""
+    return 1 << (stripes - 1).bit_length()
+
+
+DIGEST_RUNGS = tuple(range(1, LADDER_UNIT + 1)) + (2 * LADDER_UNIT,)
+
+
+def digest_rungs(row_bytes: int) -> "tuple[int, ...]":
+    """The row counts a digest of rows of ``row_bytes`` (at their staged
+    width) is launched at: every count a read settles on, and sixteen
+    for a coalesced flush or a stream's batch of blocks, within
+    LAUNCH_BYTES."""
+    cap = launch_rows(row_bytes)
+    return tuple(r for r in DIGEST_RUNGS if r <= cap)
+
+
+def reconstruct_rungs(stripe_bytes: int) -> "tuple[int, ...]":
+    """The stripe counts a reconstruct of ``stripe_bytes`` a stripe (n
+    rows at their staged width) is launched at: the ladder up to what
+    fits LAUNCH_BYTES."""
+    cap = launch_rows(stripe_bytes)
+    return tuple(sorted({ladder(r) for r in range(1, cap + 1)}))
+
+
+def _join(parts: list) -> np.ndarray:
+    """The host results of one encode call's launches, in order: one
+    launch (a call within the cap) hands its buffer through as it is,
+    several are copied together."""
+    return parts[0] if len(parts) == 1 else np.concatenate(parts)
+
+
 _pad_buffers = threading.local()
 
 
@@ -269,20 +329,26 @@ def _pad_buffer(shape: tuple, dtype) -> np.ndarray:
     return buf
 
 
-def _ladder_chunks(arr: np.ndarray, lengths: np.ndarray, row_bytes: int):
+def _ladder_chunks(
+    arr: np.ndarray, lengths: np.ndarray, rungs: "tuple[int, ...]", own=False
+):
     """Cut the leading axis into launches and pad each to the ladders:
-    yields (lo, hi, host array of ladder(hi - lo) rows at the width's
-    rung, its int32 lengths with 0 for the padding rows).  ``row_bytes``
-    counts one leading row at its staged width.  Only the last launch
-    of a call can be short of its row rung, so a call that came staged
-    at its width (codec/erasure.py stages there) fills at most one
-    padding buffer."""
-    cap = launch_rows(row_bytes)
+    yields (lo, hi, host array of as many rows as the rung of ``rungs``
+    that holds hi - lo, at the width's rung; its int32 lengths with 0
+    for the padding rows).  ``rungs`` are the leading dimensions the
+    entry point is launched at, ascending; the last is the most one
+    launch takes.  Only the last launch of a call can be short of its
+    row rung, so a call that came staged at its width (codec/erasure.py
+    stages there) fills at most one padding buffer.  ``own``: the call
+    returns while the device still reads its input (an encode's
+    begin), so a padded launch gets an array of its own and never the
+    thread's buffer."""
+    cap = rungs[-1]
     total, L = arr.shape[0], arr.shape[-1]
     width = width_rung(L)
     for lo in range(0, total, cap):
         hi = min(lo + cap, total)
-        rows = ladder(hi - lo)
+        rows = next(r for r in rungs if r >= hi - lo)
         part, lens = arr[lo:hi], lengths[lo:hi]
         if rows != hi - lo or width != L:
             shape = (rows,) + arr.shape[1:-1] + (width,)
@@ -292,7 +358,7 @@ def _ladder_chunks(arr: np.ndarray, lengths: np.ndarray, row_bytes: int):
             # a call are read back together, after the last is issued)
             padded = (
                 _pad_buffer(shape, arr.dtype)
-                if width == L
+                if width == L and not own
                 else np.zeros(shape, dtype=arr.dtype)
             )
             padded[: hi - lo, ..., :L] = part
@@ -310,15 +376,14 @@ def _ladder_chunks(arr: np.ndarray, lengths: np.ndarray, row_bytes: int):
 # each inside somebody's request.  Once it is serving (server/__main__
 # starts this after ``ready``; nothing else does, so tests and tools
 # trace only what they launch), the first launch at a staged width
-# queues that width's family - the encode at 1, 2 and 4 stripes, the
-# digest at every row rung a read settles on, the reconstruct at 1 and 2
-# stripes - and one background thread runs each on zeros, narrowest
+# queues that width's family - the encode at every rung it can launch
+# (``encode_rungs``), the digest likewise (``digest_rungs``), the
+# reconstruct at 1 and 2 stripes - and one background thread runs
+# each on zeros, narrowest
 # width first: a load from the compile cache where the cache has it, a
 # compile where not, on a thread no request waits for.  It adapts to the
 # traffic: a deployment of one object size warms one width.
 
-_WARM_ENCODE = (1, 2, 4)  # stripes: the batcher pads a flush to a power of two
-_WARM_DIGEST = tuple(range(1, LADDER_UNIT + 1)) + (2 * LADDER_UNIT,)
 _WARM_RECONSTRUCT = (1, 2)
 
 
@@ -497,46 +562,51 @@ class _EagerParityRef:
 
 
 class _DeviceParityRef:
-    """One batch's device-resident parity plane: (B, m, w) u32 words.
+    """One encode call's device-resident parity: a (rows, m, w) u32
+    plane for each launch the call went out as, padding rows included.
 
     ``drain()`` is the single D2H seam: thread-safe and memoized, so
     the m per-disk parity writers sharing this ref pay one transfer.
-    Registered with the ParityPlaneCache, which accounts the plane
-    until it is drained or released.
+    Registered with the ParityPlaneCache, which accounts the planes
+    until they are drained or released.
     """
 
-    __slots__ = ("_lk", "_cache", "_plane", "_host", "_width", "nbytes")
+    __slots__ = ("_lk", "_cache", "_planes", "_host", "_width", "nbytes")
 
-    def __init__(self, cache: ParityPlaneCache, plane, width: int):
+    def __init__(self, cache: ParityPlaneCache, planes: list, width: int):
         self._lk = threading.Lock()
         self._cache = cache
-        self._plane = plane
+        self._planes = planes  # [(real rows, plane)], in the call's order
         self._host: "np.ndarray | None" = None
-        self._width = width  # of the caller's rows, at most the plane's
-        self.nbytes = int(plane.nbytes)
+        self._width = width  # of the caller's rows, at most the planes'
+        self.nbytes = sum(int(plane.nbytes) for _, plane in planes)
         cache.add(self)
 
     def drain(self) -> np.ndarray:
         """(B, m, L) uint8 parity bytes at the caller's width,
         materialized at most once: the one sanctioned eager readback of
-        a parity plane."""
+        a parity plane.  One launch (a call within the cap) hands its
+        buffer through as a view; several are joined on the host."""
         from ..ops import codec_step
 
         with self._lk:
-            if self._host is None and self._plane is not None:
-                self._host = codec_step.host_words_to_bytes(
-                    _host_readback(self._plane, "parity")
-                )[..., : self._width]
-                self._plane = None
+            if self._host is None and self._planes is not None:
+                self._host = _join([
+                    codec_step.host_words_to_bytes(
+                        _host_readback(plane, "parity")
+                    )[:rows, :, : self._width]
+                    for rows, plane in self._planes
+                ])
+                self._planes = None
                 self._cache.forget(self)
             return self._host
 
     def release(self) -> None:
-        """Drop an unused plane without the transfer (error-path
+        """Drop unused planes without the transfer (error-path
         cleanup of handles whose writers were never scheduled)."""
         with self._lk:
-            if self._plane is not None:
-                self._plane = None
+            if self._planes is not None:
+                self._planes = None
                 self._cache.forget(self)
 
 
@@ -631,6 +701,13 @@ class CodecBackend:
         there, so staging costs no second copy).  Host codecs work at
         the exact width."""
         return nbytes
+
+    def encode_stripes(self, stripe_bytes: int) -> "int | None":
+        """Most stripes of ``stripe_bytes`` (k rows at their staged
+        width) this backend launches at once, None where it takes a
+        batch of any size in one go.  The batcher copies jobs together
+        no further than this."""
+        return None
 
     def encode(self, data: np.ndarray, parity_shards: int, lengths=None):
         """(B, k, L) u8 -> (parity (B, m, L) u8, digests (B, k+m, 8) u32).
@@ -857,11 +934,18 @@ class TpuBackend(CodecBackend):
     def stage_width(self, nbytes: int) -> int:
         return width_rung(nbytes)
 
+    def encode_stripes(self, stripe_bytes: int) -> "int | None":
+        if self.placement_router() is not None:
+            return None  # a mesh takes the flush whole, over its devices
+        return encode_rungs(stripe_bytes)[-1]
+
     def _family(self, family: tuple):
         """The programs of one family of a staged width, as thunks that
         launch each on zeros exactly as the seam launches it (the same
         statics, shapes and placement, so the program a request finds is
-        this one); launches above LAUNCH_BYTES are not the seam's."""
+        this one).  Of the encode and the digest that is every program
+        the width can launch; of the reconstruct the two any traffic
+        meets."""
         from ..ops import codec_step
 
         kind, width = family[0], family[-1]
@@ -872,19 +956,17 @@ class TpuBackend(CodecBackend):
 
         if kind == "encode":
             _, k, m, _ = family
-            for B in _WARM_ENCODE:
-                if B * k * width <= LAUNCH_BYTES:
-                    yield lambda B=B: codec_step.encode_words_fused1(
-                        zeros(B, k, width // 4), m, np.zeros(B, np.int32),
-                        use_pallas=use_pallas, interpret=interpret,
-                    )
+            for B in encode_rungs(k * width):
+                yield lambda B=B: codec_step.encode_words_fused1(
+                    zeros(B, k, width // 4), m, np.zeros(B, np.int32),
+                    use_pallas=use_pallas, interpret=interpret,
+                )
         elif kind == "digest":
-            for rows in _WARM_DIGEST:
-                if rows <= launch_rows(width):
-                    yield lambda rows=rows: codec_step.digest_words(
-                        zeros(1, rows, width // 4),
-                        np.zeros((1, rows), np.int32),
-                    )
+            for rows in digest_rungs(width):
+                yield lambda rows=rows: codec_step.digest_words(
+                    zeros(1, rows, width // 4),
+                    np.zeros((1, rows), np.int32),
+                )
         elif kind == "reconstruct":
             _, k, m, _ = family
             survivors = np.arange(k, dtype=np.int32)
@@ -914,8 +996,7 @@ class TpuBackend(CodecBackend):
         data = np.ascontiguousarray(data, dtype=np.uint8)
         B, k, L = data.shape
         lens = stripe_lengths(data, lengths)
-        data = self._at_rung(data)
-        width = data.shape[-1]
+        width = width_rung(L)
         compiled = parity_shards > 0 and codec_step.pallas_compiled(
             width // 4
         )
@@ -926,6 +1007,19 @@ class TpuBackend(CodecBackend):
             # encode/write overlap survives on the mesh path too
             from ..parallel import mesh as pm
 
+            # a mesh builds its programs a placement and a batch shape:
+            # a power of two of stripes keeps them few (zero stripes
+            # pad, and their results are dropped at the end)
+            padded = encode_rung(B)
+            if padded != B:
+                wide = np.zeros((padded, k, width), dtype=np.uint8)
+                wide[:B, :, :L] = data
+                data = wide
+                lens = np.concatenate(
+                    [lens, np.zeros(padded - B, np.int32)]
+                )
+            else:
+                data = at_width(data, width)
             # on a mesh the staging happens inside the mesh call
             with _launch():
                 h = pm.mesh_encode_hash_begin(
@@ -938,14 +1032,19 @@ class TpuBackend(CodecBackend):
                 "mesh_encode_hash",
                 pallas=compiled and mesh.shape["shard"] == 1,
             )
-            return _AsyncHandle("async-mesh", (h, L))
-        words = self._stage(data)
-        with _launch():
-            parity_w, digests = codec_step.encode_and_hash_words(
-                words, parity_shards, lens
-            )
-        _record_pass("encode_and_hash_words", pallas=compiled)
-        return _AsyncHandle("async", ((parity_w, digests), L))
+            return _AsyncHandle("async-mesh", (h, B, L))
+        launched = []
+        for lo, hi, part, plens in _ladder_chunks(
+            data, lens, encode_rungs(k * width), own=True
+        ):
+            words = self._stage(part)
+            with _launch():
+                parity_w, digests = codec_step.encode_and_hash_words(
+                    words, parity_shards, plens
+                )
+            _record_pass("encode_and_hash_words", pallas=compiled)
+            launched.append((hi - lo, parity_w, digests))
+        return _AsyncHandle("async", (launched, B, L))
 
     def encode_end(self, handle):
         if not isinstance(handle, _AsyncHandle):
@@ -954,20 +1053,28 @@ class TpuBackend(CodecBackend):
             return handle.result
         from ..ops import codec_step
 
-        payload, L = handle.payload
+        payload, B, L = handle.payload
         if handle.kind == "async-mesh":
             from ..parallel import mesh as pm
 
-            parity_w, digests = pm.mesh_encode_hash_end(payload)
+            launched = [(B,) + tuple(pm.mesh_encode_hash_end(payload))]
         elif handle.kind == "async":
-            parity_w, digests = payload
+            launched = payload
         else:
             raise ValueError(
                 f"encode_end: unknown handle kind {handle.kind!r}"
             )
-        parity_w = _host_readback(parity_w, "parity")
-        digests = _host_readback(digests, "data")
-        result = codec_step.host_words_to_bytes(parity_w)[..., :L], digests
+        parity = _join([
+            codec_step.host_words_to_bytes(
+                _host_readback(parity_w, "parity")
+            )[:rows, :, :L]
+            for rows, parity_w, _ in launched
+        ])
+        digests = _join([
+            _host_readback(digests_d, "data")[:rows]
+            for rows, _, digests_d in launched
+        ])
+        result = parity, digests
         handle.result = result
         handle.consumed = True
         handle.payload = None  # drop the device refs
@@ -976,7 +1083,10 @@ class TpuBackend(CodecBackend):
     def encode_digest_begin(self, data, parity_shards, lengths=None):
         """Digest-only start: the fused donated kernel keeps parity on
         device behind a ParityRef; only the 32-byte digests are
-        scheduled for readback."""
+        scheduled for readback.  A call of more stripes than one launch
+        holds (a stream's batch of four full blocks, a coalesced flush)
+        goes out as several, each at an encode rung, as the read side's
+        do: views of the caller's array where it lies at its width."""
         from ..ops import codec_step
 
         data = np.ascontiguousarray(data, dtype=np.uint8)
@@ -990,27 +1100,33 @@ class TpuBackend(CodecBackend):
                 self.encode_begin(data, parity_shards, lengths),
             )
         lens = stripe_lengths(data, lengths)
-        data = self._at_rung(data)
-        width = data.shape[-1]
+        width = width_rung(L)
         use_pallas, interpret = codec_step.pallas_dispatch(width // 4)
-        words = self._stage(data)
-        with _launch():
-            parity_w, digests = codec_step.encode_words_fused1(
-                words,
-                parity_shards,
-                lens,
-                use_pallas=use_pallas,
-                interpret=interpret,
-            )
-        _record_pass("encode_words_fused1", pallas=use_pallas)
-        _record_ragged(np.repeat(lens, k), width)
+        launched, sizes = [], []
+        for lo, hi, part, plens in _ladder_chunks(
+            data, lens, encode_rungs(k * width), own=True
+        ):
+            words = self._stage(part)
+            with _launch():
+                parity_w, digests = codec_step.encode_words_fused1(
+                    words,
+                    parity_shards,
+                    plens,
+                    use_pallas=use_pallas,
+                    interpret=interpret,
+                )
+            _record_pass("encode_words_fused1", pallas=use_pallas)
+            _record_ragged(np.repeat(plens, k), width)
+            sizes.append(part.nbytes)
+            launched.append((hi - lo, parity_w, digests))
+        _record_launches(sizes)
         if self._warmer is not None and parity_shards > 0:
             self._warmer.note(
                 ("encode", k, parity_shards, width),
                 ("digest", width),
                 ("reconstruct", k, parity_shards, width),
             )
-        return _AsyncHandle("digest", (parity_w, digests, L))
+        return _AsyncHandle("digest", (launched, L))
 
     def encode_digest_end(self, handle):
         if not isinstance(handle, _AsyncHandle) or handle.kind not in (
@@ -1031,11 +1147,18 @@ class TpuBackend(CodecBackend):
         else:
             # digests are the ONLY eager readback (MTPU107); parity
             # stays device-resident behind the ref
-            parity_w, digests_d, L = handle.payload
-            digests = _host_readback(digests_d, "data")
+            launched, L = handle.payload
+            digests = _join([
+                _host_readback(digests_d, "data")[:rows]
+                for rows, _, digests_d in launched
+            ])
             result = (
                 digests,
-                _DeviceParityRef(parity_plane_cache(), parity_w, L),
+                _DeviceParityRef(
+                    parity_plane_cache(),
+                    [(rows, parity_w) for rows, parity_w, _ in launched],
+                    L,
+                ),
             )
         handle.result = result
         handle.consumed = True
@@ -1082,11 +1205,14 @@ class TpuBackend(CodecBackend):
             _record_h2d("data", dw.nbytes)
             _record_d2h("data", dw.nbytes)
             return codec_step.host_words_to_bytes(dw)[..., :L]
-        launched = []
+        launched, sizes = [], []
         # the decode takes no length (column-wise), so its launches are
         # not in ``ragged``: their rows lie at the same rungs
         no_lengths = np.zeros(B, dtype=np.int32)
-        for lo, hi, part, _ in _ladder_chunks(shards, no_lengths, n * width):
+        for lo, hi, part, _ in _ladder_chunks(
+            shards, no_lengths, reconstruct_rungs(n * width)
+        ):
+            sizes.append(part.nbytes)
             words = self._stage(part)
             with _launch():
                 dw = codec_step.reconstruct_words_batch(
@@ -1100,6 +1226,7 @@ class TpuBackend(CodecBackend):
                 )
             _record_pass("reconstruct_words_batch", pallas=use_pallas)
             launched.append((lo, hi, dw))
+        _record_launches(sizes)
         if self._warmer is not None:
             self._warmer.note(
                 ("reconstruct", data_shards, parity_shards, width)
@@ -1222,8 +1349,11 @@ class TpuBackend(CodecBackend):
         # came in and whatever lengths its rows have
         width = width_rung(L)
         out = np.empty((B * n, 8), dtype=np.uint32)
-        launched = []
-        for lo, hi, part, plens in _ladder_chunks(rows, lens, width):
+        launched, sizes = [], []
+        for lo, hi, part, plens in _ladder_chunks(
+            rows, lens, digest_rungs(width)
+        ):
+            sizes.append(part.nbytes)
             words = self._stage(part[None])
             # the healthy-read digest has no Pallas kernel: one XLA pass
             with _launch():
@@ -1231,6 +1361,7 @@ class TpuBackend(CodecBackend):
             _record_pass("digest_words")
             _record_ragged(plens, width)
             launched.append((lo, hi, got))
+        _record_launches(sizes)
         if self._warmer is not None:
             self._warmer.note(("digest", width))
         for lo, hi, got in launched:

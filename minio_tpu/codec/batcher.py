@@ -483,50 +483,96 @@ class BatchingBackend(CodecBackend):
             self._run_group(op, key, group)
         except BaseException as e:  # noqa: BLE001
             for j in group:
-                j.error = e
-                j.done.set()
+                if not j.done.is_set():  # an earlier piece's are served
+                    j.error = e
+                    j.done.set()
 
     def _run_group(self, op: str, key: tuple, group: "list[_Job]") -> None:
-        if len(group) == 1:
-            j = group[0]
-            arr = j.arrays[0]
-            out = self._call(op, key, arr, j.lengths)
-            if j.width != arr.shape[-1]:  # not staged by its caller
-                out = self._rows_of(op, out, 0, arr.shape[0], j)
-            j.result = out
-            j.done.set()
+        """Fulfil the jobs of one group.  Every job lies at the key's
+        staged width, whatever the true lengths of its rows: they travel
+        beside it.  The seam cuts a call into launches and pads each to
+        its ladders (codec.backend), so nothing is padded here.  The
+        read side's jobs merge into one call; an encode's go down in
+        pieces (``_encode_pieces``), each begun before the one before
+        it is ended: the device works on a piece while the digests of
+        the last are read back and its jobs' streams go on, and holds
+        two pieces at most."""
+        if op in ("encode", "encode_digest"):
+            pieces = self._encode_pieces(key, group)
+            begin = getattr(self.inner, op + "_begin")
+            end = getattr(self.inner, op + "_end")
+            ahead = None
+            for piece in pieces:
+                arr, lengths = self._merged(piece)
+                handle = begin(arr, key[2], lengths)
+                if ahead is not None:
+                    self._fulfil(op, ahead[0], end(ahead[1]))
+                ahead = (piece, handle)
+            self._fulfil(op, ahead[0], end(ahead[1]))
             return
-        rows = [j.arrays[0].shape[0] for j in group]
-        # every job of a group lies at the key's staged width, whatever
-        # the true lengths of its rows: they travel beside it
-        merged = np.concatenate([j.arrays[0] for j in group], axis=0)
-        lengths = np.concatenate([j.lengths for j in group])
-        total = merged.shape[0]
-        # device backends jit-compile per batch shape: arbitrary merged
-        # sizes would each pay a fresh XLA compile (seconds).  Pad the
-        # merged encode batch up to a power of two so the compile cache
-        # stays O(log max_batch) regardless of traffic mix.  (The read
-        # side - digest, reconstruct - walks its own ladder inside the
-        # seam, codec.backend.ladder: its rows flatten first.)
-        padded = total
-        if getattr(self.inner, "name", "") == "tpu" and op in (
-            "encode", "encode_digest"
-        ):
-            padded = 1 << (total - 1).bit_length()
-            if padded != total:
-                pad = np.zeros(
-                    (padded - total,) + merged.shape[1:], merged.dtype
-                )
-                merged = np.concatenate([merged, pad], axis=0)
-                lengths = np.concatenate(
-                    [lengths, np.zeros(padded - total, np.int32)]
-                )
-        out = self._call(op, key, merged, lengths)
-        # split along the batch axis and fulfill each job
-        offsets = np.cumsum([0] + rows)
-        for i, j in enumerate(group):
-            j.result = self._rows_of(op, out, offsets[i], offsets[i + 1], j)
+        arr, lengths = self._merged(group)
+        if op == "digest":
+            out = self.inner.digest(arr, lengths)
+        elif op == "reconstruct":
+            n, L, present, k, m = key
+            out = self.inner.reconstruct(arr, present, k, m)
+        else:
+            raise ValueError(f"unknown op {op}")
+        self._fulfil(op, group, out)
+
+    def _encode_pieces(
+        self, key: tuple, group: "list[_Job]"
+    ) -> "list[list[_Job]]":
+        """Cut an encode group into seam calls of whole jobs.  Where the
+        backend launches at most so many stripes at once, no array
+        larger than a launch is built: a job of that many stripes or
+        more (a stream's batch of full blocks) goes down alone, as it
+        lies - the seam launches views of it - and the smaller ones are
+        copied together a launch at a time.  A backend without a cap
+        (the host codec's one native call, a mesh) takes the group
+        merged."""
+        k, width, _ = key
+        cap = self.inner.encode_stripes(k * width)
+        if cap is None or len(group) == 1:
+            return [group]
+        pieces, small, held = [], [], 0
+        for j in group:
+            stripes = j.arrays[0].shape[0]
+            if stripes >= cap:
+                pieces.append([j])
+                continue
+            if held + stripes > cap:
+                pieces.append(small)
+                small, held = [], 0
+            small.append(j)
+            held += stripes
+        if small:
+            pieces.append(small)
+        return pieces
+
+    @staticmethod
+    def _merged(jobs: "list[_Job]"):
+        """(rows, lengths) of one seam call: a lone job's array as it
+        lies, several copied together."""
+        if len(jobs) == 1:
+            return jobs[0].arrays[0], jobs[0].lengths
+        return (
+            np.concatenate([j.arrays[0] for j in jobs], axis=0),
+            np.concatenate([j.lengths for j in jobs]),
+        )
+
+    def _fulfil(self, op: str, jobs: "list[_Job]", out) -> None:
+        """Split one call's result along the batch axis, a job its rows."""
+        lo = 0
+        for j in jobs:
+            arr = j.arrays[0]
+            hi = lo + arr.shape[0]
+            if len(jobs) == 1 and j.width == arr.shape[-1]:
+                j.result = out  # staged by its caller: the call's own result
+            else:
+                j.result = self._rows_of(op, out, lo, hi, j)
             j.done.set()
+            lo = hi
 
     @staticmethod
     def _rows_of(op: str, out, lo: int, hi: int, j: _Job):
@@ -541,20 +587,6 @@ class BatchingBackend(CodecBackend):
         if op == "reconstruct":
             return out[lo:hi, :, : j.width]
         return out[lo:hi]
-
-    def _call(self, op: str, key: tuple, arr, lengths):
-        if op == "encode":
-            return self.inner.encode(arr, key[2], lengths)
-        if op == "encode_digest":
-            return self.inner.encode_digest_end(
-                self.inner.encode_digest_begin(arr, key[2], lengths)
-            )
-        if op == "digest":
-            return self.inner.digest(arr, lengths)
-        if op == "reconstruct":
-            n, L, present, k, m = key
-            return self.inner.reconstruct(arr, present, k, m)
-        raise ValueError(f"unknown op {op}")
 
 
 def maybe_wrap(backend: CodecBackend) -> CodecBackend:

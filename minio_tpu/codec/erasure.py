@@ -411,6 +411,9 @@ class Erasure:
                 (self.shard_size_padded(len(b)), base_block + len(full),
                  [b])
             )
+        KERNEL_STATS.record_stream_batch(
+            "encode", len(blocks), tail=bool(full and tail)
+        )
         started = []
         for shard_len, group_block, group in groups:
             with _staged("assemble") as sp:
@@ -692,7 +695,10 @@ class Erasure:
                 aux=True,
             )
             for i, batch_idx in enumerate(batches):
-                datas, healed = fut.result_or_raise()
+                # the handler's wait for the batch its read-ahead decodes
+                datas, healed = fut.result_or_raise(
+                    span_name=spans.STREAM_READAHEAD_WAIT
+                )
                 fut = None
                 heal_required = heal_required or healed
                 if i + 1 < len(batches):
@@ -780,6 +786,9 @@ class Erasure:
             self.shard_size_padded(self._block_len(b, total_length))
             for b in block_indices
         ]
+        KERNEL_STATS.record_stream_batch(
+            "decode", len(block_indices), tail=len(set(sizes)) > 1
+        )
         readers = None
         heal = False
         all_online = False  # every drive of the set, when the read began

@@ -104,6 +104,25 @@ class KernelStats:
         )
         self._widths_true: "set[int]" = set()
         self._staged_rows: "dict[int, int]" = {}
+        # what a stream is made of, by direction (encode | decode):
+        # streams that ended, the blocks and batches of blocks they
+        # carried, and the batches in which a ragged tail block shared
+        # the batch with full ones (one add a batch: erasure.py)
+        self._stream = {
+            d: dict.fromkeys(
+                ("streams", "blocks", "batches", "tail_groups"), 0
+            )
+            for d in ("encode", "decode")
+        }
+        # launches of the three served entry points at the seam
+        # (encode_words_fused1, digest_words, reconstruct_words_batch):
+        # how many, the bytes of their inputs as staged, the seam calls
+        # that went out as more than one, and launches by input bytes -
+        # the two ladders bound its keys; the largest key is the largest
+        # launch since boot, and a window's is the largest whose count
+        # moved
+        self._launch = dict.fromkeys(("count", "bytes", "split_calls"), 0)
+        self._launch_sizes: "dict[int, int]" = {}
         # submesh placement: outcome ("span"|"route") -> batches, and
         # per-submesh in-flight depth (current + high-water mark)
         self._placement: "dict[str, int]" = {}
@@ -135,6 +154,32 @@ class KernelStats:
             row = self._streams.setdefault(kind, [0, 0])
             row[0] += 1
             row[1] += nbytes
+            if kind in self._stream:  # heal streams are not batched here
+                self._stream[kind]["streams"] += 1
+
+    def record_stream_batch(
+        self, direction: str, blocks: int, tail: bool = False
+    ) -> None:
+        """One batch of blocks of an erasure stream (direction = encode
+        | decode); ``tail``: a ragged last block beside full ones."""
+        with self._mu:
+            row = self._stream[direction]
+            row["blocks"] += blocks
+            row["batches"] += 1
+            row["tail_groups"] += bool(tail)
+
+    def record_launches(self, sizes: "list[int]") -> None:
+        """The launches one seam call of a served entry point went out
+        as: the staged input bytes of each."""
+        with self._mu:
+            row = self._launch
+            row["count"] += len(sizes)
+            row["bytes"] += sum(sizes)
+            row["split_calls"] += len(sizes) > 1
+            for size in sizes:
+                self._launch_sizes[size] = (
+                    self._launch_sizes.get(size, 0) + 1
+                )
 
     def record_heal_required(self) -> None:
         with self._mu:
@@ -322,6 +367,15 @@ class KernelStats:
                         for w, n in sorted(self._staged_rows.items())
                     },
                 },
+                "stream": {d: dict(row) for d, row in self._stream.items()},
+                "launch": {
+                    **self._launch,
+                    "max_bytes": max(self._launch_sizes, default=0),
+                    "sizes": {
+                        str(size): n
+                        for size, n in sorted(self._launch_sizes.items())
+                    },
+                },
                 "breaker": _breaker_demotions(),
                 "meta_read": _meta_read_counts(),
                 "remove": _remove_counts(),
@@ -390,6 +444,10 @@ class KernelStats:
             self._ragged = dict.fromkeys(self._ragged, 0)
             self._widths_true.clear()
             self._staged_rows.clear()
+            for row in self._stream.values():
+                row.update(dict.fromkeys(row, 0))
+            self._launch = dict.fromkeys(self._launch, 0)
+            self._launch_sizes.clear()
             self._placement.clear()
             self._submesh_depth.clear()
             self._submesh_depth_hwm.clear()
@@ -501,6 +559,9 @@ class InstrumentedBackend(CodecBackend):
 
     def stage_width(self, nbytes: int) -> int:
         return self.inner.stage_width(nbytes)
+
+    def encode_stripes(self, stripe_bytes: int) -> "int | None":
+        return self.inner.encode_stripes(stripe_bytes)
 
     def encode(self, data, parity_shards, lengths=None):
         return self._timed(

@@ -75,6 +75,25 @@ from ..utils.log import kv, logger
 _log = logger("objectlayer")
 
 
+class _FirstWrite:
+    """A GET's writer, stamping ``get_first_write`` when the first body
+    bytes are handed to it: how long ``get_object`` took to have any
+    (the lock, the metadata round and the first batch of blocks, which
+    is decoded whole before a byte leaves)."""
+
+    __slots__ = ("_writer", "_since_ns")
+
+    def __init__(self, writer, since_ns: int):
+        self._writer = writer
+        self._since_ns = since_ns
+
+    def write(self, data):
+        if self._since_ns:
+            spans.wait(spans.GET_FIRST_WRITE, self._since_ns)
+            self._since_ns = 0
+        return self._writer.write(data)
+
+
 class ErasureObjects(MultipartMixin, ObjectLayer):
     """One erasure set over ``disks`` (offline entries are None)."""
 
@@ -806,6 +825,7 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
         holds the lock and the FileInfo, so neither is taken again (a
         second read lock behind a waiting writer would never be
         granted)."""
+        writer = _FirstWrite(writer, spans.now())
         if locked_fi is None:
             check_object_name(object_name)
             self._require_bucket(bucket)

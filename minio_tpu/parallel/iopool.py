@@ -112,14 +112,27 @@ class IOFuture:
     def done(self) -> bool:
         return self._event.is_set()
 
-    def wait(self, timeout: "float | None" = None) -> bool:
-        if self._event.is_set():
-            return True
-        with spans.span(spans.IOPOOL_RESULT_WAIT):
+    def wait(
+        self,
+        timeout: "float | None" = None,
+        span_name: "str | None" = None,
+    ) -> bool:
+        """``span_name``: a caller whose wait has a name of its own (the
+        GET stream's wait for its read-ahead) is counted under it, every
+        call; the anonymous wait only where it does wait."""
+        if span_name is None:
+            if self._event.is_set():
+                return True
+            span_name = spans.IOPOOL_RESULT_WAIT
+        with spans.span(span_name):
             return self._event.wait(timeout)
 
-    def result_or_raise(self, timeout: "float | None" = None):
-        if not self.wait(timeout):
+    def result_or_raise(
+        self,
+        timeout: "float | None" = None,
+        span_name: "str | None" = None,
+    ):
+        if not self.wait(timeout, span_name):
             raise IopoolTimeout(
                 f"iopool job did not complete within {timeout}s"
             )

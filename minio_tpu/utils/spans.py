@@ -85,6 +85,7 @@ SIGV4_VERIFY = "sigv4_verify"
 HASHREADER_READ = "hashreader_read"
 OL_PUT_OBJECT = "ol_put_object"
 OL_GET_OBJECT = "ol_get_object"
+GET_FIRST_WRITE = "get_first_write"  # ol_get_object's start -> first body bytes
 OL_GET_OBJECT_INFO = "ol_get_object_info"
 OL_DELETE_OBJECT = "ol_delete_object"
 NSLOCK_WAIT = "nslock_wait"
@@ -104,6 +105,7 @@ IOPOOL_RESULT_WAIT = "iopool_result_wait"
 STREAM_ASSEMBLE = "stream_assemble"
 STREAM_CODEC_WAIT = "stream_codec_wait"
 STREAM_DISK = "stream_disk"
+STREAM_READAHEAD_WAIT = "stream_readahead_wait"  # a GET's wait for its prefetch
 BATCH_QUEUE_WAIT = "batch_queue_wait"
 BATCH_FLUSH = "batch_flush"
 FLUSH_TO_LAUNCH = "flush_to_launch"

@@ -29,11 +29,12 @@ PKG = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), 
 TABLE = [
     "aio_queue_wait", "s3_request", "body_read_wait", "resp_write_wait",
     "sigv4_verify", "hashreader_read", "ol_put_object", "ol_get_object",
-    "ol_get_object_info", "ol_delete_object", "nslock_wait", "meta_read_all",
+    "get_first_write", "ol_get_object_info", "ol_delete_object", "nslock_wait", "meta_read_all",
     "xl_read_version", "xl_read_all", "xl_write_all", "xl_rename_data",
     "xl_delete_version", "xl_delete_file", "iopool_queue_wait", "iopool_job",
     "xl_shard_write", "xl_shard_fsync", "xl_shard_read", "iopool_result_wait",
-    "stream_assemble", "stream_codec_wait", "stream_disk", "batch_queue_wait",
+    "stream_assemble", "stream_codec_wait", "stream_disk",
+    "stream_readahead_wait", "batch_queue_wait",
     "batch_flush", "flush_to_launch", "batch_result_wait", "seam_matrix",
     "seam_stage",
     "seam_launch", "seam_kernel_wait", "seam_d2h", "probe",

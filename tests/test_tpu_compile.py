@@ -15,7 +15,7 @@ makes, the digest pass at every rung of the seam's ladder, the
 reconstruct pass at every stripe count a launch takes, heal's two
 passes; then the ragged width of EC 12+4 and a 4 KiB object, and the
 mesh kernels on the four topology devices at B = 1, 4, 8.  The leading
-dimensions come from ``backend.ladder`` / ``launch_rows``, so the list
+dimensions come from the seam's own ``*_rungs``, so the list
 cannot drift from the seam.  A program Mosaic refuses fails here, on the
 CPU, before anyone spends chip time on it.
 
@@ -48,12 +48,6 @@ BLOCK = 10 * 1024 * 1024
 GRID = ((4, 2), (8, 4), (16, 4))
 
 
-def _rungs(row_bytes: int) -> "list[int]":
-    """Every leading dimension the seam launches for rows this long."""
-    cap = backend.launch_rows(row_bytes)
-    return sorted({backend.ladder(r) for r in range(1, cap + 1)})
-
-
 def _cases() -> "list[tuple[str, str, dict]]":
     """(name, kind, params) for every compile; names are the test ids,
     and a single-device kind is the jitted entry point it compiles."""
@@ -68,20 +62,30 @@ def _cases() -> "list[tuple[str, str, dict]]":
 
     for k, m in GRID:
         L = Erasure(k, m).shard_size_padded(BLOCK)
-        # PUT: a flush of the batcher is 1-4 blocks under the
-        # benchmark's traffic (batch_fill 1.1-4.5), 8 under a burst
-        for B in (1, 2, 3, 4, 8):
+        # PUT: however many blocks a flush or a stream's batch holds,
+        # the seam launches an encode at one of its few rungs
+        for B in backend.encode_rungs(k * backend.width_rung(L)):
             add("encode_words_fused1", k, m, B)
         # healthy read: the rows of a flush lie flat, (1, rows, w)
-        for rows in _rungs(L):
+        for rows in backend.digest_rungs(backend.width_rung(L)):
             add("digest_words", k, m, rows)
         # degraded read: stripes of n rows
-        for stripes in _rungs((k + m) * L):
+        for stripes in backend.reconstruct_rungs((k + m) * backend.width_rung(L)):
             add("reconstruct_words_batch", k, m, stripes)
         # heal: verify+reconstruct, then the re-encode
         add("verify_and_reconstruct_words", k, m, 1)
         add("encode_and_hash_words", k, m, 1)
     add("verify_and_reconstruct_words", 8, 4, 8)
+    # a 64 MiB object ends in a 4 MiB block: 32-tile shards at EC 8+4,
+    # beside the full blocks' 80 in the same stream
+    tail = 64 * 1024 * 1024 % BLOCK
+    L = Erasure(8, 4).shard_size_padded(tail)
+    for B in backend.encode_rungs(8 * backend.width_rung(L)):
+        add("encode_words_fused1", 8, 4, B, tail)
+    for rows in backend.digest_rungs(backend.width_rung(L)):
+        add("digest_words", 8, 4, rows, tail)
+    for stripes in backend.reconstruct_rungs(12 * backend.width_rung(L)):
+        add("reconstruct_words_batch", 8, 4, stripes, tail)
     # ragged widths are staged at a rung of the width ladder and take
     # the Pallas kernels with their length an operand: EC 12+4's 10 MiB
     # block (54 tiles -> 56), a 4 KiB object at EC 8+4 (512-byte shards
