@@ -379,6 +379,7 @@ class KernelStats:
                 "breaker": _breaker_demotions(),
                 "meta_read": _meta_read_counts(),
                 "remove": _remove_counts(),
+                "liveness": _liveness_counts(),
                 "body_read": _body_read_counts(),
                 "stages": [
                     {
@@ -456,6 +457,9 @@ class KernelStats:
             from ..storage import xl
 
             xl.META_READ[:] = [0, 0, 0]
+            from ..storage import diskcheck
+
+            diskcheck.LIVENESS[:] = [0, 0, 0]
             from ..server import aio
 
             aio.BODY_READ[:] = [0, 0, 0]
@@ -499,6 +503,14 @@ def _remove_counts() -> dict:
     from ..storage import xl
 
     return xl.remove_counts()
+
+
+def _liveness_counts() -> dict:
+    """The drives' liveness questions (storage/diskcheck.py counts them
+    where they are asked): asked, looked, reset."""
+    from ..storage import diskcheck
+
+    return diskcheck.liveness_counts()
 
 
 def _body_read_counts() -> dict:

@@ -227,9 +227,19 @@ class ErasureZones(ObjectLayer):
 
     # -- objects ----------------------------------------------------------
 
+    def _require_bucket(self, bucket: str) -> None:
+        """The bucket question of a request that goes on to a set.  The
+        set asks it itself before it takes the lock (a real ``stat_vol``
+        on its first live drive, never a cache: a DeleteBucket on
+        another node has to be seen), so with one zone it is asked
+        there, once a request and not twice.  Several zones keep the
+        early answer: it saves a missing bucket a probe of every zone."""
+        if len(self.zones) > 1:
+            self.zones[0].get_bucket_info(bucket)
+
     def put_object(self, bucket, object_name, reader, size=-1, metadata=None,
                    versioned=False, compress=None, sse=None):
-        self.zones[0].get_bucket_info(bucket)  # bucket must exist
+        self._require_bucket(bucket)
         zi = self._put_zone_index(bucket, object_name, max(size, 0))
         info = self.zones[zi].put_object(
             bucket, object_name, reader, size, metadata, versioned,
@@ -240,7 +250,7 @@ class ErasureZones(ObjectLayer):
 
     def get_object(self, bucket, object_name, writer, offset=0, length=-1,
                    version_id="", sse=None):
-        self.zones[0].get_bucket_info(bucket)
+        self._require_bucket(bucket)
         # a zone without the object says so before it writes a byte
         return self._first_hit(
             lambda z: z.get_object(
@@ -250,13 +260,13 @@ class ErasureZones(ObjectLayer):
         )[1]
 
     def get_object_n_info(self, bucket, object_name, version_id=""):
-        self.zones[0].get_bucket_info(bucket)
+        self._require_bucket(bucket)
         return self._first_hit(
             lambda z: z.get_object_n_info(bucket, object_name, version_id)
         )[1]
 
     def get_object_info(self, bucket, object_name, version_id=""):
-        self.zones[0].get_bucket_info(bucket)
+        self._require_bucket(bucket)
         return self._find_zone(bucket, object_name, version_id)[1]
 
     def device_scan_source(self, bucket, object_name):
@@ -288,7 +298,7 @@ class ErasureZones(ObjectLayer):
 
     def delete_object(self, bucket, object_name, version_id="",
                       versioned=False, version_suspended=False):
-        self.zones[0].get_bucket_info(bucket)
+        self._require_bucket(bucket)
         if not version_id and (versioned or version_suspended):
             # marker goes to the object's zone, or the write zone when
             # the key never existed (AWS still mints a marker)

@@ -16,14 +16,41 @@ re-probe (storage/rest_client.py is_online backoff), and local disks
 report offline while their root dir is missing - together with the
 fresh-disk monitor this covers the reference's connectDisks loop
 (erasure-sets.go:200-295) without a dedicated thread.
+
+Whether a LOCAL drive is there is what that look last found
+(``is_online``): format.json cannot be read under a root that is gone,
+so the look already answers it, and between looks the answer is a field
+read - no ``stat`` of the root a drive every time the object layer
+takes its snapshot of live drives, three to five times a request.  A
+call that fails in a way that blames the drive forgets the last look,
+so staleness is bounded by one failed call as well as by the interval.
+Liveness is a hint for masking, never a vote: quorums are counted from
+what each drive answered.
 """
 
 from __future__ import annotations
 
+import errno
 import threading
 import time
 
 from . import errors
+from .xl import SYS_DIR
+
+# is_online() over every DiskIDCheck of the process: [asked, looked
+# (looks at the drive: format.json read, from is_online or from a
+# wrapped call's check), reset (failed calls that blamed the drive and
+# forced the next look)].  Plain adds under the GIL like xl.META_READ;
+# kernel-stats carries them as ``liveness``.  looked / asked is the
+# share of the liveness questions that still cost system calls.
+LIVENESS = [0, 0, 0]
+
+_NEVER = float("-inf")
+
+
+def liveness_counts() -> dict:
+    asked, looked, reset = LIVENESS
+    return {"asked": asked, "looked": looked, "reset": reset}
 
 
 class DiskIDCheck:
@@ -46,8 +73,10 @@ class DiskIDCheck:
         self._expected = expected_id
         self._interval = check_interval_s
         self._mu = threading.Lock()
-        self._last_check = 0.0
+        self._last_check = _NEVER
         self._last_err: "Exception | None" = None
+        # a remote drive's client keeps its own flag and back-off
+        self._local = bool(disk.is_local())
 
     def _check(self) -> None:
         now = time.monotonic()
@@ -57,6 +86,7 @@ class DiskIDCheck:
                     raise self._last_err
                 return
             self._last_check = now
+            LIVENESS[1] += 1
             err: "Exception | None" = None
             try:
                 from ..objectlayer.format import read_format
@@ -81,7 +111,8 @@ class DiskIDCheck:
                 raise err
 
     def is_online(self) -> bool:
-        if not self.unwrapped.is_online():
+        LIVENESS[0] += 1
+        if not self._local and not self.unwrapped.is_online():
             return False
         try:
             self._check()
@@ -89,12 +120,38 @@ class DiskIDCheck:
             return False
         return True
 
+    def _blames_drive(self, exc: Exception) -> bool:
+        """Whether a failed call says the drive, not the object, is at
+        fault.  A volume or a path that is not there may just not
+        exist: the root is asked then, on this error path only."""
+        if isinstance(exc, errors.DiskNotFound):
+            return True
+        if isinstance(exc, errors.VolumeNotFound):
+            # every formatted drive has its system volume
+            return exc.args[:1] == (SYS_DIR,) or not self.unwrapped.is_online()
+        if isinstance(exc, OSError):
+            return exc.errno == errno.EIO or (
+                exc.errno in (errno.ENOENT, errno.ENOTDIR)
+                and not self.unwrapped.is_online()
+            )
+        return False
+
+    def _forget_last_look(self) -> None:
+        with self._mu:
+            self._last_check = _NEVER
+        LIVENESS[2] += 1
+
     def __getattr__(self, name: str):
         attr = getattr(self.unwrapped, name)
         if name in self._CHECKED and callable(attr):
             def wrapped(*a, **k):
                 self._check()
-                return attr(*a, **k)
+                try:
+                    return attr(*a, **k)
+                except (errors.StorageError, OSError) as e:
+                    if self._blames_drive(e):
+                        self._forget_last_look()
+                    raise
 
             wrapped.__name__ = name
             return wrapped

@@ -4,6 +4,7 @@ without restart, dynamic timeouts
 dynamic-timeouts.go)."""
 
 import io
+import os
 import shutil
 
 import pytest
@@ -126,6 +127,213 @@ def test_fresh_disk_monitor_restores_wiped_disk(tmp_path):
     buf = io.BytesIO()
     sets.get_object("bkt", "k", buf)
     assert buf.getvalue() == b"survive-me"
+
+
+# -- the liveness question (PR 34) ------------------------------------------
+#
+# Whether a local drive is there is what its DiskIDCheck last found: the
+# look at format.json, once an interval, fails under a root that is gone,
+# and between looks ``is_online()`` is a field read.  A failed call that
+# blames the drive forgets the last look, so the next question looks again.
+
+
+class _Clock:
+    now = 1000.0
+
+    def monotonic(self):
+        return self.now
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    from minio_tpu.storage import diskcheck
+
+    c = _Clock()
+    monkeypatch.setattr(diskcheck, "time", c)
+    return c
+
+
+def _liveness():
+    from minio_tpu.codec.telemetry import KERNEL_STATS
+
+    return KERNEL_STATS.snapshot()["liveness"]
+
+
+def _moved(before):
+    after = _liveness()
+    return [after[k] - before[k] for k in ("asked", "looked", "reset")]
+
+
+def _server_stack(ordered, ref, interval):
+    from minio_tpu.storage import metered
+
+    return [
+        DiskIDCheck(metered.wrap(d), ref.sets[0][i], check_interval_s=interval)
+        for i, d in enumerate(ordered)
+    ]
+
+
+def _lose_root(raw, tmp_path):
+    kept = str(tmp_path / "kept")
+    shutil.move(raw.root, kept)
+    return kept
+
+
+def _after_a_failed_call(drive, raw, tmp_path, clock):
+    _lose_root(raw, tmp_path)
+    before = _liveness()
+    assert drive.is_online()  # what the drive last said; no look
+    assert _moved(before) == [1, 0, 0]
+    with pytest.raises(serrors.VolumeNotFound):
+        drive.read_version("bkt", "k")
+    assert _moved(before) == [1, 0, 1]
+    assert not drive.is_online()  # the same instant: the call said so
+    assert _moved(before) == [2, 1, 1]
+    with pytest.raises(serrors.DiskNotFound):  # from memory, and no reset
+        drive.read_version("bkt", "k")
+    assert _moved(before) == [2, 1, 1]
+
+
+def _after_the_interval(drive, raw, tmp_path, clock):
+    _lose_root(raw, tmp_path)
+    before = _liveness()
+    clock.now += 0.9
+    assert drive.is_online()
+    clock.now += 0.1
+    assert not drive.is_online()
+    assert not drive.is_online()
+    assert _moved(before) == [3, 1, 0]
+
+
+def _a_root_that_returns(drive, raw, tmp_path, clock):
+    kept = _lose_root(raw, tmp_path)
+    clock.now += 1.0
+    assert not drive.is_online()
+    shutil.move(kept, raw.root)
+    before = _liveness()
+    assert not drive.is_online()  # what it last said, for the interval
+    clock.now += 1.0
+    assert drive.is_online()
+    assert drive.read_all(".sys", "format.json")
+    assert _moved(before) == [2, 1, 0]
+
+
+def _a_missing_bucket_blames_no_drive(drive, raw, tmp_path, clock):
+    before = _liveness()
+    with pytest.raises(serrors.VolumeNotFound):
+        drive.stat_vol("no-such-bucket")
+    with pytest.raises(serrors.FileNotFound):
+        drive.read_version("bkt", "no-such-key")
+    assert drive.is_online()
+    assert _moved(before) == [1, 0, 0]
+
+
+def _a_swapped_format(drive, raw, tmp_path, clock):
+    fmt = read_format(raw)
+    write_format(
+        raw, FormatErasure(id=fmt.id, this="intruder-uuid", sets=fmt.sets)
+    )
+    clock.now += 1.0
+    assert not drive.is_online()
+    with pytest.raises(serrors.DiskNotFound, match="mismatch"):
+        drive.read_all(".sys", "format.json")
+    write_format(raw, fmt)
+    clock.now += 1.0
+    assert drive.is_online()
+
+
+LIVENESS_CASES = {
+    "masked-after-one-failed-call": _after_a_failed_call,
+    "masked-after-the-interval": _after_the_interval,
+    "live-again-after-the-interval": _a_root_that_returns,
+    "a-missing-bucket-blames-no-drive": _a_missing_bucket_blames_no_drive,
+    "a-swapped-format-is-refused": _a_swapped_format,
+}
+
+
+@pytest.mark.parametrize("case", LIVENESS_CASES)
+def test_liveness_is_what_the_drive_last_said(tmp_path, clock, case):
+    ref, ordered = _formatted_disks(tmp_path / "drives")
+    ordered[0].make_vol("bkt")
+    drive = _server_stack(ordered, ref, 1.0)[0]
+    before = _liveness()
+    assert drive.is_online() and drive.is_online()
+    assert _moved(before) == [2, 1, 0]
+    LIVENESS_CASES[case](drive, ordered[0], tmp_path, clock)
+
+
+def test_interval_zero_looks_every_call(tmp_path, clock):
+    ref, ordered = _formatted_disks(tmp_path / "drives")
+    drive = _server_stack(ordered, ref, 0.0)[0]
+    before = _liveness()
+    assert drive.is_online() and drive.is_online() and drive.is_online()
+    assert _moved(before) == [3, 3, 0]
+    _lose_root(ordered[0], tmp_path)
+    assert not drive.is_online()
+    assert _moved(before) == [4, 4, 0]
+
+
+def test_a_bare_drive_and_a_remote_one_keep_their_own_answer(tmp_path, clock):
+    """A bare XLStorage still stats its root (the heal's fresh-disk monitor
+    probes ``raw.is_online()``); a wrapper over a drive that is not local
+    asks its client's flag first, as before."""
+    ref, ordered = _formatted_disks(tmp_path / "drives")
+    raw = ordered[0]
+
+    class Remote:
+        online = True
+
+        def is_local(self):
+            return False
+
+        def is_online(self):
+            return self.online
+
+        def __getattr__(self, name):
+            return getattr(raw, name)
+
+    remote = Remote()
+    drive = DiskIDCheck(remote, ref.sets[0][0], check_interval_s=1.0)
+    assert drive.is_online()
+    remote.online = False
+    assert not drive.is_online()  # no wait for the interval
+    remote.online = True
+    assert drive.is_online()
+    _lose_root(raw, tmp_path)
+    assert not raw.is_online()
+
+
+@pytest.mark.parametrize("lost", [1, 2])
+def test_put_and_get_in_the_second_a_root_goes(tmp_path, clock, lost):
+    """Liveness is a hint for masking, never a vote: a drive that vanishes
+    inside the interval is met on the error path, the write still counts
+    its quorum from what each drive answered and the read decodes from
+    the shards that verify."""
+    ref, ordered = _formatted_disks(tmp_path / "drives", n=6)
+    guarded = _server_stack(ordered, ref, 1.0)
+    ol = ErasureObjects(guarded, parity_blocks=2, block_size=4096, min_part_size=1)
+    ol.make_bucket("bkt")
+    old, new = os.urandom(20000), os.urandom(30000)
+    ol.put_object("bkt", "old", io.BytesIO(old), len(old))
+    assert all(d is not None for d in ol._online_disks())
+    before = _liveness()
+    for i in range(lost):
+        shutil.rmtree(ordered[2 + i].root)
+    # the same instant: every drive still says it is there
+    assert all(d.is_online() for d in guarded)
+    ol.put_object("bkt", "new", io.BytesIO(new), len(new))
+    for key, want in (("new", new), ("old", old)):
+        buf = io.BytesIO()
+        ol.get_object("bkt", key, buf)
+        assert buf.getvalue() == want
+    assert ol.get_object_info("bkt", "new").size == len(new)
+    online = ol._online_disks()
+    assert [d is None for d in online] == [2 <= i < 2 + lost for i in range(6)]
+    assert _moved(before)[2] >= lost
+    # at write quorum (4 of 6): the new object is on every drive that is left
+    for i, raw in enumerate(ordered):
+        assert os.path.exists(os.path.join(raw.root, "bkt", "new", "xl.meta")) == (
+            not 2 <= i < 2 + lost)
 
 
 # -- dynamic timeouts -----------------------------------------------------

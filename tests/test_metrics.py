@@ -492,6 +492,59 @@ def test_admin_kernel_stats_remove_counts_a_served_removal(server, client, case)
         assert client.get_object("metrbkt", key).status == 404
 
 
+@pytest.fixture(scope="module")
+def guarded_server(tmp_path_factory):
+    """A server over the stack ``server/__main__`` builds: every drive a
+    DiskIDCheck(MeteredDisk(XLStorage)), which is where liveness is kept."""
+    from minio_tpu.server.__main__ import build_object_layer
+
+    root = tmp_path_factory.mktemp("guarded")
+    ol = build_object_layer([str(root / "d{1...4}")])
+    iam = IAMSys("minioadmin", "minioadmin", ol)
+    srv = S3Server(ol, address="127.0.0.1:0", iam=iam).start()
+    c = S3Client(srv.endpoint)
+    c.make_bucket("livebkt")
+    yield srv, c
+    srv.shutdown()
+
+
+# request -> snapshots of the live drives it takes (x 4 drives = asked)
+LIVENESS_SERVED = {"STAT": 2, "GET": 3, "PUT": 3, "DELETE": 3}
+
+
+@pytest.mark.parametrize("verb", LIVENESS_SERVED)
+def test_admin_kernel_stats_liveness_counts_the_questions(guarded_server, verb):
+    """``liveness`` (storage/diskcheck.py::LIVENESS, beside ``meta_read`` and
+    ``remove``): a request asks every drive of the set two or three times
+    whether it is there and looks at most once a drive a second."""
+    _, c = guarded_server
+
+    def counts():
+        r = c.request("GET", f"{ADMIN}/kernel-stats")
+        assert r.status == 200, r.body
+        doc = json.loads(r.body)
+        assert set(doc["liveness"]) == {"asked", "looked", "reset"}
+        return doc["liveness"]
+
+    key = f"live-{verb}"
+    assert c.put_object("livebkt", key, b"l" * 5000).status == 200
+    before, t0 = counts(), time.monotonic()
+    if verb == "STAT":
+        assert c.request("HEAD", f"/livebkt/{key}").status == 200
+    elif verb == "GET":
+        assert c.get_object("livebkt", key).body == b"l" * 5000
+    elif verb == "PUT":
+        assert c.put_object("livebkt", key, b"m" * 5000).status == 200
+    else:
+        assert c.request("DELETE", f"/livebkt/{key}").status == 204
+    after, took = counts(), time.monotonic() - t0
+    moved = {k: after[k] - before[k] for k in after}
+    # what else asks in between: the admin call itself asks no drive
+    assert moved["asked"] == 4 * LIVENESS_SERVED[verb]
+    assert moved["looked"] <= 4 * int(took + 1)
+    assert moved["reset"] == 0
+
+
 def test_admin_healthinfo_includes_api_stats(server, client):
     r = client.request("GET", f"{ADMIN}/healthinfo")
     assert r.status == 200, r.body
