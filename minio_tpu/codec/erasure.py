@@ -589,6 +589,9 @@ class Erasure:
         with _staged("disk") as sp:
             dead = flusher.flush(jobs, write_quorum)
         stages["disk"] += sp.seconds
+        spans.fanout_done(
+            spans.phase.PUT_FLUSH, sp.wall_ns, flusher.quorum_job
+        )
         for s in dead:
             if s < len(writers):
                 writers[s] = None
@@ -1021,6 +1024,8 @@ class Erasure:
         last_hedge = 0.0
         hedges = 0
         launched = 0  # shard reads of this group, hedges among them
+        waited_ns = 0  # in wait_any, over the group's rounds
+        last_read = None  # the read whose end completed the set
 
         def launch(hedge: bool) -> None:
             nonlocal launched
@@ -1067,6 +1072,7 @@ class Erasure:
                         [v[0] for v in outstanding.values()], timeout
                     )
                 stages["disk"] += sp.seconds
+                waited_ns += sp.wall_ns
                 if not done:
                     # deadline expired, quorum still short: duplicate
                     # read on the next preferred (parity) shard
@@ -1077,6 +1083,9 @@ class Erasure:
                 # settle every completed slot in one batch
                 batch = sorted(
                     s for s, v in outstanding.items() if v[0].done()
+                )
+                last_read = iopool.last_done(
+                    [outstanding[s][0] for s in batch]
                 )
                 with _staged("assemble") as sp:
                     for s in batch:
@@ -1144,6 +1153,8 @@ class Erasure:
                 if is_hedge:
                     KERNEL_STATS.record_hedge("wasted")
             KERNEL_STATS.record_hedge("shard_reads", launched)
+        if last_read is not None:
+            spans.fanout_done(spans.phase.GET_READS, waited_ns, last_read)
         return shards, digests, ok, heal
 
     # ---- heal (cmd/erasure-lowlevel-heal.go:28-48) ----------------------

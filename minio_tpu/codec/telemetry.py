@@ -9,8 +9,18 @@ those passes in production:
   bytes processed, and the seconds the calling thread spent inside the
   seam, labeled by the resolved backend (``tpu``/``cpu``); plus batcher
   occupancy (jobs coalesced per flush, queue wait) and erasure-stream
-  totals.  Its snapshot also carries ``spans`` and ``probe``: the
-  always-on counters of utils/spans.py, merged over the threads.
+  totals.  Its snapshot also carries the tables of utils/spans.py:
+  ``spans`` and ``probe``, the always-on counters merged over the
+  threads; ``requests`` (self time by S3 verb on the request's own
+  thread: adds up to the root's wall) and ``fanout`` (a wait for drive
+  jobs that ran abreast, by the one job that ended it), kept always, a
+  dict update a span and an add a wait; ``cpu`` (the process's CPU by
+  thread role) and ``loops`` (requests and handler-queue wait by event
+  loop), which cost the hot path nothing: whoever asks for a snapshot
+  pays for them (one clock reading a Python thread, no file).  CPU by
+  role comes from the scheduler's books because a clock reading a span
+  is a system call under the GIL (utils/spans.py) and sees only threads
+  that open spans - not the loops' wire work, the runtime's own pools.
 * ``InstrumentedBackend`` - a CodecBackend decorator recording every
   encode / encode_begin-end / digest / reconstruct /
   reconstruct_and_verify through the seam.
@@ -300,8 +310,9 @@ class KernelStats:
         from ..parallel import iopool
 
         enqueue_hwm = iopool.depth_hwm()
-        # utils/spans.py: [{role, name, count, wall_seconds, cpu_seconds}]
-        # and the interpreter probe; merged outside our mutex
+        # utils/spans.py: [{role, name, count, wall_seconds, cpu_seconds}],
+        # the interpreter probe, and requests / fanout / cpu / loops;
+        # merged outside our mutex
         span_tables = spans.snapshot()
         with self._mu:
             return {

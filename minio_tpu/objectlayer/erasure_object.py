@@ -414,7 +414,8 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
                 if s >= k and w is not None:
                     band.submit(s, iopool.stream_io_key(w), w.close)
         for err in iopool.fanout(
-            [(iopool.stream_io_key(w), w.close) for w in close_inline]
+            [(iopool.stream_io_key(w), w.close) for w in close_inline],
+            span_name=spans.PUT_CLOSE_WAIT,
         ):
             if err is not None and not isinstance(err, OSError):
                 raise err
@@ -476,7 +477,10 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
             rename_ops.append((i, iopool.disk_io_key(d) or f"disk-{i}", fn))
         for (i, _k, _f), err in zip(
             rename_ops,
-            iopool.fanout([(key, fn) for _i, key, fn in rename_ops]),
+            iopool.fanout(
+                [(key, fn) for _i, key, fn in rename_ops],
+                span_name=spans.PUT_RENAME_WAIT,
+            ),
         ):
             errs[i] = err
         try:

@@ -64,7 +64,8 @@ class IOFuture:
     """Completion handle for one pool job (result OR error, both kept)."""
 
     __slots__ = (
-        "_lk", "_event", "_finished", "_cbs", "abandoned", "result", "error"
+        "_lk", "_event", "_finished", "_cbs", "abandoned", "result",
+        "error", "queued_ns", "started_ns", "done_ns",
     )
 
     def __init__(self):
@@ -75,6 +76,11 @@ class IOFuture:
         self.abandoned = False
         self.result = None
         self.error: "BaseException | None" = None
+        # three stamps the spans read anyway (ns): into the queue, out of
+        # it (iopool_queue_wait's end), the job's end (iopool_job's); a
+        # wait for several jobs tells kernel-stats.fanout by them how late
+        # its slowest started and how long it ran
+        self.queued_ns = self.started_ns = self.done_ns = 0
 
     def abandon(self) -> None:
         """Disavow a hedged-past job: nobody will consume its result.
@@ -148,7 +154,8 @@ class _IOQueue:
         self.idx = idx
         self.label = f"q{idx}"
         self.cv = threading.Condition()
-        # (future, fn, nbytes, enqueued at ns, the submitter's span context)
+        # (future - it holds the enqueue stamp -, fn, nbytes, the
+        # submitter's span context)
         self.items: "collections.deque" = collections.deque()
         self.thread: "threading.Thread | None" = None
         self.depth_hwm = 0  # deepest backlog seen at enqueue; cv held
@@ -231,7 +238,8 @@ class IOPool:
             # stamped once through the backpressure: the wait measured
             # at dequeue is the queue's, the submitter's own stall shows
             # in whatever span it submits from
-            q.items.append((fut, fn, nbytes, spans.now(), ctx))
+            fut.queued_ns = spans.now()
+            q.items.append((fut, fn, nbytes, ctx))
             if len(q.items) > q.depth_hwm:
                 q.depth_hwm = len(q.items)
             if q.thread is None:
@@ -254,11 +262,13 @@ class IOPool:
                     q.cv.wait(0.5)
                 if not q.items:
                     return  # shut down and drained
-                fut, fn, nbytes, since_ns, ctx = q.items.popleft()
+                fut, fn, nbytes, ctx = q.items.popleft()
                 depth = len(q.items)
                 q.cv.notify_all()  # wake backpressured submitters
             with spans.adopt(ctx):
-                spans.wait(spans.IOPOOL_QUEUE_WAIT, since_ns)
+                fut.started_ns = spans.wait(
+                    spans.IOPOOL_QUEUE_WAIT, fut.queued_ns
+                )
                 self._run_job(q, fut, fn, nbytes, depth)
             # an idle worker must not pin its last job's closure or
             # result (a decoded read-ahead batch is many MiB) until
@@ -282,6 +292,7 @@ class IOPool:
         if fut.abandoned:
             # hedged past while still queued: resolve without running
             # so the band slot frees now, not behind a straggling disk
+            fut.done_ns = fut.started_ns
             fut._resolve(
                 None, IopoolAbandoned("job abandoned before dequeue")
             )
@@ -293,6 +304,9 @@ class IOPool:
                 result = fn()
             except BaseException as e:  # noqa: BLE001 - surfaced via future
                 error = e
+        if not fut.started_ns:  # run inline: it waited in no queue
+            fut.queued_ns = fut.started_ns = sp.t0
+        fut.done_ns = sp.t0 + sp.wall_ns
         try:
             _stats_record_job(q.label, nbytes, sp.seconds, depth)
         except Exception as exc:  # stats must never wedge a future
@@ -352,6 +366,9 @@ class ShardFlusher:
         self._reported: "set[int]" = set()
         self._acked_gens: "set[int]" = set()
         self.submitted = 0
+        # the job whose ack made the last flush()'s quorum: the one its
+        # wait waited for (kernel-stats.fanout.put_flush)
+        self.quorum_job: "IOFuture | None" = None
         # Invoked (outside the flusher lock) as on_late_dead(slot, err)
         # when a job fails AFTER its batch already returned from
         # flush() — i.e. past the quorum ack, where nobody is left
@@ -427,21 +444,30 @@ class ShardFlusher:
                 self._slot_pending[s] = self._slot_pending.get(s, 0) + 1
             self._pending_total += len(jobs)
             self.submitted += len(jobs)
+        futs: "dict[int, list[IOFuture]]" = {s: [] for s in slots}
         for slot, key, fn, nbytes in jobs:
             fut = self._pool.submit(key, fn, nbytes=nbytes)
+            futs[slot].append(fut)
             fut.add_done_callback(
                 lambda f, g=gen, s=slot: self._on_done(g, s, f)
             )
         with self._cv:
             while True:
-                acked = sum(
-                    1
+                acked = [
+                    s
                     for s in slots
                     if self._cur_pending.get(s, 0) == 0
                     and s not in self._cur_failed
-                )
-                if acked >= quorum:
+                ]
+                if len(acked) >= quorum:
                     self._acked_gens.add(gen)
+                    # slots may have acked since the quorum-th did: it is
+                    # the quorum-th by its end that ended the wait
+                    ends = sorted(
+                        (last_done(futs[s]) for s in acked),
+                        key=lambda f: f.done_ns,
+                    )
+                    self.quorum_job = ends[quorum - 1] if quorum else None
                     return self._take_dead_locked()
                 possible = len(slots) - len(self._cur_failed)
                 if possible < quorum:
@@ -637,19 +663,35 @@ def stream_io_key(stream):
     return getattr(stream, "io_key", None) or id(stream)
 
 
-def fanout(ops, pool: "IOPool | None" = None) -> list:
+def fanout(
+    ops, pool: "IOPool | None" = None, span_name: "str | None" = None
+) -> list:
     """Run ``[(key, fn), ...]`` concurrently; return ``[error, ...]``
     (None on success) in submission order.  The object layer's per-disk
     commit loops (writer close -> fsync, rename_data -> meta fsync) go
     through here so a PUT pays one disk's metadata latency, not the sum
     over all n — fsync parks in the kernel and releases the GIL, so the
-    overlap is real even on a single-core host."""
+    overlap is real even on a single-core host.
+
+    ``span_name``: a site whose wait has a name of its own (as
+    ``IOFuture.wait`` takes one) is counted under it and not under the
+    anonymous wait, and as a phase of ``kernel-stats.fanout`` with the
+    job that finished last."""
     p = pool or get_pool()
     futs = [p.submit(k, f) for k, f in ops]
-    with spans.span(spans.IOPOOL_RESULT_WAIT):
+    with spans.span(span_name or spans.IOPOOL_RESULT_WAIT) as sp:
         for fut in futs:
             fut._event.wait()
+    phase = spans.phase.OF_WAIT.get(span_name)
+    if phase is not None and futs:
+        spans.fanout_done(phase, sp.wall_ns, last_done(futs))
     return [fut.error for fut in futs]
+
+
+def last_done(futs) -> IOFuture:
+    """Of finished jobs, the one that finished last: the one a wait for
+    all of them waited for."""
+    return max(futs, key=lambda f: f.done_ns)
 
 
 def wait_any(futs, timeout: "float | None" = None) -> list:

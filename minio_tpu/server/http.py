@@ -480,6 +480,14 @@ class S3Server:
         return f"{scheme}://{self.host}:{self.port}"
 
 
+# a HEAD's policy action -> its S3 API name (kernel-stats.requests' verb)
+_HEAD_VERBS = {
+    "GetObject": "HeadObject",
+    "GetObjectVersion": "HeadObject",
+    "ListBucket": "HeadBucket",
+}
+
+
 class _Handler(BaseHTTPRequestHandler):
     protocol_version = "HTTP/1.1"
     s3: S3Server = None  # injected subclass attribute
@@ -719,11 +727,15 @@ class _Handler(BaseHTTPRequestHandler):
         records and ride the request's trace entry."""
         spans.begin_request(self.s3.tracer.active)
         self._trace_tail = None
+        self._verb = ""  # the S3 API call, once _authorize has resolved one
         try:
             with spans.span(spans.S3_REQUEST):
                 self._route()
         finally:
-            records = spans.end_request()
+            # kernel-stats.requests: the request's self times go to its verb
+            records = spans.end_request(
+                self._verb, getattr(self, "_queue_wait_ns", 0)
+            )
             if self._trace_tail is not None:
                 self._emit_trace_audit(*self._trace_tail, records)
 
@@ -1144,6 +1156,13 @@ class _Handler(BaseHTTPRequestHandler):
             self.command, bucket, key, query, dict(self.headers.items())
         )
         self._action = action.partition(":")[2]  # metrics API label
+        # kernel-stats.requests: a HEAD is authorized as the GET it answers
+        # like, and is a verb of its own
+        self._verb = (
+            _HEAD_VERBS.get(self._action, self._action)
+            if self.command == "HEAD"
+            else self._action
+        )
         if not self._check_action(action, bucket, key, ctx.access_key):
             raise S3Error("AccessDenied")
         # CopyObject/UploadPartCopy additionally need read access on the

@@ -37,9 +37,9 @@ Three sinks, no fourth:
 
 1. *Counters, always on.*  Per thread a plain dict ``name -> [count,
    wall_ns, cpu_ns]``, touched without a lock; the thread's role (loop,
-   handler, iopool, batcher, other) comes from its name.
-   ``KernelStats.snapshot()`` merges them into ``kernel-stats.spans``
-   and the probe below into ``kernel-stats.probe``.
+   handler, iopool, batcher, warmer, crawler, probe, other) comes from
+   its name.  ``KernelStats.snapshot()`` merges them into
+   ``kernel-stats.spans`` and the probe below into ``kernel-stats.probe``.
 2. *The profiler's trace, while one is being taken.*  A span enters
    ``jax.profiler.TraceAnnotation("mtpu/<name>", req=<id>)``, the same
    clock as the device's planes.  Building the annotation costs ten
@@ -55,6 +55,47 @@ Three sinks, no fourth:
 The request identifier is minted once per request (``begin_request``),
 kept in the thread's state, captured where work is handed to another
 thread (``capture``) and restored there (``adopt``).
+
+Four tables beside ``spans`` and ``probe`` account a request's wall and the
+server's CPU, each to 100 %; the first two are kept always, the last two
+are made when a snapshot is asked for and cost the hot path nothing:
+
+``kernel-stats.requests`` - *self time by verb, on the request's own thread.*
+    A span's self time is its wall less the wall of the spans opened under
+    it on the same thread (each span remembers the one it was opened under
+    and adds its wall to that one's ``_under`` when it ends: two or three
+    attribute writes).  Between ``begin_request`` and ``end_request`` the
+    thread that called them keeps ``name -> [count, self_ns]`` in one small
+    dict, which ``end_request(verb, queue_wait_ns)`` folds into the row of
+    the S3 API call the handler resolved (``other`` if none), with the
+    root's own wall and CPU readings: no clock is read for it.  A verb's
+    self times add up to its wall to the nanosecond.  The root's own self
+    time and the four ``ol_*`` spans' are the time inside no named child:
+    the map's blind spot, reported.  Spans of other threads (an iopool job,
+    the flush, a read-ahead) are not subtracted - the request's thread was
+    in some span of its own meanwhile, waiting - and ``wait()`` hand-overs
+    are no part of the nesting.
+``kernel-stats.fanout`` - *the drive fan-outs by the job that ended them.*
+    A PUT waits three times for jobs that run twelve abreast (a batch's
+    shard writes to quorum, the writers' close, ``rename_data``), a GET
+    once a block group; summing the jobs' queue waits counts twelve side
+    by side twelve times.  ``fanout_done(phase, wall_ns, job)`` adds the
+    wait's wall (its span's own reading) and, from three stamps an
+    ``IOFuture`` gets for free, the queue wait and the run of the one job
+    whose completion ended the wait.  A late start is the hand-off and the
+    GIL; a long run is the drive's own calls.
+``kernel-stats.cpu`` - *the process's CPU by thread role.*  Why not from a
+    clock reading a span: that is the 5.6 us above, thousands of times a
+    second, and it sees only the threads that open spans.  The scheduler
+    keeps the account anyway, for every thread: ``_cpu_table`` reads each
+    Python thread's CPU clock by its tid when a snapshot is taken (the
+    number ``/proc/self/task/<tid>/schedstat`` prints) and adds what each
+    burnt since the last snapshot to its role, beside ``process_seconds``;
+    what the process burnt and no Python thread did is ``native``: XLA's
+    and PJRT's pools.
+``kernel-stats.loops`` - the handler threads' ``s3_request`` count and
+    ``aio_queue_wait`` by the ``aio<N>`` of their names, before the merge by
+    role throws it away: which loops a run's connections hashed onto.
 
 The interpreter probe (``PROBE``) is one daemon thread that sleeps 20 ms
 at a time and records how late each wake-up was: with one GIL, that is
@@ -102,6 +143,8 @@ XL_SHARD_WRITE = "xl_shard_write"
 XL_SHARD_FSYNC = "xl_shard_fsync"
 XL_SHARD_READ = "xl_shard_read"
 IOPOOL_RESULT_WAIT = "iopool_result_wait"
+PUT_CLOSE_WAIT = "put_close_wait"  # a PUT's wait for its writers' close, every drive
+PUT_RENAME_WAIT = "put_rename_wait"  # ... and for rename_data, every drive
 STREAM_ASSEMBLE = "stream_assemble"
 STREAM_CODEC_WAIT = "stream_codec_wait"
 STREAM_DISK = "stream_disk"
@@ -125,6 +168,23 @@ CPU_SPANS = frozenset({
 })
 
 
+# the object layer's own spans: with the root's, their self time is the time
+# inside no named child - kernel-stats.requests' blind spot
+OL_SPANS = (OL_PUT_OBJECT, OL_GET_OBJECT, OL_GET_OBJECT_INFO, OL_DELETE_OBJECT)
+
+
+
+class phase:
+    """The rows of ``kernel-stats.fanout``: a wait for drive jobs that ran
+    abreast, named by its site (not spans: nothing opens them)."""
+
+    PUT_FLUSH = "put_flush"  # ShardFlusher.flush: a batch's shard writes, to quorum
+    PUT_CLOSE = "put_close"  # the writers' close, every drive
+    PUT_RENAME = "put_rename"  # rename_data, every drive
+    GET_READS = "get_reads"  # a block group's shard reads, to the k it decodes from
+    OF_WAIT = {PUT_CLOSE_WAIT: PUT_CLOSE, PUT_RENAME_WAIT: PUT_RENAME}
+
+
 def _role_of(thread_name: str) -> str:
     if thread_name.startswith("aio-loop"):
         return "loop"
@@ -134,7 +194,19 @@ def _role_of(thread_name: str) -> str:
         return "iopool"
     if thread_name.startswith("codec-batcher"):
         return "batcher"
+    if thread_name.startswith("codec-warmer"):
+        return "warmer"
+    if thread_name.startswith("data-crawler"):
+        return "crawler"
+    if thread_name.startswith("interp-probe"):
+        return "probe"
     return "other"
+
+
+def _loop_of(thread_name: str) -> "int | None":
+    """The ``N`` of a handler thread's ``aio<N>-worker-<i>``."""
+    head = thread_name.partition("-worker-")[0]
+    return int(head[3:]) if head[3:].isdigit() else None
 
 
 # -- per-thread state ------------------------------------------------------
@@ -144,17 +216,27 @@ class _State:
     """One thread's counters and the request context it is working for."""
 
     __slots__ = (
-        "thread", "role", "counters", "req", "sink", "parent", "handoff",
+        "thread", "role", "loop", "counters", "req", "sink", "parent",
+        "handoff", "open", "acc", "root", "verbs",
     )
 
     def __init__(self, thread: threading.Thread):
         self.thread = thread
         self.role = _role_of(thread.name)
+        self.loop = _loop_of(thread.name) if self.role == "handler" else None
         self.counters: "dict[str, list]" = {}
         self.req = ""
         self.sink = None  # the request's record list while admin trace listens
         self.parent = None  # the open span's record, same condition
         self.handoff = None  # (name, since_ns) waiting for its pick-up
+        self.open = None  # the innermost span open on THIS thread
+        # between begin_request and end_request, on the thread that called
+        # them: name -> [count, self_ns] of the request's spans here, and the
+        # [wall_ns, cpu_ns] of those no span encloses (the root)
+        self.acc: "dict[str, list] | None" = None
+        self.root = None
+        # verb -> [count, wall_ns, cpu_ns, queue_wait_ns, {name: [count, self_ns]}]
+        self.verbs: "dict[str, list]" = {}
 
 
 _tls = threading.local()
@@ -162,6 +244,8 @@ _REG_LK = threading.Lock()
 _STATES: "list[_State]" = []
 # (role, name) -> [count, wall_ns, cpu_ns] of threads that have exited
 _RETIRED: "dict[tuple[str, str], list]" = {}
+_RETIRED_VERBS: "dict[str, list]" = {}  # their verb rows
+_RETIRED_LOOPS: "dict[int, list]" = {}  # loop -> [requests, queue_wait_ns] of theirs
 _SWEEP_AT = 256  # fold dead threads' counters once this many states exist
 
 
@@ -187,6 +271,29 @@ def _items(counters: dict) -> list:
             continue
 
 
+def _add_verbs(into: "dict[str, list]", verbs: "dict[str, list]") -> None:
+    for verb, row in _items(verbs):
+        have = into.get(verb)
+        if have is None:
+            have = into[verb] = [0, 0, 0, 0, {}]
+        for i in range(4):
+            have[i] += row[i]
+        for name, (n, ns) in _items(row[4]):
+            cell = have[4].setdefault(name, [0, 0])
+            cell[0] += n
+            cell[1] += ns
+
+
+_ZERO = (0, 0, 0)
+
+
+def _add_loop(into: "dict[int, list]", st: _State) -> None:
+    if st.loop is not None:
+        cell = into.setdefault(st.loop, [0, 0])
+        cell[0] += st.counters.get(S3_REQUEST, _ZERO)[0]
+        cell[1] += st.counters.get(AIO_QUEUE_WAIT, _ZERO)[1]
+
+
 def _fold_dead_locked() -> None:
     live = []
     for st in _STATES:
@@ -197,6 +304,8 @@ def _fold_dead_locked() -> None:
             into = _RETIRED.setdefault((st.role, name), [0, 0, 0])
             for i in range(3):
                 into[i] += row[i]
+        _add_verbs(_RETIRED_VERBS, st.verbs)
+        _add_loop(_RETIRED_LOOPS, st)
     _STATES[:] = live
 
 
@@ -238,7 +347,10 @@ class span:
     """``with span(name, **args) as sp:`` - see the module docstring.
     ``args`` ride the profiler annotation beside ``req``."""
 
-    __slots__ = ("name", "args", "t0", "wall_ns", "_st", "_c0", "_ann", "_rec")
+    __slots__ = (
+        "name", "args", "t0", "wall_ns", "_st", "_c0", "_ann", "_rec",
+        "_up", "_under",
+    )
 
     def __init__(self, name: str, **args):
         self.name = name
@@ -258,6 +370,11 @@ class span:
             ann = ann(PREFIX + self.name, req=st.req, **self.args)
             ann.__enter__()
         self._ann = ann
+        # self time: what the spans opened under this one on this thread
+        # take is theirs, the rest is this one's own
+        self._up = st.open
+        self._under = 0
+        st.open = self
         # the CPU readings lie inside the wall readings, so cpu <= wall
         self.t0 = now()
         self._c0 = _cpu() if self.name in CPU_SPANS else -1
@@ -274,6 +391,20 @@ class span:
             self._ann.__exit__(*exc)
         st = self._st
         _count(st, self.name, wall, cpu or 0)
+        up = st.open = self._up
+        acc = st.acc
+        if acc is not None:
+            if up is None:
+                st.root[0] += wall
+                st.root[1] += cpu or 0
+            row = acc.get(self.name)
+            if row is None:
+                acc[self.name] = [1, wall - self._under]
+            else:
+                row[0] += 1
+                row[1] += wall - self._under
+        if up is not None:
+            up._under += wall
         rec = self._rec
         if rec is not None:
             rec[1], rec[2], rec[3] = self.t0, wall, cpu
@@ -343,6 +474,9 @@ def begin_request(recording: bool) -> str:
     st.req = "%016X" % random.getrandbits(64)
     st.sink = [] if recording else None
     st.parent = None
+    st.open = None
+    st.acc = {}
+    st.root = [0, 0]
     return st.req
 
 
@@ -350,11 +484,31 @@ def request_id() -> str:
     return _state().req
 
 
-def end_request() -> "list[dict] | None":
-    """Forget the request; its records, rendered for ``trace_info``: the
-    root first, offsets from the root's start, only spans that had ended
-    when the root did."""
+def end_request(verb: str = "", queue_wait_ns: int = 0) -> "list[dict] | None":
+    """Forget the request.  Its self times on this thread are folded into
+    the row of ``verb`` (the S3 API call the handler resolved; anything
+    else is ``other``), with the root's own wall and CPU readings and the
+    wait in the handler queue that the caller holds.  Returns its records,
+    rendered for ``trace_info``: the root first, offsets from the root's
+    start, only spans that had ended when the root did."""
     st = _state()
+    acc, st.acc = st.acc, None
+    if acc:
+        row = st.verbs.get(verb or "other")
+        if row is None:
+            row = st.verbs[verb or "other"] = [0, 0, 0, 0, {}]
+        row[0] += 1
+        row[1] += st.root[0]
+        row[2] += st.root[1]
+        row[3] += queue_wait_ns
+        into = row[4]
+        for name, (n, ns) in acc.items():
+            cell = into.get(name)
+            if cell is None:
+                into[name] = [n, ns]
+            else:
+                cell[0] += n
+                cell[1] += ns
     sink, st.req, st.sink, st.parent = st.sink, "", None, None
     if not sink:
         return None
@@ -568,21 +722,114 @@ class _Probe:
 PROBE = _Probe()
 
 
+# -- the drive fan-outs ---------------------------------------------------------
+
+_FAN_LK = threading.Lock()
+# phase -> [count, wall_ns, last_queue_ns, last_run_ns]
+_FANOUT: "dict[str, list]" = {}
+
+
+def fanout_done(phase: str, wall_ns: int, job) -> None:
+    """A wait for jobs that ran abreast has ended after ``wall_ns``: add it
+    to ``kernel-stats.fanout.<phase>`` with the queue wait and the run of
+    ``job``, the one whose completion ended it (anything with the stamps
+    ``queued_ns`` / ``started_ns`` / ``done_ns``, as an ``IOFuture`` keeps
+    them).  A
+    late start is the hand-off and the GIL, a long run the drive's calls."""
+    with _FAN_LK:
+        row = _FANOUT.get(phase)
+        if row is None:
+            row = _FANOUT[phase] = [0, 0, 0, 0]
+        row[0] += 1
+        row[1] += wall_ns
+        if job is not None:
+            row[2] += job.started_ns - job.queued_ns
+            row[3] += job.done_ns - job.started_ns
+
+
+# -- the process's CPU by thread role --------------------------------------------
+
+_CPU_LK = threading.Lock()
+_CPU_SEEN: "dict[int, int]" = {}  # tid -> on-CPU ns when last read
+_CPU_ROLE: "dict[str, int]" = {}  # role -> ns, only ever added to
+
+
+def _task_cpu_ns(tid: int) -> "int | None":
+    """What the scheduler has charged the thread, in ns: its CPU clock
+    ``MAKE_THREAD_CPUCLOCK(tid, CPUCLOCK_SCHED)``, which reads the
+    ``sum_exec_runtime`` that ``/proc/self/task/<tid>/schedstat`` prints.
+    The kernel looks the tid up, so one that has exited (or is another
+    process's) is EINVAL here, not a dangling ``pthread_t``: None."""
+    try:
+        return time.clock_gettime_ns((~tid << 3) | 6)
+    except OSError:
+        return None
+
+
+def _cpu_table() -> dict:
+    """Read at snapshot time only, from the scheduler's books: nothing on
+    any request's path.  A thread's CPU since it was last read goes to the
+    role its name gives it now, so every row only grows and a thread that
+    has exited keeps what it was last seen with.  ``native`` is what the
+    process has burnt (``process_seconds``) and no Python thread has been
+    seen with: the runtime's own threads (XLA's and PJRT's pools), and what
+    a thread burnt between its last reading and its exit.
+
+    Why the clocks and not ``/proc/self/task``: ``open`` / ``read`` /
+    ``close`` each hand the GIL back, as does every ``readdir`` of a
+    listing, and a thread that wants it back waits behind whoever runs
+    (0.3 ms for 35 threads on an idle interpreter, 700 ms beside two that
+    spin; listing ~130 tasks under ``mixed-10m`` on the chip's host: 170-180
+    ms).  A clock reading keeps the GIL: a snapshot makes no system call
+    that could lose it."""
+    with _CPU_LK:
+        seen = {}
+        for t in threading.enumerate():
+            tid = t.native_id
+            ns = None if tid is None else _task_cpu_ns(tid)
+            if ns is None:
+                continue
+            last = _CPU_SEEN.get(tid, 0)
+            role = _role_of(t.name)
+            # a tid that reads less than it did belongs to a new thread
+            _CPU_ROLE[role] = _CPU_ROLE.get(role, 0) + (ns - last if ns >= last else ns)
+            seen[tid] = ns
+        _CPU_SEEN.clear()
+        _CPU_SEEN.update(seen)
+        process = time.process_time_ns()
+        known = sum(v for k, v in _CPU_ROLE.items() if k != "native")
+        # the threads were read a moment before the process: never shrink
+        _CPU_ROLE["native"] = max(_CPU_ROLE.get("native", 0), process - known)
+        out = {role: round(ns / 1e9, 6) for role, ns in sorted(_CPU_ROLE.items())}
+    out["process_seconds"] = round(process / 1e9, 6)
+    return out
+
+
 # -- reading -------------------------------------------------------------------
 
 
 def snapshot() -> dict:
-    """``{"spans": [...], "probe": {...}}`` for ``KernelStats.snapshot()``:
+    """``{"spans": [...], "probe": {...}, "requests": [...], "fanout":
+    {...}, "cpu": {...}, "loops": [...]}`` for ``KernelStats.snapshot()``:
     the live threads' dicts merged with what exited threads left."""
     with _REG_LK:
         _fold_dead_locked()
         merged = {k: list(v) for k, v in _RETIRED.items()}
+        verbs: "dict[str, list]" = {}
+        _add_verbs(verbs, _RETIRED_VERBS)
+        loops = {k: list(v) for k, v in _RETIRED_LOOPS.items()}
         states = list(_STATES)
+    for c in PROBE.loops:  # a loop no connection landed on is a row too
+        loops.setdefault(c.index, [0, 0])
     for st in states:
         for name, row in _items(st.counters):
             into = merged.setdefault((st.role, name), [0, 0, 0])
             for i in range(3):
                 into[i] += row[i]
+        _add_verbs(verbs, st.verbs)
+        _add_loop(loops, st)
+    with _FAN_LK:
+        fans = {k: list(v) for k, v in sorted(_FANOUT.items())}
     return {
         "spans": [
             {
@@ -597,13 +844,52 @@ def snapshot() -> dict:
             for (role, name), (n, wall, cpu) in sorted(merged.items())
         ],
         PROBE_NAME: PROBE.snapshot(),
+        # nine digits: a verb's self times add up to its wall to the ns
+        "requests": [
+            {
+                "verb": verb,
+                "count": n,
+                "wall_seconds": round(wall / 1e9, 9),
+                "cpu_seconds": round(cpu / 1e9, 9),
+                "queue_wait_seconds": round(queue / 1e9, 9),
+                "self": {
+                    name: [c, round(ns / 1e9, 9)]
+                    for name, (c, ns) in sorted(own.items())
+                },
+            }
+            for verb, (n, wall, cpu, queue, own) in sorted(verbs.items())
+        ],
+        "fanout": {
+            phase: {
+                "count": n,
+                "wall_seconds": round(wall / 1e9, 6),
+                "last_queue_seconds": round(queue / 1e9, 6),
+                "last_run_seconds": round(run / 1e9, 6),
+            }
+            for phase, (n, wall, queue, run) in fans.items()
+        },
+        "cpu": _cpu_table(),
+        "loops": [
+            {
+                "loop": loop,
+                "requests": n,
+                "queue_wait_seconds": round(queue / 1e9, 6),
+            }
+            for loop, (n, queue) in sorted(loops.items())
+        ],
     }
 
 
 def reset() -> None:
-    """Tests: zero every counter (the threads keep their dicts)."""
+    """Tests: zero every counter (the threads keep their dicts).  The CPU
+    table is the scheduler's and stays."""
     with _REG_LK:
         _RETIRED.clear()
+        _RETIRED_VERBS.clear()
+        _RETIRED_LOOPS.clear()
         for st in _STATES:
             st.counters.clear()
+            st.verbs.clear()
+    with _FAN_LK:
+        _FANOUT.clear()
     PROBE.reset()

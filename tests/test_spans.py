@@ -1,8 +1,10 @@
 """Spans inside the program (utils/spans.py): the primitive itself, the
 request identifier across the iopool and the batcher, the three sinks,
-the interpreter probe, and the table of names."""
+the interpreter probe, the table of names, and the four tables that account
+a request's wall and the server's CPU (requests, fanout, cpu, loops)."""
 
 import asyncio
+import json
 import os
 import re
 import threading
@@ -33,6 +35,7 @@ TABLE = [
     "xl_read_version", "xl_read_all", "xl_write_all", "xl_rename_data",
     "xl_delete_version", "xl_delete_file", "iopool_queue_wait", "iopool_job",
     "xl_shard_write", "xl_shard_fsync", "xl_shard_read", "iopool_result_wait",
+    "put_close_wait", "put_rename_wait",
     "stream_assemble", "stream_codec_wait", "stream_disk",
     "stream_readahead_wait", "batch_queue_wait",
     "batch_flush", "flush_to_launch", "batch_result_wait", "seam_matrix",
@@ -372,7 +375,8 @@ def test_snapshot_merges_threads_and_keeps_what_exited_threads_left():
 @pytest.mark.parametrize("thread_name,role", [
     ("aio-loop-0", "loop"), ("aio2-worker-3", "handler"), ("iopool-7", "iopool"),
     ("codec-batcher", "batcher"), ("codec-batcher-sub1", "batcher"),
-    ("aio0-stream-1", "other"), ("MainThread", "other")])
+    ("aio0-stream-1", "other"), ("MainThread", "other"),
+    ("codec-warmer", "warmer"), ("data-crawler", "crawler"), ("interp-probe", "probe")])
 def test_role_comes_from_the_thread_name(thread_name, role):
     assert spans._role_of(thread_name) == role
 
@@ -555,6 +559,435 @@ def test_served_requests_move_every_layer_counter_and_export_the_probe(server):
                    "miniotpu_interpreter_probe_samples_total",
                    "miniotpu_server_loop_lag_seconds_total"):
         assert re.search(rf"^{family}(\{{[^}}]*\}})? [0-9.e+-]+$", text, re.M), family
+
+
+# -- kernel-stats.requests: self time by verb ------------------------------------------
+
+
+def verb_row(verb: str) -> "dict | None":
+    return next((r for r in spans.snapshot()["requests"] if r["verb"] == verb), None)
+
+
+def _nested():
+    with spans.span(spans.OL_PUT_OBJECT):
+        with spans.span(spans.NSLOCK_WAIT):
+            time.sleep(0.002)
+        with spans.span(spans.STREAM_DISK):
+            with spans.span(spans.XL_SHARD_WRITE):
+                time.sleep(0.002)
+    with spans.span(spans.RESP_WRITE_WAIT):
+        pass
+    return {"s3_request", "ol_put_object", "nslock_wait", "stream_disk",
+            "xl_shard_write", "resp_write_wait"}
+
+
+def _child_on_another_thread():
+    """An iopool job under adopt: its time is its own thread's, the request's
+    thread was waiting in the span it holds open meanwhile."""
+    with spans.span(spans.STREAM_DISK):
+        ctx = spans.capture()
+
+        def job():
+            with spans.adopt(ctx), spans.span(spans.IOPOOL_JOB), \
+                    spans.span(spans.XL_SHARD_WRITE):
+                time.sleep(0.01)
+
+        t = threading.Thread(target=job, name="iopool-self-time")
+        t.start()
+        t.join(5)
+        assert not t.is_alive()
+    return {"s3_request", "stream_disk"}
+
+
+def _child_outlives_its_parent():
+    done = threading.Event()
+    with spans.span(spans.STREAM_CODEC_WAIT):
+        ctx = spans.capture()
+
+        def later():
+            with spans.adopt(ctx), spans.span(spans.BATCH_FLUSH):
+                time.sleep(0.03)
+            done.set()
+
+        threading.Thread(target=later, name="codec-batcher-self-time").start()
+    assert done.wait(5)
+    return {"s3_request", "stream_codec_wait"}
+
+
+@pytest.mark.parametrize("body", [_nested, _child_on_another_thread,
+                                  _child_outlives_its_parent])
+def test_self_times_sum_to_the_roots_wall_to_the_nanosecond(body):
+    verb = "Sum-" + body.__name__
+    spans.begin_request(False)
+    with spans.span(spans.S3_REQUEST) as root:
+        names = body()
+    spans.end_request(verb, 1_234_000)
+    n, wall, cpu, queue, own = spans._state().verbs[verb]
+    assert (n, wall, queue) == (1, root.wall_ns, 1_234_000)
+    assert 0 <= cpu <= wall
+    assert set(own) == names  # spans of other threads are not the request thread's
+    assert sum(ns for _, ns in own.values()) == wall  # exact: integers
+    assert all(ns >= 0 for _, ns in own.values())
+    row = verb_row(verb)
+    assert set(row) == {"verb", "count", "wall_seconds", "cpu_seconds",
+                        "queue_wait_seconds", "self"}
+    assert abs(sum(v[1] for v in row["self"].values()) - row["wall_seconds"]) < 1e-9
+    assert row["queue_wait_seconds"] == 0.001234
+    if body is not _nested:
+        # the child's 10-30 ms ran elsewhere: the span that waited keeps them
+        held = "stream_disk" if body is _child_on_another_thread else "s3_request"
+        assert own[held][1] >= 9_000_000
+
+
+def test_a_span_is_what_its_children_on_the_same_thread_leave():
+    spans.begin_request(False)
+    with spans.span(spans.S3_REQUEST):
+        with spans.span(spans.OL_GET_OBJECT) as ol:
+            with spans.span(spans.META_READ_ALL) as a:
+                time.sleep(0.005)
+            with spans.span(spans.META_READ_ALL) as b:
+                pass
+            spans.wait(spans.GET_FIRST_WRITE, ol.t0)  # a hand-over: no part of the nesting
+    spans.end_request("Leave")
+    own = spans._state().verbs["Leave"][4]
+    assert own["meta_read_all"] == [2, a.wall_ns + b.wall_ns]
+    assert own["ol_get_object"] == [1, ol.wall_ns - a.wall_ns - b.wall_ns]
+    assert "get_first_write" not in own
+
+
+def test_the_fold_by_verb():
+    def serve(verb, queue_ns):
+        spans.begin_request(False)
+        with spans.span(spans.S3_REQUEST), spans.span(spans.SIGV4_VERIFY):
+            pass
+        spans.end_request(verb, queue_ns)
+
+    before = verb_row("other")["count"] if verb_row("other") else 0
+    for verb, q in (("FoldA", 5), ("FoldB", 7), ("FoldA", 11), ("", 0)):
+        serve(verb, q)
+    verbs = spans._state().verbs
+    assert verbs["FoldA"][0] == 2 and verbs["FoldA"][3] == 16
+    assert verbs["FoldB"][0] == 1 and verbs["FoldB"][3] == 7
+    assert verbs["FoldA"][4]["sigv4_verify"][0] == 2
+    assert verb_row("other")["count"] == before + 1  # an action nobody resolved
+    assert [r["verb"] for r in spans.snapshot()["requests"]] == sorted(
+        r["verb"] for r in spans.snapshot()["requests"])
+
+
+def test_nothing_is_kept_without_begin_request():
+    st = spans._state()
+    kept = {v: row[0] for v, row in st.verbs.items()}
+    with spans.span(spans.S3_REQUEST), spans.span(spans.SIGV4_VERIFY):
+        assert st.acc is None
+    assert spans.end_request("Nobody") is None
+    assert {v: row[0] for v, row in st.verbs.items()} == kept
+    spans.begin_request(False)
+    assert spans.end_request("Nobody") is None  # a request that opened no span
+    assert "Nobody" not in st.verbs and st.acc is None
+
+
+def test_verb_rows_outlive_their_thread():
+    def serve():
+        spans.begin_request(False)
+        with spans.span(spans.S3_REQUEST):
+            pass
+        spans.end_request("Outlive")
+
+    threads = [threading.Thread(target=serve, name=f"aio0-worker-{i}") for i in range(3)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(5)
+    assert verb_row("Outlive")["count"] == 3
+    assert verb_row("Outlive")["count"] == 3  # folded once
+
+
+def test_a_put_waits_under_the_two_names_and_not_the_anonymous_one(tmp_path):
+    import io
+
+    disks = [XLStorage(str(tmp_path / f"d{i}")) for i in range(4)]
+    ol = ErasureObjects(disks, block_size=4096)
+    ol.make_bucket("named")
+    before = {p: dict(spans.snapshot()["fanout"].get(p, {"count": 0}))
+              for p in ("put_flush", "put_close", "put_rename")}
+    spans.begin_request(False)
+    with spans.span(spans.S3_REQUEST):
+        ol.put_object("named", "k", io.BytesIO(os.urandom(3 * 4096)), 3 * 4096)
+    spans.end_request("NamedPut")
+    own = spans._state().verbs["NamedPut"][4]
+    assert own["put_close_wait"][0] == 1 and own["put_rename_wait"][0] == 1
+    assert "iopool_result_wait" not in own
+    fan = spans.snapshot()["fanout"]
+    for p in before:
+        assert fan[p]["count"] == before[p]["count"] + 1, p
+    # the call sites: the anonymous wait went from the two that got a name
+    with open(os.path.join(PKG, "objectlayer", "erasure_object.py"), encoding="utf-8") as f:
+        text = f.read()
+    assert "span_name=spans.PUT_CLOSE_WAIT" in text and "span_name=spans.PUT_RENAME_WAIT" in text
+    assert len(re.findall(r"\bspans\.IOPOOL_RESULT_WAIT\b", SOURCES)) == 3  # 3 in iopool.py
+
+
+# -- kernel-stats.fanout: the slowest job of a wait ----------------------------------------
+
+
+@pytest.mark.parametrize("slow", ["queue", "run"])
+def test_fanout_tells_a_late_start_from_a_long_run(slow):
+    pool = iopool.IOPool(queues=4, depth=4, name_prefix="iopool-fan")
+    try:
+        a = spans.snapshot()["fanout"].get("put_close", dict.fromkeys(
+            ("count", "wall_seconds", "last_queue_seconds", "last_run_seconds"), 0))
+        anon = counters("iopool_result_wait")[0]
+        if slow == "queue":
+            blocker = pool.submit("disk-a", lambda: time.sleep(0.15))
+            ops = [("disk-a", lambda: None), ("disk-b", lambda: None)]
+        else:
+            blocker = None
+            ops = [("disk-a", lambda: time.sleep(0.15)), ("disk-b", lambda: None)]
+        assert iopool.fanout(ops, pool, span_name=spans.PUT_CLOSE_WAIT) == [None, None]
+        if blocker is not None:
+            blocker.result_or_raise(5)
+        b = spans.snapshot()["fanout"]["put_close"]
+        assert set(b) == {"count", "wall_seconds", "last_queue_seconds", "last_run_seconds"}
+        d = {k: b[k] - a[k] for k in b}
+        assert d["count"] == 1 and 0.1 < d["wall_seconds"] < 5
+        late, long_ = d["last_queue_seconds"], d["last_run_seconds"]
+        if slow == "queue":
+            assert late > 0.1 and long_ < 0.05
+        else:
+            assert long_ > 0.1 and late < 0.05
+        # the wait opens once every job is submitted: a job is queued a little before it
+        assert late + long_ <= d["wall_seconds"] + 0.05
+        assert counters("iopool_result_wait")[0] == anon  # named, so not doubled
+        # with no name it is the anonymous wait, and no phase
+        assert iopool.fanout([("disk-b", lambda: None)], pool) == [None]
+        assert counters("iopool_result_wait")[0] == anon + 1
+        assert spans.snapshot()["fanout"]["put_close"]["count"] == b["count"]
+    finally:
+        pool.shutdown()
+
+
+def test_a_flush_is_told_by_the_job_that_made_its_quorum():
+    pool = iopool.IOPool(queues=4, depth=4, name_prefix="iopool-quorum")
+    try:
+        fl = iopool.ShardFlusher(pool)
+        gate = threading.Event()
+        jobs = [(0, "disk-0", lambda: None, 1),
+                (1, "disk-1", lambda: time.sleep(0.05), 1),
+                (2, "disk-2", lambda: gate.wait(5), 1)]
+        assert fl.flush(jobs, quorum=2) == set()
+        job = fl.quorum_job
+        gate.set()
+        fl.drain()
+        assert job.queued_ns <= job.started_ns <= job.done_ns
+        assert 40_000_000 < job.done_ns - job.started_ns < 2_000_000_000  # slot 1's, not the straggler's
+    finally:
+        pool.shutdown()
+
+
+def test_a_job_keeps_its_three_stamps_for_free(monkeypatch):
+    pool = iopool.IOPool(queues=2, depth=4, name_prefix="iopool-stamps")
+    try:
+        reads = []
+        real = spans.now
+        fut = pool.submit("disk-a", lambda: None)
+        fut.result_or_raise(5)
+        monkeypatch.setattr(spans, "now", lambda: reads.append(1) or real())
+        fut = pool.submit("disk-a", lambda: time.sleep(0.01))
+        fut.result_or_raise(5)
+        monkeypatch.undo()
+        # enqueue, dequeue, the job's start and end: the readings the spans
+        # made before the stamps were kept (the waiter's own span is two more)
+        assert len(reads) <= 6
+        assert 0 < fut.queued_ns <= fut.started_ns < fut.done_ns
+        assert fut.done_ns - fut.started_ns >= 10_000_000
+    finally:
+        pool.shutdown()
+
+
+# -- kernel-stats.cpu and .loops: read at snapshot time ---------------------------------------
+
+
+def test_cpu_by_role_is_the_schedulers_account():
+    stop, burnt = threading.Event(), threading.Event()
+
+    def spin():
+        t = time.thread_time()
+        while time.thread_time() - t < 0.25:
+            pass
+        burnt.set()
+        stop.wait(10)
+
+    sleeper = threading.Thread(target=stop.wait, args=(10,), name="codec-batcher-cpu-test")
+    spinner = threading.Thread(target=spin, name="iopool-cpu-test")
+    a = spans.snapshot()["cpu"]
+    sleeper.start()
+    spinner.start()
+    assert burnt.wait(30)
+    b = spans.snapshot()["cpu"]  # both alive: all they burnt is on the books
+    stop.set()
+    for t in (sleeper, spinner):
+        t.join(5)
+        assert not t.is_alive()
+    c = spans.snapshot()["cpu"]
+    assert 0.2 < b["iopool"] - a.get("iopool", 0) < 0.5
+    assert b.get("batcher", 0) - a.get("batcher", 0) < 0.05
+
+    def roles(t):
+        return sum(v for k, v in t.items() if k != "process_seconds")
+
+    d_roles, d_proc = roles(b) - roles(a), b["process_seconds"] - a["process_seconds"]
+    assert d_proc > 0.2 and abs(d_roles - d_proc) <= 0.05 * d_proc + 0.03, (d_roles, d_proc)
+    # monotone, every row: a thread that has exited keeps what it was last seen with
+    for x, y in ((a, b), (b, c)):
+        assert all(y[k] >= v for k, v in x.items()), (x, y)
+    assert c["iopool"] - b["iopool"] < 0.05
+    assert set(c) >= {"iopool", "batcher", "other", "process_seconds"}
+    assert roles(c) <= c["process_seconds"] * 1.02 + 0.05
+
+
+def test_cpu_of_a_task_that_is_gone_reads_none():
+    t = threading.Thread(target=lambda: None)
+    t.start()
+    tid = t.native_id
+    t.join(5)
+    deadline = time.monotonic() + 5  # join() returns a moment before the task is gone
+    while spans._task_cpu_ns(tid) is not None and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert spans._task_cpu_ns(tid) is None  # looked up by the kernel: no dangling handle
+    assert spans._task_cpu_ns(threading.get_native_id()) > 0
+    # ... and it is the number /proc prints for the thread
+    me = threading.get_native_id()
+    with open(f"/proc/self/task/{me}/schedstat") as f:
+        printed = int(f.read().split()[0])
+    assert 0 <= spans._task_cpu_ns(me) - printed < 50_000_000
+
+
+def test_a_snapshot_makes_no_call_that_hands_the_gil_back(monkeypatch):
+    """Files under /proc cost a hand-back of the GIL an open, a read, a close and a
+    directory entry (170-180 ms a listing under load on the chip's host): the CPU
+    table reads clocks and nothing else."""
+    import builtins
+
+    def refuse(*a, **kw):
+        raise AssertionError("a file system call inside a snapshot")
+
+    spans.snapshot()
+    for mod, name in ((os, "listdir"), (os, "scandir"), (os, "stat"), (os, "open"),
+                      (builtins, "open")):
+        monkeypatch.setattr(mod, name, refuse)
+    cpu = spans.snapshot()["cpu"]
+    monkeypatch.undo()
+    assert cpu["process_seconds"] > 0 and cpu["other"] > 0
+
+
+def test_native_is_what_the_tasks_python_does_not_know_have_burnt():
+    """The remainder against the kernel's own list of the process's tasks: the CPU
+    clocks of the tids that no Python thread owns."""
+    known = {t.native_id for t in threading.enumerate()}
+    a = spans.snapshot()["cpu"]
+    others = [int(t) for t in os.listdir("/proc/self/task") if int(t) not in known]
+    burnt = sum(spans._task_cpu_ns(t) or 0 for t in others) / 1e9
+    b = spans.snapshot()["cpu"]
+    # native also keeps what exited threads burnt unseen: at least the live ones' CPU
+    assert b["native"] >= a["native"] >= 0
+    assert b["native"] >= burnt - 0.05
+
+
+def test_a_thread_of_no_python_name_is_native(monkeypatch):
+    """XLA's and PJRT's pools: a tid that threading.enumerate() does not know."""
+    monkeypatch.setattr(spans.threading, "enumerate", lambda: [])
+    a = spans.snapshot()["cpu"].get("native", 0)
+    t = time.thread_time()
+    while time.thread_time() - t < 0.05:
+        pass
+    assert spans.snapshot()["cpu"]["native"] - a > 0.03
+
+
+def test_loops_are_the_handlers_counters_before_the_merge():
+    def handler(requests):
+        for _ in range(requests):
+            since = spans.now() - 2_000_000
+            spans.wait(spans.AIO_QUEUE_WAIT, since, now_ns=since + 2_000_000)
+            spans.begin_request(False)
+            with spans.span(spans.S3_REQUEST):
+                pass
+            spans.end_request("Loops")
+
+    threads = [threading.Thread(target=handler, args=(n,), name=name)
+               for n, name in ((3, "aio71-worker-0"), (2, "aio71-worker-3"), (4, "aio93-worker-1"))]
+    for t in threads:
+        t.start()
+    threads[0].join(5)
+    rows = {r["loop"]: r for r in spans.snapshot()["loops"]}  # live and exited alike
+    for t in threads[1:]:
+        t.join(5)
+    rows = {r["loop"]: r for r in spans.snapshot()["loops"]}
+    assert rows[71] == {"loop": 71, "requests": 5, "queue_wait_seconds": 0.01}
+    assert rows[93] == {"loop": 93, "requests": 4, "queue_wait_seconds": 0.008}
+    assert spans._loop_of("aio2-worker-3") == 2 and spans._loop_of("aio-loop-0") is None
+    # by role they still merge as they did
+    assert sum(r["count"] for r in spans.snapshot()["spans"]
+               if r["name"] == "s3_request" and r["role"] == "handler") >= 9
+
+
+def test_spans_and_probe_keep_their_shape_beside_the_new_tables():
+    with spans.span(spans.XL_READ_ALL):
+        pass
+    snap = spans.snapshot()
+    assert list(snap) == ["spans", "probe", "requests", "fanout", "cpu", "loops"]
+    assert all(list(r) == ["role", "name", "count", "wall_seconds", "cpu_seconds"]
+               for r in snap["spans"])
+    assert list(snap["probe"]) == ["samples", "late_seconds", "late_max_seconds",
+                                   "interval_seconds", "loops"]
+    cell = spans.PROBE.add_loop(977)
+    try:
+        probe = spans.snapshot()
+        row = next(r for r in probe["probe"]["loops"] if r["loop"] == 977)
+        assert list(row) == ["samples", "late_seconds", "late_max_seconds", "loop"]
+        # a loop that no connection landed on is a row of kernel-stats.loops too
+        assert {"loop": 977, "requests": 0, "queue_wait_seconds": 0.0} in probe["loops"]
+    finally:
+        spans.PROBE.loops = [c for c in spans.PROBE.loops if c is not cell]
+    ks = KERNEL_STATS.snapshot()
+    assert {"requests", "fanout", "cpu", "loops", "spans", "probe"} <= set(ks)
+
+
+def test_served_requests_are_accounted_by_verb_phase_and_loop(server):
+    c = S3Client(server.endpoint)
+    c.make_bucket("account")
+    data = os.urandom(3 * 4096 + 5)
+    have = {r["verb"]: r["count"] for r in spans.snapshot()["requests"]}
+    fans = {p: r["count"] for p, r in spans.snapshot()["fanout"].items()}
+    served = sum(r["requests"] for r in spans.snapshot()["loops"])
+    assert c.put_object("account", "k", data).status == 200
+    assert c.get_object("account", "k").body == data
+    assert c.head_object("account", "k").status == 200
+    assert c.request("DELETE", "/account/k").status == 204
+    assert c.list_objects("account").status == 200
+    verbs = ("PutObject", "GetObject", "HeadObject", "DeleteObject", "ListBucket")
+    deadline = time.monotonic() + 5
+    while True:
+        # a request is folded once its root has closed, a moment after its answer left
+        ks = json.loads(c.request("GET", "/minio-tpu/admin/v1/kernel-stats").body)
+        rows = {r["verb"]: r for r in ks["requests"]}
+        if all(v in rows and rows[v]["count"] > have.get(v, 0) for v in verbs):
+            break
+        assert time.monotonic() < deadline, sorted(rows)
+        time.sleep(0.05)
+    for verb in verbs:
+        r = rows[verb]
+        assert r["count"] == have.get(verb, 0) + 1, verb
+        assert abs(sum(v[1] for v in r["self"].values()) - r["wall_seconds"]) < 1e-9 * len(r["self"])
+        assert 0 <= r["cpu_seconds"] <= r["wall_seconds"] and r["queue_wait_seconds"] >= 0
+        assert "s3_request" in r["self"] and "sigv4_verify" in r["self"]
+    assert {"put_close_wait", "put_rename_wait", "stream_disk", "ol_put_object"} <= set(
+        rows["PutObject"]["self"])
+    assert "ol_get_object_info" in rows["HeadObject"]["self"]
+    for p in ("put_flush", "put_close", "put_rename", "get_reads"):
+        assert ks["fanout"][p]["count"] > fans.get(p, 0), p
+        assert ks["fanout"][p]["wall_seconds"] >= 0
+    assert sum(r["requests"] for r in ks["loops"]) >= served + 5
+    assert ks["cpu"]["process_seconds"] > 0 and ks["cpu"]["handler"] > 0
 
 
 # -- the table ---------------------------------------------------------------------
