@@ -390,6 +390,7 @@ class KernelStats:
                 "breaker": _breaker_demotions(),
                 "meta_read": _meta_read_counts(),
                 "remove": _remove_counts(),
+                "drive_write": _drive_write_counts(),
                 "liveness": _liveness_counts(),
                 "body_read": _body_read_counts(),
                 "stages": [
@@ -514,6 +515,14 @@ def _remove_counts() -> dict:
     from ..storage import xl
 
     return xl.remove_counts()
+
+
+def _drive_write_counts() -> dict:
+    """The drives' writes and shard opens (storage/xl.py counts them
+    where they are made): calls, and how many of them had to ask."""
+    from ..storage import xl
+
+    return xl.drive_write_counts()
 
 
 def _liveness_counts() -> dict:

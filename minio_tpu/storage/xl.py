@@ -62,6 +62,24 @@ def remove_counts() -> dict:
     return {"named": named, "walked": walked, "calls": calls}
 
 
+# The calls that write or open a shard - create_file, read_file_stream,
+# rename_data, write_all - over every XLStorage of the process: [calls,
+# asked (times one of them, its system call refused, went to look at a
+# volume or ran makedirs to find out why)].  Plain adds like META_READ;
+# kernel-stats carries them as ``drive_write``.  asked / calls is the
+# share that left the path on which nothing is asked before the work.
+DRIVE_WRITE = [0, 0]
+
+# the errnos by which a system call says that a component of its path is
+# not there: what sends a drive call to look at the volume and the parents
+_NO_PARENT = (FileNotFoundError, NotADirectoryError)
+
+
+def drive_write_counts() -> dict:
+    calls, asked = DRIVE_WRITE
+    return {"calls": calls, "asked": asked}
+
+
 def _check_name(name: str) -> None:
     if not name or name.startswith("/") or ".." in name.split("/"):
         raise errors.FileAccessDenied(name)
@@ -69,7 +87,6 @@ def _check_name(name: str) -> None:
 
 class _FileShardWriter(ShardWriter):
     def __init__(self, path: str):
-        os.makedirs(os.path.dirname(path), exist_ok=True)
         self._f = open(path, "wb")
 
     @spans.spanned(spans.XL_SHARD_WRITE)
@@ -107,7 +124,8 @@ class XLStorage(StorageAPI):
     def __init__(self, root: str, endpoint: str = ""):
         self.root = os.path.abspath(root)
         self._endpoint = endpoint or self.root
-        os.makedirs(os.path.join(self.root, TMP_DIR), exist_ok=True)
+        self._tmp_root = os.path.join(self.root, TMP_DIR)
+        os.makedirs(self._tmp_root, exist_ok=True)
         self._disk_id = ""
 
     # ---- identity / health ----------------------------------------------
@@ -157,6 +175,16 @@ class XLStorage(StorageAPI):
         if not os.path.isdir(vp):
             raise errors.VolumeNotFound(volume)
         return vp
+
+    def _ask(self, volume: str, directory: str) -> None:
+        """What a write or an open does once its system call has said
+        that a component of its path is missing: the volume first (a
+        missing one, a file in its place and a lost root are
+        VolumeNotFound, and nothing is made under them), then the
+        parents, made on demand."""
+        DRIVE_WRITE[1] += 1
+        self._require_vol(volume)
+        os.makedirs(directory, exist_ok=True)
 
     # ---- volumes --------------------------------------------------------
 
@@ -249,15 +277,23 @@ class XLStorage(StorageAPI):
         # so their number is the wall time of a metadata round; the
         # volume is looked at only once the open has failed
         full = self._file_path(volume, path)
-        META_READ[0] += 1
         try:
-            fd = os.open(full, os.O_RDONLY)
-        except (FileNotFoundError, NotADirectoryError) as e:
+            return self._read_whole(full)
+        except _NO_PARENT as e:
             META_READ[2] += 1
             self._require_vol(volume)
             if isinstance(e, NotADirectoryError):
                 raise  # a parent component is a regular file
             raise errors.FileNotFound(path) from None
+        except IsADirectoryError:
+            raise errors.IsNotRegular(path) from None
+
+    @staticmethod
+    def _read_whole(full: str) -> bytes:
+        """The file's bytes by open, read, close; what the open or the
+        read raises is the caller's to name."""
+        META_READ[0] += 1
+        fd = os.open(full, os.O_RDONLY)
         try:
             data = os.read(fd, READ_CHUNK)
             if len(data) == READ_CHUNK:
@@ -269,26 +305,48 @@ class XLStorage(StorageAPI):
                     chunks.append(os.read(fd, READ_CHUNK))
                 data = b"".join(chunks)
             return data
-        except IsADirectoryError:
-            raise errors.IsNotRegular(path) from None
         finally:
             os.close(fd)
 
-    @spans.spanned(spans.XL_WRITE_ALL)
     def write_all(self, volume: str, path: str, data: bytes) -> None:
-        self._require_vol(volume)
-        full = self._file_path(volume, path)
-        os.makedirs(os.path.dirname(full), exist_ok=True)
-        tmp = os.path.join(
-            self.root, TMP_DIR, f"wa-{uuid.uuid4().hex}"
-        )
-        # the tmp area may have been pruned by delete_file parent cleanup
-        os.makedirs(os.path.dirname(tmp), exist_ok=True)
-        with open(tmp, "wb") as f:
-            f.write(data)
-            f.flush()
-            os.fsync(f.fileno())
-        os.replace(tmp, full)
+        DRIVE_WRITE[0] += 1
+        self._commit(volume, self._file_path(volume, path), data)
+
+    @spans.spanned(spans.XL_WRITE_ALL)
+    def _commit(self, volume: str, full: str, data: bytes) -> None:
+        """``data`` becomes the file ``full`` of ``volume`` or the old
+        file stays: written to a temp file in the staging area, fsynced,
+        closed, then ``replace`` - five system calls and none in front.
+        Whether the volume, the target's parents and the staging area
+        are there is asked only once one of the five has said no."""
+        tmp = os.path.join(self._tmp_root, f"wa-{uuid.uuid4().hex}")
+        flags = os.O_WRONLY | os.O_CREAT | os.O_EXCL
+        try:
+            fd = os.open(tmp, flags, 0o666)
+        except _NO_PARENT:
+            # delete_file's parent cleanup can take the tmp area with the
+            # last staging dir; a lost root is the volume's to report
+            self._ask(volume, self._tmp_root)
+            fd = os.open(tmp, flags, 0o666)
+        try:
+            try:
+                left = memoryview(data)
+                while left:
+                    left = left[os.write(fd, left):]
+                os.fsync(fd)
+            finally:
+                os.close(fd)
+            try:
+                os.replace(tmp, full)
+            except _NO_PARENT:
+                self._ask(volume, os.path.dirname(full))
+                os.replace(tmp, full)
+        except BaseException:
+            try:
+                os.unlink(tmp)
+            except OSError:
+                pass
+            raise
 
     @spans.spanned(spans.XL_DELETE_FILE)
     def delete_file(
@@ -410,12 +468,38 @@ class XLStorage(StorageAPI):
     # ---- shard streams --------------------------------------------------
 
     def create_file(self, volume: str, path: str) -> ShardWriter:
-        self._require_vol(volume)
-        return _FileShardWriter(self._file_path(volume, path))
+        # a staged shard (tmp/<id>/<data_dir>/part.N, tmp/<id>/part.N):
+        # the staging area is this drive's own and there since boot, so
+        # the directories under it are made top down, one mkdir each, and
+        # the file opened: no stat.  The first that is there already (a
+        # heal's second part) says the rest is too.  Any other path: the
+        # open first.  A call that says a parent is missing sends the
+        # drive to look at the volume and make the parents, once
+        full = self._file_path(volume, path)
+        DRIVE_WRITE[0] += 1
+        try:
+            made = self._tmp_root
+            if full.startswith(made + os.sep):
+                for name in full[len(made) + 1:].split(os.sep)[:-1]:
+                    made = os.path.join(made, name)
+                    try:
+                        os.mkdir(made)
+                    except FileExistsError:
+                        break
+            return _FileShardWriter(full)
+        except _NO_PARENT:
+            self._ask(volume, os.path.dirname(full))
+            return _FileShardWriter(full)
 
     def read_file_stream(self, volume: str, path: str) -> ShardReader:
-        self._require_vol(volume)
-        return _FileShardReader(self._file_path(volume, path))
+        DRIVE_WRITE[0] += 1
+        try:
+            return _FileShardReader(self._file_path(volume, path))
+        except (errors.FileNotFound, NotADirectoryError):
+            # no such file, or no such volume: the volume is asked now
+            DRIVE_WRITE[1] += 1
+            self._require_vol(volume)
+            raise
 
     # ---- object metadata ------------------------------------------------
 
@@ -470,30 +554,55 @@ class XLStorage(StorageAPI):
         dst_volume: str,
         dst_path: str,
     ) -> None:
-        self._require_vol(src_volume)
-        self._require_vol(dst_volume)
+        # mkdir the object's directory, rename the data dir into it, read
+        # the journal, commit the new one: the calls that do the work and
+        # no stat in front of them.  What stood in front (the volumes, the
+        # staging dir, the staged data dir, the parents) is asked in that
+        # order once a call has said no, so a caller sees the same error
         src_dir = self._file_path(src_volume, src_path)
         dst_obj = self._file_path(dst_volume, dst_path)
-        if not os.path.isdir(src_dir):
-            raise errors.FileNotFound(src_path)
-        os.makedirs(dst_obj, exist_ok=True)
-        if fi.data_dir:
-            dst_data = os.path.join(dst_obj, fi.data_dir)
-            staged = os.path.join(src_dir, fi.data_dir)
-            if not os.path.isdir(staged):
-                raise errors.FileNotFound(f"{src_path}/{fi.data_dir}")
-            if os.path.isdir(dst_data):
-                shutil.rmtree(dst_data)
-            os.replace(staged, dst_data)
-        # merge + commit version journal
+        DRIVE_WRITE[0] += 1
         try:
-            xl = self.read_xl(dst_volume, dst_path)
-        except errors.FileNotFound:
-            xl = XLMeta()
-        xl.add_version(fi)
-        self.write_all(
-            dst_volume, f"{dst_path}/{XL_META}", xl.to_bytes()
-        )
+            os.mkdir(dst_obj)
+            made = True
+        except FileExistsError:
+            made = False  # an overwrite, another version, a heal
+        except _NO_PARENT:
+            self._ask(dst_volume, dst_obj)  # a key with parents
+            made = True
+        try:
+            if fi.data_dir:
+                self._move_data_dir(
+                    src_volume, src_path, dst_volume, dst_obj, fi.data_dir
+                )
+            elif not os.path.isdir(src_dir):
+                # nothing to move, so nothing to fail: the one question
+                DRIVE_WRITE[1] += 1
+                self._require_vol(src_volume)
+                raise errors.FileNotFound(src_path)
+            # merge + commit version journal.  The object's directory was
+            # made or found above: no journal in it is a new key
+            meta_path = os.path.join(dst_obj, XL_META)
+            try:
+                xl = XLMeta.from_bytes(
+                    self._read_whole(meta_path), dst_volume, dst_path
+                )
+            except FileNotFoundError:
+                xl = XLMeta()
+            except IsADirectoryError:
+                raise errors.IsNotRegular(f"{dst_path}/{XL_META}") from None
+            xl.add_version(fi)
+            self._commit(dst_volume, meta_path, xl.to_bytes())
+        except BaseException:
+            if made:
+                # nothing of the object came to be: no empty directory
+                # is left for a listing to show as a prefix
+                try:
+                    os.rmdir(dst_obj)
+                    self._prune_parents(dst_volume, dst_obj)
+                except OSError:
+                    pass
+            raise
         # the staging dir's one entry was moved out above: one rmdir, and
         # the walk only where something else is still in it
         REMOVE[2] += 1
@@ -503,6 +612,39 @@ class XLStorage(StorageAPI):
         except OSError:
             REMOVE[1] += 1
             shutil.rmtree(src_dir, ignore_errors=True)
+
+    def _move_data_dir(
+        self,
+        src_volume: str,
+        src_path: str,
+        dst_volume: str,
+        dst_obj: str,
+        data_dir: str,
+    ) -> None:
+        """rename_data's one rename, ``<staging dir>/<data_dir>`` into
+        the object's directory, with nothing in front of it."""
+        src_dir = self._file_path(src_volume, src_path)
+        staged = os.path.join(src_dir, data_dir)
+        dst_data = os.path.join(dst_obj, data_dir)
+        try:
+            os.replace(staged, dst_data)
+        except _NO_PARENT:
+            DRIVE_WRITE[1] += 1
+            self._require_vol(src_volume)
+            if not os.path.isdir(src_dir):
+                raise errors.FileNotFound(src_path) from None
+            if not os.path.isdir(staged):
+                raise errors.FileNotFound(f"{src_path}/{data_dir}") from None
+            # the source is whole: the object's directory went meanwhile
+            self._require_vol(dst_volume)
+            os.makedirs(dst_obj, exist_ok=True)
+            os.replace(staged, dst_data)
+        except OSError as e:
+            if e.errno not in (errno.ENOTEMPTY, errno.EEXIST):
+                raise
+            # the same data dir once more (a heal, a retried PUT)
+            shutil.rmtree(dst_data)
+            os.replace(staged, dst_data)
 
     # ---- maintenance ----------------------------------------------------
 

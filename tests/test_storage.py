@@ -277,7 +277,8 @@ def test_read_all_answers(disk, case):
 
 COUNTED = ("os.open", "os.read", "os.close", "os.stat", "os.path.isdir",
            "builtins.open", "os.unlink", "os.rmdir", "os.lstat", "os.scandir",
-           "os.fstat")
+           "os.fstat", "os.mkdir", "os.makedirs", "os.replace", "os.rename",
+           "os.fsync", "os.write")
 
 
 def _calls(**made):
@@ -290,15 +291,20 @@ def _calls(**made):
 
 @pytest.fixture
 def syscalls(monkeypatch):
-    """Counts of the calls a drive's read or removal could make, while
-    ``on`` (shutil.rmtree looks its own up in ``os``, so a walk shows)."""
+    """Counts of the calls a drive's read, write or removal could make,
+    while ``on`` (shutil.rmtree and os.makedirs look their own up in ``os``,
+    so a walk and a parent's mkdir show); ``order`` is the names as made.
+    A file object's own write, flush and close are not ``os`` calls and do
+    not show: ``builtins.open`` stands for a shard file's."""
     calls = dict.fromkeys(COUNTED, 0)
     state = {"on": False}
+    names = []
 
     def counted(name, fn):
         def wrapper(*a, **kw):
             if state["on"]:
                 calls[name] += 1
+                names.append(name)
             return fn(*a, **kw)
 
         return wrapper
@@ -309,6 +315,8 @@ def syscalls(monkeypatch):
         monkeypatch.setattr(owner, attr, counted(name, getattr(owner, attr)))
 
     class Counter:
+        order = names
+
         def __enter__(self):
             state["on"] = True
             return calls
@@ -906,17 +914,19 @@ def test_names_reach_the_drive_through_its_wrappers(tmp_path, syscalls):
 # (`build_object_layer`, 12 drives, EC 8+4, DiskIDCheck(MeteredDisk(XLStorage))),
 # as the parent commit 85d9f77 made them:
 #
-# | request        | `stat` | of them before and round the metadata round | the round |
-# |----------------|--------|----------------------------------------------|-----------|
-# | STAT           |  40    | 40: three snapshots x 12 `isdir`, 2 `stat_vol` x 2 | 12 x open/read/close |
-# | GET            |  64    | 52: four snapshots, 2 `stat_vol` (+ 12 `_require_vol` at the shard opens) | 36 |
-# | PUT, new key   | 244    | 64 (+ 15 a drive x 12, the drive's own writes) | - |
-# | PUT, overwrite | 232    | 52 (+ 15 a drive x 12)                       | 36        |
-# | DELETE         |  52    | 52: four snapshots, 2 `stat_vol`             | 36        |
+# | request        | `stat` | of them before and round the metadata round | the round | now (PR 36) |
+# |----------------|--------|----------------------------------------------|-----------|-------------|
+# | STAT           |  40    | 40: three snapshots x 12 `isdir`, 2 `stat_vol` x 2 | 12 x open/read/close | 2 |
+# | GET            |  64    | 52: four snapshots, 2 `stat_vol` (+ 12 `_require_vol` at the shard opens) | 36 | 2 |
+# | PUT, new key   | 244    | 64 (+ 15 a drive x 12, the drive's own writes) | - | 14: 2 + the round's error path |
+# | PUT, overwrite | 232    | 52 (+ 15 a drive x 12)                       | 36        | 2 |
+# | DELETE         |  52    | 52: four snapshots, 2 `stat_vol`             | 36        | 2 |
 #
-# Now a snapshot makes none and the bucket question is asked once (one
-# `stat_vol` = 2 `stat`, on the first live drive, never from a cache).  What
-# is left is the drive's own, by the XLStorage call that made it.
+# PR 34: a snapshot makes none and the bucket question is asked once (one
+# `stat_vol` = 2 `stat`, on the first live drive, never from a cache).  PR 36:
+# a drive's writes and shard opens ask nothing before the call that does the
+# work (DRIVE_WRITE_CALLS below).  What is left, by the XLStorage call that
+# made it, is the look at the volume when a new key's round finds no xl.meta.
 
 
 def _stats_by_drive_call(monkeypatch):
@@ -948,12 +958,11 @@ def _stats_by_drive_call(monkeypatch):
 # request -> os.stat by the drive call that made them, at 1 MiB on 12 drives
 LIVENESS_CALLS = {
     "STAT": dict(stat_vol=2),
-    "GET": dict(stat_vol=2, read_file_stream=12),
-    # create_file: _require_vol and the two makedirs of a shard's path;
+    "GET": dict(stat_vol=2),
     # read_version: the round finds no xl.meta and looks at the volume (PR
-    # 28's error path); rename_data: ROADMAP queue 1 item 2, the next to go
-    "PUT-new": dict(stat_vol=2, create_file=36, read_version=12, rename_data=144),
-    "PUT-overwrite": dict(stat_vol=2, create_file=36, rename_data=144),
+    # 28's error path, ROADMAP queue 1 item 3b)
+    "PUT-new": dict(stat_vol=2, read_version=12),
+    "PUT-overwrite": dict(stat_vol=2),
     "DELETE": dict(stat_vol=2),
 }
 
@@ -1003,3 +1012,563 @@ def test_a_request_asks_no_drive_whether_it_is_there(tmp_path, monkeypatch, requ
     assert (moved["asked"], moved["reset"]) == (12 * snapshots, 0)
     # a look a drive a second (format.json: open, read, close; no stat)
     assert moved["looked"] <= 12 * int(took + 1)
+
+
+# ---- a drive writes first and asks only when the write fails (PR 36) -------
+# create_file, read_file_stream, rename_data and write_all make the system
+# calls that do the work; the volume, the parents and the staging area are
+# looked at once one of those has said no.  Every case: the exact counted
+# calls (a file object's own write / close are not `os` calls: the fixture's
+# docstring), DRIVE_WRITE's [calls, asked], the error class, the tree left.
+#
+# | drive call, one drive      | parent f8e0389                     | now                         |
+# |----------------------------|------------------------------------|-----------------------------|
+# | create_file, staged shard  | 3 `stat`, 2 `mkdir`, `open`        | 2 `mkdir`, `open`           |
+# | read_file_stream           | 1 `stat`, `open`                   | `open`                      |
+# | rename_data, new key       | 12 `stat`, 3 `mkdir` (2 failing), 9 | `mkdir`, `replace`, `open` (fails), `open`, `write`, `fsync`, `close`, `replace`, `rmdir`: 9 |
+# | rename_data, overwrite     | 12 `stat`, 3 failing `mkdir`, 11   | the same with `open`, `read`, `close` of the journal and the `mkdir` failing: 11 |
+
+_SHARD = b"shard-bytes-of-part-%d"
+
+
+def _stage(disk, tmp_id="stg", data_dir="dd", parts=(1,), volume=".sys", base="tmp/"):
+    # a staged data dir as a PUT's writers leave it
+    for n in parts:
+        w = disk.create_file(volume, f"{base}{tmp_id}/{data_dir}/part.{n}")
+        w.write(_SHARD % n)
+        w.close()
+
+
+def _dw_fi(data_dir="dd", parts=(1,)):
+    fi = _parts_fileinfo(len(parts), data_dir=data_dir)
+    for part, n in zip(fi.parts, parts):
+        part.number = n
+    return fi
+
+
+def _rename(disk, fi=None, src=(".sys", "tmp/stg"), dst=("b", "obj")):
+    fi = _dw_fi() if fi is None else fi
+    return lambda: disk.rename_data(src[0], src[1], fi, dst[0], dst[1])
+
+
+def _write_and_close(disk, volume, path):
+    def act():
+        w = disk.create_file(volume, path)
+        w.write(_SHARD % int(path.rsplit(".", 1)[1]))
+        w.close()
+
+    return act
+
+
+def _open_and_close(disk, volume, path):
+    def act():
+        rd = disk.read_file_stream(volume, path)
+        assert rd.read_at(0, 100) == _SHARD % int(path.rsplit(".", 1)[1])
+        rd.close()
+
+    return act
+
+
+_SYS = {".sys": None, ".sys/tmp": None, "b": None}
+_PART = {"obj": None, "obj/dd": None, "obj/dd/part.1": _SHARD % 1}
+
+
+def _under(prefix, tree):
+    return {f"{prefix}/{k}": v for k, v in tree.items()}
+
+
+# the calls of a journal's commit and of a whole rename_data, in their order
+_COMMIT = ["os.open", "os.write", "os.fsync", "os.close", "os.replace"]
+_NEW_KEY = ["os.mkdir", "os.replace", "os.open"] + _COMMIT + ["os.rmdir"]
+_OVERWRITE = (["os.mkdir", "os.replace", "os.open", "os.read", "os.close"]
+              + _COMMIT + ["os.rmdir"])
+
+
+def _dw_staged(disk):
+    return _write_and_close(disk, ".sys", "tmp/stg/dd/part.1")
+
+
+def _dw_staged_multipart(disk):
+    return _write_and_close(disk, ".sys", "tmp/stg/part.3")
+
+
+def _dw_staged_second_part(disk):
+    _stage(disk)
+    return _write_and_close(disk, ".sys", "tmp/stg/dd/part.2")
+
+
+def _dw_staged_id_there(disk):
+    # tmp/<id> is there and <data_dir> is not: the open says so
+    os.mkdir(os.path.join(disk.root, ".sys", "tmp", "stg"))
+    return _write_and_close(disk, ".sys", "tmp/stg/dd/part.1")
+
+
+def _dw_create_dir_there(disk):
+    os.makedirs(os.path.join(disk.root, "b", "obj", "dd"))
+    return _write_and_close(disk, "b", "obj/dd/part.1")
+
+
+def _dw_create_parents_missing(disk):
+    return _write_and_close(disk, "b", "obj/dd/part.1")
+
+
+def _dw_create_no_volume(disk):
+    return _write_and_close(disk, "nob", "obj/dd/part.1")
+
+
+def _dw_create_volume_is_file(disk):
+    open(os.path.join(disk.root, "volfile"), "w").close()
+    return _write_and_close(disk, "volfile", "obj/dd/part.1")
+
+
+def _dw_create_parent_is_file(disk):
+    disk.write_all("b", "obj", b"a file")
+    return _write_and_close(disk, "b", "obj/dd/part.1")
+
+
+def _dw_create_lost_root(disk):
+    shutil.rmtree(disk.root)
+    return _write_and_close(disk, ".sys", "tmp/stg/dd/part.1")
+
+
+def _dw_create_tmp_pruned(disk):
+    os.rmdir(os.path.join(disk.root, ".sys", "tmp"))
+    return _write_and_close(disk, ".sys", "tmp/stg/dd/part.1")
+
+
+def _laid(disk):
+    _stage(disk)
+    _rename(disk)()
+
+
+def _dw_read_hit(disk):
+    _laid(disk)
+    return _open_and_close(disk, "b", "obj/dd/part.1")
+
+
+def _dw_read_missing(disk):
+    _laid(disk)
+    return _open_and_close(disk, "b", "obj/dd/part.2")
+
+
+def _dw_read_no_volume(disk):
+    return _open_and_close(disk, "nob", "obj/dd/part.1")
+
+
+def _dw_read_volume_is_file(disk):
+    open(os.path.join(disk.root, "volfile"), "w").close()
+    return _open_and_close(disk, "volfile", "obj/dd/part.1")
+
+
+def _dw_read_parent_is_file(disk):
+    _laid(disk)
+    return _open_and_close(disk, "b", "obj/dd/part.1/part.1")
+
+
+def _dw_read_directory(disk):
+    _laid(disk)
+    return lambda: disk.read_file_stream("b", "obj/dd")
+
+
+def _dw_read_lost_root(disk):
+    _laid(disk)
+    shutil.rmtree(disk.root)
+    open(disk.root, "w").close()
+    return _open_and_close(disk, "b", "obj/dd/part.1")
+
+
+def _dw_rename_new(disk):
+    _stage(disk)
+    return _rename(disk)
+
+
+def _dw_rename_overwrite(disk):
+    _laid(disk)
+    _stage(disk, data_dir="d2")
+    return _rename(disk, _dw_fi("d2"))
+
+
+def _dw_rename_parents(disk):
+    _stage(disk)
+    return _rename(disk, dst=("b", "a/b/c"))
+
+
+def _dw_rename_same_data_dir(disk):
+    _laid(disk)
+    _stage(disk)
+    return _rename(disk)
+
+
+def _dw_rename_two_parts(disk):
+    _stage(disk, parts=(1, 2))
+    return _rename(disk, _dw_fi(parts=(1, 2)))
+
+
+def _dw_rename_no_staging(disk):
+    return _rename(disk)
+
+
+def _dw_rename_no_staged_data(disk):
+    _stage(disk, data_dir="other")
+    return _rename(disk)
+
+
+def _dw_rename_no_staged_data_overwrite(disk):
+    _laid(disk)
+    _stage(disk, data_dir="other")
+    return _rename(disk, _dw_fi("d2"))
+
+
+def _dw_rename_no_src_volume(disk):
+    return _rename(disk, src=("nosrc", "stg"))
+
+
+def _dw_rename_no_dst_volume(disk):
+    _stage(disk)
+    return _rename(disk, dst=("nob", "obj"))
+
+
+def _dw_rename_lost_root(disk):
+    _stage(disk)
+    shutil.rmtree(disk.root)
+    return _rename(disk)
+
+
+def _dw_rename_tmp_pruned(disk):
+    # staged outside the tmp area, which parent cleanup has taken
+    _stage(disk, volume="b", base="staging/")
+    os.rmdir(os.path.join(disk.root, ".sys", "tmp"))
+    return _rename(disk, src=("b", "staging/stg"))
+
+
+def _dw_rename_no_data_dir(disk):
+    os.mkdir(os.path.join(disk.root, ".sys", "tmp", "stg"))
+    return _rename(disk, _dw_fi("", parts=()))
+
+
+def _dw_rename_no_data_dir_no_staging(disk):
+    return _rename(disk, _dw_fi("", parts=()))
+
+
+def _dw_rename_journal_is_dir(disk):
+    _stage(disk)
+    os.makedirs(os.path.join(disk.root, "b", "obj", "xl.meta"))
+    return _rename(disk)
+
+
+def _dw_write_all(disk):
+    disk.write_all("b", "cfg/old.json", b"old")
+    return lambda: disk.write_all("b", "cfg/doc.json", b"document")
+
+
+def _dw_write_all_replaces(disk):
+    disk.write_all("b", "cfg/doc.json", b"old")
+    return lambda: disk.write_all("b", "cfg/doc.json", b"document")
+
+
+def _dw_write_all_no_parent(disk):
+    return lambda: disk.write_all("b", "cfg/sub/doc.json", b"document")
+
+
+def _dw_write_all_no_volume(disk):
+    return lambda: disk.write_all("nob", "cfg/doc.json", b"document")
+
+
+def _dw_write_all_lost_root(disk):
+    shutil.rmtree(disk.root)
+    return lambda: disk.write_all("b", "cfg/doc.json", b"document")
+
+
+def _dw_write_all_tmp_pruned(disk):
+    os.makedirs(os.path.join(disk.root, "b", "cfg"))
+    os.rmdir(os.path.join(disk.root, ".sys", "tmp"))
+    return lambda: disk.write_all("b", "cfg/doc.json", b"document")
+
+
+def _dw_write_all_onto_dir(disk):
+    os.makedirs(os.path.join(disk.root, "b", "cfg", "doc.json"))
+    return lambda: disk.write_all("b", "cfg/doc.json", b"document")
+
+
+_STG = {".sys/tmp/stg": None, ".sys/tmp/stg/dd": None,
+        ".sys/tmp/stg/dd/part.1": _SHARD % 1}
+_CFG = {"b/cfg": None, "b/cfg/doc.json": b"document"}
+# a journal is given as the list of its versions' data dirs
+_OBJ = {**_under("b", _PART), "b/obj/xl.meta": ["dd"]}
+
+# case -> (build, the counted calls in their order - or as a dict where a
+# rmtree's or a makedirs' own order is not the point -, DRIVE_WRITE's
+# [calls, asked], the error class, the drive's tree afterwards)
+DRIVE_WRITE_CALLS = {
+    "create-staged": (
+        _dw_staged, ["os.mkdir", "os.mkdir", "builtins.open", "os.fsync"],
+        [1, 0], None, {**_SYS, **_STG}),
+    "create-staged-multipart-part": (
+        _dw_staged_multipart, ["os.mkdir", "builtins.open", "os.fsync"],
+        [1, 0], None,
+        {**_SYS, ".sys/tmp/stg": None, ".sys/tmp/stg/part.3": _SHARD % 3}),
+    "create-staged-second-part": (
+        _dw_staged_second_part, ["os.mkdir", "builtins.open", "os.fsync"],
+        [1, 0], None, {**_SYS, **_STG, ".sys/tmp/stg/dd/part.2": _SHARD % 2}),
+    "create-staged-id-there-data-dir-not": (
+        _dw_staged_id_there,
+        {"os.mkdir": 2, "builtins.open": 2, "os.path.isdir": 1, "os.stat": 2,
+         "os.makedirs": 1, "os.fsync": 1},
+        [1, 1], None, {**_SYS, **_STG}),
+    "create-directory-there": (
+        _dw_create_dir_there, ["builtins.open", "os.fsync"],
+        [1, 0], None, {**_SYS, **_under("b", _PART)}),
+    "create-parents-missing": (
+        _dw_create_parents_missing,
+        {"builtins.open": 2, "os.path.isdir": 1, "os.stat": 3, "os.makedirs": 2,
+         "os.mkdir": 2, "os.fsync": 1},
+        [1, 1], None, {**_SYS, **_under("b", _PART)}),
+    "create-volume-missing": (
+        _dw_create_no_volume, ["builtins.open", "os.path.isdir", "os.stat"],
+        [1, 1], errors.VolumeNotFound, _SYS),
+    "create-volume-is-a-file": (
+        _dw_create_volume_is_file, ["builtins.open", "os.path.isdir", "os.stat"],
+        [1, 1], errors.VolumeNotFound, {**_SYS, "volfile": b""}),
+    "create-parent-is-a-file": (
+        _dw_create_parent_is_file,
+        {"builtins.open": 1, "os.path.isdir": 2, "os.stat": 3, "os.makedirs": 1,
+         "os.mkdir": 1},
+        [1, 1], NotADirectoryError, {**_SYS, "b/obj": b"a file"}),
+    "create-root-gone": (
+        _dw_create_lost_root, ["os.mkdir", "os.path.isdir", "os.stat"],
+        [1, 1], errors.VolumeNotFound, None),
+    "create-tmp-area-pruned": (
+        _dw_create_tmp_pruned,
+        {"os.mkdir": 4, "os.path.isdir": 1, "os.stat": 4, "os.makedirs": 3,
+         "builtins.open": 1, "os.fsync": 1},
+        [1, 1], None, {**_SYS, **_STG}),
+    "read-hit": (
+        _dw_read_hit, ["builtins.open"], [1, 0], None, {**_SYS, **_OBJ}),
+    "read-missing-file": (
+        _dw_read_missing, ["builtins.open", "os.path.isdir", "os.stat"],
+        [1, 1], errors.FileNotFound, {**_SYS, **_OBJ}),
+    "read-volume-missing": (
+        _dw_read_no_volume, ["builtins.open", "os.path.isdir", "os.stat"],
+        [1, 1], errors.VolumeNotFound, _SYS),
+    "read-volume-is-a-file": (
+        _dw_read_volume_is_file, ["builtins.open", "os.path.isdir", "os.stat"],
+        [1, 1], errors.VolumeNotFound, {**_SYS, "volfile": b""}),
+    "read-parent-is-a-file": (
+        _dw_read_parent_is_file, ["builtins.open", "os.path.isdir", "os.stat"],
+        [1, 1], NotADirectoryError, {**_SYS, **_OBJ}),
+    "read-a-directory": (
+        _dw_read_directory, ["builtins.open"],
+        [1, 0], errors.IsNotRegular, {**_SYS, **_OBJ}),
+    "read-root-became-a-file": (
+        _dw_read_lost_root, ["builtins.open", "os.path.isdir", "os.stat"],
+        [1, 1], errors.VolumeNotFound, {"": b""}),
+    # the data dir renamed before the journal is replaced; the journal's temp
+    # file fsynced before its replace; nine calls, none of them a question
+    "rename-new-key": (
+        _dw_rename_new, _NEW_KEY, [1, 0], None, {**_SYS, **_OBJ}),
+    "rename-overwrite": (
+        _dw_rename_overwrite, _OVERWRITE, [1, 0], None,
+        {**_SYS, **_OBJ, "b/obj/d2": None, "b/obj/d2/part.1": _SHARD % 1,
+         "b/obj/xl.meta": ["d2"]}),
+    "rename-two-parts": (
+        _dw_rename_two_parts, _NEW_KEY, [1, 0], None,
+        {**_SYS, **_OBJ, "b/obj/dd/part.2": _SHARD % 2}),
+    "rename-key-with-parents": (
+        _dw_rename_parents,
+        {"os.mkdir": 4, "os.path.isdir": 1, "os.stat": 4, "os.makedirs": 3,
+         "os.replace": 2, "os.open": 2, "os.write": 1, "os.fsync": 1,
+         "os.close": 1, "os.rmdir": 1},
+        [1, 1], None,
+        {**_SYS, "b/a": None, "b/a/b": None, **_under("b/a/b", {
+            "c": None, "c/dd": None, "c/dd/part.1": _SHARD % 1,
+            "c/xl.meta": ["dd"]})}),
+    "rename-same-data-dir-twice": (
+        _dw_rename_same_data_dir,
+        {"os.mkdir": 1, "os.replace": 3, "os.open": 3, "os.read": 1,
+         "os.close": 3, "os.write": 1, "os.fsync": 1, "os.rmdir": 2,
+         "os.unlink": 1, "os.lstat": 1, "os.scandir": 1, "os.fstat": 1},
+        [1, 0], None, {**_SYS, **_OBJ}),
+    "rename-staging-dir-missing": (
+        _dw_rename_no_staging,
+        ["os.mkdir", "os.replace", "os.path.isdir", "os.stat", "os.path.isdir",
+         "os.stat", "os.rmdir"],
+        [1, 1], errors.FileNotFound, _SYS),
+    "rename-staged-data-dir-missing": (
+        _dw_rename_no_staged_data,
+        ["os.mkdir", "os.replace"] + ["os.path.isdir", "os.stat"] * 3 + ["os.rmdir"],
+        [1, 1], errors.FileNotFound,
+        {**_SYS, ".sys/tmp/stg": None, ".sys/tmp/stg/other": None,
+         ".sys/tmp/stg/other/part.1": _SHARD % 1}),
+    "rename-staged-data-dir-missing-overwrite": (
+        _dw_rename_no_staged_data_overwrite,
+        ["os.mkdir", "os.replace"] + ["os.path.isdir", "os.stat"] * 3,
+        [1, 1], errors.FileNotFound,
+        {**_SYS, **_OBJ, ".sys/tmp/stg": None, ".sys/tmp/stg/other": None,
+         ".sys/tmp/stg/other/part.1": _SHARD % 1}),
+    "rename-source-volume-missing": (
+        _dw_rename_no_src_volume,
+        ["os.mkdir", "os.replace", "os.path.isdir", "os.stat", "os.rmdir"],
+        [1, 1], errors.VolumeNotFound, _SYS),
+    "rename-destination-volume-missing": (
+        _dw_rename_no_dst_volume, ["os.mkdir", "os.path.isdir", "os.stat"],
+        [1, 1], errors.VolumeNotFound, {**_SYS, **_STG}),
+    "rename-root-gone": (
+        _dw_rename_lost_root, ["os.mkdir", "os.path.isdir", "os.stat"],
+        [1, 1], errors.VolumeNotFound, None),
+    "rename-tmp-area-pruned-before-the-commit": (
+        _dw_rename_tmp_pruned,
+        {"os.mkdir": 2, "os.replace": 2, "os.open": 3, "os.path.isdir": 1,
+         "os.stat": 2, "os.makedirs": 1, "os.write": 1, "os.fsync": 1,
+         "os.close": 1, "os.rmdir": 1},
+        [1, 1], None, {**_SYS, **_OBJ, "b/staging": None}),
+    "rename-no-data-dir": (
+        _dw_rename_no_data_dir,
+        ["os.mkdir", "os.path.isdir", "os.stat", "os.open"] + _COMMIT + ["os.rmdir"],
+        [1, 0], None, {**_SYS, "b/obj": None, "b/obj/xl.meta": [""]}),
+    "rename-no-data-dir-staging-dir-missing": (
+        _dw_rename_no_data_dir_no_staging,
+        ["os.mkdir"] + ["os.path.isdir", "os.stat"] * 2 + ["os.rmdir"],
+        [1, 1], errors.FileNotFound, _SYS),
+    "rename-journal-is-a-directory": (
+        _dw_rename_journal_is_dir,
+        ["os.mkdir", "os.replace", "os.open", "os.read", "os.close"],
+        [1, 0], errors.IsNotRegular,
+        {**_SYS, **_under("b", _PART), "b/obj/xl.meta": None, ".sys/tmp/stg": None}),
+    "write-all-parent-there": (
+        _dw_write_all, _COMMIT, [1, 0], None,
+        {**_SYS, **_CFG, "b/cfg/old.json": b"old"}),
+    "write-all-replaces": (
+        _dw_write_all_replaces, _COMMIT, [1, 0], None, {**_SYS, **_CFG}),
+    "write-all-parent-missing": (
+        _dw_write_all_no_parent,
+        {"os.open": 1, "os.write": 1, "os.fsync": 1, "os.close": 1,
+         "os.replace": 2, "os.path.isdir": 1, "os.stat": 3, "os.makedirs": 2,
+         "os.mkdir": 2},
+        [1, 1], None,
+        {**_SYS, "b/cfg": None, "b/cfg/sub": None, "b/cfg/sub/doc.json": b"document"}),
+    "write-all-volume-missing": (
+        _dw_write_all_no_volume,
+        _COMMIT + ["os.path.isdir", "os.stat", "os.unlink"],
+        [1, 1], errors.VolumeNotFound, _SYS),
+    "write-all-root-gone": (
+        _dw_write_all_lost_root, ["os.open", "os.path.isdir", "os.stat"],
+        [1, 1], errors.VolumeNotFound, None),
+    "write-all-tmp-area-pruned": (
+        _dw_write_all_tmp_pruned,
+        {"os.open": 2, "os.path.isdir": 1, "os.stat": 2, "os.makedirs": 1,
+         "os.mkdir": 1, "os.write": 1, "os.fsync": 1, "os.close": 1,
+         "os.replace": 1},
+        [1, 1], None, {**_SYS, **_CFG}),
+    "write-all-onto-a-directory": (
+        _dw_write_all_onto_dir, _COMMIT + ["os.unlink"],
+        [1, 0], IsADirectoryError,
+        {**_SYS, "b/cfg": None, "b/cfg/doc.json": None}),
+}
+
+
+def _drive_tree(root):
+    """_tree with a journal as the list of its versions' data dirs, or None
+    where the drive's root is gone."""
+    if not os.path.lexists(root):
+        return None
+    tree = _tree(root)
+    for rel, content in tree.items():
+        if rel.endswith("xl.meta") and content is not None:
+            tree[rel] = [v.data_dir for v in XLMeta.from_bytes(content).versions]
+    return tree
+
+
+@pytest.mark.parametrize("case", DRIVE_WRITE_CALLS)
+def test_a_drive_writes_first_and_asks_only_when_the_write_fails(disk, syscalls, case):
+    build, want_calls, want_counts, error, leaves = DRIVE_WRITE_CALLS[case]
+    disk.make_vol("b")
+    act = build(disk)
+    before = xl_mod.drive_write_counts()
+    with syscalls as calls:
+        got = _outcome(act)
+    after = xl_mod.drive_write_counts()
+    assert got is error
+    if isinstance(want_calls, list):
+        assert syscalls.order == want_calls
+    else:
+        assert {k: v for k, v in calls.items() if v} == want_calls
+    assert [after[k] - before[k] for k in ("calls", "asked")] == want_counts
+    assert _drive_tree(disk.root) == leaves
+    if not want_counts[1] and case not in (
+        "rename-no-data-dir",  # nothing to move: the staging dir is asked
+        "rename-same-data-dir-twice",  # the walk that removes the old copy
+    ):
+        # the path that asks nothing: no stat of any kind and no makedirs
+        asking = ("os.stat", "os.path.isdir", "os.lstat", "os.fstat", "os.makedirs")
+        assert not any(calls[name] for name in asking)
+
+
+def test_the_journal_is_synced_before_it_is_replaced_and_after_the_data_dir(disk, syscalls):
+    """The order that makes a PUT durable and atomic on one drive: the data
+    dir is in place before the journal names it, and the journal's temp file
+    is on the medium (fsync) before the replace that publishes it; the shard
+    file was fsynced when its writer closed."""
+    disk.make_vol("b")
+    with syscalls:
+        _stage(disk)
+    assert syscalls.order == ["os.mkdir", "os.mkdir", "builtins.open", "os.fsync"]
+    seen = []
+    real_replace, real_fsync = os.replace, os.fsync
+
+    def replace(src, dst):
+        seen.append(("replace", os.path.basename(dst),
+                     os.path.exists(os.path.join(disk.root, "b", "obj", "dd", "part.1"))))
+        return real_replace(src, dst)
+
+    def fsync(fd):
+        seen.append(("fsync", os.path.basename(os.readlink(f"/proc/self/fd/{fd}"))[:3], None))
+        return real_fsync(fd)
+
+    os.replace, os.fsync = replace, fsync
+    try:
+        _rename(disk)()
+    finally:
+        os.replace, os.fsync = real_replace, real_fsync
+    assert seen == [("replace", "dd", False), ("fsync", "wa-", None),
+                    ("replace", "xl.meta", True)]
+
+
+def test_kernel_stats_drive_write_counts_a_put_and_a_get_that_ask_nothing(tmp_path):
+    """``kernel-stats.drive_write`` through the server's own stack on 12
+    drives: a PUT is 12 ``create_file`` + 12 ``rename_data``, a GET 12
+    ``read_file_stream``, and none of them asks; the first PUT under a new
+    prefix asks once a drive (the parents).  What was PUT reads back."""
+    import io
+
+    from minio_tpu.codec.telemetry import KERNEL_STATS
+    from minio_tpu.server.__main__ import build_object_layer
+
+    ol = build_object_layer([str(tmp_path / "d{1...12}")], parity=4)
+    ol.make_bucket("bkt")
+
+    def moved(act):
+        before = KERNEL_STATS.snapshot()["drive_write"]
+        act()
+        after = KERNEL_STATS.snapshot()["drive_write"]
+        assert set(after) == {"calls", "asked"}
+        return [after[k] - before[k] for k in ("calls", "asked")]
+
+    def put(key, body):
+        return lambda: ol.put_object("bkt", key, io.BytesIO(body), len(body))
+
+    def get(key):
+        got = io.BytesIO()
+        ol.get_object("bkt", key, got)
+        return got.getvalue()
+
+    first, second, third = (os.urandom(1 << 20) for _ in range(3))
+    assert moved(put("obj", first)) == [24, 0]
+    assert moved(lambda: get("obj")) == [12, 0]
+    assert moved(put("obj", second)) == [24, 0]  # an overwrite
+    assert get("obj") == second
+    ol.delete_object("bkt", "obj")
+    assert moved(put("obj", third)) == [24, 0]  # a PUT that follows a DELETE
+    assert get("obj") == third
+    assert moved(put("new/prefix/obj", first)) == [24, 12]
+    assert moved(put("new/prefix/other", second)) == [24, 0]
+    assert get("new/prefix/obj") == first and get("new/prefix/other") == second
