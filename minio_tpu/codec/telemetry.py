@@ -492,7 +492,7 @@ class KernelStats:
             diskcheck.LIVENESS[:] = [0, 0, 0]
             from ..server import aio
 
-            aio.BODY_READ[:] = [0, 0, 0]
+            aio.BODY_READ[:] = [0, 0, 0, 0]
 
 
 def _parity_cache_stats() -> dict:
@@ -554,7 +554,7 @@ def _liveness_counts() -> dict:
 def _body_read_counts() -> dict:
     """Request bodies handed from the event loops to their handlers
     (server/aio.py counts them where they cross): handovers, bytes,
-    loop_reads."""
+    loop_reads, direct."""
     from ..server import aio
 
     return aio.body_read_counts()

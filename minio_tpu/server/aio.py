@@ -34,18 +34,23 @@ are unchanged — ``_Handler.route()`` runs on a worker thread over two
 thin bridges:
 
 ``_LoopReader``
-    Blocking file-like over the connection's ``asyncio.StreamReader``.
-    Each ``read(n)`` is ONE ``run_coroutine_threadsafe`` round-trip and
-    reads full (``n`` bytes, fewer only at EOF, as a ``BufferedReader``
-    does): the loop collects the pieces the transport delivers into
-    the buffer the worker brought and hands it over once, so a PUT
+    Blocking file-like over the connection's ``_BodyProtocol``.  Each
+    ``read(n)`` is ONE hand-over to the loop and reads full (``n``
+    bytes, fewer only at EOF, as a ``BufferedReader`` does), so a PUT
     body streams block-by-block into ``HashReader`` -> ``encode_begin``
     with bounded memory (one hand-over fills at most ``_MAX_HANDOVER``;
-    the connection reads ahead ``_BODY_READAHEAD`` to twice that) and
-    the worker never touches the socket.  A 10 MiB block used to cross
-    in 40-42 pieces of one ``recv`` each, every one a wake-up of the
-    loop and of the worker; ``kernel-stats.body_read`` counts what
-    crosses now.
+    between hand-overs the connection reads ahead ``_BODY_READAHEAD``
+    to twice that) and the worker never touches the socket.  The loop
+    copies what the StreamReader already holds into the buffer the
+    worker brought, then points the transport at the rest of that
+    buffer: each readiness event is one ``recv_into`` of everything the
+    socket holds, up to what is still asked for, the copy made by the
+    kernel with the GIL released.  A 10 MiB block used to cross in
+    40-42 ``recv`` of asyncio's 256 KiB, each copied three times more
+    on the loop and each a turn of it; ``kernel-stats.body_read`` counts
+    the hand-overs, their bytes, the reads that gave them bytes (the
+    staged take and each ``recv_into``) and the bytes that came
+    ``direct``.
 
 ``_LoopWriter``
     Blocking writes through ``transport.write`` + ``drain()``.  A
@@ -71,6 +76,7 @@ a busy-spin to prove the blast radius stops at the loop boundary.
 from __future__ import annotations
 
 import asyncio
+import concurrent.futures
 import io
 import os
 import queue
@@ -107,19 +113,29 @@ _MAX_HANDOVER = 16 << 20
 # before, and a fill that keeps the buffer drained is never paused.
 _BODY_READAHEAD = 1 << 20
 
+# A loop's one buffer for reads that no hand-over waits for (heads, the
+# read-ahead): asyncio's own recv size.  The transport calls get_buffer,
+# recv_into and buffer_updated back to back on the loop's thread, and
+# buffer_updated hands the bytes on at once, so the loop's connections
+# can share it.
+_SCRATCH = 256 << 10
+
 # Bodies handed from the loops to their handlers, over the process:
 # [handovers (cross-thread calls of a _LoopReader), bytes handed over,
-# loop_reads (reads of the StreamReader the loop made for them that gave
-# bytes: the pieces it collected)].  Plain adds under the GIL on the
-# handler's thread, no lock and no clock; kernel-stats carries them as
-# ``body_read`` (codec/telemetry.py).  Hand-overs a MiB and pieces a
-# hand-over are read from it; handovers is body_read_wait's count.
-BODY_READ = [0, 0, 0]
+# loop_reads (reads that gave a hand-over bytes: the take of what the
+# StreamReader held, and each recv_into straight into the handler's
+# buffer), direct (bytes of those recv_into: the body that no copy on
+# the loop touched)].  Plain adds under the GIL on the handler's thread,
+# no lock and no clock; kernel-stats carries them as ``body_read``
+# (codec/telemetry.py).  Hand-overs a MiB, pieces a hand-over and the
+# direct share are read from it; handovers is body_read_wait's count.
+BODY_READ = [0, 0, 0, 0]
 
 
 def body_read_counts() -> dict:
-    handovers, nbytes, loop_reads = BODY_READ
-    return {"handovers": handovers, "bytes": nbytes, "loop_reads": loop_reads}
+    handovers, nbytes, loop_reads, direct = BODY_READ
+    return {"handovers": handovers, "bytes": nbytes,
+            "loop_reads": loop_reads, "direct": direct}
 
 
 def _set_flow_limit(reader: asyncio.StreamReader, limit: int) -> None:
@@ -172,81 +188,178 @@ def _split(total: int, parts: int) -> "list[int]":
     return [base + (1 if i < rem else 0) for i in range(parts)]
 
 
+class _BodyProtocol(asyncio.StreamReaderProtocol, asyncio.BufferedProtocol):
+    """A connection's protocol: asyncio's StreamReader for heads, and a
+    body read by the socket straight into the buffer its handler brought.
+
+    Being a ``BufferedProtocol``, it is asked by the transport where to
+    ``recv_into`` (``get_buffer``) and told how much came
+    (``buffer_updated``).  With no hand-over armed that is the loop's
+    scratch buffer and the bytes go on to the StreamReader, so heads,
+    their limits and timeouts and the flow control are asyncio's own.
+    ``take`` arms a hand-over: the StreamReader's bytes first, then the
+    rest of the handler's buffer itself, never past its end, so what
+    follows a body (a pipelined request) stays in the socket for the
+    StreamReader.  Everything here runs on the loop's thread."""
+
+    def __init__(self, reader: asyncio.StreamReader, client_connected_cb=None,
+                 *, scratch: memoryview, loop):
+        super().__init__(reader, client_connected_cb, loop=loop)
+        # held strongly: bytes staged before a close are still the body's
+        self._reader = reader
+        self._scratch = scratch
+        # the armed hand-over: the handler's buffer, bytes in it, reads
+        # that gave them, bytes taken from the StreamReader, the future
+        # the handler waits on, the idle timeout, the time of the last
+        # bytes and the timer that checks it
+        self._view: "memoryview | None" = None
+        self._got = self._reads = self._staged = 0
+        self._done: "concurrent.futures.Future | None" = None
+        self._timeout = 0.0
+        self._last = 0.0
+        self._timer: "asyncio.TimerHandle | None" = None
+
+    def take(self, buf: bytearray, done: concurrent.futures.Future,
+             timeout: float) -> None:
+        """Fill ``buf`` for a handler and resolve ``done`` with (the
+        bytes, reads that gave them, bytes of them that came straight
+        from the socket): full, short at EOF, or an error.  ``timeout``
+        bounds the wait for the NEXT bytes, not the hand-over: a slow
+        client that keeps sending is not cut."""
+        reader = self._reader
+        if reader._exception is not None:
+            done.set_exception(reader._exception)
+            return
+        _set_flow_limit(reader, _BODY_READAHEAD)
+        staged = reader._buffer
+        got = min(len(staged), len(buf))
+        if got:
+            with memoryview(staged) as view:
+                buf[:got] = view[:got]
+            del staged[:got]
+            reader._maybe_resume_transport()
+        self._view, self._got, self._staged = memoryview(buf), got, got
+        self._reads = 1 if got else 0
+        self._done = done
+        if got == len(buf) or reader._eof:
+            self._settle()
+            return
+        self._timeout = timeout
+        self._last = self._loop.time()
+        self._timer = self._loop.call_at(self._last + timeout, self._idle)
+
+    def _idle(self) -> None:
+        due = self._last + self._timeout
+        if self._loop.time() < due:
+            self._timer = self._loop.call_at(due, self._idle)
+            return
+        self._timer = None
+        self._settle(TimeoutError("body read timed out"))
+
+    def _settle(self, exc: "BaseException | None" = None) -> None:
+        view, done, got = self._view, self._done, self._got
+        self._view = self._done = None
+        if self._timer is not None:
+            self._timer.cancel()
+            self._timer = None
+        if exc is not None:
+            done.set_exception(exc)
+            return
+        # a short buffer is copied, not cut: the transport may still hold
+        # a view of it
+        buf = view.obj
+        data = buf if got == len(buf) else buf[:got]
+        done.set_result((data, self._reads, got - self._staged))
+
+    def get_buffer(self, sizehint: int) -> memoryview:
+        if self._view is None:
+            return self._scratch
+        return self._view[self._got:]
+
+    def buffer_updated(self, nbytes: int) -> None:
+        if self._view is None:
+            self.data_received(self._scratch[:nbytes])
+            return
+        self._got += nbytes
+        self._reads += 1
+        if self._got == len(self._view):
+            self._settle()
+        else:
+            self._last = self._loop.time()
+
+    def eof_received(self):
+        if self._view is not None:
+            self._settle()
+        return super().eof_received()
+
+    def connection_lost(self, exc) -> None:
+        if self._view is not None:
+            self._settle(exc or ConnectionResetError("connection lost"))
+        super().connection_lost(exc)
+
+
 class _LoopReader:
-    """Synchronous file-like over the owning loop's StreamReader, used
+    """Synchronous file-like over a connection's ``_BodyProtocol``, used
     by the handler thread.  Every call blocks the *worker*, never the
     loop.  ``owner`` is the connection's ``_ServerLoop``."""
 
-    def __init__(self, owner: "_ServerLoop", reader: asyncio.StreamReader):
+    def __init__(self, owner: "_ServerLoop", proto: _BodyProtocol):
         self._owner = owner
-        self._reader = reader
+        self._proto = proto
 
-    def _call(self, coro):
-        """One hand-over: ``coro`` runs on the loop and gives (bytes-like,
-        reads of the StreamReader it took)."""
+    def _call(self, start):
+        """One hand-over: ``start()`` sets the loop's side going and gives
+        a future of (bytes-like, reads that gave them, direct bytes)."""
         try:
             # the handler blocked on the loop (and, behind it, the client)
             with spans.span(spans.BODY_READ_WAIT):
-                fut = asyncio.run_coroutine_threadsafe(
-                    coro, self._owner.loop
-                )
-                data, loop_reads = fut.result()
-        except asyncio.TimeoutError:
+                data, loop_reads, direct = start().result()
+        except TimeoutError:
             raise socket.timeout("body read timed out") from None
         except (RuntimeError, ConnectionError, asyncio.CancelledError) as e:
             raise OSError(f"connection lost: {e}") from None
         BODY_READ[0] += 1
         BODY_READ[1] += len(data)
         BODY_READ[2] += loop_reads
+        BODY_READ[3] += direct
         return data
+
+    def _run(self, coro):
+        return lambda: asyncio.run_coroutine_threadsafe(coro, self._owner.loop)
 
     def read(self, n: int = -1):
         """``n`` bytes, fewer only at EOF (io.ReadFull; what the
         threaded plane's ``rfile`` gives) or above ``_MAX_HANDOVER``, in
         one hand-over from the loop; ``read(-1)`` reads to EOF.  The
-        buffer is allocated here, on the worker, and comes back as it
-        is: a ``bytearray``, so no copy of the block is made for a
-        ``bytes``."""
+        buffer is allocated here, on the worker, filled by the loop
+        (``_BodyProtocol.take``) and comes back as it is: a
+        ``bytearray``, so no copy of the block is made for a ``bytes``."""
         if n == 0:
             return b""
-        reader = self._reader
         timeout = self._owner.body_timeout
 
         if n < 0:
-            async def _rd():
-                return await asyncio.wait_for(reader.read(-1), timeout), 1
+            reader = self._proto._reader
 
-            return self._call(_rd())
+            async def _rd():
+                return await asyncio.wait_for(reader.read(-1), timeout), 1, 0
+
+            return self._call(self._run(_rd()))
 
         buf = bytearray(min(n, _MAX_HANDOVER))
+        loop, proto = self._owner.loop, self._proto
 
-        async def _fill():
-            # never more than was asked for: what follows the body in
-            # the StreamReader is the next request's.  The timeout
-            # bounds the wait for the NEXT bytes, not the hand-over: a
-            # slow client that keeps sending is not cut
-            _set_flow_limit(reader, _BODY_READAHEAD)
-            want = len(buf)
-            got = pieces = 0
-            while got < want:
-                piece = await asyncio.wait_for(
-                    reader.read(want - got), timeout
-                )
-                if not piece:
-                    break
-                # same length on both sides: a copy in place, no resize
-                buf[got:got + len(piece)] = piece
-                got += len(piece)
-                pieces += 1
-            del buf[got:]
-            return buf, pieces
+        def _start():
+            done = concurrent.futures.Future()
+            loop.call_soon_threadsafe(proto.take, buf, done, timeout)
+            return done
 
-        return self._call(_fill())
+        return self._call(_start)
 
     def readline(self, limit: int = -1) -> bytes:
         """Bounded line read (internode chunked framing uses 1024)."""
         timeout = self._owner.body_timeout
-        reader = self._reader
+        reader = self._proto._reader
 
         async def _rl():
             out = bytearray()
@@ -257,9 +370,9 @@ class _LoopReader:
                 out += b
                 if b == b"\n":
                     break
-            return bytes(out), len(out)
+            return bytes(out), len(out), 0
 
-        return self._call(_rl())
+        return self._call(self._run(_rl()))
 
 
 class _LoopWriter:
@@ -393,6 +506,7 @@ class _ServerLoop:
         self._tasks: "set[asyncio.Task]" = set()
         self._srv = None
         self._thread: "threading.Thread | None" = None
+        self._scratch = memoryview(bytearray(_SCRATCH))
         self.lstats.register_stage("parse", lambda: len(self._conns))
         self.lstats.register_stage("handler", self.pool.depth)
         # the loop's lag (kernel-stats.probe.loops): queue time seen from
@@ -435,13 +549,11 @@ class _ServerLoop:
 
         async def _boot():
             if sock is not None:
-                return await asyncio.start_server(
-                    self._serve_conn, sock=sock, ssl=ssl_ctx,
-                    limit=_MAX_HEAD,
+                return await self.loop.create_server(
+                    self._protocol, sock=sock, ssl=ssl_ctx
                 )
-            return await asyncio.start_server(
-                self._serve_conn, host, port, ssl=ssl_ctx,
-                limit=_MAX_HEAD,
+            return await self.loop.create_server(
+                self._protocol, host, port, ssl=ssl_ctx
             )
 
         self._srv = asyncio.run_coroutine_threadsafe(
@@ -456,16 +568,20 @@ class _ServerLoop:
     def bound_port(self) -> int:
         return self._srv.sockets[0].getsockname()[1]
 
+    def _protocol(self) -> _BodyProtocol:
+        """A new connection's protocol (what ``asyncio.start_server``
+        builds, with the body read of ``_BodyProtocol``)."""
+        reader = asyncio.StreamReader(limit=_MAX_HEAD, loop=self.loop)
+        return _BodyProtocol(reader, self._serve_conn,
+                             scratch=self._scratch, loop=self.loop)
+
     async def _adopt(self, conn: socket.socket, ssl_ctx) -> None:
         """Round-robin handoff target: wrap an already-accepted socket
         in this loop's streams and serve it like a native accept."""
         conn.setblocking(False)
-        reader = asyncio.StreamReader(limit=_MAX_HEAD)
-        proto = asyncio.StreamReaderProtocol(reader, self._serve_conn)
         try:
-            # factory, not instance: one _adopt call wraps one socket
             await self.loop.connect_accepted_socket(
-                lambda: proto, conn, ssl=ssl_ctx
+                self._protocol, conn, ssl=ssl_ctx
             )
         except (OSError, asyncio.CancelledError):
             conn.close()
@@ -650,7 +766,7 @@ class _ServerLoop:
         h.headers = headers
         h.client_address = writer.get_extra_info("peername") or ("", 0)
         h.close_connection = _wants_close(version, headers)
-        h.rfile = _LoopReader(self, reader)
+        h.rfile = _LoopReader(self, writer.transport.get_protocol())
         h.wfile = _LoopWriter(self, writer)
         h._plane_admitted = True
         h._loop_index = self.index

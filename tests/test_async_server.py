@@ -9,10 +9,13 @@ where http.client would hide the wire behaviour under test
 (pipelining, deferred 100-continue, partial heads).
 """
 
+import asyncio
+import concurrent.futures
 import datetime
 import hashlib
 import os
 import socket
+import struct
 import threading
 import time
 
@@ -544,7 +547,10 @@ def _moved(before):
 def test_put_10mib_crosses_in_one_handover(leakcheck, tmp_path):
     """A signed 10 MiB PUT read with the reference's 10 MiB block: the
     body crosses from the loop to its handler once (40-42 times when a
-    read(n) returned one recv), every byte of it, and reads back."""
+    read(n) returned one recv), every byte of it, and reads back.  All
+    but what the connection had staged before the hand-over went from
+    the socket into the handler's buffer (40-42 recv of 256 KiB when
+    each was copied through the StreamReader)."""
     size = 10 << 20
     booted = _boot(tmp_path, "async", block_size=size)
     try:
@@ -556,12 +562,14 @@ def test_put_10mib_crosses_in_one_handover(leakcheck, tmp_path):
         moved = _moved(before)
         assert moved["handovers"] == moved["waits"] == 1
         assert moved["bytes"] == size
-        assert 1 <= moved["loop_reads"] <= size // 4096
+        assert 1 <= moved["loop_reads"] <= 64
+        assert 0.9 * size <= moved["direct"] <= size
         before = _body_read()
         g = c.get_object("once", "block")
         assert g.status == 200 and g.body == body
         assert _moved(before) == {
-            "handovers": 0, "bytes": 0, "loop_reads": 0, "waits": 0,
+            "handovers": 0, "bytes": 0, "loop_reads": 0, "direct": 0,
+            "waits": 0,
         }
     finally:
         _teardown(booted)
@@ -719,13 +727,35 @@ def test_head_limits_hold_after_a_body(leakcheck, tmp_path, head, status):
         _teardown(booted)
 
 
+def _body_protocol(loop):
+    from minio_tpu.server import aio
+
+    stream = asyncio.StreamReader(limit=aio._MAX_HEAD, loop=loop)
+    scratch = memoryview(bytearray(aio._SCRATCH))
+    return aio._BodyProtocol(stream, scratch=scratch, loop=loop)
+
+
+def _deliver(proto, data):
+    """What the transport does with bytes the socket holds: recv_into
+    the protocol's buffer, as much as fits, and tell it; None is EOF."""
+    if data is None:
+        proto.eof_received()
+        return
+    view = memoryview(data)
+    while view:
+        buf = proto.get_buffer(-1)
+        n = min(len(buf), len(view))
+        buf[:n] = view[:n]
+        proto.buffer_updated(n)
+        view = view[n:]
+
+
 class _Wire:
-    """A _LoopReader over a StreamReader that the test feeds by hand: the
-    bridge alone, with no socket and no server around it."""
+    """A _LoopReader over a _BodyProtocol that the test feeds by hand as
+    its transport would: the bridge alone, with no socket and no server
+    around it."""
 
     def __init__(self, timeout=5.0):
-        import asyncio
-
         from minio_tpu.server import aio
 
         self.loop = asyncio.new_event_loop()
@@ -734,15 +764,13 @@ class _Wire:
             target=self.loop.run_forever, name="wire-loop", daemon=True
         )
         self._thread.start()
-        self.stream = asyncio.StreamReader(limit=aio._MAX_HEAD, loop=self.loop)
-        self.reader = aio._LoopReader(self, self.stream)
+        self.proto = _body_protocol(self.loop)
+        self.stream = self.proto._reader
+        self.reader = aio._LoopReader(self, self.proto)
 
     def feed(self, data):
         """None is EOF."""
-        if data is None:
-            self.loop.call_soon_threadsafe(self.stream.feed_eof)
-        else:
-            self.loop.call_soon_threadsafe(self.stream.feed_data, data)
+        self.loop.call_soon_threadsafe(_deliver, self.proto, data)
 
     def close(self):
         self.loop.call_soon_threadsafe(self.loop.stop)
@@ -820,6 +848,103 @@ def test_loop_reader_times_out_on_the_next_bytes_only(leakcheck):
         assert wire.reader.readline(1024) == b"1f\r\n"
     finally:
         wire.close()
+
+
+@pytest.mark.parametrize("staged", [0, 1000, 5000], ids=["nothing", "part", "more"])
+def test_body_protocol_takes_the_staged_bytes_then_reads_into_the_buffer(
+    leakcheck, staged
+):
+    """A hand-over of 3000 bytes with 0, 1000 or all of them (and the next
+    request's head) already in the StreamReader: the staged bytes come
+    first, then the transport is given a view of exactly what is still
+    asked for in the handler's own buffer, so a pipelined head that
+    follows in the socket stays for the StreamReader."""
+    from minio_tpu.server import aio
+
+    body = _pay(3000, seed=61)
+    nxt = b"GET /next HTTP/1.1\r\nHost: x\r\n\r\n"
+    wire = body + nxt
+
+    async def main():
+        proto = _body_protocol(asyncio.get_running_loop())
+        _deliver(proto, wire[:staged])  # no hand-over armed: staged
+        buf = bytearray(len(body))
+        done = concurrent.futures.Future()
+        proto.take(buf, done, 5.0)
+        assert bytes(buf[:min(staged, len(body))]) == body[:staged]
+        if staged < len(body):
+            assert not done.done()
+            view = proto.get_buffer(-1)
+            assert view.obj is buf and len(view) == len(body) - staged
+            # the socket holds the body's rest and the next head: one
+            # recv_into takes the rest, the head goes to the StreamReader
+            rest = wire[staged:]
+            view[:] = rest[:len(view)]
+            proto.buffer_updated(len(view))
+            _deliver(proto, rest[len(view):])
+        data, reads, direct = done.result(timeout=0)
+        assert data is buf and data == body
+        assert direct == max(0, len(body) - staged)
+        assert reads == (staged > 0) + (staged < len(body))
+        assert proto.get_buffer(-1) is proto._scratch  # disarmed
+        assert proto._reader._limit == aio._BODY_READAHEAD
+        assert await proto._reader.readuntil(b"\r\n\r\n") == nxt
+
+    asyncio.run(main())
+
+
+def test_connection_lost_mid_body_fails_the_read_and_stores_nothing(
+    leakcheck, tmp_path
+):
+    """The client resets the connection halfway through a body its
+    handler is waiting for: the armed hand-over fails, the handler's
+    read raises OSError, and no object is stored."""
+    booted = _boot(tmp_path, "async", block_size=1 << 20)
+    srv = booted.srv
+    orig = srv.object_layer.put_object
+    raised = []
+
+    class _Spy:
+        def __init__(self, inner):
+            self._inner = inner
+
+        def read(self, n=-1):
+            try:
+                return self._inner.read(n)
+            except Exception as exc:
+                raised.append(exc)
+                raise
+
+        def __getattr__(self, name):
+            return getattr(self._inner, name)
+
+    def spying_put(bucket, object_name, reader, *args, **kw):
+        return orig(bucket, object_name, _Spy(reader), *args, **kw)
+
+    srv.object_layer.put_object = spying_put
+    try:
+        c = S3Client(srv.endpoint)
+        assert c.make_bucket("cut").status == 200
+        body = _pay(600_000, seed=34)
+        s = _connect(srv)
+        try:
+            s.sendall(_signed_head(c, "PUT", "/cut/obj", body=body))
+            s.sendall(body[:200_000])
+            time.sleep(0.5)  # the handler is waiting for the rest
+            # linger on, for 0 s
+            s.setsockopt(
+                socket.SOL_SOCKET, socket.SO_LINGER, struct.pack("ii", 1, 0)
+            )
+        finally:
+            s.close()  # a reset, not an EOF
+        deadline = time.monotonic() + 10
+        while not raised and time.monotonic() < deadline:
+            time.sleep(0.02)
+        assert raised and all(isinstance(e, OSError) for e in raised)
+        assert c.get_object("cut", "obj").status == 404
+    finally:
+        srv.object_layer.put_object = orig
+        _teardown(booted)
 
 
 class _StreamPlane:

@@ -423,13 +423,17 @@ def test_admin_kernel_stats_route(server, client):
 def test_admin_kernel_stats_body_read_adds_up_over_a_put(server, client, size):
     """``body_read`` (server/aio.py::BODY_READ, beside ``meta_read``): a PUT's
     body crosses from the loop to its handler once a 4096-byte block of this
-    server, every byte of it, and each crossing is one ``body_read_wait``."""
+    server, every byte of it, and each crossing is one ``body_read_wait``;
+    ``direct`` is the part of those bytes that the socket read straight into
+    the handler's buffer."""
 
     def counts():
         r = client.request("GET", f"{ADMIN}/kernel-stats")
         assert r.status == 200, r.body
         doc = json.loads(r.body)
-        assert set(doc["body_read"]) == {"handovers", "bytes", "loop_reads"}
+        assert set(doc["body_read"]) == {
+            "handovers", "bytes", "loop_reads", "direct"
+        }
         waits = sum(
             s["count"] for s in doc["spans"] if s["name"] == "body_read_wait"
         )
@@ -443,6 +447,7 @@ def test_admin_kernel_stats_body_read_adds_up_over_a_put(server, client, size):
     assert moved["handovers"] == moved["waits"] == blocks
     assert moved["bytes"] == size
     assert blocks <= moved["loop_reads"] <= size
+    assert 0 <= moved["direct"] <= moved["bytes"]
 
 
 # what a client does to a key that holds an object -> kernel-stats.remove
