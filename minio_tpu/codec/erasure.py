@@ -42,6 +42,10 @@ _log = logger("codec")
 BLOCK_SIZE_V1 = 10 * 1024 * 1024  # reference blockSizeV1
 DEFAULT_BATCH_BLOCKS = 4
 
+# a decode's heal verdict: 0 when no shard was wanting, else these flags
+HEAL_UNOPENED = 1  # a slot had no reader when the read began
+HEAL_DAMAGED = 2  # a shard read from an open reader was missing or bad
+
 # read-ahead jobs carry a fresh sequence key so concurrent GETs spread
 # across pool queues instead of serializing behind one worker
 _RA_SEQ = itertools.count()
@@ -608,17 +612,19 @@ class Erasure:
         batch_blocks: int = DEFAULT_BATCH_BLOCKS,
         backend: "backend_mod.CodecBackend | None" = None,
         cache_ctx=None,
-    ) -> tuple[int, bool]:
+    ) -> tuple[int, int]:
         """Reconstruct [offset, offset+length) into ``writer``.
 
         ``readers`` is the shard reader list OR a zero-arg callable
         producing it (lazy open: with a ``cache_ctx`` whose groups all
         hit, the readers are never opened at all).
 
-        Returns (bytes_written, heal_required): heal_required is set when
-        any shard was missing or failed bitrot verification but quorum
-        still allowed reconstruction (errHealRequired semantics,
-        erasure-decode.go:165-167).
+        Returns (bytes_written, heal_required): heal_required is non-zero
+        when any shard was missing or failed bitrot verification but
+        quorum still allowed reconstruction (errHealRequired semantics,
+        erasure-decode.go:165-167) - ``HEAL_UNOPENED`` where a slot had
+        no reader from the start, ``HEAL_DAMAGED`` where a shard read
+        from an open reader was missing, short or failed its digest.
         """
         stages = {"assemble": 0.0, "codec": 0.0, "disk": 0.0}
         written, heal_required = self._decode_stream(
@@ -642,9 +648,9 @@ class Erasure:
         backend: "backend_mod.CodecBackend | None" = None,
         stages: "dict | None" = None,
         cache_ctx=None,
-    ) -> tuple[int, bool]:
+    ) -> tuple[int, int]:
         if length == 0:
-            return 0, False
+            return 0, 0
         if offset < 0 or length < 0 or offset + length > total_length:
             raise ValueError("range out of bounds")
         be = backend or backend_mod.get_backend()
@@ -661,14 +667,14 @@ class Erasure:
             batches.append(batch_idx)
             bi += len(batch_idx)
         written = 0
-        heal_required = False
+        heal_required = 0
         if len(batches) <= 1:
             for batch_idx in batches:
                 datas, healed = self._decode_blocks(
                     be, bank, batch_idx, total_length, stages,
                     cache_ctx,
                 )
-                heal_required = heal_required or healed
+                heal_required |= healed
                 w, done = self._write_blocks(
                     writer, datas, batch_idx, offset, length,
                     total_length,
@@ -703,7 +709,7 @@ class Erasure:
                     span_name=spans.STREAM_READAHEAD_WAIT
                 )
                 fut = None
-                heal_required = heal_required or healed
+                heal_required |= healed
                 if i + 1 < len(batches):
                     fut = pool.submit(
                         ("readahead", next(_RA_SEQ)),
@@ -764,8 +770,9 @@ class Erasure:
         self, be, bank: "_ReaderBank", block_indices: list[int],
         total_length: int, stages: "dict | None" = None,
         cache_ctx=None,
-    ) -> tuple[list[bytes], bool]:
-        """Read + verify + reconstruct a batch of blocks -> raw block bytes.
+    ) -> tuple[list[bytes], int]:
+        """Read + verify + reconstruct a batch of blocks -> raw block
+        bytes and the batch's ``HEAL_*`` flags.
 
         Reads only ``data_blocks`` shards up front (local readers
         preferred, data shards first among equals) and escalates to
@@ -793,7 +800,7 @@ class Erasure:
             "decode", len(block_indices), tail=len(set(sizes)) > 1
         )
         readers = None
-        heal = False
+        heal = 0
         all_online = False  # every drive of the set, when the read began
         out: list[bytes] = []
         # group contiguous runs with equal shard size into one device pass
@@ -831,11 +838,13 @@ class Erasure:
                 # never need it (a fully-cached GET skips this check by
                 # design — it observes no disks at all)
                 all_online = all(readers[s] is not None for s in range(n))
-                heal = heal or not all_online
+                if not all_online:
+                    heal |= HEAL_UNOPENED
             shards, digests, ok, g_heal = self._read_group_quorum(
                 be, readers, group, shard_len, stages
             )
-            heal = heal or g_heal
+            if g_heal:
+                heal |= HEAL_DAMAGED
             # verify stays a separate pass HERE (unlike heal, which
             # uses the fused reconstruct_and_verify - ONE device
             # launch under fused1): the quorum read needs per-shard

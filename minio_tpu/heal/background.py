@@ -4,7 +4,10 @@ The reference splits this across three pieces that we mirror:
 
 - MRF ("most recently failed", erasure.go:40-45 + addPartial,
   erasure-object.go:999): writes that missed some disks enqueue the
-  object for immediate heal instead of waiting for a crawl.
+  object for immediate heal instead of waiting for a crawl.  An entry
+  whose missing shards all lie on drives that are gone could write
+  nothing, so it is set aside until one of those drives answers again
+  (healMRFRoutine waits for disksConnectEvent the same way).
 - Heal routine (healRoutine.run, background-heal-ops.go:77): one
   background consumer draining heal tasks against the object layer,
   throttled so it cannot starve foreground I/O.
@@ -33,12 +36,18 @@ _log = logger("heal")
 # ``healed`` (a shard was written back), ``unwritable`` (shards are
 # missing, but every drive that misses one is still gone: nothing could be
 # written), ``clean`` (nothing was missing), ``failed`` (it raised) - with
-# the tasks' wall seconds and the shard bytes they read and wrote.  One
-# add a push, a few a task; never a byte.
+# the tasks' wall seconds and the shard bytes they read and wrote; and
+# what the queue did with pushes that could write nothing (``parked``):
+# ``set_aside`` (kept out of the FIFO until a drive it waits for
+# answers), ``absorbed`` (its object was already set aside), ``requeued``
+# (a drive answered: back into the FIFO).  One add a push, a few a task;
+# never a byte.
 PUSH_SOURCES = ("partial_write", "bitrot_read", "degraded_read", "crawl")
 OUTCOMES = ("healed", "unwritable", "clean", "failed")
+PARK_EVENTS = ("set_aside", "absorbed", "requeued")
 PUSHES = dict.fromkeys(PUSH_SOURCES, 0)
 TASKS = dict.fromkeys(OUTCOMES, 0)
+PARKED = dict.fromkeys(PARK_EVENTS, 0)
 TASK_TOTALS = {"seconds": 0.0, "read_bytes": 0, "written_bytes": 0}
 _COUNT_LK = threading.Lock()  # pushes come from every handler thread
 _QUEUES: "weakref.WeakSet[HealQueue]" = weakref.WeakSet()
@@ -48,6 +57,11 @@ def pushed(source: str) -> None:
     """One object handed to the heal queue's hook, and why."""
     with _COUNT_LK:
         PUSHES[source] += 1
+
+
+def _parked(event: str, n: int = 1) -> None:
+    with _COUNT_LK:
+        PARKED[event] += n
 
 
 def _task_done(outcome: str, seconds: float, result: dict) -> None:
@@ -66,11 +80,19 @@ def _outcome_of(result: dict) -> str:
 
 
 def heal_counts() -> dict:
-    """``kernel-stats.heal``; ``queue_depth`` is read now, every queue's."""
+    """``kernel-stats.heal``; ``queue_depth`` (queued + set aside) and
+    ``parked_depth`` are read now, every queue's."""
     with _COUNT_LK:
-        out = {"pushed": dict(PUSHES), "tasks": dict(TASKS), **TASK_TOTALS}
+        out = {
+            "pushed": dict(PUSHES),
+            "tasks": dict(TASKS),
+            "parked": dict(PARKED),
+            **TASK_TOTALS,
+        }
     out["seconds"] = round(out["seconds"], 6)
-    out["queue_depth"] = sum(len(q) for q in list(_QUEUES))
+    queues = list(_QUEUES)
+    out["queue_depth"] = sum(len(q) for q in queues)
+    out["parked_depth"] = sum(q.parked_depth() for q in queues)
     return out
 
 
@@ -85,27 +107,93 @@ class HealTask:
 
 class HealQueue:
     """Deduplicating FIFO feeding the heal routine (the healTask
-    channel analogue, bounded by dedup rather than depth)."""
+    channel analogue, bounded by dedup rather than depth), and beside it
+    the entries set aside: tasks that could write nothing, each keyed
+    once and held until a drive it waits for answers again."""
 
     def __init__(self):
         self._mu = threading.Lock()
         self._cond = threading.Condition(self._mu)
         self._queue: collections.deque = collections.deque()
         self._pending: set = set()
+        self._inflight = 0
+        # task -> the drives it waits for; drive -> the tasks waiting on it
+        self._parked: "dict[HealTask, tuple]" = {}
+        self._waiters: "dict[object, set[HealTask]]" = {}
         _QUEUES.add(self)
 
-    def push(self, task: HealTask) -> None:
+    def push(self, task: HealTask, waits: tuple = ()) -> None:
+        """Queue ``task``.  ``waits`` names the drives that hold every
+        shard the pusher found wanting, when each of them was offline: a
+        heal could write nothing now, so the task is set aside until one
+        of them answers.  A push with nothing to wait for can write, and
+        runs at once even where the object was set aside."""
         with self._cond:
             if task in self._pending:
                 return
-            self._pending.add(task)
-            self._queue.append(task)
-            self._cond.notify()
+            if waits:
+                self._park(task, waits)
+                return
+            if task in self._parked:
+                self._unpark(task)
+            self._enqueue(task)
 
     def push_object(
-        self, bucket: str, object_name: str, version_id: str = ""
+        self, bucket: str, object_name: str, version_id: str = "",
+        waits: tuple = (),
     ) -> None:
-        self.push(HealTask(bucket, object_name, version_id))
+        self.push(HealTask(bucket, object_name, version_id), waits)
+
+    def _enqueue(self, task: HealTask) -> None:
+        self._pending.add(task)
+        self._queue.append(task)
+        self._cond.notify()
+
+    def _park(self, task: HealTask, waits: tuple) -> None:
+        held = self._parked.get(task)
+        if held is None:
+            self._parked[task] = tuple(waits)
+            new = waits
+            _parked("set_aside")
+        else:
+            new = tuple(d for d in waits if d not in held)
+            if new:
+                self._parked[task] = held + new
+            _parked("absorbed")
+        for d in new:
+            self._waiters.setdefault(d, set()).add(task)
+
+    def _unpark(self, task: HealTask) -> None:
+        for d in self._parked.pop(task):
+            tasks = self._waiters.get(d)
+            if tasks is not None:
+                tasks.discard(task)
+                if not tasks:
+                    del self._waiters[d]
+
+    def requeue_answered(self) -> int:
+        """Ask each drive that set-aside tasks wait for whether it is
+        online (once a drive, whatever the number of tasks on it), and
+        put every task waiting on one that answers back into the FIFO.
+        Returns how many went back."""
+        if not self._waiters:
+            return 0
+        with self._mu:
+            drives = list(self._waiters)
+        back = [d for d in drives if _answers(d)]
+        if not back:
+            return 0
+        moved = 0
+        with self._cond:
+            for d in back:
+                for task in list(self._waiters.get(d, ())):
+                    self._unpark(task)
+                    if task not in self._pending:
+                        self._enqueue(task)
+                        moved += 1
+        if moved:
+            _parked("requeued", moved)
+        return moved
 
     def pop(self, timeout: "float | None" = None) -> "HealTask | None":
         with self._cond:
@@ -121,19 +209,35 @@ class HealQueue:
             self._inflight += 1
             return task
 
-    _inflight = 0
-
     def task_done(self) -> None:
         with self._mu:
             self._inflight -= 1
 
     def idle(self) -> bool:
+        """Nothing queued, nothing running, and no set-aside task waits
+        on a drive that answers again (those go back into the FIFO)."""
+        self.requeue_answered()
         with self._mu:
             return not self._queue and self._inflight == 0
 
-    def __len__(self) -> int:
+    def parked_depth(self) -> int:
         with self._mu:
-            return len(self._queue)
+            return len(self._parked)
+
+    def __len__(self) -> int:
+        """Objects awaiting a heal: queued and set aside."""
+        with self._mu:
+            return len(self._queue) + len(self._parked)
+
+
+def _answers(drive) -> bool:
+    """Whether a drive a set-aside task waits for is online again
+    (``DiskIDCheck`` answers from its last look, once an interval)."""
+    try:
+        return bool(drive.is_online())
+    except Exception as exc:  # noqa: BLE001 - the heal routine keeps running
+        _log.debug("liveness probe failed", extra=kv(err=str(exc)))
+        return False
 
 
 class HealRoutine:
@@ -178,6 +282,8 @@ class HealRoutine:
 
     def _run(self) -> None:
         while not self._stop.is_set():
+            # once a wake: set-aside tasks whose drive answered go back
+            self.queue.requeue_answered()
             task = self.queue.pop(timeout=0.25)
             if task is None:
                 continue

@@ -150,6 +150,36 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
             wq += 1
         return wq
 
+    def _gone_drives(
+        self, disks: list, distribution: list, wanting: list
+    ) -> tuple:
+        """The drives of the slots ``wanting`` a shard when every one
+        of them was offline in ``disks`` (the operation's shuffled
+        snapshot of ``_online_disks``), else (): a slot on a drive that
+        is there, or one with no drive to ask, can take a heal now."""
+        if not wanting or any(disks[i] is not None for i in wanting):
+            return ()
+        raw = shuffle_disks(self.disks, distribution)
+        gone = tuple(raw[i] for i in wanting)
+        return () if any(d is None for d in gone) else gone
+
+    def _mrf_push(
+        self, source: str, bucket: str, object_name: str, gone: tuple = ()
+    ) -> None:
+        """Hand the object to the MRF hook (addPartial), and count why.
+        ``gone``: the drives every wanting shard lies on, all offline -
+        the queue sets the object aside until one of them answers."""
+        heal_bg.pushed(source)
+        try:
+            if gone:
+                self.heal_hook(bucket, object_name, waits=gone)
+            else:
+                self.heal_hook(bucket, object_name)
+        except Exception as exc:
+            _log.debug(
+                "MRF heal hook failed", extra=kv(source=source, err=str(exc))
+            )
+
     def _online_disks(self) -> list:
         """Live disks, with breaker-tripped ones masked to None.
 
@@ -491,30 +521,23 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
             self._cleanup_tmp(disks, tmp_ids)
             raise
         # MRF: quorum met but some disks missed the write - queue the
-        # object for immediate background heal (addPartial)
+        # object for background heal (addPartial)
         if self.heal_hook is not None and any(
             e is not None for e in errs
         ):
-            heal_bg.pushed("partial_write")
-            try:
-                self.heal_hook(bucket, object_name)
-            except Exception as exc:
-                _log.debug("partial-write heal hook failed", extra=kv(err=str(exc)))
+            self._mrf_push(
+                "partial_write", bucket, object_name,
+                self._gone_drives(
+                    disks, distribution,
+                    [i for i, e in enumerate(errs) if e is not None],
+                ),
+            )
         if band is not None:
             # settle the parity plane in the background; anything that
             # fails past this ack is heal-flagged through the MRF hook
-            hook = self.heal_hook
-
             def _on_settled(b, _bucket=bucket, _obj=object_name):
-                if b.heal_required and hook is not None:
-                    heal_bg.pushed("partial_write")
-                    try:
-                        hook(_bucket, _obj)
-                    except Exception as exc:
-                        _log.debug(
-                            "parity settle heal hook failed",
-                            extra=kv(err=str(exc)),
-                        )
+                if b.heal_required and self.heal_hook is not None:
+                    self._mrf_push("partial_write", _bucket, _obj)
 
             band.finish(on_done=_on_settled)
         # overwrite cleanup: drop the replaced data dir (best effort)
@@ -877,7 +900,8 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
             disks = shuffle_disks(
                 self._online_disks(), fi.erasure.distribution
             )
-            heal_required = False
+            heal_required = 0  # ecodec.HEAL_* flags, every part's
+            damaged = False  # a shard wanting on a drive that is there
             # stream the parts covering [offset, offset+length).  Ranges
             # address LOGICAL bytes; each transformed part is an
             # independent stream (deflate and/or DARE packages), so
@@ -961,7 +985,16 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
                                 r.close()
                             except Exception as exc:
                                 _log.debug("shard reader close failed", extra=kv(err=str(exc)))
-                heal_required = heal_required or healed
+                heal_required |= healed
+                # a slot whose drive is there but whose shard would not
+                # open counts as damage, as a failed read or digest does
+                damaged = damaged or bool(healed & ecodec.HEAL_DAMAGED) or (
+                    bool(healed)
+                    and any(
+                        r is None and d is not None
+                        for r, d in zip(opened, disks)
+                    )
+                )
                 if sink is not writer:
                     sink.finish()
                 cur += in_len
@@ -971,18 +1004,21 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
                 info.user_defined["x-internal-heal-required"] = "true"
                 # bitrot / missing shard seen on the read path: queue a
                 # deep heal (deepHealObject, erasure-object.go:306-310);
-                # degraded where a drive of the set was gone as the read
-                # began, else a shard that failed its read or its digest
+                # bitrot where a shard on a drive that is there failed
+                # its open, read or digest (a heal can write it now),
+                # else degraded: every slot wanting is on a drive that
+                # was gone as the read began
                 if self.heal_hook is not None:
-                    heal_bg.pushed(
-                        "degraded_read"
-                        if any(d is None for d in disks)
-                        else "bitrot_read"
-                    )
-                    try:
-                        self.heal_hook(bucket, object_name)
-                    except Exception as exc:
-                        _log.debug("deep-heal hook failed", extra=kv(err=str(exc)))
+                    if damaged:
+                        self._mrf_push("bitrot_read", bucket, object_name)
+                    else:
+                        self._mrf_push(
+                            "degraded_read", bucket, object_name,
+                            self._gone_drives(
+                                disks, fi.erasure.distribution,
+                                [i for i, d in enumerate(disks) if d is None],
+                            ),
+                        )
             return info
 
     def device_scan_source(self, bucket, object_name):

@@ -265,7 +265,7 @@ class Metrics:
         if queue is not None:
             emit(
                 "miniotpu_heal_queue_depth", "gauge",
-                "Tasks waiting in the heal queue",
+                "Objects awaiting a heal: queued, or set aside until a drive answers",
                 [({}, len(queue))],
             )
         if audit is not None:

@@ -10,6 +10,7 @@ import time
 import numpy as np
 import pytest
 
+from minio_tpu.heal import background as heal_bg
 from minio_tpu.heal.background import (
     FreshDiskMonitor,
     HealQueue,
@@ -47,6 +48,63 @@ def test_heal_queue_dedup_and_order():
     # re-push after pop is allowed (no longer pending)
     q.push(HealTask("b", "o1"))
     assert len(q) == 1
+
+
+class _Drive:
+    """A drive a set-aside task waits for: answers as told, counts asks."""
+
+    def __init__(self, online: bool = False):
+        self.online = online
+        self.asked = 0
+
+    def is_online(self) -> bool:
+        self.asked += 1
+        return self.online
+
+
+def _parked_since(before: dict) -> dict:
+    return {k: v - before[k] for k, v in heal_bg.PARKED.items()}
+
+
+def test_heal_queue_dedups_across_the_fifo_and_the_set_aside():
+    q = HealQueue()
+    gone = _Drive()
+    before = dict(heal_bg.PARKED)
+    q.push(HealTask("b", "o1"), waits=(gone,))  # could write nothing: aside
+    q.push(HealTask("b", "o1"), waits=(gone,))  # absorbed
+    q.push(HealTask("b", "o2"))  # can write: queued
+    q.push(HealTask("b", "o2"), waits=(gone,))  # already queued: dropped
+    assert len(q) == 2 and q.parked_depth() == 1
+    assert q.pop(timeout=0.05) == HealTask("b", "o2")
+    q.task_done()
+    assert q.pop(timeout=0.05) is None  # o1 waits for its drive
+    assert q.idle()
+    # a push that can write takes the object out of the set-aside, once
+    q.push(HealTask("b", "o1"))
+    q.push(HealTask("b", "o1"), waits=(gone,))
+    assert q.parked_depth() == 0 and len(q) == 1
+    assert q.pop(timeout=0.05) == HealTask("b", "o1")
+    q.task_done()
+    assert _parked_since(before) == {"set_aside": 1, "absorbed": 1, "requeued": 0}
+
+
+def test_heal_queue_requeues_what_waits_on_a_drive_that_answers():
+    q = HealQueue()
+    d1, d2 = _Drive(), _Drive()
+    before = dict(heal_bg.PARKED)
+    q.push(HealTask("b", "o1"), waits=(d1,))
+    q.push(HealTask("b", "o2"), waits=(d1, d2))
+    q.push(HealTask("b", "o3"), waits=(d2,))
+    q.push(HealTask("b", "o3"), waits=(d1,))  # absorbed, and waits on d1 too
+    assert q.requeue_answered() == 0
+    assert (d1.asked, d2.asked) == (1, 1)  # once a drive, not once a task
+    d1.online = True
+    assert not q.idle()  # d1 answers: its tasks go back before idle
+    got = {q.pop(timeout=0.05) for _ in range(3)}
+    assert got == {HealTask("b", f"o{i}") for i in (1, 2, 3)}
+    assert q.parked_depth() == 0 and len(q) == 0
+    assert q.requeue_answered() == 0 and d2.asked == 2  # nothing left to ask
+    assert _parked_since(before) == {"set_aside": 3, "absorbed": 1, "requeued": 3}
 
 
 # -- MRF: partial write -> hook -> routine heals ---------------------------

@@ -69,12 +69,24 @@ class Set:
         self.eset.heal_hook = self.queue.push_object
         self.layer.make_bucket(BUCKET)
 
-    def lose(self, drives) -> None:
+    def lose(self, drives, undoably: bool = False) -> None:
         """The drives' paths stop being directories: nothing can be read
-        from them and nothing written back."""
+        from them and nothing written back.  ``undoably`` moves each
+        drive's directory aside, for ``bring_back``."""
         for d in drives:
-            shutil.rmtree(self.paths[d - 1])
-            open(self.paths[d - 1], "w").close()
+            path = self.paths[d - 1]
+            if undoably:
+                os.rename(path, path + ".away")
+            else:
+                shutil.rmtree(path)
+            open(path, "w").close()
+
+    def bring_back(self, drives) -> None:
+        """Drives lost ``undoably`` answer again, holding what they held."""
+        for d in drives:
+            path = self.paths[d - 1]
+            os.remove(path)
+            os.rename(path + ".away", path)
 
     def put(self, key: str, body: bytes):
         return self.layer.put_object(BUCKET, key, io.BytesIO(body), len(body))
@@ -208,6 +220,10 @@ def test_kernel_stats_report_heal_outcomes_and_the_heal_role(tmp_path, k, m, los
     batch = objects("heal")
     for key, body in batch:
         s.put(key, body)
+    # a short PUT's entry waits for a lost drive; a push with nothing to
+    # wait for (a crawl's) runs the task now, against drives still gone
+    for key, _ in batch:
+        s.queue.push_object(BUCKET, key)
     a = KERNEL_STATS.snapshot()
     routine = heal_bg.HealRoutine(s.layer, s.queue).start()
     try:
@@ -282,3 +298,133 @@ def test_a_puts_time_on_drives_that_fail_is_counted_by_phase(tmp_path, k, m, los
     b = KERNEL_STATS.snapshot()["fanout_failed"][phase]
     assert b["jobs"] - a["jobs"] == 2
     assert 0.1 <= b["run_seconds"] - a["run_seconds"] < 5
+
+
+def _heal_counts() -> dict:
+    return KERNEL_STATS.snapshot()["heal"]
+
+
+def _moved(a: dict, b: dict, table: str) -> dict:
+    return {k: v - a[table].get(k, 0) for k, v in b[table].items()}
+
+
+def _decodes_with(s: Set, key: str, body: bytes, must: "list[int]") -> bool:
+    """The object decodes through the reference from the shard indices
+    ``must`` and as many others as k takes."""
+    parts = s.shards(key)
+    rest = [i for i in sorted(parts) if i not in must]
+    use = sorted(list(must) + rest[: s.k - len(must)])
+    return reference.decode_object(parts, use, len(body), s.k, s.m, BLOCK) == body
+
+
+@pytest.mark.parametrize("k,m,lost", GEOMETRIES)
+def test_heals_that_can_write_nothing_are_set_aside(tmp_path, k, m, lost):
+    s = Set(tmp_path, k, m)
+    s.lose(lost, undoably=True)
+    batch = objects("park")
+    a = _heal_counts()
+    for key, body in batch:
+        s.put(key, body)
+        assert s.get(key) == body  # degraded: pushed again, absorbed
+    routine = heal_bg.HealRoutine(s.layer, s.queue).start()
+    try:
+        assert routine.drain(30)  # nothing waits on a drive that answers
+    finally:
+        routine.stop()
+    b = _heal_counts()
+    assert _moved(a, b, "tasks") == dict.fromkeys(heal_bg.OUTCOMES, 0)
+    assert _moved(a, b, "parked") == {
+        "set_aside": len(batch), "absorbed": len(batch), "requeued": 0}
+    pushed = _moved(a, b, "pushed")
+    assert pushed["partial_write"] == pushed["degraded_read"] == len(batch)
+    assert pushed["bitrot_read"] == 0
+    assert len(s.queue) == s.queue.parked_depth() == len(batch)
+    assert b["parked_depth"] >= len(batch) and b["queue_depth"] >= len(batch)
+
+
+@pytest.mark.parametrize("k,m,lost", GEOMETRIES)
+def test_set_aside_heals_run_once_their_drives_answer(tmp_path, k, m, lost):
+    s = Set(tmp_path, k, m)
+    s.lose(lost, undoably=True)
+    batch = objects("back")
+    for key, body in batch:
+        s.put(key, body)
+        assert s.get(key) == body
+    a = _heal_counts()
+    s.bring_back(lost)
+    # the drives answer: what waited on them is owed a heal before idle
+    assert not s.queue.idle()
+    routine = heal_bg.HealRoutine(s.layer, s.queue).start()
+    try:
+        assert routine.drain(60)
+    finally:
+        routine.stop()
+    b = _heal_counts()
+    assert _moved(a, b, "tasks") == {
+        "healed": len(batch), "unwritable": 0, "clean": 0, "failed": 0}
+    assert _moved(a, b, "parked")["requeued"] == len(batch)
+    assert len(s.queue) == 0
+    returned = [s.paths[d - 1] for d in lost]
+    for key, body in batch:
+        back = reference.shards_on_drives(returned, BUCKET, key)
+        assert len(back) == len(lost) and len(s.shards(key)) == s.n
+        assert _decodes_with(s, key, body, sorted(back))
+
+
+@pytest.mark.parametrize("k,m,lost", GEOMETRIES)
+def test_a_read_that_meets_bitrot_heals_at_once_with_drives_lost(tmp_path, k, m, lost):
+    s = Set(tmp_path, k, m)
+    s.lose(lost)
+    batch = objects("rot-now")
+    for key, body in batch:
+        s.put(key, body)
+    key, body = batch[-1]
+    parts = s.shards(key)
+    victim = min(i for i in parts if i < k)  # a data shard the GET reads
+    with open(parts[victim], "r+b") as f:
+        f.seek(reference.FRAME_DIGEST + 1)
+        b0 = f.read(1)
+        f.seek(reference.FRAME_DIGEST + 1)
+        f.write(bytes([b0[0] ^ 0xFF]))
+    a = _heal_counts()
+    assert s.get(key) == body
+    b = _heal_counts()
+    assert _moved(a, b, "pushed")["bitrot_read"] == 1
+    assert _moved(a, b, "pushed")["degraded_read"] == 0
+    assert s.queue.parked_depth() == len(batch) - 1  # it left the set-aside
+    routine = heal_bg.HealRoutine(s.layer, s.queue).start()
+    try:
+        assert routine.drain(30)
+    finally:
+        routine.stop()
+    c = _heal_counts()
+    assert _moved(b, c, "tasks") == {"healed": 1, "unwritable": 0, "clean": 0, "failed": 0}
+    assert len(s.queue) == s.queue.parked_depth() == len(batch) - 1
+    assert _decodes_with(s, key, body, [victim])
+
+
+@pytest.mark.parametrize("k,m,lost", GEOMETRIES)
+def test_a_put_refused_by_a_drive_that_is_there_heals_at_once(tmp_path, k, m, lost):
+    s = Set(tmp_path, k, m)
+    s.lose(lost)
+    slot = next(i for i in range(s.n) if i + 1 not in lost)
+    good = s.eset.disks[slot]
+    s.eset.disks[slot] = _FailsWrites(good, 0.0)
+    key, body = objects("refused")[0]
+    a = _heal_counts()
+    s.put(key, body)  # short on the lost drives and on the one that refused
+    s.eset.disks[slot] = good
+    b = _heal_counts()
+    assert _moved(a, b, "pushed")["partial_write"] == 1
+    assert _moved(a, b, "parked")["set_aside"] == 0
+    assert len(s.queue) == 1 and s.queue.parked_depth() == 0
+    assert len(s.shards(key)) == s.n - len(lost) - 1
+    routine = heal_bg.HealRoutine(s.layer, s.queue).start()
+    try:
+        assert routine.drain(30)
+    finally:
+        routine.stop()
+    c = _heal_counts()
+    assert _moved(b, c, "tasks") == {"healed": 1, "unwritable": 0, "clean": 0, "failed": 0}
+    assert len(s.shards(key)) == s.n - len(lost)
+    assert s.get(key) == body
